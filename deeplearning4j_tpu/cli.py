@@ -1185,6 +1185,11 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    # subcommands that compile (train/evaluate/profile/tune sweep) share
+    # the one placed compile-cache directory with every other entry point
+    from deeplearning4j_tpu.util import compile_cache
+
+    compile_cache.ensure()
     return args.fn(args)
 
 

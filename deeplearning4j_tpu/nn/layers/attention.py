@@ -35,7 +35,6 @@ from deeplearning4j_tpu.nn import inputs as it
 from deeplearning4j_tpu.nn.layers.base import Layer, apply_dropout, register_layer
 from deeplearning4j_tpu.ops import attention as att
 from deeplearning4j_tpu.ops import linear as ops
-from deeplearning4j_tpu.util import jaxcompat
 
 
 def _ring():
@@ -125,7 +124,7 @@ class PositionEmbedding(Layer):
         axis = _ring().active_sequence_axis()
         if axis is not None:
             off = jax.lax.axis_index(axis) * t
-            t_global = t * jaxcompat.axis_size(axis)
+            t_global = t * jax.lax.axis_size(axis)
         else:
             off = 0
             t_global = t
@@ -216,55 +215,38 @@ class MultiHeadAttention(Layer):
     def regularizable(self, params):
         return {k: v for k, v in params.items() if k.startswith("W")}
 
-    def _use_pallas(self, t: int, d: int, mask, dtype=None) -> bool:
-        """Helper discovery, mirroring the reference's reflective cuDNN
-        helper load (ConvolutionLayer.java:74-84): pallas flash attention
-        when requested or auto-enabled on TPU — but only where it earns
-        its keep. Round 5 re-measured the boundary AFTER the block
-        autotune (pick_flash_blocks — the old 128/128 blocks were the
-        bottleneck, not the kernel): with tuned blocks t=512 bf16 is
-        1.13x of sdpa (was 0.47-0.81x), t=1024 2.30x bf16 / 3.44x f32
-        (was par-within-noise), t=2048 3.3-3.4x (was ~1.1x), so the
-        auto admission drops from t >= 1024 to t >= 512
-        (BENCH_DETAIL['ab'] re-records each round; earlier-session
-        numbers in docs/DEVNOTES.md). Below 512 XLA's materialized-
-        scores path still wins while the scores fit on-chip.
-        Shape preconditions: no key-padding mask, block-aligned t, head
-        dim 64 or lane-aligned, and a one-time compile probe of BOTH
-        directions in the caller's dtype. Explicit
-        attention_impl='pallas' skips the length gate."""
+    def _use_pallas(self, b: int, t: int, d: int, mask) -> bool:
+        """Admission of the Pallas flash kernel: a rule on what the call
+        site can see — the request (`attention_impl`), the backend, the
+        shapes and the ambient mesh — and nothing else. There is no
+        compile probe: a shape this rule admits and Mosaic refuses fails
+        the step's compile with the kernel's name (which carries the
+        shape) in the error, instead of silently becoming an XLA path.
+
+        'auto' admits on TPU only, from t >= 512 (below that XLA's
+        materialized-scores path holds while the scores fit on-chip; the
+        boundary was set by builder A/Bs in July and has no driver
+        number — ROADMAP S3). Explicit attention_impl='pallas' skips the
+        backend and length gates (CPU tests run it interpreted).
+        Shape rule: no key-padding mask, block-aligned t, head dim 64 or
+        lane-aligned. Mesh rule: the kernel runs per batch shard under a
+        data mesh (parallel/mesh.py per_batch_shard): 'auto' declines when
+        the batch does not split evenly or the mesh shards anything else;
+        an explicit request there raises in per_batch_shard."""
         if self.attention_impl not in ("pallas", "auto"):
             return False
-        import jax as _jax
-
         from deeplearning4j_tpu.ops import pallas_kernels as pk
+        from deeplearning4j_tpu.parallel import mesh as mesh_mod
 
-        interpret = _jax.default_backend() != "tpu"
-        if self.attention_impl == "auto" and (not pk.helpers_enabled()
-                                              or interpret):
-            # opt-outs (DL4J_TPU_PALLAS=0) and non-TPU backends must be
-            # decided BEFORE the probe — it compiles a real pallas kernel
+        auto = self.attention_impl == "auto"
+        on_tpu = jax.default_backend() == "tpu"
+        if auto and not (pk.helpers_enabled() and on_tpu and t >= 512):
             return False
-        shape_ok = mask is None and (t <= 128 or t % 128 == 0)
-        if self.attention_impl == "auto" and not interpret and t < 512:
+        if mask is not None or not (t <= 128 or t % 128 == 0):
             return False
-        if not shape_ok:
+        if on_tpu and d % 128 != 0 and d != 64:
             return False
-        if interpret:
-            return True
-        if d % 128 != 0 and d != 64:
-            return False
-        # probe EVERY admitted dim with the caller's dtype/causal AND the
-        # tuned blocks the real call will use (cached) — a backend that
-        # takes the f32 or small-block kernel but rejects bf16 or the
-        # 512-wide blocks must fall back here, not crash the real call.
-        # Resolve the dtype BEFORE picking blocks: pick_flash_blocks is
-        # dtype-sensitive, and probing f32 at bf16's blocks would admit
-        # a block config the real f32 call never compiled.
-        dtype = dtype or jnp.float32
-        bq, bk = pk.pick_flash_blocks(t, d, dtype)
-        return pk.flash_probe(d, bq, dtype=dtype, causal=self.causal,
-                              bk=bk)
+        return not auto or mesh_mod.per_device_batch(b) > 0
 
     def apply(self, params, x, *, state, train, rng, mask=None):
         b, t, f = x.shape
@@ -285,12 +267,19 @@ class MultiHeadAttention(Layer):
         elif self.attention_impl == "blockwise":
             o = att.blockwise(q, k, v, mask=mask, causal=self.causal,
                               block_size=self.block_size)
-        elif self._use_pallas(t, d, mask, q.dtype):
+        elif self._use_pallas(b, t, d, mask):
             from deeplearning4j_tpu.ops import pallas_kernels as pk
+            from deeplearning4j_tpu.parallel import mesh as mesh_mod
 
             bq, bk = pk.pick_flash_blocks(t, d, q.dtype)
-            o = pk.flash_attention(q, k, v, self.causal, None, bq, bk,
-                                   jax.default_backend() != "tpu")
+            interpret = jax.default_backend() != "tpu"
+
+            def flash(q_, k_, v_):
+                return pk.flash_attention(q_, k_, v_, self.causal, None,
+                                          bq, bk, interpret)
+
+            o = mesh_mod.per_batch_shard(flash, (q, k, v),
+                                         (True, True, True))
         else:
             o = att.sdpa(q, k, v, mask=mask, causal=self.causal)
         o = o.transpose(0, 2, 1, 3).reshape(b, t, f)

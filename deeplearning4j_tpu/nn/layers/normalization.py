@@ -15,6 +15,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn import inputs as it
@@ -108,15 +109,21 @@ class BatchNorm(Layer):
             from deeplearning4j_tpu.ops import pallas_kernels as pk
 
             if pk.convbn_mode() == "forced" and pk.helpers_enabled():
-                import jax as _jax
+                from deeplearning4j_tpu.parallel import mesh as mesh_mod
 
-                interp = _jax.default_backend() != "tpu"
-                br = pk.pick_bn_block(x.shape, x.dtype)
-                if br and (interp or pk.bn_probe(x.shape[-1], x.dtype, br)):
+                # per-device rows under a data mesh (parallel/mesh.py)
+                b_dev = mesh_mod.per_device_batch(x.shape[0])
+                br = pk.pick_bn_block((b_dev,) + tuple(x.shape[1:]),
+                                      x.dtype) if b_dev else 0
+                if br:
+                    interp = jax.default_backend() != "tpu"
                     # scale/shift pass through untouched (f32 in normal
                     # runs, f64 under x64 gradient checks); the kernel
                     # casts to x.dtype exactly as the XLA path does
-                    return pk.bn_act(x, scale, shift, act, br, interp)
+                    return mesh_mod.per_batch_shard(
+                        lambda x_, s_, h_: pk.bn_act(x_, s_, h_, act, br,
+                                                     interp),
+                        (x, scale, shift), (True, False, False))
         y = x * scale.astype(x.dtype) + shift.astype(x.dtype)
         return self.act_fn("identity")(y)
 

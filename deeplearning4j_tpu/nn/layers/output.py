@@ -83,15 +83,30 @@ class Output(Dense, BaseOutputLayer):
         n = 1
         for s in x2.shape[:-1]:
             n *= int(s)
-        p = xk.plan(n, Wc.shape[0], Wc.shape[1], xc.dtype)
+        # under a data mesh each device runs the kernel on its own rows
+        # (GSPMD would gather the batch around the custom call), so the
+        # block plan is for the per-device row count; a mesh that shards
+        # anything else keeps the XLA path
+        from deeplearning4j_tpu.parallel import mesh as mesh_mod
+
+        b_dev = mesh_mod.per_device_batch(x2.shape[0])
+        if not b_dev:
+            return None
+        p = xk.plan(n // x2.shape[0] * b_dev, Wc.shape[0], Wc.shape[1],
+                    xc.dtype)
         if p is None:
             return None
         bias = (params["b"] if self.has_bias and "b" in params
                 else jnp.zeros((Wc.shape[1],), jnp.float32))
-        per_row = xk.linear_xent_rows(
-            xc.reshape(n, xc.shape[-1]), Wc, bias,
-            labels.reshape(n, labels.shape[-1]), p,
-            jax.default_backend() != "tpu")
+        interpret = jax.default_backend() != "tpu"
+
+        def rows(x_, w_, b_, t_):
+            return xk.linear_xent_rows(x_, w_, b_, t_, p, interpret)
+
+        per_row = mesh_mod.per_batch_shard(
+            rows, (xc.reshape(n, xc.shape[-1]), Wc, bias,
+                   labels.reshape(n, labels.shape[-1])),
+            (True, False, False, True))
         return per_row.reshape(labels.shape[:-1])
 
     def compute_loss(self, params, x, labels, *, state, mask=None, rng=None):

@@ -114,13 +114,20 @@ def _lstm_scan(params, x, carry, gate_fn, act_fn, peephole: bool,
             and gate_fn is act_mod.get("sigmoid")
             and act_fn is act_mod.get("tanh")):
         from deeplearning4j_tpu.ops import pallas_kernels as pk
+        from deeplearning4j_tpu.parallel import mesh as mesh_mod
 
+        # under a data mesh each device runs the scan on its own rows
+        # (parallel/mesh.py per_batch_shard), so regime and block plans
+        # are judged on the per-device batch; a mesh that shards
+        # anything else keeps the lax.scan path
+        b_dev = mesh_mod.per_device_batch(zx.shape[0])
+        shape_dev = (b_dev,) + tuple(zx.shape[1:])
         mode = pk.lstm_helper_mode()
         forced = pk.helpers_enabled() and mode == "forced"
         auto = (pk.helpers_enabled() and mode != "off"
-                and chunked_lstm_auto_regime(zx.shape[0], zx.shape[1], n,
+                and chunked_lstm_auto_regime(b_dev, zx.shape[1], n,
                                              zx.dtype))
-        if forced or auto:
+        if b_dev and (forced or auto):
             interp = jax.default_backend() != "tpu"
             zk = jnp.flip(zx, axis=1) if reverse else zx
             mk = None
@@ -130,32 +137,34 @@ def _lstm_scan(params, x, carry, gate_fn, act_fn, peephole: bool,
             # f32 while activations are bf16, and the custom-vjp's scan
             # reference needs one consistent carry dtype
             Rk = R.astype(zx.dtype)
+            peep = None
             if peephole:
-                p = jnp.stack([params[prefix + "pi"],
-                               params[prefix + "pf"],
-                               params[prefix + "po"]]).astype(zx.dtype)
+                peep = jnp.stack([params[prefix + "pi"],
+                                  params[prefix + "pf"],
+                                  params[prefix + "po"]]).astype(zx.dtype)
             # the kernels own their memory models: full-t when opted in
             # and it fits, else the chunked plan
-            bb = pk.pick_lstm_block(zk.shape, zk.dtype) if forced else 0
-            plan = pk.pick_lstm_chunk(zk.shape, zk.dtype,
+            bb = pk.pick_lstm_block(shape_dev, zk.dtype) if forced else 0
+            plan = pk.pick_lstm_chunk(shape_dev, zk.dtype,
                                       masked=mk is not None)
-            hs = None
-            if bb:
-                if peephole:
-                    hs, hT, cT = pk.lstm_scan_peephole(
-                        zk, Rk, p, carry[0], carry[1], bb, interp, mk)
-                else:
-                    hs, hT, cT = pk.lstm_scan(zk, Rk, carry[0], carry[1],
-                                              bb, interp, mk)
-            elif plan:
-                cb, tc = plan
-                if peephole:
-                    hs, hT, cT = pk.lstm_scan_chunked_peephole(
-                        zk, Rk, p, carry[0], carry[1], cb, tc, interp, mk)
-                else:
-                    hs, hT, cT = pk.lstm_scan_chunked(
-                        zk, Rk, carry[0], carry[1], cb, tc, interp, mk)
-            if hs is not None:
+            if bb or plan:
+                def scan_kernel(zk_, h0_, c0_, mk_, Rk_, p_):
+                    if bb and peephole:
+                        return pk.lstm_scan_peephole(
+                            zk_, Rk_, p_, h0_, c0_, bb, interp, mk_)
+                    if bb:
+                        return pk.lstm_scan(zk_, Rk_, h0_, c0_, bb, interp,
+                                            mk_)
+                    cb, tc = plan
+                    if peephole:
+                        return pk.lstm_scan_chunked_peephole(
+                            zk_, Rk_, p_, h0_, c0_, cb, tc, interp, mk_)
+                    return pk.lstm_scan_chunked(zk_, Rk_, h0_, c0_, cb, tc,
+                                                interp, mk_)
+
+                hs, hT, cT = mesh_mod.per_batch_shard(
+                    scan_kernel, (zk, carry[0], carry[1], mk, Rk, peep),
+                    (True, True, True, True, False, False))
                 if reverse:
                     hs = jnp.flip(hs, axis=1)
                 return hs, (hT, cT)

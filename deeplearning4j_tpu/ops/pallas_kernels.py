@@ -26,14 +26,17 @@ q-block, dkv kernel per k-block). Numerics match the XLA formulations
 (CuDNNGradientChecks-pattern equivalence tests); an over-VMEM-budget LSTM
 bwd falls back to the XLA-recompute vjp.
 
-Helper discovery (helpers_enabled): on by default on TPU backends, off on
-CPU (where `interpret=True` would be slower than XLA); override with
-DL4J_TPU_PALLAS=1/0. The LSTM kernels are additionally OPT-IN via
-DL4J_TPU_PALLAS_LSTM=1 and flash 'auto' admission requires t >= 1024 —
-both set by round-3 long-window A/Bs in which XLA's builtin paths win the
-short/small shapes (see lstm_helper_enabled and
-MultiHeadAttention._use_pallas). Shapes must satisfy TPU tiling (lane dim
-multiple of 128 where required) or callers fall through to XLA.
+Admission (helpers_enabled + the per-layer shape rules in nn/layers): on
+by default on TPU backends, off on CPU (where `interpret=True` would be
+slower than XLA); override with DL4J_TPU_PALLAS=1/0. The full-resident
+LSTM kernels are additionally OPT-IN via DL4J_TPU_PALLAS_LSTM=1, bn_act
+via DL4J_TPU_PALLAS_CONVBN=1. Admission is a rule on shapes, dtypes, the
+backend and the ambient mesh — never a trial compile: a kernel Mosaic
+refuses fails the enclosing step's compile, and every pallas_call is
+named for its family and shape (`kernel_name`) so the error, the HLO and a
+profiler trace all say which call it was. Under a device mesh the call
+sites run each kernel per batch shard (parallel/mesh.py
+per_batch_shard); GSPMD has no partitioning rule for the custom call.
 """
 from __future__ import annotations
 
@@ -48,9 +51,17 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.util import envflags
 from deeplearning4j_tpu.util.cotangent import zeros_cotangent
-from deeplearning4j_tpu.util.jaxcompat import CompilerParams
 
 NEG_INF = -1e30
+
+
+def kernel_name(family: str, dtype, **dims) -> str:
+    """Kernel name for a pallas_call: family, the dims that fix its block
+    plan, dtype — e.g. dl4j_flash_fwd_bh128_t512_d64_bq512_bk512_bfloat16.
+    It is the custom call's kernel_name in the HLO, the event name in a
+    profiler trace, and what a Mosaic refusal is reported under."""
+    parts = "_".join(f"{k}{v}" for k, v in dims.items())
+    return f"dl4j_{family}_{parts}_{jnp.dtype(dtype).name}"
 
 
 def helpers_enabled() -> bool:
@@ -170,6 +181,7 @@ def _flash_fwd(q, k, v, *, causal: bool, scale: float, bq: int, bk: int,
             pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0)),
         ],
         out_specs=out_spec,
+        name=kernel_name("flash_fwd", q.dtype, bh=b * h, t=t, d=d, bq=bq, bk=bk),
         interpret=interpret,
     )(qf, kf, vf)
     if return_lse:
@@ -298,6 +310,7 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal: bool, scale: float, bq: int,
         grid=(bh, t // bq),
         in_specs=[qblk, seq, seq, qblk, qblk1, qblk1],
         out_specs=qblk,
+        name=kernel_name("flash_bwd_dq", q.dtype, bh=bh, t=t, d=d, bq=bq, bk=bk),
         interpret=interpret,
     )(qf, kf, vf, dof, lsef, delta)
 
@@ -309,6 +322,8 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal: bool, scale: float, bq: int,
         grid=(bh, t // bk),
         in_specs=[seq, kblk, kblk, seq, seq1, seq1],
         out_specs=(kblk, kblk),
+        name=kernel_name("flash_bwd_dkv", q.dtype, bh=bh, t=t, d=d, bq=bq,
+                    bk=bk),
         interpret=interpret,
     )(qf, kf, vf, dof, lsef, delta)
     return (dq.reshape(b, h, t, d), dk.reshape(b, h, t, d),
@@ -455,6 +470,7 @@ def _lstm_fwd(zx, R, h0, c0, *, block_b: int, interpret: bool, p=None,
             pl.BlockSpec((block_b, n), lambda i: (i, 0)),
             pl.BlockSpec((block_b, n), lambda i: (i, 0)),
         ),
+        name=kernel_name("lstm_fwd", zx.dtype, b=b, t=t, n=n, bb=block_b),
         interpret=interpret,
     )(*args)
     if time_major:
@@ -803,6 +819,7 @@ def _lstm_bwd(zx, R, h0, c0, hs, g, *, interpret: bool, p=None,
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         scratch_shapes=scratch,
+        name=kernel_name("lstm_bwd", zx.dtype, b=b, t=t, n=n, bb=bb),
         interpret=interpret,
     )(*args)
     if p is not None:
@@ -1175,8 +1192,10 @@ def _lstm_chunked(zx, R, h0, c0, bb, tck, interpret, p=None, mask=None):
         out_specs=(hs_spec, carry, carry, ck_spec, ck_spec),
         scratch_shapes=[pltpu.VMEM((bb, n), jnp.float32),
                         pltpu.VMEM((bb, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
+        name=kernel_name("lstm_chunk_fwd", zx.dtype, b=b, t=t, n=n, bb=bb,
+                    tc=tck),
         interpret=interpret,
     )(*args)
     if time_major:
@@ -1245,8 +1264,10 @@ def _lstm_chunked_bwd(zx, R, hck, cck, g, bb, tck, interpret, p=None,
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         scratch_shapes=scratch,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
+        name=kernel_name("lstm_chunk_bwd", zx.dtype, b=b, t=t, n=n, bb=bb,
+                    tc=tck),
         interpret=interpret,
     )(*args)
     if p is not None:
@@ -1437,6 +1458,7 @@ def _bn_act_impl(x, scale, shift, act, block_rows, interpret):
         ],
         out_specs=pl.BlockSpec((block_rows, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, c), x.dtype),
+        name=kernel_name("bn_act", x.dtype, rows=rows, c=c, br=block_rows),
         interpret=interpret,
     )(x2, s2, b2)
     return out.reshape(x.shape)
@@ -1466,74 +1488,3 @@ def _bn_act_vjp_bwd(act, block_rows, interpret, res, g):
 
 
 bn_act.defvjp(_bn_act_vjp_fwd, _bn_act_vjp_bwd)
-
-
-_BN_PROBE_CACHE = {}
-
-
-def bn_probe(c: int, dtype=jnp.float32, block_rows: int = 8) -> bool:
-    """flash_probe's contract for the epilogue: one tiny compile on the
-    real backend decides whether this channel width/dtype is admitted
-    (Mosaic pads sub-lane channel widths on most generations; one that
-    refuses sends callers back to XLA instead of crashing the step)."""
-    dtype = jnp.dtype(dtype)
-    key = (c, dtype.name, block_rows)
-    got = _BN_PROBE_CACHE.get(key)
-    if got is not None:
-        return got
-    try:
-        import numpy as _np
-
-        x = jnp.asarray(_np.zeros((block_rows, c), dtype))
-        s = jnp.asarray(_np.ones((c,), _np.float32))
-        bn_act(x, s, s, "relu", block_rows, False)
-        # training admits it too: the recompute backward must trace
-        jax.grad(lambda a: bn_act(a, s, s, "relu", block_rows, False)
-                 .astype(jnp.float32).sum())(x)
-        ok = True
-    except Exception:
-        ok = False
-    _BN_PROBE_CACHE[key] = ok
-    return ok
-
-
-_FLASH_PROBE_CACHE = {}
-
-
-def flash_probe(d: int, bq: int = 128, dtype=jnp.float32,
-                causal: bool = True, bk: int = None) -> bool:
-    """Helper discovery for non-lane-aligned head dims: try ONE tiny
-    flash_attention compile on the real backend and cache the verdict.
-    The reference loads its cuDNN helpers reflectively and falls through
-    on failure (ConvolutionLayer.java:74-84); this is the same contract
-    for Mosaic — a TPU generation that rejects a d-wide lane just sends
-    callers back to the XLA path instead of crashing. The cache is keyed
-    on (d, blocks, dtype, causal) and the probe runs the caller's
-    dtype/causal variant at the caller's ACTUAL block sizes
-    (pick_flash_blocks) — a backend that compiles the small-block kernel
-    but rejects the tuned 512-wide one must fall back, not crash the
-    admitted real call. t = max(bq, bk) keeps the probe the smallest
-    input that exercises those blocks."""
-    dtype = jnp.dtype(dtype)
-    bk = bq if bk is None else bk
-    key = (d, bq, bk, dtype.name, causal)
-    got = _FLASH_PROBE_CACHE.get(key)
-    if got is not None:
-        return got
-    try:
-        import numpy as _np
-
-        t = max(bq, bk)
-        q = jnp.asarray(_np.zeros((1, 1, t, d), dtype))
-        flash_attention(q, q, q, causal, None, bq, bk, False)
-        # training admits the kernel too: the fused backward (dq + dkv
-        # kernels) must also compile, or the train step would crash after
-        # a clean forward probe
-        jax.grad(lambda a: flash_attention(
-            a, a, a, causal, None, bq, bk, False
-        ).astype(jnp.float32).sum())(q)
-        ok = True
-    except Exception:
-        ok = False
-    _FLASH_PROBE_CACHE[key] = ok
-    return ok

@@ -39,9 +39,9 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deeplearning4j_tpu.ops import pallas_kernels as pk
 from deeplearning4j_tpu.util import envflags
 from deeplearning4j_tpu.util.cotangent import zeros_cotangent
-from deeplearning4j_tpu.util.jaxcompat import CompilerParams
 
 # leave room for double-buffered streamed blocks (same budget philosophy
 # as pallas_kernels.pick_lstm_block)
@@ -55,8 +55,6 @@ def xent_helper_enabled() -> bool:
     env = envflags.flag("DL4J_TPU_PALLAS_XENT")
     if env is not None:
         return env
-    from deeplearning4j_tpu.ops import pallas_kernels as pk
-
     return pk.helpers_enabled()
 
 
@@ -190,8 +188,9 @@ def _fwd(x, w, b2, t, bn: int, bv: int, interpret: bool):
         ],
         scratch_shapes=([pltpu.VMEM((bn, 1), f32) for _ in range(6)]
                         + [pltpu.VMEM((bn, 1), jnp.int32)]),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
+        name=pk.kernel_name("xent_fwd", x.dtype, n=n, d=d, v=v, bn=bn, bv=bv),
         interpret=interpret,
     )(x, w, b2, t)
 
@@ -295,8 +294,10 @@ def _bwd(x, w, b2, t_or_idx, lse, ts, g, bn: int, bv: int, interpret: bool,
         ],
         scratch_shapes=[pltpu.VMEM((bn, d), jnp.float32),
                         pltpu.VMEM((1, v), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
+        name=pk.kernel_name("xent_bwd_idx" if use_idx else "xent_bwd_dense",
+                    x.dtype, n=n, d=d, v=v, bn=bn, bv=bv),
         interpret=interpret,
     )(x, w, b2, t_or_idx, lse, ts, g)
     # xᵀ [d, n] · dz [n, v] on the MXU — the one materialized [N, V]

@@ -282,7 +282,9 @@ class ParallelInference:
                 x = np.concatenate([x, np.repeat(x[-1:], pad, axis=0)],
                                    axis=0)
             sh = NamedSharding(self.mesh, P("data", *([None] * (x.ndim - 1))))  # jaxlint: disable=JX018 — input staging (batch split), not a param placement
-            out = np.asarray(self.model.output(jax.device_put(x, sh)))
+            xd = jax.device_put(x, sh)
+            with jax.set_mesh(self.mesh):  # kernels run per batch shard
+                out = np.asarray(self.model.output(xd))
             if pad:
                 out = out[: out.shape[0] - pad]
             off = 0

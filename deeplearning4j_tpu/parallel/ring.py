@@ -33,7 +33,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.ops import attention as att
-from deeplearning4j_tpu.util import jaxcompat
 
 _tls = threading.local()
 
@@ -155,7 +154,7 @@ def ring_attention(
             scale=scale, block_size=block_size,
         )
 
-    return jaxcompat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=qs,
         check_vma=False,
     )(*args)

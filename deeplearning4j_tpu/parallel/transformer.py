@@ -57,7 +57,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from deeplearning4j_tpu.nn import updaters as upd_mod
 from deeplearning4j_tpu.parallel import layout as layout_mod
 from deeplearning4j_tpu.parallel import ring
-from deeplearning4j_tpu.util import jaxcompat
 
 PyTree = Any
 
@@ -427,7 +426,7 @@ class ShardedTransformerLM:
             loss = lax.psum(local_loss, (d, s, self.ax_p))
             return loss, grads
 
-        smapped = jaxcompat.shard_map(
+        smapped = jax.shard_map(
             sharded_grads, mesh=self.mesh,
             in_specs=(specs, x_spec, x_spec, x_spec),
             out_specs=(P(), specs),
@@ -549,7 +548,7 @@ class ShardedTransformerLM:
         if self._fwd_fn is None:
             specs = self.param_specs()
             x_spec = P(self.ax_d, self.ax_s)
-            self._fwd_fn = jax.jit(jaxcompat.shard_map(
+            self._fwd_fn = jax.jit(jax.shard_map(
                 self._forward_local, mesh=self.mesh,
                 in_specs=(specs, x_spec),
                 out_specs=P(self.ax_d, self.ax_s, None),

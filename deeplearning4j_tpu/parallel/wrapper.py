@@ -346,7 +346,7 @@ class ParallelWrapper:
             x_spec = P(d_ax, s_ax, *([None] * (x_ndim - 2)))
             y_spec = P(d_ax, s_ax, *([None] * (y_ndim - 2)))
             m_spec = P(d_ax, s_ax)
-            smapped = jaxcompat.shard_map(
+            smapped = jax.shard_map(
                 local_grads, mesh=mesh,
                 in_specs=(P(), P(), x_spec, y_spec, P(),
                           m_spec if has_fm else P(),
@@ -595,7 +595,7 @@ class ParallelWrapper:
 
             x_spec = P("data", *([None] * (len(x_sh) - 1)))
             y_spec = P("data", *([None] * (len(y_sh) - 1)))
-            smapped = jaxcompat.shard_map(
+            smapped = jax.shard_map(
                 local_grads, mesh=mesh,
                 in_specs=(P(), x_spec, y_spec,
                           P("data") if has_lm else P(), P()),
@@ -682,16 +682,31 @@ class ParallelWrapper:
         # CheckpointManager-resumed rerun must survive (tier-1 proven)
         chaos.fault_point("collective")
         model._rng, sub = jax.random.split(model._rng)
-        (model.params, model.state, model.opt_state,
-         score) = self._step(
-            model.params, model.state, model.opt_state,
-            jnp.asarray(model.iteration), sub, x, y, fm, lm,
-        )
+        it = jnp.asarray(model.iteration)
+        with self._step_scope():
+            (model.params, model.state, model.opt_state,
+             score) = self._step(
+                model.params, model.state, model.opt_state, it, sub,
+                x, y, fm, lm,
+            )
         model.score_ = float(score)
         model.last_batch_size = unpadded
         model.iteration += 1
         for lst in model.listeners:
             lst.iteration_done(model, model.iteration, model.score_)
+
+    def _step_scope(self):
+        """Entered around every jitted standard-step call (per-step and
+        windowed): the mesh is AMBIENT for what the call traces, and Pallas
+        kernel call sites read it to run per batch shard (parallel/mesh.py
+        per_batch_shard) — GSPMD cannot partition their custom calls.
+        The scope holds the jitted call only: eager work under an ambient
+        mesh is placed on that mesh, which would strand host-side state
+        (the rng key) on a mesh the elastic masters later shrink or
+        regrow. The tbptt chunk loop interleaves eager carry handling with
+        its jitted chunk steps and is not covered: a kernel forced on
+        there fails to lower on a TPU mesh, loudly."""
+        return jax.set_mesh(self.mesh)
 
     def _ensure_std_step(self):
         if self._step is None:
@@ -841,6 +856,7 @@ class ParallelWrapper:
             # this hook adds the same env-gated chaos site as
             # _fit_std_batch, once per dispatched window
             on_dispatch=lambda: chaos.fault_point("collective"),
+            dispatch_scope=self._step_scope,
             place_window=place_window, span_category="collective",
             watch_prefix="ParallelWrapper")
         # on a crash the prefetch producer thread we started would

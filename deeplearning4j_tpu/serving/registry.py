@@ -22,7 +22,9 @@ Sources served side by side with no user-code changes:
 
 Warm starts: when a warm-cache dir is configured (``DL4J_TPU_WARM_CACHE``
 or the ``warm_cache_dir`` argument) the registry enables the JAX
-persistent compilation cache there (serving/warmstart.py) and ``warm()``
+persistent compilation cache (serving/warmstart.py; the executables live
+in the one directory util/compile_cache.py owns, the manifests under the
+warm-cache dir) and ``warm()``
 both dispatches every bucket AND records the warm manifest — so the
 NEXT replica's ``warm()`` needs no example at all: it synthesizes the
 batch from the manifest and its "compiles" are disk reads
@@ -50,6 +52,7 @@ from deeplearning4j_tpu.serving import buckets as buckets_mod
 from deeplearning4j_tpu.serving import warmstart
 from deeplearning4j_tpu.serving.breaker import CircuitBreaker
 from deeplearning4j_tpu.serving.runtime import InferenceServer
+from deeplearning4j_tpu.util import compile_cache
 
 ZOO_PREFIX = "zoo:"
 
@@ -163,6 +166,7 @@ class ModelRegistry:
         # ONLY inside this registry's locked methods — callers holding a
         # ModelEntry from entry() must treat it as read-only
         self._entries: Dict[str, ModelEntry] = {}  # guarded-by: self._lock
+        compile_cache.ensure()
         d = warm_cache_dir or warmstart.cache_dir_from_env()
         self.warm_cache_dir = warmstart.enable(d) if d else None
         _REGISTRIES.add(self)

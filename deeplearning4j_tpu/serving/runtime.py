@@ -302,7 +302,11 @@ class InferenceServer:
 
         def dispatch(xp, _model=model, _mesh=mesh):
             sh = NamedSharding(_mesh, P("data", *([None] * (xp.ndim - 1))))
-            return np.asarray(_model.output(jax.device_put(xp, sh)))
+            xd = jax.device_put(xp, sh)
+            # ambient mesh around the jitted forward: kernel call sites
+            # run per batch shard (parallel/mesh.py)
+            with jax.set_mesh(_mesh):
+                return np.asarray(_model.output(xd))
 
         return dispatch, align
 

@@ -5,8 +5,11 @@ dispatching every bucketed shape once — but each fresh replica (restart,
 autoscale-up) still pays the full cold-compile bill before serving its
 first request. This module removes that bill with two pieces:
 
-  compilation cache   ``enable(cache_dir)`` points JAX's persistent
-                      compilation cache at a shared directory and drops
+  compilation cache   ``enable(cache_dir)`` turns JAX's persistent
+                      compilation cache on in the one directory
+                      ``util/compile_cache.py`` owns (placed from
+                      outside by ``JAX_COMPILATION_CACHE_DIR``, else
+                      ``<checkout>/.jax_cache``) and drops
                       ``jax_persistent_cache_min_compile_time_secs`` to
                       0 so EVERY serving executable is persisted (the
                       default 1 s floor would skip exactly the small
@@ -16,7 +19,7 @@ first request. This module removes that bill with two pieces:
                       function of ``(model version, bucket signature)``
                       — the per-model key the fleet needs, for free.
   warm manifests      ``record_warm`` writes one small JSON per
-                      ``(model, version)`` next to the cache entries
+                      ``(model, version)`` under ``cache_dir``
                       recording the request signature and bucket sizes
                       that were warmed. A fresh replica that has never
                       seen a request calls ``warmup_example`` /
@@ -30,8 +33,8 @@ compile watcher (telemetry/introspect.py) counts cache-retrieval events
 separately and ``watcher().cold_compile_count()`` is the number a
 restart test pins to zero (tests/test_serving_fleet.py).
 
-Gate: ``DL4J_TPU_WARM_CACHE`` — a directory path; when set, the
-ModelRegistry enables the cache there at construction. ``enable`` is
+Gate: ``DL4J_TPU_WARM_CACHE`` — the manifest directory; when set, the
+ModelRegistry enables warm starts at construction. ``enable`` is
 also directly callable for embedders. Pure manifest I/O goes through
 ``resilience/checkpoint.py``'s atomic writer (a torn manifest must not
 brick a replica boot).
@@ -60,39 +63,23 @@ def cache_dir_from_env() -> Optional[str]:
 
 
 def enable(cache_dir: str) -> str:
-    """Point the JAX persistent compilation cache at ``cache_dir`` and
-    make it persist EVERY compile (min-compile-time floor to 0 — the
+    """Turn warm starts on: manifests live under ``cache_dir``; the
+    compiled executables live in the process's ONE compile-cache
+    directory (util/compile_cache.py — this function never repoints it),
+    persisted for EVERY compile (min-compile-time floor to 0 — the
     bucketed serving forwards are exactly the fast compiles the default
-    1 s floor would silently skip). Idempotent; returns the directory."""
+    1 s floor would silently skip). Idempotent; returns the manifest
+    directory."""
     import jax
+
+    from deeplearning4j_tpu.util import compile_cache
 
     d = os.path.abspath(cache_dir)
     os.makedirs(d, exist_ok=True)
-    already = jax.config.jax_compilation_cache_dir == d
-    jax.config.update("jax_compilation_cache_dir", d)
+    compile_cache.ensure()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        # older jaxlib combinations lack the entry-size knob; the dir +
-        # time floor alone are sufficient for cache hits
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:  # pragma: no cover - config drift across versions
-        pass  # jaxlint: disable=JX009
-    if not already:
-        _reset_jax_cache_state()
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return d
-
-
-def _reset_jax_cache_state() -> None:
-    """JAX latches its cache-used decision on the FIRST compile of the
-    process (``_cache_checked``/``_cache_initialized`` in
-    jax._src.compilation_cache): a process that compiled anything before
-    the warm cache was enabled would silently never read or write it.
-    Un-latch so the new directory takes effect mid-process."""
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:  # pragma: no cover - private API drift
-        pass  # jaxlint: disable=JX009
 
 
 def _slug(name: str) -> str:

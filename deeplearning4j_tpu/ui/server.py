@@ -6,7 +6,7 @@ page; a /remote POST endpoint accepts reports from other processes
 (RemoteReceiverModule), paired with storage.RemoteUIStatsStorageRouter. The
 Play framework + SBE + Scala templates collapse into a stdlib
 ThreadingHTTPServer with JSON endpoints and one self-contained HTML page —
-no dependencies, works over an SSH tunnel to a TPU VM.
+no dependencies, works over an SSH port-forward to a TPU VM.
 
 Page anatomy: stat tiles (score / iteration / throughput / memory), the
 score-vs-iteration line, and the per-layer log10(update/param) ratio chart

@@ -261,8 +261,8 @@ _declare("DL4J_TPU_SERVING_COOLDOWN", "float", 1.0,
 _declare("DL4J_TPU_SERVING_PROBES", "int", 2,
          "Half-open probe successes required to close the breaker", lo=1)
 _declare("DL4J_TPU_WARM_CACHE", "str", None,
-         "Warm-start cache dir: persistent compilation cache + warmup "
-         "manifests (serving/warmstart.py)")
+         "Warm-start manifest dir; also persists every compile into "
+         "the compile-cache dir (serving/warmstart.py)")
 # --- distributed / resilience ----------------------------------------------
 _declare("DL4J_TPU_CHAOS", "str", None,
          "Fault-injection schedule, comma-separated point@N:M clauses "

@@ -1,10 +1,7 @@
-"""Version-compat bindings for jax API moves.
+"""The jit seam every hot-path entry point binds through.
 
-The framework targets current jax spellings; older releases (0.4.x) ship
-the same functionality under pre-stabilization names. Bind once here so
-call sites stay on the modern API and version drift is one module's
-problem (the jaxlint/analyzer philosophy: one normalized seam instead of
-per-call-site drift — the same shape as util.envflags for env gates).
+``jit`` is ``jax.jit`` plus the compile watcher (telemetry/introspect.py)
+and the donation metadata the analyzer audits.
 """
 from __future__ import annotations
 
@@ -17,9 +14,7 @@ def jit(fn, *, watch_name=None, **jit_kwargs):
     """``jax.jit`` through the compile-watcher seam (telemetry/
     introspect.py). The repo's hot-path jit entry points (train steps,
     output fns, ParallelWrapper's SPMD steps) bind here so the watcher
-    can count compilations, time them, and flag retrace storms — the
-    version-compat module is also the one place every call site already
-    routes through, which is exactly what a watch seam needs.
+    can count compilations, time them, and flag retrace storms.
 
     Gate contract: with ``DL4J_TPU_TELEMETRY`` off the wrapper is the
     raw jitted call behind one enabled-check — no fingerprinting, no
@@ -47,51 +42,3 @@ def jit(fn, *, watch_name=None, **jit_kwargs):
         (donate,) if isinstance(donate, int) else tuple(donate))
     wrapper.__watch_name__ = name
     return wrapper
-
-
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # pre-0.5 jax: experimental namespace + old kwargs
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                  check_vma=None, axis_names=None, **kw):
-        """Adapter to the 0.4.x surface: check_vma was check_rep, and
-        axis_names (the MANUAL axes) was expressed inversely as `auto`
-        (the axes left automatic)."""
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        if axis_names is not None:
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-            if auto:
-                kw["auto"] = auto
-        return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, **kw)
-
-try:
-    axis_size = jax.lax.axis_size
-except AttributeError:  # pre-0.5: jax.core.axis_frame IS the static size
-    from jax import core as _core
-
-    def axis_size(axis_name):
-        """Static (Python int) size of a named mesh axis. 0.4.36+ returns
-        the int directly; earlier 0.4.x returns an AxisEnvFrame carrying
-        it as .size."""
-        frame = _core.axis_frame(axis_name)
-        return getattr(frame, "size", frame)
-
-
-def __getattr__(name):
-    # CompilerParams binds lazily (PEP 562): only the two pallas kernel
-    # modules need it, and shard_map/axis_size consumers must not pay
-    # (or crash on) the jax.experimental.pallas import chain
-    if name == "CompilerParams":
-        from jax.experimental.pallas import tpu as pltpu
-
-        # pltpu.TPUCompilerParams -> CompilerParams rename
-        cp = getattr(pltpu, "CompilerParams", None)
-        if cp is None:
-            cp = pltpu.TPUCompilerParams
-        globals()["CompilerParams"] = cp
-        return cp
-    raise AttributeError(name)

@@ -23,7 +23,6 @@ from deeplearning4j_tpu.nn.layers import (
     TransformerBlock,
 )
 from deeplearning4j_tpu.models import MultiLayerNetwork
-from deeplearning4j_tpu.util import jaxcompat
 from deeplearning4j_tpu.ops import attention as att
 from deeplearning4j_tpu.parallel import ring
 
@@ -218,7 +217,7 @@ class TestSequenceParallel:
                                              rng=None)
             return acts
 
-        sharded = jaxcompat.shard_map(
+        sharded = jax.shard_map(
             fwd, mesh=mesh,
             in_specs=(P(), P(), P(None, "seq", None)),
             out_specs=P(None, "seq", None),
@@ -250,34 +249,31 @@ def test_flash_attention_d64_matches_sdpa(rng):
 
     mha = MultiHeadAttention(n_heads=2, attention_impl="auto")
     with mock.patch("jax.default_backend", return_value="tpu"), \
-            mock.patch.object(pk, "helpers_enabled", return_value=True), \
-            mock.patch.object(pk, "flash_probe", return_value=True):
-        # round-5 policy: auto admits t >= 512 — the block autotune
-        # (pick_flash_blocks) made the kernel win at the bench shape
-        # (1.13x at t=512 with a whole-sequence block); below 512 XLA's
-        # materialized-scores path still wins
-        assert mha._use_pallas(1024, 64, None)       # long-context path
-        assert mha._use_pallas(2048, 128, None)      # lane-aligned
-        assert mha._use_pallas(512, 64, None)        # bench shape: admitted
-        assert not mha._use_pallas(256, 64, None)    # short: sdpa wins
-        assert not mha._use_pallas(1024, 96, None)   # unmeasured dim
-        assert not mha._use_pallas(1000, 64, None)   # non-block t
-        assert not mha._use_pallas(1024, 64, object())  # masked input
+            mock.patch.object(pk, "helpers_enabled", return_value=True):
+        # admission is the shape rule alone (no compile probe): auto
+        # admits t >= 512; below that XLA's materialized-scores path
+        # holds
+        assert mha._use_pallas(4, 1024, 64, None)       # long-context path
+        assert mha._use_pallas(4, 2048, 128, None)      # lane-aligned
+        assert mha._use_pallas(4, 512, 64, None)        # bench shape
+        assert not mha._use_pallas(4, 256, 64, None)    # short: sdpa
+        assert not mha._use_pallas(4, 1024, 96, None)   # untileable dim
+        assert not mha._use_pallas(4, 1000, 64, None)   # non-block t
+        assert not mha._use_pallas(4, 1024, 64, object())  # masked input
         # explicit request skips the length gate
         forced = MultiHeadAttention(n_heads=2, attention_impl="pallas")
-        assert forced._use_pallas(256, 64, None)
-    with mock.patch("jax.default_backend", return_value="tpu"), \
-            mock.patch.object(pk, "helpers_enabled", return_value=True), \
-            mock.patch.object(pk, "flash_probe",
-                              return_value=False) as probe:
-        # a Mosaic generation that rejects these shapes falls through —
-        # EVERY admitted dim consults the probe with the caller's
-        # dtype/causal (keyed cache), so a backend that compiles f32 but
-        # rejects bf16 falls back instead of crashing the real call
-        assert not mha._use_pallas(1024, 64, None)
-        assert not mha._use_pallas(1024, 128, None)
-        assert not mha._use_pallas(1024, 64, None, jnp.bfloat16)
-        # probed at the caller's TUNED blocks (pick_flash_blocks), not a
-        # fixed tiny shape — the verdict must cover the real kernel
-        probe.assert_called_with(64, 256, dtype=jnp.bfloat16,
-                                 causal=mha.causal, bk=512)
+        assert forced._use_pallas(4, 256, 64, None)
+        # under a data mesh the kernel runs per batch shard: the batch
+        # must split evenly; a mesh sharding anything else declines auto
+        # and refuses a forced kernel call
+        from deeplearning4j_tpu.parallel import MeshSpec, build_mesh
+
+        with jax.set_mesh(build_mesh(MeshSpec(data=8))):
+            assert mha._use_pallas(8, 1024, 64, None)
+            assert not mha._use_pallas(6, 1024, 64, None)
+        from deeplearning4j_tpu.parallel import mesh as mesh_mod
+
+        with jax.set_mesh(build_mesh(MeshSpec(data=4, model=2))):
+            assert not mha._use_pallas(8, 1024, 64, None)
+            with pytest.raises(ValueError, match="per 'data' shard"):
+                mesh_mod.per_batch_shard(lambda a: a, (q,), (True,))

@@ -2,8 +2,8 @@
 comparing two bench artifacts. Synthetic fixtures pin the exit-code
 contract — a 10% throughput drop fails, noise passes, lower-is-better
 rows (p99/shed) gate in the opposite direction, rows present in only
-one file never gate — plus the real BENCH_r04 -> BENCH_r05 artifacts
-run clean. Pure-JSON path: importing bench never imports jax."""
+one file never gate. Pure-JSON path: importing bench never imports
+jax."""
 import json
 import os
 import sys
@@ -112,16 +112,6 @@ class TestCheckRegression:
         errs = capsys.readouterr().err
         assert "unreadable" in errs and "no comparable rows" in errs
         assert "share no rows" in errs
-
-    def test_real_artifacts_round4_to_round5_clean(self, capsys):
-        """ISSUE 10 acceptance: the committed r04 -> r05 artifacts show
-        only noise (resnet50 -0.9%), so the gate passes."""
-        old = os.path.join(_ROOT, "BENCH_r04.json")
-        new = os.path.join(_ROOT, "BENCH_r05.json")
-        if not (os.path.exists(old) and os.path.exists(new)):
-            pytest.skip("bench artifacts not present")
-        assert bench.check_regression(old, new) == 0
-        assert "resnet50_images_per_sec_per_chip" in capsys.readouterr().out
 
     def test_importing_bench_does_not_import_jax(self):
         """The regression gate must run before (and without) jax — it is

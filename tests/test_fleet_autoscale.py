@@ -468,7 +468,6 @@ class TestLoadStepArc:
         import jax
         import jax.numpy as jnp
 
-        from deeplearning4j_tpu.serving import warmstart
         from deeplearning4j_tpu.telemetry import introspect
 
         trace_mod.configure(enabled=True)
@@ -545,8 +544,8 @@ class TestLoadStepArc:
             if pool is not None:
                 pool.shutdown()
             reg.shutdown()
-            jax.config.update("jax_compilation_cache_dir", None)
-            warmstart._reset_jax_cache_state()
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs", 1.0)
 
 
 # ===========================================================================

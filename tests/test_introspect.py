@@ -168,6 +168,24 @@ class TestMfu:
         snap = metrics_mod.registry().snapshot()
         assert snap["dl4j_tpu_mfu"] == pytest.approx(rep2["mfu"])
 
+    def test_peaks_are_keyed_by_device_kind(self, monkeypatch):
+        """An accelerator takes the row of its own `device_kind`; one
+        that is not in the table raises instead of borrowing the v5e's
+        peaks."""
+        import types
+
+        import jax
+
+        def chip(kind):
+            return [types.SimpleNamespace(platform="tpu", device_kind=kind)]
+
+        monkeypatch.setattr(jax, "devices", lambda *a: chip("TPU v5 lite"))
+        assert profiler.peak_flops() == 197e12
+        assert profiler.peak_hbm_bytes_per_s() == 819e9
+        monkeypatch.setattr(jax, "devices", lambda *a: chip("TPU v9"))
+        with pytest.raises(KeyError, match="TPU v9"):
+            profiler.peak_flops()
+
     def test_step_mfu_falls_back_to_analyzer(self, rng):
         """A net whose step can't be lowered still gets a labeled
         DLA008-estimate MFU."""

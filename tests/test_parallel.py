@@ -147,6 +147,47 @@ def test_zoo_transformer_lm_dp_tp_matches_single_device(rng):
 
 
 @needs_8
+def test_pallas_kernels_run_per_data_shard(rng, monkeypatch):
+    """GSPMD has no partitioning rule for a pallas custom call: fed
+    batch-sharded operands it would gather the batch onto every device.
+    Under ParallelWrapper's data mesh the flash and xent kernels (forced
+    on, interpreted here) must instead sit inside manual per-shard
+    regions of the step and reproduce the single-device loss."""
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.parallel.wrapper import _put
+    from deeplearning4j_tpu.zoo import TransformerLM
+
+    monkeypatch.setenv("DL4J_TPU_PALLAS", "1")
+    vocab = 2048  # the narrowest head the xent plan admits
+
+    def lm():
+        conf = TransformerLM(num_classes=vocab, max_length=128,
+                             d_model=128, n_heads=2, n_layers=1).conf()
+        for layer in conf.layers:
+            if hasattr(layer, "attention_impl"):
+                layer.attention_impl = "pallas"
+        return MultiLayerNetwork(conf).init()
+
+    ids = rng.integers(0, vocab, (8, 128))
+    ds = DataSet(ids.astype(np.float32),
+                 np.eye(vocab, dtype=np.float32)[np.roll(ids, -1, 1)])
+    a = lm()
+    a.fit(ds)
+    b = lm()
+    pw = ParallelWrapper(b, mesh_spec=MeshSpec(data=8))
+    pw.fit(ListDataSetIterator(ds, batch=8))
+    np.testing.assert_allclose(a.score_, b.score_, rtol=3e-4)
+    with jax.set_mesh(pw.mesh):
+        text = b._train_step.lower(
+            b.params, b.state, b.opt_state, jnp.asarray(0),
+            jax.random.PRNGKey(0), _put(pw.mesh, ds.features),
+            _put(pw.mesh, ds.labels), None, None).as_text()
+    # flash fwd + bwd, xent fwd + bwd
+    assert text.count("sdy.manual_computation") == 4
+
+
+@needs_8
 def test_zoo_transformer_lm_dp_sp_matches_single_device(rng):
     """Same zoo TransformerLM under dp=2 x seq=4: shard_map + ring
     attention over the sequence axis (MultiHeadAttention dispatches under

@@ -439,10 +439,11 @@ class TestWarmStart:
             assert out.shape == (1, 3)
             reg2.shutdown()
         finally:
-            # the persistent cache is process-global config: detach it so
-            # later tests don't write compilation artifacts to tmp_path
-            jax.config.update("jax_compilation_cache_dir", None)
-            warmstart._reset_jax_cache_state()
+            # persist-every-compile is process-global config: restore
+            # the default floor so later tests don't spool every tiny
+            # executable to disk
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs", 1.0)
 
     def test_warm_without_cache_or_manifest_raises(self, tmp_path):
         reg = ModelRegistry()  # no cache dir
@@ -461,8 +462,8 @@ class TestWarmStart:
                 reg2.warm("m")  # cache dir exists, no manifest yet
         finally:
             reg2.shutdown()
-            jax.config.update("jax_compilation_cache_dir", None)
-            warmstart._reset_jax_cache_state()
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs", 1.0)
 
     def test_manifest_roundtrip_and_slug(self, tmp_path):
         d = str(tmp_path / "wc")
