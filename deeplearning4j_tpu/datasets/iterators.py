@@ -205,11 +205,14 @@ class AsyncDataSetIterator(DataSetIterator):
                 trace_mod.tracer().set_thread_name(
                     threading.get_ident(), name)
             try:
-                for d in self.underlying:
-                    if self.place is not None:
-                        # issue the host->device copy HERE, overlapped
-                        # with the consumer's compute on the prior batch
-                        d = self.place(d)
+                # with `place`, the host->device copy is issued HERE,
+                # overlapped with the consumer's compute on the prior batch
+                source = (self.underlying if self.place is None
+                          else map(self.place, self.underlying))
+                # `produce`: this thread's share of the feed, on the
+                # profiler's clock beside the fit thread's `etl`
+                for d in trace_mod.tracer().spanned("produce", source,
+                                                    category="data"):
                     t0 = time.perf_counter()
                     while not stop.is_set():
                         try:

@@ -26,6 +26,8 @@ from deeplearning4j_tpu.datasets.iterators import (
     ListDataSetIterator,
 )
 from deeplearning4j_tpu import dtypes
+from deeplearning4j_tpu.telemetry import trace as trace_mod
+from deeplearning4j_tpu.training import engine as engine_mod
 from deeplearning4j_tpu.util import jaxcompat
 from deeplearning4j_tpu.nn import weightnoise as wn_mod
 from deeplearning4j_tpu.nn import updaters as upd_mod
@@ -352,8 +354,6 @@ class ComputationGraph:
         unchanged, with the same TOTAL-epoch-target resume contract as
         MultiLayerNetwork.fit (docs/RESILIENCE.md)."""
         from deeplearning4j_tpu.telemetry import introspect
-        from deeplearning4j_tpu.training import engine as engine_mod
-
         # the run restores any resume state FIRST, before steps build
         run = engine_mod.TrainingRun(self, "ComputationGraph.fit",
                                      epochs=epochs, **attachments)
@@ -372,8 +372,6 @@ class ComputationGraph:
         (engine.run_partition) so both ride ONE inner loop. Plain
         DataSet batches (the workers' shard shape) are adapted to
         MultiDataSet at the seam."""
-        from deeplearning4j_tpu.training import engine as engine_mod
-
         def to_mds(ds):
             return (ds if isinstance(ds, MultiDataSet)
                     else MultiDataSet.from_dataset(ds))
@@ -544,24 +542,27 @@ class ComputationGraph:
     def _fit_mds(self, mds: MultiDataSet):
         if self._tbptt_mds(mds):
             return self._fit_tbptt(mds)
-        self._rng, sub = jax.random.split(self._rng)
-        inputs = tuple(jnp.asarray(f) for f in mds.features)
-        labels = tuple(jnp.asarray(l) for l in mds.labels)
-        fmasks = (tuple(None if m is None else jnp.asarray(m)
-                        for m in mds.features_masks)
-                  if mds.features_masks is not None else None)
-        lmasks = (tuple(None if m is None else jnp.asarray(m)
-                        for m in mds.labels_masks)
-                  if mds.labels_masks is not None else None)
-        self.params, self.state, self.opt_state, score = self._train_step(
-            self.params, self.state, self.opt_state,
-            jnp.asarray(self.iteration), sub, inputs, labels, fmasks, lmasks,
-        )
-        self.score_ = float(score)
-        self.last_batch_size = int(inputs[0].shape[0])
-        self.iteration += 1
-        for lst in self.listeners:
-            lst.iteration_done(self, self.iteration, self.score_)
+        # the phases of the engine's `step` span (docs/TELEMETRY.md)
+        tr = trace_mod.tracer()
+        with tr.span("put", category="train",
+                     bytes=engine_mod.host_nbytes(mds)):
+            inputs = tuple(jnp.asarray(f) for f in mds.features)
+            labels = tuple(jnp.asarray(l) for l in mds.labels)
+            fmasks = (tuple(None if m is None else jnp.asarray(m)
+                            for m in mds.features_masks)
+                      if mds.features_masks is not None else None)
+            lmasks = (tuple(None if m is None else jnp.asarray(m)
+                            for m in mds.labels_masks)
+                      if mds.labels_masks is not None else None)
+        with tr.span("dispatch", category="train"):
+            self._rng, sub = jax.random.split(self._rng)
+            (self.params, self.state, self.opt_state,
+             score) = self._train_step(
+                self.params, self.state, self.opt_state,
+                jnp.asarray(self.iteration), sub, inputs, labels, fmasks,
+                lmasks,
+            )
+        engine_mod.finish_step(tr, self, score, int(inputs[0].shape[0]))
 
     def _as_mds_iter(self, data, labels):
         if isinstance(data, MultiDataSet):
@@ -573,8 +574,6 @@ class ComputationGraph:
                 wrap = (not isinstance(data, AsyncDataSetIterator)
                         and data.async_supported())
                 if wrap:
-                    from deeplearning4j_tpu.training import engine as engine_mod
-
                     # DL4J_TPU_DEVICE_PREFETCH: producer-side device_put
                     # (None = exact historical behavior)
                     it_ = AsyncDataSetIterator(

@@ -44,6 +44,8 @@ from deeplearning4j_tpu.nn.layers.output import BaseOutputLayer
 from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrent
 from deeplearning4j_tpu.nn.regularization import apply_constraints
 from deeplearning4j_tpu.datasets.dataset import DataSet
+from deeplearning4j_tpu.telemetry import trace as trace_mod
+from deeplearning4j_tpu.training import engine as engine_mod
 from deeplearning4j_tpu.datasets.iterators import (
     AsyncDataSetIterator,
     DataSetIterator,
@@ -370,8 +372,6 @@ class MultiLayerNetwork:
         TOTAL-epoch-target resume contract as before
         (docs/RESILIENCE.md)."""
         from deeplearning4j_tpu.telemetry import introspect
-        from deeplearning4j_tpu.training import engine as engine_mod
-
         # the run restores any resume state FIRST, before steps build
         run = engine_mod.TrainingRun(self, "MultiLayerNetwork.fit",
                                      epochs=epochs, **attachments)
@@ -391,8 +391,6 @@ class MultiLayerNetwork:
         """This model's engine-loop wiring (stage / exec_one / raw step),
         shared by fit() and the distributed workers
         (engine.run_partition) so both ride ONE inner loop."""
-        from deeplearning4j_tpu.training import engine as engine_mod
-
         use_tbptt = self.conf.defaults.backprop_type == "tbptt"
         sgd = self.conf.defaults.optimization_algo in (
             "stochastic_gradient_descent", "sgd")
@@ -436,20 +434,24 @@ class MultiLayerNetwork:
         if self.conf.defaults.optimization_algo not in (
                 "stochastic_gradient_descent", "sgd"):
             return self._fit_batch_solver(ds)
-        self._rng, sub = jax.random.split(self._rng)
-        x = jnp.asarray(ds.features)
-        y = jnp.asarray(ds.labels)
-        fm = None if ds.features_mask is None else jnp.asarray(ds.features_mask)
-        lm = None if ds.labels_mask is None else jnp.asarray(ds.labels_mask)
-        self.params, self.state, self.opt_state, score = self._train_step(
-            self.params, self.state, self.opt_state,
-            jnp.asarray(self.iteration), sub, x, y, fm, lm,
-        )
-        self.score_ = float(score)
-        self.last_batch_size = int(x.shape[0])
-        self.iteration += 1
-        for lst in self.listeners:
-            lst.iteration_done(self, self.iteration, self.score_)
+        # the phases of the engine's `step` span (docs/TELEMETRY.md)
+        tr = trace_mod.tracer()
+        with tr.span("put", category="train",
+                     bytes=engine_mod.host_nbytes(ds)):
+            x = jnp.asarray(ds.features)
+            y = jnp.asarray(ds.labels)
+            fm = (None if ds.features_mask is None
+                  else jnp.asarray(ds.features_mask))
+            lm = (None if ds.labels_mask is None
+                  else jnp.asarray(ds.labels_mask))
+        with tr.span("dispatch", category="train"):
+            self._rng, sub = jax.random.split(self._rng)
+            (self.params, self.state, self.opt_state,
+             score) = self._train_step(
+                self.params, self.state, self.opt_state,
+                jnp.asarray(self.iteration), sub, x, y, fm, lm,
+            )
+        engine_mod.finish_step(tr, self, score, int(x.shape[0]))
 
     def _fit_batch_solver(self, ds: DataSet):
         """Line-search solver path (Solver.java → ConjugateGradient/LBFGS/
@@ -641,8 +643,6 @@ class MultiLayerNetwork:
     def _as_iterator(self, data, labels) -> DataSetIterator:
         if isinstance(data, DataSetIterator):
             if data.async_supported() and not isinstance(data, AsyncDataSetIterator):
-                from deeplearning4j_tpu.training import engine as engine_mod
-
                 # DL4J_TPU_DEVICE_PREFETCH: the producer thread issues
                 # each batch's device_put, double-buffering H2D with
                 # compute (None = exact historical behavior)

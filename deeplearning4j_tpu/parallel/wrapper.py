@@ -673,27 +673,28 @@ class ParallelWrapper:
                     f"sequence length {t} must divide by the seq "
                     f"axis ({n_seq}); bucket or pad the iterator "
                     f"(BucketSequenceIterator) to a multiple")
-        x = _put(mesh, ds.features, seq=self._sp)
-        y = _put(mesh, ds.labels, seq=self._sp)
-        fm = _put(mesh, ds.features_mask, seq=self._sp)
-        lm = _put(mesh, ds.labels_mask, seq=self._sp)
+        # the phases of the engine's `step` span (docs/TELEMETRY.md)
+        tr = trace_mod.tracer()
+        with tr.span("put", category="collective",
+                     bytes=engine_mod.host_nbytes(ds)):
+            x = _put(mesh, ds.features, seq=self._sp)
+            y = _put(mesh, ds.labels, seq=self._sp)
+            fm = _put(mesh, ds.features_mask, seq=self._sp)
+            lm = _put(mesh, ds.labels_mask, seq=self._sp)
         # env-gated chaos site for the multi-device step: a "preempted
         # collective" surfaces here as ChaosError out of fit(), which a
         # CheckpointManager-resumed rerun must survive (tier-1 proven)
         chaos.fault_point("collective")
-        model._rng, sub = jax.random.split(model._rng)
-        it = jnp.asarray(model.iteration)
-        with self._step_scope():
-            (model.params, model.state, model.opt_state,
-             score) = self._step(
-                model.params, model.state, model.opt_state, it, sub,
-                x, y, fm, lm,
-            )
-        model.score_ = float(score)
-        model.last_batch_size = unpadded
-        model.iteration += 1
-        for lst in model.listeners:
-            lst.iteration_done(model, model.iteration, model.score_)
+        with tr.span("dispatch", category="collective"):
+            model._rng, sub = jax.random.split(model._rng)
+            it = jnp.asarray(model.iteration)
+            with self._step_scope():
+                (model.params, model.state, model.opt_state,
+                 score) = self._step(
+                    model.params, model.state, model.opt_state, it, sub,
+                    x, y, fm, lm,
+                )
+        engine_mod.finish_step(tr, model, score, unpadded)
 
     def _step_scope(self):
         """Entered around every jitted standard-step call (per-step and
@@ -781,9 +782,6 @@ class ParallelWrapper:
                 iterator, self.prefetch_buffer,
                 place=engine_mod.device_prefetch_place())
         n_data = dict(mesh.shape)["data"]
-        from deeplearning4j_tpu.telemetry import introspect
-
-        tr = trace_mod.tracer()
 
         def prep(ds):
             b = ds.features.shape[0]
@@ -828,30 +826,9 @@ class ParallelWrapper:
 
             return jax.tree_util.tree_map(put_w, window)
 
-        def after_dispatch(n, ds, elapsed):
-            # one lane per mesh device (thread_name metadata) instead of
-            # every device collapsing into the caller's thread lane.
-            # One SPMD program = one host-observed step time, so
-            # per-device skew is NOT measurable here — these lanes are
-            # trace visualization; straggler ratios come from lanes with
-            # independently measured durations (per-worker EventStats in
-            # the masters; health.observe_worker_skew is public for
-            # runtimes that have real per-device timings).
-            if not tr.enabled:
-                return None
-            stats = introspect.hbm_stats()
-            # per-STEP duration, not per-window: a K-step dispatch
-            # would otherwise render K-fold-inflated lane spans next
-            # to the engine's per-step main-lane spans
-            introspect.emit_device_step_lanes(
-                tr, mesh, elapsed / max(1, n), stats)
-            # returning the stats dict shares this single memory-stats
-            # query with the engine's watermark tracker
-            return stats
-
         loop = engine_mod.WindowedFitLoop(
             model, raw_step=self._raw_window_step(),
-            stage=stage, exec_one=exec_one, after_dispatch=after_dispatch,
+            stage=stage, exec_one=exec_one,
             # the engine beats the watchdog before the windowed dispatch;
             # this hook adds the same env-gated chaos site as
             # _fit_std_batch, once per dispatched window

@@ -273,6 +273,9 @@ class InferenceServer:
         self._depths: "deque[int]" = deque(maxlen=512)  # guarded-by: self._cond
         self.warmed_rows: set = set()
         self.dispatched_rows: set = set()
+        # calls of the dispatch callable, failed ones included
+        # (`snapshot()["dispatched_batches"]`)
+        self._dispatched_batches = 0  # guarded-by: self._cond
         # raw reservoir behind dl4j_tpu_request_rows: the last 512
         # submitted row counts, the tuner's re-cut planning input
         self._row_sizes: "deque[int]" = deque(maxlen=512)
@@ -556,6 +559,7 @@ class InferenceServer:
             depths = sorted(self._depths)
             stopping = self._stopping
             ema = self._ema_latency_s
+            batches = self._dispatched_batches
             by_tenant = (self._q.queued_by_tenant()
                          if self.tenancy is not None else None)
 
@@ -574,6 +578,7 @@ class InferenceServer:
             "latency_p50_s": (round(pct(lat, 0.5), 6) if lat else None),
             "latency_p99_s": (round(pct(lat, 0.99), 6) if lat else None),
             "ema_latency_s": (round(ema, 6) if ema is not None else None),
+            "dispatched_batches": batches,
             "breaker": self.breaker.snapshot(),
             "stopping": stopping,
         }
@@ -763,6 +768,8 @@ class InferenceServer:
                                          rows=total, bucket=target) as sp:
                 if member_traces:
                     sp.set(member_traces=member_traces)
+                with self._cond:
+                    self._dispatched_batches += 1
                 out = np.asarray(self._dispatch(xp))
             self.dispatched_rows.add((sig, target))
             if chaos.silent_fault("serving_nan"):
@@ -851,7 +858,10 @@ class InferenceServer:
                                f"serving-dispatch-{self.name}")
         try:
             while True:
-                batch = self._next_batch()
+                # waiting for the first request and gathering the rest
+                with trace_mod.tracer().span("serving.coalesce",
+                                             category="serving"):
+                    batch = self._next_batch()
                 if batch is None:
                     break
                 inflight = batch

@@ -16,12 +16,15 @@ that layer for the TPU build:
            with label support, rendered in Prometheus text exposition
            (scrape ``/metrics`` on ui/server.py).
 
-Everything spans-related is gated by ``DL4J_TPU_TELEMETRY`` (through
-util/envflags.py, jaxlint JX001): when the gate is off, ``tracer()``
-hands back a disabled Tracer whose ``span()`` returns a shared no-op
-singleton — no span records are allocated, so the instrumented hot loops
-(MultiLayerNetwork.fit / ComputationGraph.fit / ParallelWrapper.fit) pay
-one attribute check per phase. Metrics at resilience sites (checkpoint
+The span ring is gated by ``DL4J_TPU_TELEMETRY`` (through
+util/envflags.py, jaxlint JX001): when the gate is off no span record is
+allocated. Two sinks of the same ``tracer().span(...)`` seam are always
+on and cost the instrumented hot loops (MultiLayerNetwork.fit /
+ComputationGraph.fit / ParallelWrapper.fit) a few microseconds a step:
+a ``jax.profiler`` annotation (``dl4j.<name>``, a no-op outside a
+profiler session) and the phase account behind ``fit_log()`` — per fit,
+the calls, seconds and bytes of ``etl`` / ``put`` / ``dispatch`` /
+``score_wait`` / ``listeners``. Metrics at resilience sites (checkpoint
 writes, retries, sentry trips, chaos injections) are always live: they
 fire on cold failure/IO paths where a dict update is free, and a crash
 post-mortem must not depend on a gate having been set beforehand.
@@ -99,6 +102,7 @@ from deeplearning4j_tpu.telemetry.trace import (  # noqa: F401
     TELEMETRY_GATE,
     Tracer,
     configure,
+    fit_log,
     traced,
     tracer,
 )

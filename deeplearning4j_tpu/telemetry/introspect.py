@@ -65,10 +65,9 @@ RETRACE_GATE = "DL4J_TPU_RETRACE_THRESHOLD"
 LAYER_GATE = "DL4J_TPU_PROFILE_LAYERS"
 CENSUS_GATE = "DL4J_TPU_COLLECTIVE_CENSUS"
 
-# dedicated trace lanes (below the merge lanes at 999+; real thread ids
-# are process addresses far above either block)
+# dedicated trace lane (below the merge lanes at 999+; real thread ids
+# are process addresses far above that block)
 _LAYER_TID = 998
-_DEVICE_TID_BASE = 2000
 
 _compiles_total = metrics_mod.counter(
     "dl4j_tpu_compiles_total",
@@ -382,10 +381,12 @@ class CompileWatcher:
         }
 
     def compile_count(self) -> int:
-        """Best available compilation count: the process-wide monitoring
-        counter when it saw anything, else the seam count."""
-        backend = int(_backend_compiles.value)
-        return backend if backend else self.snapshot()["seam_compiles"]
+        """XLA backend compilations of this process (jax.monitoring; gate
+        on or off). Only where the listener could not be registered, the
+        jit seam's count of trace-cache misses, which needs the gate."""
+        if _monitoring_installed:  # noqa: DLC002 — written once, under _watcher_lock, before any watcher exists
+            return int(_backend_compiles.value)
+        return self.snapshot()["seam_compiles"]
 
     def cold_compile_count(self) -> int:
         """Backend compiles that actually RAN XLA. jax fires a
@@ -420,9 +421,9 @@ def watcher() -> CompileWatcher:
 
 def _install_monitoring() -> None:
     """Register the jax.monitoring compile-duration listener once per
-    process. Listeners cannot be individually removed, so the callback
-    itself re-checks the gate (compiles are cold-path: the check is
-    free where it matters)."""
+    process. It counts with the telemetry gate on or off: a compilation
+    is a cold path, and `compile_count()` is what `telemetry.fit_log()`
+    reports for every fit."""
     global _monitoring_installed
     if _monitoring_installed:  # noqa: DLC002 — only reachable from watcher(), which already holds _watcher_lock around the call
         return
@@ -433,8 +434,6 @@ def _install_monitoring() -> None:
 
     def _on_duration(name: str, seconds: float, **kw) -> None:
         try:
-            if _watcher is None or not _watcher.enabled:
-                return
             if name.endswith("backend_compile_duration"):
                 _backend_compiles.inc()
                 _compile_seconds.inc(float(seconds))
@@ -505,7 +504,7 @@ class _NullFitIntrospection:
 
     __slots__ = ()
 
-    def after_step(self, stats=None):
+    def after_step(self):
         pass
 
     def end(self, model=None):
@@ -526,9 +525,8 @@ class FitIntrospection:
         self.peak_bytes = 0
         self._sample()
 
-    def _sample(self, stats=None):
-        if stats is None:
-            stats = hbm_stats()
+    def _sample(self):
+        stats = hbm_stats()
         sample_hbm(stats)
         # prefer the backend's own high-water mark: bytes_in_use at a
         # post-step boundary misses the intra-step activation peak that
@@ -540,8 +538,8 @@ class FitIntrospection:
             if used > self.peak_bytes:
                 self.peak_bytes = used
 
-    def after_step(self, stats=None):
-        self._sample(stats)
+    def after_step(self):
+        self._sample()
 
     def end(self, model=None):
         self._sample()
@@ -736,33 +734,6 @@ def top_layers(k: int = 5) -> List[Dict[str, Any]]:
             for n, t in totals.items()]
     rows.sort(key=lambda r: -r["total_ms"])
     return rows[:k]
-
-
-# ---------------------------------------------------------------------------
-# device lanes (ParallelWrapper)
-# ---------------------------------------------------------------------------
-
-
-def emit_device_step_lanes(tr, mesh, dur_s: float,
-                           stats: Optional[Dict] = None) -> None:
-    """Render the just-finished SPMD step on one lane per mesh device
-    (Chrome thread_name metadata), with live HBM bytes attached where
-    the backend reports them. The step is one program over all devices,
-    so each lane shows the same wall window — the point is that device
-    lanes exist at all (memory attrs and future per-device events land
-    somewhere visible instead of collapsing into the caller's thread).
-    Pass a precomputed ``hbm_stats()`` result to share one device query
-    with the watermark tracker."""
-    used = sample_hbm(stats)
-    for i, d in enumerate(mesh.devices.flat):
-        tid = _DEVICE_TID_BASE + i
-        label = f"{d.platform}:{d.id}"
-        tr.set_thread_name(tid, f"device {label}")
-        attrs = {"device": label}
-        if label in used:
-            attrs["hbm_bytes"] = used[label]
-        tr.add_span("device.step", dur_s * 1e3, category="collective",
-                    thread_id=tid, **attrs)
 
 
 def reset() -> None:

@@ -13,9 +13,24 @@ microsecond ts/dur), which loads directly in Perfetto or chrome://tracing.
 objects or their ``to_json()`` dicts) so Spark-style orchestration-phase
 timelines land in the same trace, one lane per worker.
 
-Gate: ``DL4J_TPU_TELEMETRY`` (util/envflags.py). Disabled tracers return a
-shared no-op span singleton from ``span()`` — zero span records allocated,
-the contract the disabled-mode tier-1 test asserts.
+One seam, three sinks. ``tracer().span(name, category, **attrs)`` (and
+``step_span`` for the optimizer step) is the only call a site makes; on
+enter/exit the process tracer feeds
+
+  the profiler  a ``jax.profiler.TraceAnnotation("dl4j." + name)`` entered
+                around the work (``StepTraceAnnotation`` for the step): a
+                no-op unless a profiler session records host events, and
+                then on the device trace's own clock;
+  the ring      a ``SpanRecord``, only while ``DL4J_TPU_TELEMETRY``
+                (util/envflags.py) is on; names stay unprefixed;
+  the account   always on: per span name calls, total seconds, max seconds
+                and the summed ``bytes=`` attribute (``PhaseAccount``) —
+                fixed memory, no ``SpanRecord``. ``fit_log()`` holds what
+                each of the last fits added to it.
+
+A ``Tracer`` built directly is a ring alone: disabled, its ``span()``
+returns a shared no-op singleton — zero span records allocated, the
+contract the disabled-mode tier-1 test asserts.
 """
 from __future__ import annotations
 
@@ -123,47 +138,159 @@ class _NullSpan:
     def set(self, **attrs):
         return self
 
+    def discard(self):
+        return self
+
 
 NULL_SPAN = _NullSpan()
+_EXHAUSTED = object()
+
+
+class PhaseAccount:
+    """Always-on totals per span name: ``[calls, total_s, max_s, bytes]``
+    since the process started, plus the longest single span since the last
+    ``mark()`` (a fit marks at its start, so its entry in ``fit_log()``
+    carries its own maximum). One small list per distinct name — fixed
+    memory however long the process runs. The lock is held for the four
+    additions only: spans of one name close on many threads at once in
+    serving."""
+
+    __slots__ = ("_lock", "_by")
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._by: Dict[str, List[float]] = {}  # guarded-by: self._lock
+
+    def add(self, name: str, seconds: float, nbytes: float = 0) -> None:
+        with self._lock:
+            e = self._by.get(name)
+            if e is None:
+                e = self._by[name] = [0, 0.0, 0.0, 0, 0.0]
+            e[0] += 1
+            e[1] += seconds
+            if seconds > e[2]:
+                e[2] = seconds
+            e[3] += nbytes
+            if seconds > e[4]:
+                e[4] = seconds
+
+    def mark(self) -> Dict[str, tuple]:
+        """Snapshot for ``since``; restarts the maximum-since-mark."""
+        with self._lock:
+            for e in self._by.values():
+                e[4] = 0.0
+            return {k: tuple(e) for k, e in self._by.items()}
+
+    def since(self, mark: Dict[str, tuple]) -> Dict[str, Dict[str, float]]:
+        """What was added after ``mark``: {name: {calls, total_s, max_s,
+        bytes}}, names with no new call left out."""
+        out = {}
+        with self._lock:
+            now = {k: tuple(e) for k, e in self._by.items()}
+        for name, e in sorted(now.items()):
+            b = mark.get(name, (0, 0.0, 0.0, 0, 0.0))
+            if e[0] > b[0]:
+                out[name] = {"calls": e[0] - b[0], "total_s": e[1] - b[1],
+                             "max_s": e[4], "bytes": e[3] - b[3]}
+        return out
+
+    def snapshot(self) -> Dict[str, Dict[str, float]]:
+        """{name: {calls, total_s, max_s, bytes}} since process start."""
+        with self._lock:
+            return {k: {"calls": e[0], "total_s": e[1], "max_s": e[2],
+                        "bytes": e[3]}
+                    for k, e in sorted(self._by.items())}
+
+
+def _attr_bytes(attrs: Optional[Dict[str, Any]]):
+    """The numeric `bytes=` attribute of a span, else 0."""
+    b = attrs.get("bytes") if attrs else None
+    return b if isinstance(b, (int, float)) else 0
+
+
+_annotations = None
+
+
+def _profiler_annotations():
+    """(TraceAnnotation, StepTraceAnnotation), imported at the first span:
+    this module is imported by tools that never touch jax."""
+    global _annotations
+    if _annotations is None:
+        from jax import profiler
+
+        _annotations = (profiler.TraceAnnotation,
+                        profiler.StepTraceAnnotation)
+    return _annotations
 
 
 class _Span:
+    """One open span of a tracer with any sink on. The ring flag is read
+    when the span is made, so a gate flipped mid-span cannot half-record
+    it."""
+
     __slots__ = ("_tracer", "name", "category", "attrs", "_t0", "_ctx",
-                 "_token")
+                 "_token", "_ring", "_annotation", "_discarded")
 
     def __init__(self, tracer: "Tracer", name: str, category: str,
-                 attrs: Optional[Dict[str, Any]]):
+                 attrs: Optional[Dict[str, Any]],
+                 step_num: Optional[int] = None):
         self._tracer = tracer
         self.name = name
         self.category = category
         self.attrs = attrs
+        self._ring = tracer.enabled
+        self._discarded = False
+        self._ctx = None
+        self._token = None
+        self._annotation = None
+        if tracer.account is not None:
+            plain, step = _profiler_annotations()
+            self._annotation = (
+                plain("dl4j." + name) if step_num is None
+                else step("dl4j." + name, step_num=step_num))
 
     def set(self, **attrs):
-        """Attach attributes mid-span (rendered as Chrome `args`)."""
+        """Attach attributes mid-span (rendered as Chrome `args`; a
+        numeric `bytes` is summed by the phase account)."""
         if self.attrs is None:
             self.attrs = {}
         self.attrs.update(attrs)
         return self
 
+    def discard(self):
+        """Feed neither the ring nor the account at exit (the wait that
+        only learned the iterator had ended)."""
+        self._discarded = True
+        return self
+
     def __enter__(self):
-        # inherit the active trace context: this span becomes a child of
-        # the current span AND the parent of anything nested inside it
-        cur = context_mod.current()
-        if cur is not None:
-            self._ctx = cur.child()
-            self._token = context_mod.attach(self._ctx)
-        else:
-            self._ctx = None
-            self._token = None
+        if self._ring:
+            # inherit the active trace context: this span becomes a child
+            # of the current span AND the parent of anything nested in it
+            cur = context_mod.current()
+            if cur is not None:
+                self._ctx = cur.child()
+                self._token = context_mod.attach(self._ctx)
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         if self._token is not None:
             context_mod.detach(self._token)
-        self._tracer._record(self.name, self.category, self._t0,
-                             t1 - self._t0, self.attrs, ctx=self._ctx)
+        if self._discarded:
+            return False
+        tracer = self._tracer
+        if tracer.account is not None:
+            tracer.account.add(self.name, t1 - self._t0,
+                               _attr_bytes(self.attrs))
+        if self._ring:
+            tracer._record(self.name, self.category, self._t0,
+                           t1 - self._t0, self.attrs, ctx=self._ctx)
         return False
 
 
@@ -181,7 +308,11 @@ class Tracer:
     """
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY,
-                 enabled: bool = False):
+                 enabled: bool = False,
+                 account: Optional[PhaseAccount] = None):
+        # with an account (the process tracer's) spans also feed it and
+        # the profiler (module docstring); without, a ring alone
+        self.account = account
         self._lock = threading.Lock()
         self._buf: deque = deque(maxlen=max(1, int(capacity)))  # guarded-by: self._lock
         self._total = 0  # guarded-by: self._lock
@@ -211,10 +342,35 @@ class Tracer:
         return self._wall0 + (perf_t - self._perf0)
 
     def span(self, name: str, category: str = "", **attrs):
-        """Context-manager span; the no-op singleton when disabled."""
-        if not self.enabled:
+        """Context-manager span into every sink that is on; the no-op
+        singleton when none is."""
+        return self._open(name, category, attrs, None)
+
+    def step_span(self, name: str, step_num: int, category: str = "",
+                  **attrs):
+        """`span` for one optimizer step: the profiler gets a
+        ``StepTraceAnnotation`` carrying `step_num`, so its tools group
+        the device work by step."""
+        return self._open(name, category, attrs, int(step_num))
+
+    def _open(self, name, category, attrs, step_num):
+        if not self.enabled and self.account is None:
             return NULL_SPAN
-        return _Span(self, name, category, attrs or None)
+        return _Span(self, name, category, attrs or None, step_num)
+
+    def spanned(self, name: str, iterable, category: str = ""):
+        """Iterate `iterable` with every `next()` inside a span of its
+        own, open WHILE the iterator works (the fit loop's `etl`, the
+        prefetch thread's `produce`). The call that only finds the
+        iterator exhausted is left out of the ring and the account."""
+        source = iter(iterable)
+        while True:
+            with self.span(name, category) as sp:
+                item = next(source, _EXHAUSTED)
+                if item is _EXHAUSTED:
+                    sp.discard()
+                    return
+            yield item
 
     def _record(self, name: str, category: str, perf_start: float,
                 duration_s: float, attrs: Optional[Dict[str, Any]],
@@ -238,7 +394,11 @@ class Tracer:
         time themselves). `start` is anchored-wall seconds; default = the
         span ended now and started `duration_ms` ago. The active
         TraceContext's ids are stamped on (the span reads as a child of
-        the current span)."""
+        the current span). Feeds the phase account and, while the gate is
+        on, the ring; an interval that is already over cannot reach the
+        profiler."""
+        if self.account is not None:
+            self.account.add(name, duration_ms / 1e3, _attr_bytes(attrs))
         if not self.enabled:
             return
         if start is None:
@@ -463,7 +623,8 @@ def tracer() -> Tracer:
             if t is None:
                 t = _global = Tracer(
                     capacity=envflags.int_value(BUFFER_GATE,
-                                                DEFAULT_CAPACITY))
+                                                DEFAULT_CAPACITY),
+                    account=PhaseAccount())
     t.enabled = (envflags.enabled(TELEMETRY_GATE, False)
                  if _forced is None else _forced)
     return t
@@ -484,11 +645,41 @@ def configure(enabled=_KEEP, capacity: Optional[int] = None) -> Tracer:
     with _lock:
         if capacity is not None:
             old = _global.records() if _global is not None else []
-            _global = Tracer(capacity=capacity)
+            _global = Tracer(
+                capacity=capacity,
+                account=(_global.account if _global is not None
+                         else PhaseAccount()))
             for r in old[-capacity:]:
                 _global._buf.append(r)
                 _global._total += 1
     return tracer()
+
+
+# ---------------------------------------------------------------------------
+# the per-fit phase log
+# ---------------------------------------------------------------------------
+
+FIT_LOG_LENGTH = 64
+_fits: deque = deque(maxlen=FIT_LOG_LENGTH)  # guarded-by: _lock
+
+
+def record_fit(entry: Dict[str, Any]) -> None:
+    """Append one finished fit (training/engine.py `TrainingRun.execute`)."""
+    with _lock:
+        _fits.append(entry)
+
+
+def fit_log() -> List[Dict[str, Any]]:
+    """The last fits of this process, oldest first, gate on or off:
+    ``{path, steps, wall_s, compiles, phases: {name: {calls, total_s,
+    max_s, bytes}}}`` — which entry point ran, how many optimizer steps,
+    the wall seconds of the fit, the XLA compilations inside it, and what
+    each span name added to the phase account meanwhile (`max_s` the
+    longest single span of the fit). The account is the process's: spans
+    closed by other threads during the fit (a server, a second fit) are
+    counted in. docs/TELEMETRY.md "Reading a fit's phases"."""
+    with _lock:
+        return list(_fits)
 
 
 def traced(name: Optional[str] = None, category: str = ""):

@@ -2,9 +2,10 @@
 
 The per-step host round-trip is the fit loops' hidden tax: every
 minibatch pays one jit dispatch, one `float(score)` device sync, and one
-round of listener/heartbeat bookkeeping. Whether that tax is a visible
-share of a step on the chip is not measured (ROADMAP S2); K defaults
-to 1.
+round of listener/heartbeat bookkeeping. Each is a span of the step
+(`put`, `dispatch`, `score_wait`, `listeners`; docs/TELEMETRY.md), so
+`telemetry.fit_log()` says after any fit what share of a step it was;
+PERF.md section 5 has it for the benchmark's cell. K defaults to 1.
 
 This module rolls K optimizer steps into ONE jitted `lax.scan` with a
 donated `(params, state, opt_state, rng)` carry and a pre-staged
@@ -51,6 +52,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from deeplearning4j_tpu.telemetry import trace as trace_mod
 from deeplearning4j_tpu.util import compile_cache
 from deeplearning4j_tpu.util import envflags
 from deeplearning4j_tpu.util import jaxcompat
@@ -126,6 +128,38 @@ def place_batch(ds, put: Callable):
     return jax.tree_util.tree_map(put, ds)
 
 
+def host_nbytes(batch) -> int:
+    """Bytes of the host arrays of a DataSet/MultiDataSet (masks included)
+    or of any pytree of arrays: what a `put` span hands to the runtime. An
+    array that is already on a device counts nothing."""
+    import jax
+
+    from deeplearning4j_tpu.datasets.dataset import DataSet, MultiDataSet
+
+    if isinstance(batch, DataSet):
+        batch = (batch.features, batch.labels, batch.features_mask,
+                 batch.labels_mask)
+    elif isinstance(batch, MultiDataSet):
+        batch = (batch.features, batch.labels, batch.features_masks,
+                 batch.labels_masks)
+    return sum(int(getattr(a, "nbytes", 0))
+               for a in jax.tree_util.tree_leaves(batch)
+               if not isinstance(a, jax.Array))
+
+
+def finish_step(tr, model, score, batch_size: int) -> None:
+    """The tail every per-step path shares, as two phases of the `step`
+    span: `score_wait` (the host blocks until the device has the loss)
+    and `listeners` (the `iteration_done` loop)."""
+    with tr.span("score_wait", category="train"):
+        model.score_ = float(score)
+    model.last_batch_size = batch_size
+    model.iteration += 1
+    with tr.span("listeners", category="train"):
+        for lst in model.listeners:
+            lst.iteration_done(model, model.iteration, model.score_)
+
+
 def build_window_scan(raw_step: Callable, n: int, *, watch_name: str,
                       donate_window: bool = False):
     """ONE jitted program running `n` train steps as a lax.scan.
@@ -190,10 +224,8 @@ class WindowedFitLoop:
       after_dispatch(n, ds, elapsed_s)
                              optional PATH EXTRA fired once per dispatch
                              (per step at K=1), `ds` the last batch
-                             staged — per-device trace lanes, sampled
-                             layer spans. May return an hbm-stats dict
-                             to share its memory query with the
-                             engine-owned watermark tracker.
+                             staged — sampled layer spans, a worker's
+                             heartbeat.
       on_dispatch()          optional hook fired immediately before a
                              windowed scan (ParallelWrapper's chaos
                              `collective` fault point).
@@ -209,7 +241,9 @@ class WindowedFitLoop:
                              window axis unsharded, batch axis on the
                              mesh).
 
-    The loop owns etl timing/spans, window accumulation keyed on the
+    The loop owns the `etl` and `step` spans (and, windowed, the
+    window's `put`/`dispatch`/`score_wait`/`listeners`; per step they are
+    the path's own, in `exec_one`), window accumulation keyed on the
     batch signature (shape/dtype/mask-structure churn flushes early —
     bounded compiles, the BucketSequenceIterator contract), the scanned
     dispatch, and the per-step score replay. The per-dispatch
@@ -307,7 +341,6 @@ class WindowedFitLoop:
         trace_id — unless the caller (a distributed master) already
         attached one, in which case the steps join that trace."""
         from deeplearning4j_tpu.telemetry import context as context_mod
-        from deeplearning4j_tpu.telemetry import trace as trace_mod
 
         tr = trace_mod.tracer()
         token = None
@@ -316,11 +349,11 @@ class WindowedFitLoop:
         try:
             t0 = time.perf_counter()
             try:
-                for ds in batches:
-                    etl_ms = (time.perf_counter() - t0) * 1e3
-                    self.model.last_etl_time_ms = etl_ms
-                    if tr.enabled:
-                        tr.add_span("etl", etl_ms, category="data")
+                # the `etl` span is open WHILE the iterator works, so the
+                # profiler sees the wait where it happens
+                for ds in tr.spanned("etl", batches, category="data"):
+                    self.model.last_etl_time_ms = (
+                        time.perf_counter() - t0) * 1e3
                     self._consume(ds, tr)
                     t0 = time.perf_counter()
             except BaseException:
@@ -341,7 +374,11 @@ class WindowedFitLoop:
         if not self.windowed:
             self._exec_fallback(ds, tr)
             return
-        staged = self.stage(ds)
+        with tr.span("put", category=self.span_category,
+                     bytes=host_nbytes(ds)) as sp:
+            staged = self.stage(ds)
+            if staged is None:
+                sp.discard()  # exec_one makes, and spans, its own put
         if staged is None:
             # incompatible batch kind (tbptt chunk / solver / sp / pp):
             # apply the pending window first so step ORDER is preserved
@@ -361,7 +398,8 @@ class WindowedFitLoop:
 
     def _exec_fallback(self, ds, tr) -> None:
         t_step = time.perf_counter()
-        with tr.span("step", category=self.span_category):
+        with tr.step_span("step", self.model.iteration,
+                          category=self.span_category):
             self.exec_one(ds)
         if tr.enabled:
             _step_hist().observe(time.perf_counter() - t_step)
@@ -369,15 +407,11 @@ class WindowedFitLoop:
 
     def _post_dispatch(self, n, ds, elapsed) -> None:
         """Once per dispatch (per step at K=1): the path extra first
-        (trace lanes / layer spans), then the engine-owned watermark
-        sample and watchdog beat. A dict returned by the path extra is
-        its own hbm_stats query, shared with the tracker instead of
-        sampling twice."""
-        stats = None
+        (layer spans, a worker's beat), then the engine-owned watermark
+        sample and watchdog beat."""
         if self.after_dispatch is not None:
-            stats = self.after_dispatch(n, ds, elapsed)
-        self.introspection.after_step(stats if isinstance(stats, dict)
-                                      else None)
+            self.after_dispatch(n, ds, elapsed)
+        self.introspection.after_step()
         self.health.beat(self.model.iteration)
 
     # ------------------------------------------------------------------
@@ -388,8 +422,6 @@ class WindowedFitLoop:
         if not self._buf:
             return
         if tr is None:
-            from deeplearning4j_tpu.telemetry import trace as trace_mod
-
             tr = trace_mod.tracer()
         batch, self._buf = self._buf, []
         n = len(batch)
@@ -411,10 +443,13 @@ class WindowedFitLoop:
         import jax.numpy as jnp
 
         t_host0 = time.perf_counter()
-        window = jax.tree_util.tree_map(
-            lambda *leaves: jnp.stack(leaves), *[a for a, _ in batch])
-        if self.place_window is not None:
-            window = self.place_window(window)
+        # the staged batches are on the device already (their `put` spans
+        # carry the bytes); this one is the stack and the re-placement
+        with tr.span("put", category=self.span_category):
+            window = jax.tree_util.tree_map(
+                lambda *leaves: jnp.stack(leaves), *[a for a, _ in batch])
+            if self.place_window is not None:
+                window = self.place_window(window)
         scan = self._scans.get((self.raw_step, n))
         cold = scan is None
         if cold:
@@ -422,17 +457,19 @@ class WindowedFitLoop:
                 self.raw_step, n,
                 watch_name=f"{self.watch_prefix}.window_step[{n}]")
         t_step = time.perf_counter()
-        it0 = jnp.asarray(m.iteration)
-        with self.dispatch_scope():
-            m.params, m.state, m.opt_state, m._rng, scores = scan(
-                m.params, m.state, m.opt_state, m._rng, it0, window)
+        with tr.span("dispatch", category=self.span_category):
+            it0 = jnp.asarray(m.iteration)
+            with self.dispatch_scope():
+                m.params, m.state, m.opt_state, m._rng, scores = scan(
+                    m.params, m.state, m.opt_state, m._rng, it0, window)
         # the jitted call returned (async dispatch enqueued): everything
         # up to here — window stacking, placement, cache lookup, jit
         # call/trace — is HOST work a wider window amortizes; the sync
         # below is where device time is paid
         t_call = time.perf_counter()
         # ONE host sync per window (vs one float(score) per step)
-        scores = np.asarray(scores)
+        with tr.span("score_wait", category=self.span_category):
+            scores = np.asarray(scores)
         elapsed = time.perf_counter() - t_step
         if self.tuning and not cold:
             # cold dispatches carry the scan COMPILE in the call-return
@@ -454,11 +491,20 @@ class WindowedFitLoop:
         # that persist (iteration, params) pairs (CheckpointListener)
         # consult this flag and defer to on_window_end, where the pair
         # is consistent again
+        with tr.span("listeners", category=self.span_category):
+            self._replay(batch, scores)
+        self._post_dispatch(n, getattr(self, "_last_ds", None), elapsed)
+
+
+    def _replay(self, batch, scores) -> None:
+        """The window's scores through `iteration_done`, one step at a
+        time, then `on_window_end`."""
+        m = self.model
         m._window_replay = True
         try:
             it_expected = m.iteration
             for (_, report_batch), s in zip(batch, scores):
-                m.score_ = float(s)  # jaxlint: disable=JX010 — s is a host numpy scalar; the one device sync is the np.asarray above
+                m.score_ = float(s)  # jaxlint: disable=JX010 — s is a host numpy scalar; the one device sync is the np.asarray in flush
                 m.last_batch_size = report_batch
                 m.iteration += 1
                 it_expected += 1
@@ -478,7 +524,6 @@ class WindowedFitLoop:
             cb = getattr(lst, "on_window_end", None)
             if cb is not None:
                 cb(m)
-        self._post_dispatch(n, getattr(self, "_last_ds", None), elapsed)
 
 
 def _signature(args) -> tuple:
@@ -599,11 +644,15 @@ class TrainingRun:
         from deeplearning4j_tpu.telemetry import flight as flight_mod
         from deeplearning4j_tpu.telemetry import health as health_mod
         from deeplearning4j_tpu.telemetry import introspect as introspect_mod
-        from deeplearning4j_tpu.telemetry import trace as trace_mod
-
         from deeplearning4j_tpu.telemetry import tuner as tuner_mod
 
         m = self.model
+        # the always-on account of this fit (telemetry.fit_log()): what
+        # the spans and the compile counter add between here and the end
+        account = trace_mod.tracer().account
+        watcher = introspect_mod.watcher()
+        phases0, compiles0 = account.mark(), watcher.compile_count()
+        iteration0, t_fit0 = m.iteration, time.perf_counter()
         hb = health_mod.fit_health(self.phase)
         fi = introspect_mod.fit_introspection(m)
         loop.health, loop.introspection = hb, fi
@@ -655,6 +704,12 @@ class TrainingRun:
             fire_lifecycle(m.listeners, "on_fit_end", m, swallow=True)
             if ctx_token is not None:
                 context_mod.detach(ctx_token)
+            trace_mod.record_fit({
+                "path": self.phase,
+                "steps": m.iteration - iteration0,
+                "wall_s": time.perf_counter() - t_fit0,
+                "compiles": watcher.compile_count() - compiles0,
+                "phases": account.since(phases0)})
         return m
 
 
@@ -708,7 +763,6 @@ def master_session(model, phase: str, registry=None,
     Yields the heartbeat handle."""
     from deeplearning4j_tpu.telemetry import context as context_mod
     from deeplearning4j_tpu.telemetry import health as health_mod
-    from deeplearning4j_tpu.telemetry import trace as trace_mod
 
     if registry is not None:
         registry.set_flight_context(model, barrier_checkpoints)

@@ -1,9 +1,9 @@
 """Runtime introspection (ISSUE 4): the compile watcher + retrace
 detector over the jaxcompat.jit seam, the MFU/roofline engine against a
 hand-counted GEMM, HBM sampling as a guarded no-op on CPU, the `profile`
-CLI + `/profile` endpoint, the `trace summary` compile/retrace rows,
-ParallelWrapper device lanes, and the telemetry-disabled zero-allocation
-contract extended to the watcher."""
+CLI + `/profile` endpoint, the `trace summary` compile/retrace rows, and
+the telemetry-disabled zero-allocation contract extended to the
+watcher."""
 import json
 import urllib.request
 import warnings
@@ -260,30 +260,6 @@ class TestLayerSpans:
         net.fit(_batch(rng, 16))
         assert not [r for r in trace_mod.tracer().records()
                     if r.category == "layer"]
-
-
-# ===========================================================================
-# ParallelWrapper device lanes
-# ===========================================================================
-
-
-class TestDeviceLanes:
-    def test_parallel_fit_emits_one_lane_per_device(self, iris_like,
-                                                    monkeypatch):
-        from deeplearning4j_tpu.parallel import MeshSpec, ParallelWrapper
-
-        monkeypatch.setenv("DL4J_TPU_TELEMETRY", "1")
-        net = _net()
-        ParallelWrapper(net, mesh_spec=MeshSpec(data=8)).fit(
-            ListDataSetIterator(iris_like, batch=40), epochs=1)
-        doc = trace_mod.tracer().to_chrome_trace()
-        dev_spans = [e for e in doc["traceEvents"]
-                     if e.get("name") == "device.step"]
-        tids = {e["tid"] for e in dev_spans}
-        assert len(tids) == 8  # one DISTINCT lane per mesh device
-        lanes = {e["args"]["name"] for e in doc["traceEvents"]
-                 if e["ph"] == "M"}
-        assert sum(1 for l in lanes if l.startswith("device ")) == 8
 
 
 # ===========================================================================

@@ -1002,3 +1002,34 @@ class TestServingGate:
         finally:
             pi.shutdown()
         assert pi._serving.stopped
+
+
+class TestDispatchedBatches:
+    def test_snapshot_counts_the_calls_of_the_dispatch_callable(self):
+        """ISSUE 24: `snapshot()["dispatched_batches"]` is a count (where
+        `dispatched_rows` is a set of shapes): the calls a wrapped
+        dispatch callable saw, a failing one included."""
+        calls = []
+
+        def dispatch(x):
+            calls.append(x.shape[0])
+            if len(calls) == 2:
+                raise RuntimeError("boom")
+            return x * 2.0
+
+        s = _server(dispatch=dispatch)
+        try:
+            assert s.snapshot()["dispatched_batches"] == 0
+            for i in range(4):   # one at a time: one batch each
+                req = s.submit(np.ones((1, 3), np.float32))
+                if i == 1:
+                    with pytest.raises(ServingError):
+                        s.result(req)
+                else:
+                    np.testing.assert_allclose(s.result(req), 2.0)
+            assert len(calls) == 4
+            assert s.snapshot()["dispatched_batches"] == len(calls)
+        finally:
+            s.shutdown()
+        coalesce = trace_mod.tracer().account.snapshot()["serving.coalesce"]
+        assert coalesce["calls"] >= 4

@@ -562,3 +562,269 @@ class TestSurfacing:
         empty.write_text('{"events": []}')
         assert main(["trace", "export", "--stats", str(empty),
                      "--out", out_path]) == 1
+
+
+# ===========================================================================
+# the one span seam (ISSUE 24): profiler annotations, the always-on phase
+# account, fit_log()
+# ===========================================================================
+
+WINDOW_GATE = "DL4J_TPU" "_STEP_WINDOW"  # parse-time concat: JX001 fixture
+LEAF_PHASES = ("etl", "put", "dispatch", "score_wait", "listeners")
+
+
+def _graph():
+    from deeplearning4j_tpu.models import ComputationGraph
+
+    conf = (NeuralNetConfiguration(
+                seed=1, updater=updaters.Adam(learning_rate=5e-3))
+            .graph()
+            .add_inputs("in")
+            .add_layer("d", Dense(n_out=8, activation="relu"), "in")
+            .add_layer("out", Output(n_out=3, loss="mcxent"), "d")
+            .set_outputs("out")
+            .set_input_types(it.feed_forward(4)))
+    return ComputationGraph(conf).init()
+
+
+def _fit_paths():
+    from deeplearning4j_tpu.parallel import MeshSpec, ParallelWrapper
+
+    return {
+        "MultiLayerNetwork.fit": lambda: _net(),
+        "ComputationGraph.fit": lambda: _graph(),
+        "ParallelWrapper.fit": lambda: ParallelWrapper(
+            _net(), mesh_spec=MeshSpec(data=8)),
+    }
+
+
+class TestSpanSeam:
+    def test_account_is_on_with_the_gate_off_and_sums_bytes(self):
+        tr = trace_mod.tracer()
+        assert not tr.enabled
+        mark = tr.account.mark()
+        with tr.span("seam.a", category="t", bytes=5):
+            with tr.span("seam.b", bytes=2.5):
+                pass
+        with tr.span("seam.a", bytes="not a number"):
+            pass
+        tr.add_span("seam.c", 3.0, bytes=7)   # after the fact: account too
+        got = tr.account.since(mark)
+        assert got["seam.a"]["calls"] == 2 and got["seam.a"]["bytes"] == 5
+        assert got["seam.b"]["bytes"] == 2.5
+        assert got["seam.c"]["total_s"] == pytest.approx(3e-3)
+        assert got["seam.a"]["max_s"] <= got["seam.a"]["total_s"]
+        assert len(tr) == 0 and tr.dropped == 0   # and no SpanRecord
+        # process totals keep counting across marks
+        assert tr.account.snapshot()["seam.a"]["calls"] >= 2
+
+    def test_spanned_opens_the_span_while_next_runs(self):
+        import time as time_mod
+
+        def slow():
+            for i in range(3):
+                time_mod.sleep(0.01)
+                yield i
+
+        tr = trace_mod.tracer()
+        mark = tr.account.mark()
+        assert list(tr.spanned("seam.next", slow())) == [0, 1, 2]
+        got = tr.account.since(mark)["seam.next"]
+        # three items, the exhausted fourth call left out; the waits inside
+        assert got["calls"] == 3 and got["total_s"] >= 0.03
+
+    def test_discarded_span_feeds_nothing(self):
+        tr = trace_mod.configure(enabled=True)
+        mark = tr.account.mark()
+        with tr.span("seam.gone") as sp:
+            sp.discard()
+        assert "seam.gone" not in tr.account.since(mark)
+        assert "seam.gone" not in [r.name for r in tr.records()]
+
+    def test_account_loses_no_update_under_threads(self):
+        import sys
+
+        tr = trace_mod.tracer()
+        mark = tr.account.mark()
+        n_threads, n_each = 16, 400
+
+        def work():
+            for _ in range(n_each):
+                with tr.span("seam.race", bytes=1):
+                    pass
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            ts = [threading.Thread(target=work) for _ in range(n_threads)]
+            for t in ts:
+                t.start()
+            for t in ts:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in ts)
+        finally:
+            sys.setswitchinterval(old)
+        got = tr.account.since(mark)["seam.race"]
+        assert got["calls"] == got["bytes"] == n_threads * n_each
+
+    def test_capacity_resize_keeps_the_account(self):
+        tr = trace_mod.tracer()
+        with tr.span("seam.kept"):
+            pass
+        tr2 = trace_mod.configure(capacity=64)
+        assert tr2.account is tr.account
+        assert tr2.account.snapshot()["seam.kept"]["calls"] >= 1
+        trace_mod.configure(capacity=trace_mod.DEFAULT_CAPACITY)
+
+    def test_fit_log_is_bounded(self):
+        for i in range(trace_mod.FIT_LOG_LENGTH + 5):
+            trace_mod.record_fit({"path": "t", "steps": i})
+        log = trace_mod.fit_log()
+        assert len(log) == trace_mod.FIT_LOG_LENGTH
+        assert log[-1]["steps"] == trace_mod.FIT_LOG_LENGTH + 4
+
+
+@pytest.fixture()
+def rows120(iris_like):
+    """120 rows: batches of 24 or 40 divide over the 8-device data axis,
+    so ParallelWrapper pads nothing and `put` bytes are the arrays'."""
+    from deeplearning4j_tpu.datasets.dataset import DataSet
+
+    return DataSet(iris_like.features[:120], iris_like.labels[:120])
+
+
+class TestFitLog:
+    @pytest.mark.parametrize("path", sorted(_fit_paths()))
+    def test_every_fit_path_accounts_its_phases(self, path, rows120):
+        """Gate off: each entry point leaves a fit_log() entry whose leaf
+        phases were entered once a step, fit inside the fit's wall time,
+        and whose `put` carries the bytes handed over."""
+        from deeplearning4j_tpu import telemetry
+
+        tr = trace_mod.tracer()
+        assert not tr.enabled
+        model = _fit_paths()[path]()
+        model.fit(ListDataSetIterator(rows120, batch=24), epochs=2)
+        fit = telemetry.fit_log()[-1]
+        assert fit["path"] == path and fit["steps"] == 10
+        for name in LEAF_PHASES + ("step",):
+            assert fit["phases"][name]["calls"] == 10, name
+        leaf_s = sum(fit["phases"][n]["total_s"] for n in LEAF_PHASES)
+        assert 0 < leaf_s <= fit["wall_s"]
+        assert fit["phases"]["step"]["total_s"] <= fit["wall_s"]
+        nbytes = rows120.features.nbytes + rows120.labels.nbytes
+        assert fit["phases"]["put"]["bytes"] == 2 * nbytes
+        assert len(tr) == 0 and tr.dropped == 0   # no SpanRecord allocated
+
+    def test_windowed_fit_dispatches_once_a_window(self, rows120,
+                                                   monkeypatch):
+        from deeplearning4j_tpu import telemetry
+        from deeplearning4j_tpu.parallel import MeshSpec, ParallelWrapper
+
+        monkeypatch.setenv(WINDOW_GATE, "2")
+        pw = ParallelWrapper(_net(), mesh_spec=MeshSpec(data=8))
+        pw.fit(ListDataSetIterator(rows120, batch=24), epochs=1)
+        assert pw.model._window_scan_cache   # windowing really engaged
+        fit = telemetry.fit_log()[-1]
+        phases = fit["phases"]
+        assert fit["steps"] == 5
+        # 5 batches at K=2: windows of 2, 2 and the tail of 1
+        assert phases["dispatch"]["calls"] == 3
+        assert phases["score_wait"]["calls"] == 3
+        assert phases["listeners"]["calls"] == 3
+        assert phases["put"]["calls"] == 5 + 3   # each stage + each stack
+        assert phases["put"]["bytes"] == (rows120.features.nbytes
+                                          + rows120.labels.nbytes)
+        assert phases["etl"]["calls"] == 5
+        assert len(trace_mod.tracer()) == 0
+
+    def test_compiles_counted_with_the_gate_off(self, rows120):
+        from deeplearning4j_tpu import telemetry
+        from deeplearning4j_tpu.parallel import MeshSpec, ParallelWrapper
+        from deeplearning4j_tpu.telemetry import introspect
+
+        assert not trace_mod.tracer().enabled
+        pw = ParallelWrapper(_net(), mesh_spec=MeshSpec(data=8))
+        c0 = introspect.watcher().compile_count()
+        pw.fit(ListDataSetIterator(rows120, batch=24), epochs=1)
+        c1 = introspect.watcher().compile_count()
+        pw.fit(ListDataSetIterator(rows120, batch=24), epochs=1)
+        c2 = introspect.watcher().compile_count()
+        assert c1 > c0 and c2 == c1
+        first, second = telemetry.fit_log()[-2:]
+        assert first["compiles"] == c1 - c0 and second["compiles"] == 0
+
+    def test_gate_on_ring_holds_the_phases_under_one_trace(self, rows120,
+                                                           monkeypatch):
+        from deeplearning4j_tpu.parallel import MeshSpec, ParallelWrapper
+
+        monkeypatch.setenv("DL4J_TPU_TELEMETRY", "1")
+        tr = trace_mod.tracer()
+        ParallelWrapper(_net(), mesh_spec=MeshSpec(data=8)).fit(
+            ListDataSetIterator(rows120, batch=40), epochs=1)
+        recs = [r for r in tr.records()
+                if r.name in LEAF_PHASES + ("step",)]
+        by = {}
+        for r in recs:
+            by.setdefault(r.name, []).append(r)
+        assert {n: len(v) for n, v in by.items()} == {
+            n: 3 for n in LEAF_PHASES + ("step",)}
+        assert len({r.trace_id for r in recs}) == 1
+        assert recs[0].trace_id is not None
+        # the phases of a step are children of its `step` span
+        steps = {r.span_id for r in by["step"]}
+        for name in ("put", "dispatch", "score_wait", "listeners"):
+            assert {r.parent_id for r in by[name]} == steps
+        assert not {r.parent_id for r in by["etl"]} & steps
+
+    def test_profiler_trace_holds_the_spans_on_its_own_clock(
+            self, rows120, tmp_path):
+        """Gate off, under jax.profiler: the host plane carries
+        dl4j.step events, each enclosing its put, dispatch and score_wait
+        on the same line, dl4j.etl outside them, and the prefetch
+        thread's dl4j.produce on another line."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        from deeplearning4j_tpu.parallel import MeshSpec, ParallelWrapper
+
+        pw = ParallelWrapper(_net(), mesh_spec=MeshSpec(data=8))
+        pw.fit(ListDataSetIterator(rows120, batch=40), epochs=1)  # warm
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            pw.fit(ListDataSetIterator(rows120, batch=40), epochs=1)
+        finally:
+            jax.profiler.stop_trace()
+        assert len(trace_mod.tracer()) == 0
+        path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                              / "*.xplane.pb"))
+        lines = []
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    evs = [(e.name.split("#")[0], e.start_ns,
+                            e.start_ns + e.duration_ns)
+                           for e in line.events
+                           if e.name.startswith("dl4j.")]
+                    if evs:
+                        lines.append(evs)
+        fit_line, = [l for l in lines if any(n == "dl4j.step"
+                                             for n, _, _ in l)]
+        steps = [(s, e) for n, s, e in fit_line if n == "dl4j.step"]
+        assert len(steps) == 3
+        for name in ("dl4j.put", "dl4j.dispatch", "dl4j.score_wait",
+                     "dl4j.listeners"):
+            inside = [(s, e) for n, s, e in fit_line if n == name]
+            assert len(inside) == 3, name
+            for (s, e), (s0, e0) in zip(sorted(inside), sorted(steps)):
+                assert s0 <= s and e <= e0, name
+        for n, s, e in fit_line:
+            if n == "dl4j.etl":
+                assert all(e <= s0 or s >= e0 for s0, e0 in steps)
+        assert sum(n == "dl4j.etl" for n, _, _ in fit_line) >= 3
+        other = [l for l in lines if l is not fit_line]
+        assert any(n == "dl4j.produce" for l in other for n, _, _ in l)
