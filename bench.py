@@ -353,9 +353,8 @@ def _window_ab_fields(net, x, y, iters: int, tuple_args: bool,
 def _prefetch_ab_fields(net, x, y, tuple_args: bool, n: int = 12) -> dict:
     """In-session prefetch on/off A/B: wall seconds for `n` per-step
     dispatches consuming host-produced batches synchronously vs through
-    AsyncDataSetIterator with device placement on the PRODUCER thread —
-    the DL4J_TPU_DEVICE_PREFETCH fit path (datasets/iterators.py +
-    training.engine.device_prefetch_place). Each batch pays a real
+    AsyncDataSetIterator with device placement on the PRODUCER thread
+    (its `place` hook, datasets/iterators.py). Each batch pays a real
     host-side ETL (a fresh augment copy) so the async arm has work to
     overlap; both arms share one warmed per-step executable, so the
     ratio isolates pipeline overlap, not compilation."""

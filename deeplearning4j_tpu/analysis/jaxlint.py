@@ -64,8 +64,9 @@ Rule catalogue (stable IDs; docs/ANALYZER.md):
            arithmetic) pass — the dynamic profiler owns those.
     JX015  inner step loop outside the engine: a For/While body in
            models/, parallel/, or distributed/ that executes a train
-           step per iteration — calling `_fit_batch` / `_fit_std_batch`
-           / `_fit_mds` / `_fit_tbptt`, or firing
+           step per iteration — calling `_dispatch_step` /
+           `_dispatch_std` / `_fit_std_batch` / `_fit_batch_solver` /
+           `_fit_tbptt`, or firing
            `listener.iteration_done` by hand — reimplements the inner
            fit loop `training/engine.py` owns. Every such private loop
            silently opts out of the engine's attachments (window gate,
@@ -311,8 +312,8 @@ def _hot_loop_dir(path: str) -> bool:
 # reimplements the inner fit loop training/engine.py owns; JX015 scope
 # is the hot-loop dirs MINUS training/ (the engine and its loop ARE the
 # blessed implementation)
-_STEP_DRIVERS = ("_fit_batch", "_fit_std_batch", "_fit_mds", "_fit_tbptt",
-                 "iteration_done")
+_STEP_DRIVERS = ("_dispatch_step", "_dispatch_std", "_fit_std_batch",
+                 "_fit_batch_solver", "_fit_tbptt", "iteration_done")
 
 
 def _step_loop_dir(path: str) -> bool:
@@ -1207,13 +1208,13 @@ class _FileLinter(ast.NodeVisitor):
     # ---- JX015: reimplemented inner step loop ----
     def _check_step_loops(self, tree: ast.Module) -> None:
         """Walk with loop-ancestry, tracking the enclosing For targets:
-        a step-driver call (`net._fit_batch(ds)`, a by-hand
+        a step-driver call (`net._fit_tbptt(ds)`, a by-hand
         `lst.iteration_done(...)`) inside a For/While body outside
         training/engine.py is a private inner fit loop. The one blessed
         per-STEP shape is exempt by receiver: `for lst in listeners:
         lst.iteration_done(...)` iterates LISTENERS for one step (the
         receiver IS the loop variable), while a step loop iterates
-        BATCHES (`for ds in shard: net._fit_batch(ds)` — the receiver is
+        BATCHES (`for ds in shard: net._fit_tbptt(ds)` — the receiver is
         not). Function/lambda bodies reset the ancestry — a callback
         defined in a loop runs at call time."""
         if not self.steppy:

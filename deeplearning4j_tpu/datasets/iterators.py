@@ -7,8 +7,9 @@ EarlyTerminationDataSetIterator, SamplingDataSetIterator,
 ExistingDataSetIterator, BenchmarkDataSetIterator (synthetic-data throughput
 harness, impl/BenchmarkDataSetIterator.java:20).
 
-TPU-native: prefetch overlaps host ETL with device compute; device_put of the
-next batch is issued while the current step runs (double buffering).
+TPU-native: prefetch overlaps host ETL with device compute; the device_put of
+the next batch is issued by the fit loop while the current step runs
+(training/engine.py, one batch of look-ahead).
 """
 from __future__ import annotations
 
@@ -31,7 +32,14 @@ class DataSetIterator:
     ride the input pipeline): every yielded batch passes through
     `pre_processor.transform(ds)` (or a bare callable), applied centrally
     by wrapping each subclass's __next__ at class-creation time so no
-    subclass needs to remember the hook."""
+    subclass needs to remember the hook.
+
+    The arrays of a yielded batch must stay unmodified through the
+    FOLLOWING `__next__`: the fit loop hands batch k to the runtime and
+    asks for batch k+1 while that transfer may still be reading them
+    (training/engine.py, one batch of look-ahead). From the call after
+    that they may be overwritten — rotate two buffers, never refill one
+    in place (docs/PERFORMANCE.md)."""
 
     pre_processor = None
 
@@ -155,12 +163,12 @@ class AsyncDataSetIterator(DataSetIterator):
     signals behind ``telemetry.health.input_verdict()`` (docs/HEALTH.md).
 
     ``place`` (optional callable DataSet -> DataSet) runs on the PRODUCER
-    thread before each enqueue — the double-buffered host->device
-    prefetch hook: the fit paths pass ``jax.device_put`` placement
-    (``training.engine.device_prefetch_place``, gated by
-    ``DL4J_TPU_DEVICE_PREFETCH``) so batch t+1's transfer is issued
-    while the device computes batch t and the bounded queue holds
-    device-resident batches. A raising ``place`` surfaces on the
+    thread before each enqueue — a caller's own placement or transform
+    (``training.engine.place_batch`` with ``jax.device_put`` makes the
+    bounded queue hold device-resident batches: ``queue_size`` + 2 of
+    them at once). The fit paths pass none: the fit loop itself hands
+    batch k+1 to the runtime while step k runs (docs/PERFORMANCE.md
+    "One batch of look-ahead"). A raising ``place`` surfaces on the
     consumer like any producer error, and the stop/drain/join teardown
     is unchanged — in-flight device batches are simply dropped."""
 

@@ -26,7 +26,6 @@ from deeplearning4j_tpu.datasets.iterators import (
     ListDataSetIterator,
 )
 from deeplearning4j_tpu import dtypes
-from deeplearning4j_tpu.telemetry import trace as trace_mod
 from deeplearning4j_tpu.training import engine as engine_mod
 from deeplearning4j_tpu.util import jaxcompat
 from deeplearning4j_tpu.nn import weightnoise as wn_mod
@@ -393,9 +392,15 @@ class ComputationGraph:
 
         return engine_mod.WindowedFitLoop(
             self, raw_step=getattr(self, "_train_step_raw", None),
-            stage=stage, exec_one=lambda ds: self._fit_mds(to_mds(ds)),
+            stage=stage, dispatch=self._dispatch_step,
+            # what `stage` declines: the tbptt chunk loop
+            exec_one=lambda ds: self._fit_tbptt(to_mds(ds)),
             after_dispatch=after_dispatch, window=window,
             span_category="train", watch_prefix="ComputationGraph")
+
+    def _dispatch_step(self, args):
+        """One jitted train step on staged `(inputs, labels, fmasks, lmasks)`."""
+        return engine_mod.dispatch_step(self, self._train_step, args)
 
     def _recurrent_vertices(self, for_streaming: bool = False):
         """for_streaming=True (rnnTimeStep) rejects bidirectional layers —
@@ -530,39 +535,14 @@ class ComputationGraph:
         return self._tbptt_step
 
     def _tbptt_mds(self, mds) -> bool:
-        """ONE predicate for the per-step router (_fit_mds) AND the
-        window stager (fit's stage callback) — the engine's K-window ==
-        K-steps guarantee needs them to agree on which batches window.
+        """ONE predicate for the engine loop's stager and its fallback
+        (`_engine_loop`'s stage / exec_one): a batch it holds for is not
+        staged and runs the tbptt chunk loop.
         Per-sequence (2D) labels can't be time-sliced: standard BPTT
         instead, as the reference does for non-3D labels."""
         return (self.conf.defaults.backprop_type == "tbptt"
                 and mds.features[0].ndim == 3
                 and all(np.ndim(l) == 3 for l in mds.labels))
-
-    def _fit_mds(self, mds: MultiDataSet):
-        if self._tbptt_mds(mds):
-            return self._fit_tbptt(mds)
-        # the phases of the engine's `step` span (docs/TELEMETRY.md)
-        tr = trace_mod.tracer()
-        with tr.span("put", category="train",
-                     bytes=engine_mod.host_nbytes(mds)):
-            inputs = tuple(jnp.asarray(f) for f in mds.features)
-            labels = tuple(jnp.asarray(l) for l in mds.labels)
-            fmasks = (tuple(None if m is None else jnp.asarray(m)
-                            for m in mds.features_masks)
-                      if mds.features_masks is not None else None)
-            lmasks = (tuple(None if m is None else jnp.asarray(m)
-                            for m in mds.labels_masks)
-                      if mds.labels_masks is not None else None)
-        with tr.span("dispatch", category="train"):
-            self._rng, sub = jax.random.split(self._rng)
-            (self.params, self.state, self.opt_state,
-             score) = self._train_step(
-                self.params, self.state, self.opt_state,
-                jnp.asarray(self.iteration), sub, inputs, labels, fmasks,
-                lmasks,
-            )
-        engine_mod.finish_step(tr, self, score, int(inputs[0].shape[0]))
 
     def _as_mds_iter(self, data, labels):
         if isinstance(data, MultiDataSet):
@@ -573,13 +553,7 @@ class ComputationGraph:
             def gen():
                 wrap = (not isinstance(data, AsyncDataSetIterator)
                         and data.async_supported())
-                if wrap:
-                    # DL4J_TPU_DEVICE_PREFETCH: producer-side device_put
-                    # (None = exact historical behavior)
-                    it_ = AsyncDataSetIterator(
-                        data, place=engine_mod.device_prefetch_place())
-                else:
-                    it_ = data
+                it_ = AsyncDataSetIterator(data) if wrap else data
                 for ds in it_:
                     yield MultiDataSet.from_dataset(ds)
             return gen

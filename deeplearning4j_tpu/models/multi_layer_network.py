@@ -44,7 +44,6 @@ from deeplearning4j_tpu.nn.layers.output import BaseOutputLayer
 from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrent
 from deeplearning4j_tpu.nn.regularization import apply_constraints
 from deeplearning4j_tpu.datasets.dataset import DataSet
-from deeplearning4j_tpu.telemetry import trace as trace_mod
 from deeplearning4j_tpu.training import engine as engine_mod
 from deeplearning4j_tpu.datasets.iterators import (
     AsyncDataSetIterator,
@@ -396,24 +395,26 @@ class MultiLayerNetwork:
             "stochastic_gradient_descent", "sgd")
 
         def tbptt_batch(ds):
-            # ONE predicate for both the fallback router and the window
-            # stager — the engine's K-window == K-steps guarantee needs
-            # exec_one and stage to agree on which batches window.
+            # ONE predicate for both the fallback router and the stager —
+            # the engine's K-window == K-steps guarantee needs exec_one
+            # and stage to agree on which batches are staged.
             # Per-sequence (2D) labels can't be time-sliced: standard
             # BPTT instead, as the reference does for non-3D labels
-            # (and ComputationGraph._fit_mds here)
+            # (and ComputationGraph._tbptt_mds here)
             return (use_tbptt and ds.features.ndim == 3
                     and ds.labels.ndim == 3)
 
         def exec_one(ds):
+            # what `stage` declines: the tbptt chunk loop, else the
+            # line-search solver
             if tbptt_batch(ds):
                 self._fit_tbptt(ds)
             else:
-                self._fit_batch(ds)
+                self._fit_batch_solver(ds)
 
         def stage(ds):
             # tbptt chunk loops and the line-search solver keep their own
-            # dispatch; only the standard jitted SGD step windows
+            # dispatch; only the standard jitted SGD step is staged
             if not sgd or tbptt_batch(ds):
                 return None
             x = jnp.asarray(ds.features)
@@ -426,32 +427,13 @@ class MultiLayerNetwork:
 
         return engine_mod.WindowedFitLoop(
             self, raw_step=getattr(self, "_train_step_raw", None),
-            stage=stage, exec_one=exec_one, after_dispatch=after_dispatch,
-            window=window, span_category="train",
-            watch_prefix="MultiLayerNetwork")
+            stage=stage, dispatch=self._dispatch_step, exec_one=exec_one,
+            after_dispatch=after_dispatch, window=window,
+            span_category="train", watch_prefix="MultiLayerNetwork")
 
-    def _fit_batch(self, ds: DataSet):
-        if self.conf.defaults.optimization_algo not in (
-                "stochastic_gradient_descent", "sgd"):
-            return self._fit_batch_solver(ds)
-        # the phases of the engine's `step` span (docs/TELEMETRY.md)
-        tr = trace_mod.tracer()
-        with tr.span("put", category="train",
-                     bytes=engine_mod.host_nbytes(ds)):
-            x = jnp.asarray(ds.features)
-            y = jnp.asarray(ds.labels)
-            fm = (None if ds.features_mask is None
-                  else jnp.asarray(ds.features_mask))
-            lm = (None if ds.labels_mask is None
-                  else jnp.asarray(ds.labels_mask))
-        with tr.span("dispatch", category="train"):
-            self._rng, sub = jax.random.split(self._rng)
-            (self.params, self.state, self.opt_state,
-             score) = self._train_step(
-                self.params, self.state, self.opt_state,
-                jnp.asarray(self.iteration), sub, x, y, fm, lm,
-            )
-        engine_mod.finish_step(tr, self, score, int(x.shape[0]))
+    def _dispatch_step(self, args):
+        """One jitted train step on staged `(x, y, fm, lm)`."""
+        return engine_mod.dispatch_step(self, self._train_step, args)
 
     def _fit_batch_solver(self, ds: DataSet):
         """Line-search solver path (Solver.java → ConjugateGradient/LBFGS/
@@ -643,11 +625,7 @@ class MultiLayerNetwork:
     def _as_iterator(self, data, labels) -> DataSetIterator:
         if isinstance(data, DataSetIterator):
             if data.async_supported() and not isinstance(data, AsyncDataSetIterator):
-                # DL4J_TPU_DEVICE_PREFETCH: the producer thread issues
-                # each batch's device_put, double-buffering H2D with
-                # compute (None = exact historical behavior)
-                return AsyncDataSetIterator(
-                    data, place=engine_mod.device_prefetch_place())
+                return AsyncDataSetIterator(data)
             return data
         if isinstance(data, DataSet):
             return ListDataSetIterator(data, batch=data.num_examples())

@@ -673,28 +673,35 @@ class ParallelWrapper:
                     f"sequence length {t} must divide by the seq "
                     f"axis ({n_seq}); bucket or pad the iterator "
                     f"(BucketSequenceIterator) to a multiple")
-        # the phases of the engine's `step` span (docs/TELEMETRY.md)
+        # the phases of the engine's `step` span (docs/TELEMETRY.md); the
+        # dp(/tp) step is staged and dispatched by the engine loop itself,
+        # this is the whole step of an sp/pp mesh (and of a tbptt
+        # wrapper's batch that cannot be time-sliced)
         tr = trace_mod.tracer()
         with tr.span("put", category="collective",
                      bytes=engine_mod.host_nbytes(ds)):
-            x = _put(mesh, ds.features, seq=self._sp)
-            y = _put(mesh, ds.labels, seq=self._sp)
-            fm = _put(mesh, ds.features_mask, seq=self._sp)
-            lm = _put(mesh, ds.labels_mask, seq=self._sp)
+            args = self._stage_std(ds, seq=self._sp)
         # env-gated chaos site for the multi-device step: a "preempted
         # collective" surfaces here as ChaosError out of fit(), which a
         # CheckpointManager-resumed rerun must survive (tier-1 proven)
         chaos.fault_point("collective")
         with tr.span("dispatch", category="collective"):
-            model._rng, sub = jax.random.split(model._rng)
-            it = jnp.asarray(model.iteration)
-            with self._step_scope():
-                (model.params, model.state, model.opt_state,
-                 score) = self._step(
-                    model.params, model.state, model.opt_state, it, sub,
-                    x, y, fm, lm,
-                )
+            score = self._dispatch_std(args)
         engine_mod.finish_step(tr, model, score, unpadded)
+
+    def _stage_std(self, ds, seq: bool = False):
+        """The (already padded) batch handed to the runtime with the
+        sharding the standard step uses: `(x, y, fm, lm)`."""
+        mesh = self.mesh
+        return tuple(_put(mesh, a, seq=seq)
+                     for a in (ds.features, ds.labels, ds.features_mask,
+                               ds.labels_mask))
+
+    def _dispatch_std(self, args):
+        """One built standard step on staged `(x, y, fm, lm)`, traced
+        under the ambient mesh."""
+        return engine_mod.dispatch_step(self.model, self._step, args,
+                                        self._step_scope)
 
     def _step_scope(self):
         """Entered around every jitted standard-step call (per-step and
@@ -775,12 +782,8 @@ class ParallelWrapper:
         if (iterator is not None and isinstance(iterator, DataSetIterator)
                 and not isinstance(iterator, AsyncDataSetIterator)
                 and iterator.async_supported()):
-            # DL4J_TPU_DEVICE_PREFETCH: producer-side device_put (default
-            # device; the step's _put re-shards on-chip). None = exact
-            # historical behavior.
             iterator = own_async = AsyncDataSetIterator(
-                iterator, self.prefetch_buffer,
-                place=engine_mod.device_prefetch_place())
+                iterator, self.prefetch_buffer)
         n_data = dict(mesh.shape)["data"]
 
         def prep(ds):
@@ -804,17 +807,14 @@ class ParallelWrapper:
                 self._fit_std_batch(ds, unpadded=b)
 
         def stage(ds):
-            # windows cover the standard dp(/tp) SPMD step; tbptt chunk
-            # loops and the shape-keyed sp/pp step caches keep their own
-            # per-step dispatch (docs/PERFORMANCE.md)
+            # the standard dp(/tp) SPMD step is staged (one batch ahead
+            # per step, K at a time windowed); tbptt chunk loops and the
+            # shape-keyed sp/pp step caches keep their own per-step
+            # dispatch (docs/PERFORMANCE.md)
             if self._tbptt or self._sp or self._pp:
                 return None
             ds, b = prep(ds)
-            x = _put(mesh, ds.features)
-            y = _put(mesh, ds.labels)
-            fm = _put(mesh, ds.features_mask)
-            lm = _put(mesh, ds.labels_mask)
-            return (x, y, fm, lm), b
+            return self._stage_std(ds), b
 
         def place_window(window):
             # window axis leads: batch axis moves to position 1, sharded
@@ -828,10 +828,9 @@ class ParallelWrapper:
 
         loop = engine_mod.WindowedFitLoop(
             model, raw_step=self._raw_window_step(),
-            stage=stage, exec_one=exec_one,
-            # the engine beats the watchdog before the windowed dispatch;
-            # this hook adds the same env-gated chaos site as
-            # _fit_std_batch, once per dispatched window
+            stage=stage, dispatch=self._dispatch_std, exec_one=exec_one,
+            # the same env-gated chaos site as _fit_std_batch, once per
+            # dispatch of staged args (a step, or a window)
             on_dispatch=lambda: chaos.fault_point("collective"),
             dispatch_scope=self._step_scope,
             place_window=place_window, span_category="collective",
@@ -863,8 +862,8 @@ class ParallelWrapper:
 def _put(mesh, arr, seq: bool = False):
     if arr is None:
         return None
-    # device arrays (DL4J_TPU_DEVICE_PREFETCH already placed them) pass
-    # straight to device_put — np.asarray would round-trip through host
+    # device arrays pass straight to device_put — np.asarray would
+    # round-trip through host
     x = arr if isinstance(arr, jax.Array) else np.asarray(arr)
     if seq and x.ndim >= 2:
         sh = NamedSharding(mesh, P("data", "seq", *([None] * (x.ndim - 2))))

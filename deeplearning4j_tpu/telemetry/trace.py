@@ -141,6 +141,9 @@ class _NullSpan:
     def discard(self):
         return self
 
+    def exclude(self, seconds):
+        return self
+
 
 NULL_SPAN = _NullSpan()
 _EXHAUSTED = object()
@@ -229,7 +232,8 @@ class _Span:
     it."""
 
     __slots__ = ("_tracer", "name", "category", "attrs", "_t0", "_ctx",
-                 "_token", "_ring", "_annotation", "_discarded")
+                 "_token", "_ring", "_annotation", "_discarded",
+                 "_excluded")
 
     def __init__(self, tracer: "Tracer", name: str, category: str,
                  attrs: Optional[Dict[str, Any]],
@@ -240,6 +244,7 @@ class _Span:
         self.attrs = attrs
         self._ring = tracer.enabled
         self._discarded = False
+        self._excluded = 0.0
         self._ctx = None
         self._token = None
         self._annotation = None
@@ -261,6 +266,14 @@ class _Span:
         """Feed neither the ring nor the account at exit (the wait that
         only learned the iterator had ended)."""
         self._discarded = True
+        return self
+
+    def exclude(self, seconds: float):
+        """Take `seconds` this span was open on something else's behalf
+        off the duration the ring and the account get (step k's span is
+        open over the etl and put of batch k+1, which have spans of
+        their own). The profiler's annotation covers the whole interval."""
+        self._excluded += seconds
         return self
 
     def __enter__(self):
@@ -285,12 +298,12 @@ class _Span:
         if self._discarded:
             return False
         tracer = self._tracer
+        duration = max(0.0, t1 - self._t0 - self._excluded)
         if tracer.account is not None:
-            tracer.account.add(self.name, t1 - self._t0,
-                               _attr_bytes(self.attrs))
+            tracer.account.add(self.name, duration, _attr_bytes(self.attrs))
         if self._ring:
-            tracer._record(self.name, self.category, self._t0,
-                           t1 - self._t0, self.attrs, ctx=self._ctx)
+            tracer._record(self.name, self.category, self._t0, duration,
+                           self.attrs, ctx=self._ctx)
         return False
 
 
@@ -671,8 +684,11 @@ def record_fit(entry: Dict[str, Any]) -> None:
 
 def fit_log() -> List[Dict[str, Any]]:
     """The last fits of this process, oldest first, gate on or off:
-    ``{path, steps, wall_s, compiles, phases: {name: {calls, total_s,
-    max_s, bytes}}}`` — which entry point ran, how many optimizer steps,
+    ``{path, steps, staged_ahead, wall_s, compiles, phases: {name:
+    {calls, total_s, max_s, bytes}}}`` — which entry point ran, how many
+    optimizer steps, how many of them had their inputs handed to the
+    runtime before the previous step's score was read (the fit loop's
+    one-batch look-ahead: `steps - 1` when every batch could be staged),
     the wall seconds of the fit, the XLA compilations inside it, and what
     each span name added to the phase account meanwhile (`max_s` the
     longest single span of the fit). The account is the process's: spans

@@ -9,6 +9,5 @@ hand-copied loops (docs/PERFORMANCE.md).
 from deeplearning4j_tpu.training.engine import (  # noqa: F401
     WindowedFitLoop,
     build_window_scan,
-    device_prefetch_place,
     window_size,
 )

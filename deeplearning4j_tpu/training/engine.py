@@ -26,9 +26,14 @@ that snapshot state (the sentry) are offered `on_window_start` before
 each dispatch so their restore point is the clean pre-window state, not
 a mid-burst one (docs/PERFORMANCE.md "windowed mode").
 
-`DL4J_TPU_STEP_WINDOW` defaults to 1 — byte-identical to the historical
-per-step loops (the K=1 path IS the path each fit() ran before this
-module existed, via the `exec_one` callback). All three fit paths
+`DL4J_TPU_STEP_WINDOW` defaults to 1: one dispatch a step, with ONE
+BATCH OF LOOK-AHEAD on the fit thread. After step k's jitted call has
+returned and before the thread blocks for its score, the loop takes
+batch k+1 from the iterator and hands it to the runtime, so its
+host->device transfer runs while the chip computes step k; listeners,
+rng schedule, scores and parameters are bitwise those of a loop that
+feeds one batch at a time (`WindowedFitLoop._run_ahead`,
+docs/PERFORMANCE.md "One batch of look-ahead"). All three fit paths
 delegate their inner loop here; the per-path deltas (tbptt chunking,
 ParallelWrapper's mesh placement and chaos site) ride the callbacks.
 
@@ -58,9 +63,9 @@ from deeplearning4j_tpu.util import envflags
 from deeplearning4j_tpu.util import jaxcompat
 
 PyTree = Any
+_END = object()
 
 _WINDOW_GATE = "DL4J_TPU_STEP_WINDOW"
-_PREFETCH_GATE = "DL4J_TPU_DEVICE_PREFETCH"
 
 _STEP_SECONDS = None
 
@@ -87,22 +92,6 @@ def window_size(default: int = 1) -> int:
     """Steps rolled into one device dispatch (`DL4J_TPU_STEP_WINDOW`).
     1 (default/unset/garbage) = the historical per-step loop."""
     return max(1, envflags.int_value(_WINDOW_GATE, default))
-
-
-def device_prefetch_place() -> Optional[Callable]:
-    """Batch placer for the async iterators' double-buffered host->device
-    prefetch (`DL4J_TPU_DEVICE_PREFETCH`, default off): the producer
-    thread issues `jax.device_put` of batch t+1 while the consumer
-    computes batch t, so the queue holds device-resident batches. None
-    when the gate is off — the exact pre-gate behavior."""
-    if not envflags.enabled(_PREFETCH_GATE, False):
-        return None
-    import jax
-
-    def place(ds):
-        return place_batch(ds, jax.device_put)
-
-    return place
 
 
 def place_batch(ds, put: Callable):
@@ -145,6 +134,24 @@ def host_nbytes(batch) -> int:
     return sum(int(getattr(a, "nbytes", 0))
                for a in jax.tree_util.tree_leaves(batch)
                if not isinstance(a, jax.Array))
+
+
+def dispatch_step(model, step: Callable, args,
+                  scope: Callable = contextlib.nullcontext):
+    """The head every per-step path shares: the next key of the model's
+    rng schedule, then the jitted `step` on staged `args` (inside
+    `scope()`: ParallelWrapper's ambient mesh, around the call alone).
+    Parameters, state and updater state are assigned on the model; the
+    device score comes back unread — `finish_step` waits for it."""
+    import jax
+    import jax.numpy as jnp
+
+    model._rng, sub = jax.random.split(model._rng)
+    it = jnp.asarray(model.iteration)
+    with scope():
+        model.params, model.state, model.opt_state, score = step(
+            model.params, model.state, model.opt_state, it, sub, *args)
+    return score
 
 
 def finish_step(tr, model, score, batch_size: int) -> None:
@@ -210,15 +217,22 @@ class WindowedFitLoop:
 
     Each fit path constructs one per fit() call and hands it:
 
-      exec_one(ds)           the path's existing per-step execution —
-                             the K=1 / fallback path, exact current
-                             behavior (listeners fired inside).
+      exec_one(ds)           the path's whole step for a batch it
+                             cannot stage (tbptt chunk loop, line-search
+                             solver, sp/pp step): put, dispatch, score
+                             and listeners inside, no look-ahead.
       stage(ds)              -> (batch_args, report_batch) with
                              batch_args the device-staged step-arg
                              pytree `(x, y, fm, lm)` (tuples for
-                             ComputationGraph), or None to route this
-                             batch through exec_one (tbptt chunks,
-                             solver paths, sp/pp steps).
+                             ComputationGraph), placed with the sharding
+                             the step uses, or None to route this batch
+                             through exec_one. An array that is already
+                             a `jax.Array` passes through at no cost.
+      dispatch(batch_args)   -> score: the path's ONE jitted step on
+                             staged args (`dispatch_step` with the
+                             path's step and scope); the device score is
+                             returned unread. Given with `stage`, or
+                             not at all.
       raw_step               the unjitted single-step fn scanned by
                              build_window_scan; None disables windowing.
       after_dispatch(n, ds, elapsed_s)
@@ -226,8 +240,9 @@ class WindowedFitLoop:
                              (per step at K=1), `ds` the last batch
                              staged — sampled layer spans, a worker's
                              heartbeat.
-      on_dispatch()          optional hook fired immediately before a
-                             windowed scan (ParallelWrapper's chaos
+      on_dispatch()          optional hook fired immediately before
+                             every dispatch of staged args, per step and
+                             windowed (ParallelWrapper's chaos
                              `collective` fault point).
       dispatch_scope()       optional context manager entered around
                              the windowed scan CALL and nothing else
@@ -241,9 +256,9 @@ class WindowedFitLoop:
                              window axis unsharded, batch axis on the
                              mesh).
 
-    The loop owns the `etl` and `step` spans (and, windowed, the
-    window's `put`/`dispatch`/`score_wait`/`listeners`; per step they are
-    the path's own, in `exec_one`), window accumulation keyed on the
+    The loop owns the `etl` and `step` spans and the `put`/`dispatch`/
+    `score_wait`/`listeners` of every staged batch (a batch through
+    `exec_one` spans its own), window accumulation keyed on the
     batch signature (shape/dtype/mask-structure churn flushes early —
     bounded compiles, the BucketSequenceIterator contract), the scanned
     dispatch, and the per-step score replay. The per-dispatch
@@ -259,6 +274,7 @@ class WindowedFitLoop:
     def __init__(self, model, *, window: Optional[int] = None,
                  raw_step: Optional[Callable] = None,
                  stage: Optional[Callable] = None,
+                 dispatch: Optional[Callable] = None,
                  exec_one: Callable,
                  after_dispatch: Optional[Callable] = None,
                  on_dispatch: Optional[Callable] = None,
@@ -282,9 +298,15 @@ class WindowedFitLoop:
         self._tune_host_s = 0.0
         self._tune_wall_s = 0.0
         self._tune_steps = 0
+        if (stage is None) != (dispatch is None):
+            raise ValueError("stage and dispatch come as a pair")
         self.raw_step = raw_step
         self.stage = stage
+        self.dispatch = dispatch
         self.exec_one = exec_one
+        # steps whose inputs were handed to the runtime before the
+        # previous step's score was read (`fit_log()`'s `staged_ahead`)
+        self.staged_ahead = 0
         self.after_dispatch = after_dispatch
         self.on_dispatch = on_dispatch
         self.dispatch_scope = dispatch_scope or contextlib.nullcontext
@@ -347,38 +369,121 @@ class WindowedFitLoop:
         if tr.enabled and context_mod.current() is None:
             token = context_mod.attach(context_mod.new_trace())
         try:
-            t0 = time.perf_counter()
-            try:
-                # the `etl` span is open WHILE the iterator works, so the
-                # profiler sees the wait where it happens
-                for ds in tr.spanned("etl", batches, category="data"):
-                    self.model.last_etl_time_ms = (
-                        time.perf_counter() - t0) * 1e3
-                    self._consume(ds, tr)
-                    t0 = time.perf_counter()
-            except BaseException:
-                # a chaos fault / preemption mid-epoch: drop the staged-
-                # but-undispatched batches (they were never applied — a
-                # resumed fit replays the epoch from its checkpoint)
-                # rather than dispatching device work during exception
-                # unwind
-                self._buf = []
-                raise
-            self.flush(tr)
+            # the `etl` span is open WHILE the iterator works, so the
+            # profiler sees the wait where it happens
+            source = tr.spanned("etl", batches, category="data")
+            if self.windowed:
+                self._run_windowed(source, tr)
+            else:
+                self._run_ahead(source, tr)
         finally:
             if token is not None:
                 context_mod.detach(token)
 
     # ------------------------------------------------------------------
-    def _consume(self, ds, tr) -> None:
-        if not self.windowed:
-            self._exec_fallback(ds, tr)
-            return
+    # K=1: one dispatch a step, one batch of look-ahead
+    # ------------------------------------------------------------------
+    def _run_ahead(self, source, tr) -> None:
+        """At most ONE staged, undispatched batch exists at any time: it
+        is this frame's `nxt`, so an epoch that unwinds (a listener's
+        exception, a chaos fault) drops it without dispatching it — it
+        was never applied, and a resumed fit replays the epoch from its
+        checkpoint."""
+        nxt = self._take(source, tr)
+        while nxt is not None:
+            ds, staged, etl_ms = nxt
+            self.model.last_etl_time_ms = etl_ms
+            if staged is None:
+                # a batch kind the path cannot stage: its whole step, in
+                # order, and nothing is taken ahead of it
+                self._exec_fallback(ds, tr)
+                nxt = self._take(source, tr)
+            else:
+                nxt = self._exec_staged(ds, staged, source, tr)
+
+    def _take(self, source, tr):
+        """The next batch off the iterator (`etl`) and into the runtime's
+        hands (`put`): (ds, staged or None, etl ms), or None at the end."""
+        t0 = time.perf_counter()
+        ds = next(source, _END)
+        if ds is _END:
+            return None
+        etl_ms = (time.perf_counter() - t0) * 1e3
+        staged = (self._stage_spanned(ds, tr)
+                  if self.stage is not None else None)
+        return ds, staged, etl_ms
+
+    def _stage_spanned(self, ds, tr):
+        """`stage(ds)` under a `put` span that carries the batch's host
+        bytes; None (and no span) for a batch the path cannot stage —
+        exec_one makes, and spans, its own put."""
         with tr.span("put", category=self.span_category,
                      bytes=host_nbytes(ds)) as sp:
             staged = self.stage(ds)
             if staged is None:
-                sp.discard()  # exec_one makes, and spans, its own put
+                sp.discard()
+        return staged
+
+    def _exec_staged(self, ds, staged, source, tr):
+        """Step k on staged args; returns batch k+1 as `_take` gives it.
+        Between the jitted call's return and the wait for its score the
+        thread takes batch k+1 and hands it to the runtime, so that
+        transfer overlaps the chip's work on step k. `iteration_done(k)`
+        still runs before step k+1 is dispatched."""
+        args, report_batch = staged
+        m = self.model
+        t_step = time.perf_counter()
+        with tr.step_span("step", m.iteration,
+                          category=self.span_category) as sp:
+            if self.on_dispatch is not None:
+                self.on_dispatch()
+            with tr.span("dispatch", category=self.span_category):
+                score = self.dispatch(args)
+            t_take = time.perf_counter()
+            try:
+                nxt = self._take(source, tr)
+            except Exception:
+                # the iterator (or the put) failed AFTER step k was
+                # dispatched, so step k is finished first, as a loop
+                # that reads the iterator only between steps would
+                # have, and then the error goes on. An interrupt
+                # (BaseException) unwinds at once, past the listeners
+                finish_step(tr, m, score, report_batch)
+                raise
+            # batch k+1's etl and put are not step k's time: the `step`
+            # record, the step histogram and `elapsed` stay what a step
+            # costs, the yardstick input_verdict() holds `etl` against
+            ahead_s = time.perf_counter() - t_take
+            sp.exclude(ahead_s)
+            finish_step(tr, m, score, report_batch)
+            if nxt is not None and nxt[1] is not None:
+                self.staged_ahead += 1
+        self._step_done(ds, time.perf_counter() - t_step - ahead_s, tr)
+        return nxt
+
+    # ------------------------------------------------------------------
+    # K>1: signature-keyed windows
+    # ------------------------------------------------------------------
+    def _run_windowed(self, source, tr) -> None:
+        t0 = time.perf_counter()
+        try:
+            for ds in source:
+                self.model.last_etl_time_ms = (
+                    time.perf_counter() - t0) * 1e3
+                self._consume(ds, tr)
+                t0 = time.perf_counter()
+        except BaseException:
+            # a chaos fault / preemption mid-epoch: drop the staged-
+            # but-undispatched batches (they were never applied — a
+            # resumed fit replays the epoch from its checkpoint)
+            # rather than dispatching device work during exception
+            # unwind
+            self._buf = []
+            raise
+        self.flush(tr)
+
+    def _consume(self, ds, tr) -> None:
+        staged = self._stage_spanned(ds, tr)
         if staged is None:
             # incompatible batch kind (tbptt chunk / solver / sp / pp):
             # apply the pending window first so step ORDER is preserved
@@ -401,9 +506,12 @@ class WindowedFitLoop:
         with tr.step_span("step", self.model.iteration,
                           category=self.span_category):
             self.exec_one(ds)
+        self._step_done(ds, time.perf_counter() - t_step, tr)
+
+    def _step_done(self, ds, elapsed, tr) -> None:
         if tr.enabled:
-            _step_hist().observe(time.perf_counter() - t_step)
-        self._post_dispatch(1, ds, time.perf_counter() - t_step)
+            _step_hist().observe(elapsed)
+        self._post_dispatch(1, ds, elapsed)
 
     def _post_dispatch(self, n, ds, elapsed) -> None:
         """Once per dispatch (per step at K=1): the path extra first
@@ -653,6 +761,7 @@ class TrainingRun:
         watcher = introspect_mod.watcher()
         phases0, compiles0 = account.mark(), watcher.compile_count()
         iteration0, t_fit0 = m.iteration, time.perf_counter()
+        ahead0 = loop.staged_ahead
         hb = health_mod.fit_health(self.phase)
         fi = introspect_mod.fit_introspection(m)
         loop.health, loop.introspection = hb, fi
@@ -707,6 +816,7 @@ class TrainingRun:
             trace_mod.record_fit({
                 "path": self.phase,
                 "steps": m.iteration - iteration0,
+                "staged_ahead": loop.staged_ahead - ahead0,
                 "wall_s": time.perf_counter() - t_fit0,
                 "compiles": watcher.compile_count() - compiles0,
                 "phases": account.since(phases0)})

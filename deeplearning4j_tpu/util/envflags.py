@@ -188,9 +188,6 @@ _declare("DL4J_TPU_STEP_WINDOW", "int", 1,
          "Steps rolled into one jitted lax.scan dispatch (K); re-read at "
          "each epoch start, so the tuner can re-key the window live",
          lo=1, hi=64, mutability=LIVE)
-_declare("DL4J_TPU_DEVICE_PREFETCH", "bool", False,
-         "Producer-thread jax.device_put of batch t+1 while the device "
-         "computes batch t (double-buffered host->device prefetch)")
 _declare("DL4J_TPU_PREFETCH_DEPTH", "int", 4,
          "Async iterator bounded-queue depth; re-read at iterator reset "
          "(epoch boundary), so the tuner can deepen prefetch live",

@@ -127,7 +127,7 @@ class TestParameterAveraging:
             def explode(ds):
                 raise Boom()
 
-            m._fit_batch = explode
+            m._dispatch_step = explode
             return m
 
         net.clone = bad_clone

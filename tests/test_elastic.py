@@ -288,7 +288,7 @@ class TestMembershipRegistry:
             def explode(ds):
                 raise Boom()
 
-            m._fit_batch = explode
+            m._dispatch_step = explode
             return m
 
         net.clone = bad_clone

@@ -771,18 +771,26 @@ class TestFitLog:
             n: 3 for n in LEAF_PHASES + ("step",)}
         assert len({r.trace_id for r in recs}) == 1
         assert recs[0].trace_id is not None
-        # the phases of a step are children of its `step` span
-        steps = {r.span_id for r in by["step"]}
-        for name in ("put", "dispatch", "score_wait", "listeners"):
-            assert {r.parent_id for r in by[name]} == steps
-        assert not {r.parent_id for r in by["etl"]} & steps
+        # dispatch, score_wait and listeners are children of their `step`
+        # span; the first batch's etl and put precede every step, and
+        # those of batch k+1 are children of step k (the look-ahead)
+        steps = [r.span_id for r in sorted(by["step"],
+                                           key=lambda r: r.start)]
+        for name in ("dispatch", "score_wait", "listeners"):
+            assert {r.parent_id for r in by[name]} == set(steps)
+        for name in ("etl", "put"):
+            recs_n = sorted(by[name], key=lambda r: r.start)
+            assert recs_n[0].parent_id not in steps
+            assert [r.parent_id for r in recs_n[1:]] == steps[:-1], name
 
     def test_profiler_trace_holds_the_spans_on_its_own_clock(
             self, rows120, tmp_path):
         """Gate off, under jax.profiler: the host plane carries
-        dl4j.step events, each enclosing its put, dispatch and score_wait
-        on the same line, dl4j.etl outside them, and the prefetch
-        thread's dl4j.produce on another line."""
+        dl4j.step events, each enclosing its dispatch, score_wait and
+        listeners on the same line and, between dispatch and score_wait,
+        the etl and put of the NEXT batch (the first batch's stand before
+        the first step); the prefetch thread's dl4j.produce is on
+        another line."""
         import glob
 
         import jax
@@ -816,15 +824,23 @@ class TestFitLog:
                                              for n, _, _ in l)]
         steps = [(s, e) for n, s, e in fit_line if n == "dl4j.step"]
         assert len(steps) == 3
-        for name in ("dl4j.put", "dl4j.dispatch", "dl4j.score_wait",
-                     "dl4j.listeners"):
-            inside = [(s, e) for n, s, e in fit_line if n == name]
-            assert len(inside) == 3, name
-            for (s, e), (s0, e0) in zip(sorted(inside), sorted(steps)):
+        steps.sort()
+
+        def of(name):
+            return sorted((s, e) for n, s, e in fit_line if n == name)
+
+        for name in ("dl4j.dispatch", "dl4j.score_wait", "dl4j.listeners"):
+            assert len(of(name)) == 3, name
+            for (s, e), (s0, e0) in zip(of(name), steps):
                 assert s0 <= s and e <= e0, name
-        for n, s, e in fit_line:
-            if n == "dl4j.etl":
-                assert all(e <= s0 or s >= e0 for s0, e0 in steps)
-        assert sum(n == "dl4j.etl" for n, _, _ in fit_line) >= 3
+        # 4 etl: the last, inside step 2, only learns the iterator ended
+        # (on the profiler's line; the ring and the account leave it out)
+        for name, count in (("dl4j.etl", 4), ("dl4j.put", 3)):
+            ahead = of(name)
+            assert len(ahead) == count, name
+            assert ahead[0][1] <= steps[0][0], name   # before step 0
+            for k, (s, e) in enumerate(ahead[1:]):    # batch k+1 in step k
+                assert of("dl4j.dispatch")[k][1] <= s, name
+                assert e <= of("dl4j.score_wait")[k][0], name
         other = [l for l in lines if l is not fit_line]
         assert any(n == "dl4j.produce" for l in other for n, _, _ in l)
