@@ -75,9 +75,19 @@ class Evaluation:
     def eval(self, labels, predictions, mask=None):
         labels = np.asarray(labels)
         predictions = np.asarray(predictions)
-        labels, predictions = _flatten_time(labels, predictions, mask)
-        self._ensure(labels.shape[-1])
-        actual = np.argmax(labels, axis=-1)
+        if (np.issubdtype(labels.dtype, np.integer)
+                and labels.ndim == predictions.ndim - 1):
+            # integer class labels ([b] or [b, t]) ARE the actual classes:
+            # no array with a class axis is built to take the argmax of
+            actual = labels.reshape(-1)
+            predictions = predictions.reshape(-1, predictions.shape[-1])
+            if mask is not None:
+                m = np.asarray(mask).reshape(-1) > 0
+                actual, predictions = actual[m], predictions[m]
+        else:
+            labels, predictions = _flatten_time(labels, predictions, mask)
+            actual = np.argmax(labels, axis=-1)
+        self._ensure(predictions.shape[-1])
         pred = np.argmax(predictions, axis=-1)
         np.add.at(self.confusion.matrix, (actual, pred), 1)
         self.total += len(actual)

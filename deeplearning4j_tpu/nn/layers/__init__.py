@@ -53,4 +53,11 @@ from deeplearning4j_tpu.nn.layers.attention import (  # noqa: F401
     PositionEmbedding,
     TransformerBlock,
 )
+from deeplearning4j_tpu.nn.layers.hybrid import (  # noqa: F401
+    GatedAttention,
+    GatedDeltaNet,
+    HybridBlock,
+    RMSNorm,
+    RoutedExperts,
+)
 from deeplearning4j_tpu.nn.layers.objdetect import Yolo2Output  # noqa: F401

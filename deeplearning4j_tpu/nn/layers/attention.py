@@ -248,6 +248,21 @@ class MultiHeadAttention(Layer):
             return False
         return not auto or mesh_mod.per_device_batch(b) > 0
 
+    def _flash(self, q, k, v):
+        """The Pallas flash kernels on [b, h, t, d] heads that
+        `_use_pallas` admitted, per batch shard under a data mesh."""
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        from deeplearning4j_tpu.parallel import mesh as mesh_mod
+
+        bq, bk = pk.pick_flash_blocks(q.shape[2], q.shape[3], q.dtype)
+        interpret = jax.default_backend() != "tpu"
+
+        def flash(q_, k_, v_):
+            return pk.flash_attention(q_, k_, v_, self.causal, None,
+                                      bq, bk, interpret)
+
+        return mesh_mod.per_batch_shard(flash, (q, k, v), (True, True, True))
+
     def apply(self, params, x, *, state, train, rng, mask=None):
         b, t, f = x.shape
         h = self.n_heads
@@ -268,18 +283,7 @@ class MultiHeadAttention(Layer):
             o = att.blockwise(q, k, v, mask=mask, causal=self.causal,
                               block_size=self.block_size)
         elif self._use_pallas(b, t, d, mask):
-            from deeplearning4j_tpu.ops import pallas_kernels as pk
-            from deeplearning4j_tpu.parallel import mesh as mesh_mod
-
-            bq, bk = pk.pick_flash_blocks(t, d, q.dtype)
-            interpret = jax.default_backend() != "tpu"
-
-            def flash(q_, k_, v_):
-                return pk.flash_attention(q_, k_, v_, self.causal, None,
-                                          bq, bk, interpret)
-
-            o = mesh_mod.per_batch_shard(flash, (q, k, v),
-                                         (True, True, True))
+            o = self._flash(q, k, v)
         else:
             o = att.sdpa(q, k, v, mask=mask, causal=self.causal)
         o = o.transpose(0, 2, 1, 3).reshape(b, t, f)

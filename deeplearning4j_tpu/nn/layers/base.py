@@ -36,6 +36,12 @@ PyTree = Any
 
 _LAYER_TYPES: Dict[str, type] = {}
 
+#: `jax.ad_checkpoint.checkpoint_name` tag for a value the 'full' remat
+#: policy keeps although it recomputes everything else: the output of a
+#: sub-computation that is ITSELF a checkpoint (it reruns in its own
+#: backward, and would run a third time in the block's recompute).
+REMAT_KEEP = "dl4j_remat_keep"
+
 
 def register_layer(cls):
     """Class decorator: adds the layer to the serde registry."""
@@ -103,6 +109,15 @@ class Layer:
 
     def has_params(self) -> bool:
         return True
+
+    def counter_summary(self, added: Dict[str, Any]) -> Tuple[str, Dict[str, Any]]:
+        """For a layer that counts on the device (running totals in its
+        state under ``counters``: telemetry/counters.py): the
+        ``telemetry.fit_log()`` key a fit reports them under, and the entry
+        made from what the fit ``added`` to each counter (numpy int64 or
+        float64, at least 1-d). Default: the sums as they are."""
+        return "counters", {k: v.tolist() if v.size > 1 else v.item()
+                            for k, v in added.items()}
 
     # ---- parallelism protocol (net-new vs reference: SURVEY.md §2.4 —
     # the reference has data parallelism only, so these hooks have no

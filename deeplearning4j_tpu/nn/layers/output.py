@@ -109,8 +109,41 @@ class Output(Dense, BaseOutputLayer):
             (True, False, False, True))
         return per_row.reshape(labels.shape[:-1])
 
+    def _index_xent_per_example(self, params, x, labels):
+        """Integer class labels ([b] or [b, t]) on a softmax head with
+        mcxent: the head and the loss in row blocks
+        (`losses.sparse_xent_rows`), so no [.., n_out] label array exists.
+        None for dense labels or another loss (-> the paths below; an
+        integer label is then expanded by `losses.compute`)."""
+        if (not jnp.issubdtype(jnp.result_type(labels), jnp.integer)
+                or self._loss_name() not in ("mcxent", "negativeloglikelihood")
+                or not loss_mod._is_softmax(self._act())):
+            return None
+        x2 = x if jnp.ndim(x) == jnp.ndim(labels) + 1 else _flatten_if_needed(x)
+        if x2.shape[:-1] != labels.shape:
+            return None
+        bias = (params["b"],) if self.has_bias and "b" in params else ()
+
+        def rows(x_, l_, w_, *b_):
+            return loss_mod.sparse_xent_rows(x_, w_, b_[0] if b_ else None, l_)
+
+        args = (x2.reshape(-1, x2.shape[-1]), labels.reshape(-1), params["W"]) + bias
+        # under a data mesh each device loops over the blocks of its own
+        # rows: a sequential loop over the batch-sharded axis would make
+        # GSPMD gather x on every device
+        from deeplearning4j_tpu.parallel import mesh as mesh_mod
+
+        if mesh_mod.per_device_batch(x2.shape[0]):
+            per_row = mesh_mod.per_batch_shard(
+                rows, args, (True, True, False) + (False,) * len(bias))
+        else:
+            per_row = rows(*args)
+        return per_row.reshape(labels.shape)
+
     def compute_loss(self, params, x, labels, *, state, mask=None, rng=None):
-        per_example = self._fused_xent_per_example(params, x, labels)
+        per_example = self._index_xent_per_example(params, x, labels)
+        if per_example is None:
+            per_example = self._fused_xent_per_example(params, x, labels)
         if per_example is not None:
             score, per_ex = loss_mod.reduce_score(per_example, mask)
             return score, per_ex, state

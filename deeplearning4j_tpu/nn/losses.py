@@ -25,6 +25,8 @@ from typing import Callable, Dict, Optional, Union
 import jax
 import jax.numpy as jnp
 
+from deeplearning4j_tpu.ops import linear as ops
+
 EPS = 1e-7
 
 # loss_fn(labels, output_activations) -> per-element loss, same shape as labels
@@ -171,6 +173,38 @@ def wasserstein(labels, y):
 # ---------------------------------------------------------------------------
 
 
+def is_class_index(labels, preout_ndim: int) -> bool:
+    """True for integer class labels: an integer array with one axis fewer
+    than the pre-activations ([b] for [b, c], [b, t] for [b, t, c]). Dense
+    (one-hot or soft) labels are floating point with the class axis."""
+    return (jnp.issubdtype(jnp.result_type(labels), jnp.integer)
+            and jnp.ndim(labels) == preout_ndim - 1)
+
+
+def sparse_xent_rows(x, w, b, labels, block_rows: int = 2048):
+    """Per-row softmax cross-entropy of the linear head x [n, f] @ w [f, c]
+    (+ b) against integer labels [n], in float32, `block_rows` rows at a
+    time: neither an [n, c] label array nor the whole [n, c] logits exist,
+    and the backward recomputes a block's logits instead of keeping them."""
+    def rows(xb, lb):
+        z = ops.dot(xb, w)
+        if b is not None:
+            z = ops.bias_add(z, b)
+        z = z.astype(jnp.float32)
+        picked = jnp.take_along_axis(z, lb[:, None].astype(jnp.int32), axis=-1)[:, 0]
+        return jax.nn.logsumexp(z, axis=-1) - picked
+
+    n = x.shape[0]
+    block = next((c for c in (block_rows, 1024, 512, 256, 128)
+                  if c < n and n % c == 0), None)
+    if block is None:
+        return rows(x, labels)
+    per = jax.lax.map(lambda xl: jax.checkpoint(rows)(*xl),
+                      (x.reshape(n // block, block, -1),
+                       labels.reshape(n // block, block)))
+    return per.reshape(n)
+
+
 def compute(
     loss: Union[str, Callable],
     labels: jnp.ndarray,
@@ -192,6 +226,14 @@ def compute(
     # the output layer; log-softmax/xent in bf16 is numerically unusable)
     if preout.dtype == jnp.bfloat16:
         preout = preout.astype(jnp.float32)
+
+    if is_class_index(labels, preout.ndim):
+        if name in ("mcxent", "negativeloglikelihood") and _is_softmax(activation_fn) \
+                and weights is None:
+            picked = jnp.take_along_axis(
+                preout, labels[..., None].astype(jnp.int32), axis=-1)[..., 0]
+            return reduce_score(jax.nn.logsumexp(preout, axis=-1) - picked, mask)
+        labels = jax.nn.one_hot(labels, preout.shape[-1], dtype=preout.dtype)
 
     if name in ("mcxent", "negativeloglikelihood") and _is_softmax(activation_fn):
         # fused log-softmax cross-entropy for stability

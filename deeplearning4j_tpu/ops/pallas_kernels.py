@@ -108,6 +108,25 @@ def lstm_helper_mode() -> str:
 
 
 # ============================================================ flash attention
+_SCOPED_VMEM_DEFAULT = 16 * 2 ** 20   # what Mosaic gives a kernel unasked (v5e)
+_VMEM_CEILING = 110 * 2 ** 20         # of the chip's 128 MiB
+
+
+def _flash_vmem(t: int, d: int, dtype, whole: int, rows: int) -> dict:
+    """`compiler_params` for a flash kernel that keeps `whole` [t, d]
+    operands and `rows` [t, 1] float32 row statistics (a lane-padded
+    [t, 128] tile each) resident, double-buffered. Nothing — Mosaic's own
+    scoped limit — while they fit it with room for the blocks (every shape
+    up to this PR's: t 1024, head 64 needs 1 MiB); a raised limit for a
+    long sequence of wide heads (t 8192, head 256: 16 MiB forward, 32 MiB
+    for dK/dV), which the default refuses at compile time."""
+    need = 2 * (whole * t * d * jnp.dtype(dtype).itemsize + rows * t * 128 * 4)
+    if need <= _SCOPED_VMEM_DEFAULT // 2:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=min(need + 32 * 2 ** 20, _VMEM_CEILING))}
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, bk: int,
                       causal: bool, scale: float):
     """One (batch·head, q-block) program. q_ref [bq, d]; k/v_ref [t, d].
@@ -183,6 +202,7 @@ def _flash_fwd(q, k, v, *, causal: bool, scale: float, bq: int, bk: int,
         out_specs=out_spec,
         name=kernel_name("flash_fwd", q.dtype, bh=b * h, t=t, d=d, bq=bq, bk=bk),
         interpret=interpret,
+        **_flash_vmem(t, d, q.dtype, whole=2, rows=0),
     )(qf, kf, vf)
     if return_lse:
         out, lse = got
@@ -312,6 +332,7 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal: bool, scale: float, bq: int,
         out_specs=qblk,
         name=kernel_name("flash_bwd_dq", q.dtype, bh=bh, t=t, d=d, bq=bq, bk=bk),
         interpret=interpret,
+        **_flash_vmem(t, d, q.dtype, whole=2, rows=0),
     )(qf, kf, vf, dof, lsef, delta)
 
     dk, dv = pl.pallas_call(
@@ -325,6 +346,7 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal: bool, scale: float, bq: int,
         name=kernel_name("flash_bwd_dkv", q.dtype, bh=bh, t=t, d=d, bq=bq,
                     bk=bk),
         interpret=interpret,
+        **_flash_vmem(t, d, q.dtype, whole=2, rows=2),
     )(qf, kf, vf, dof, lsef, delta)
     return (dq.reshape(b, h, t, d), dk.reshape(b, h, t, d),
             dv.reshape(b, h, t, d))

@@ -42,7 +42,7 @@ policy BY NAME — names lower to jax.checkpoint policies here:
 
     'none'            no checkpointing: full activation stash
     'dots_saveable'   save matmul outputs, recompute elementwise
-    'full'            save nothing, recompute the whole block
+    'full'            save nothing (but a layer's REMAT_KEEP tags), recompute the block
     'offload'         save dot outputs to host memory (pinned_host)
 
 Booleans stay accepted where the old single `remat: bool` flag lived
@@ -86,7 +86,8 @@ def canonical_policy(name: Any) -> str:
 
 def remat_policy(name: Any):
     """The jax.checkpoint `policy=` object for a canonical name ('full'
-    maps to None — jax.checkpoint's default saves nothing). Cached so the
+    saves nothing but what a layer tags `base.REMAT_KEEP` — the output of an
+    inner checkpoint, so that it is not run a third time). Cached so the
     same name always returns the SAME callable: a fresh policy closure
     per call would defeat the jit trace cache."""
     n = canonical_policy(name)
@@ -98,7 +99,11 @@ def remat_policy(name: Any):
     elif n == "offload":
         # dot outputs leave HBM for pinned host memory
         pol = cp.offload_dot_with_no_batch_dims("device", "pinned_host")
-    else:  # 'none' / 'full'
+    elif n == "full":
+        from deeplearning4j_tpu.nn.layers.base import REMAT_KEEP
+
+        pol = cp.save_only_these_names(REMAT_KEEP)
+    else:  # 'none'
         pol = None
     _POLICY_CACHE[n] = pol
     return pol

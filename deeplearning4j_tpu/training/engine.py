@@ -57,6 +57,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from deeplearning4j_tpu.telemetry import counters as counters_mod
 from deeplearning4j_tpu.telemetry import trace as trace_mod
 from deeplearning4j_tpu.util import compile_cache
 from deeplearning4j_tpu.util import envflags
@@ -760,6 +761,7 @@ class TrainingRun:
         account = trace_mod.tracer().account
         watcher = introspect_mod.watcher()
         phases0, compiles0 = account.mark(), watcher.compile_count()
+        counters0 = counters_mod.begin(m)
         iteration0, t_fit0 = m.iteration, time.perf_counter()
         ahead0 = loop.staged_ahead
         hb = health_mod.fit_health(self.phase)
@@ -813,13 +815,15 @@ class TrainingRun:
             fire_lifecycle(m.listeners, "on_fit_end", m, swallow=True)
             if ctx_token is not None:
                 context_mod.detach(ctx_token)
-            trace_mod.record_fit({
+            entry = {
                 "path": self.phase,
                 "steps": m.iteration - iteration0,
                 "staged_ahead": loop.staged_ahead - ahead0,
                 "wall_s": time.perf_counter() - t_fit0,
                 "compiles": watcher.compile_count() - compiles0,
-                "phases": account.since(phases0)})
+                "phases": account.since(phases0)}
+            entry.update(counters_mod.end(m, counters0))
+            trace_mod.record_fit(entry)
         return m
 
 

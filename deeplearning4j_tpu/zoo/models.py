@@ -462,6 +462,92 @@ class TransformerLM(ZooModel):
 
 
 @dataclass
+class HybridMoELM(ZooModel):
+    """Decoder-only LM of `HybridBlock`s: gated-delta-rule linear attention
+    with a gated softmax-attention layer every `full_attention_interval`-th
+    block, routed experts beside a shared expert in every block, RMS norms,
+    an untied bias-free head (the Qwen3-Next shape). The arguments are the
+    keys of the published `config.json`; `num_experts` is the count this
+    rank HOLDS of `num_experts_published` (default: all of them), starting
+    at `experts_first`. Input: [b, t] token ids; labels: [b, t] integer
+    next-token ids (or dense one-hot)."""
+
+    vocab_size: int = 1000
+    hidden_size: int = 256
+    num_hidden_layers: int = 4
+    full_attention_interval: int = 4
+    rms_norm_eps: float = 1e-6
+    max_length: int = 128
+    # gated softmax attention
+    num_attention_heads: int = 4
+    num_key_value_heads: int = 2
+    head_dim: int = 64
+    partial_rotary_factor: float = 0.25
+    rope_theta: float = 1e7
+    # gated delta rule
+    linear_num_key_heads: int = 2
+    linear_num_value_heads: int = 4
+    linear_key_head_dim: int = 32
+    linear_value_head_dim: int = 32
+    linear_conv_kernel_dim: int = 4
+    # routed experts
+    num_experts: int = 8
+    num_experts_published: Optional[int] = None
+    experts_first: int = 0
+    num_experts_per_tok: int = 2
+    moe_intermediate_size: int = 64
+    shared_expert_intermediate_size: int = 64
+    norm_topk_prob: bool = True
+    capacity_factor: float = 1.25
+    # per-block activation-checkpoint policy (parallel/layout.py)
+    remat: Optional[str] = None
+
+    def mixer_kinds(self):
+        """The per-layer list of mixer kinds."""
+        return ["attention" if (i + 1) % self.full_attention_interval == 0
+                else "delta" for i in range(self.num_hidden_layers)]
+
+    def conf(self):
+        from deeplearning4j_tpu.nn.layers import (
+            EmbeddingSequence,
+            HybridBlock,
+            RMSNorm,
+        )
+
+        n_all = self.num_experts_published or self.num_experts
+        blocks = [
+            HybridBlock(
+                mixer=kind, eps=self.rms_norm_eps,
+                n_heads=self.num_attention_heads,
+                n_kv_heads=self.num_key_value_heads, head_dim=self.head_dim,
+                rotary_fraction=self.partial_rotary_factor,
+                rope_theta=self.rope_theta,
+                n_key_heads=self.linear_num_key_heads,
+                n_value_heads=self.linear_num_value_heads,
+                key_dim=self.linear_key_head_dim,
+                value_dim=self.linear_value_head_dim,
+                conv_width=self.linear_conv_kernel_dim,
+                n_experts=n_all, top_k=self.num_experts_per_tok,
+                expert_width=self.moe_intermediate_size,
+                shared_width=self.shared_expert_intermediate_size,
+                experts_held=(self.experts_first, self.num_experts),
+                capacity_factor=self.capacity_factor,
+                norm_topk=self.norm_topk_prob, remat=self.remat)
+            for kind in self.mixer_kinds()
+        ]
+        return NeuralNetConfiguration(
+            seed=self.seed, updater=updaters.Adam(learning_rate=3e-4),
+            weight_init="xavier",
+        ).list([
+            EmbeddingSequence(n_in=self.vocab_size, n_out=self.hidden_size),
+            *blocks,
+            RMSNorm(eps=self.rms_norm_eps),
+            RnnOutput(n_out=self.vocab_size, loss="mcxent",
+                      activation="softmax", has_bias=False),
+        ]).set_input_type(it.recurrent(self.vocab_size, self.max_length))
+
+
+@dataclass
 class VisionTransformer(ZooModel):
     """ViT-style image classifier — net-new 14th zoo architecture (the
     reference zoo is pre-transformer). Patch embedding via a stride=patch

@@ -1,0 +1,427 @@
+"""The hybrid decoder layers (nn/layers/hybrid.py) against the benchmark's
+plain reference (benchmark/reference/qwen3_next.py) at a small size: every
+layer forward and gradients on seeded weights, the chunked delta rule against
+the token-by-token recurrence, the expert layer's shares, its static device
+work, its overflow, its counters, and the zoo class."""
+import dataclasses
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import qwen3_next as ref
+from deeplearning4j_tpu import telemetry, zoo
+from deeplearning4j_tpu.datasets.dataset import DataSet
+from deeplearning4j_tpu.datasets.iterators import ListDataSetIterator
+from deeplearning4j_tpu.models import ComputationGraph, MultiLayerNetwork, serialization
+from deeplearning4j_tpu.nn import inputs as it
+from deeplearning4j_tpu.nn import updaters
+from deeplearning4j_tpu.nn.conf import MultiLayerConfiguration, NeuralNetConfiguration
+from deeplearning4j_tpu.nn.layers import (
+    GatedAttention,
+    GatedDeltaNet,
+    HybridBlock,
+    RMSNorm,
+    RnnOutput,
+    RoutedExperts,
+)
+from deeplearning4j_tpu.nn.layers.base import Layer
+from deeplearning4j_tpu.nn.layers import hybrid
+
+CFG = dict(
+    hidden_size=32, vocab_size=48, num_hidden_layers=4, full_attention_interval=4,
+    num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+    partial_rotary_factor=0.25, rope_theta=1e7,
+    linear_num_key_heads=2, linear_num_value_heads=4, linear_key_head_dim=8,
+    linear_value_head_dim=8, linear_conv_kernel_dim=4,
+    num_experts=4, num_experts_published=8, experts_first=2, num_experts_per_tok=3,
+    moe_intermediate_size=16, shared_expert_intermediate_size=16,
+    norm_topk_prob=True, rms_norm_eps=1e-6)
+ZOO_ARGS = dict(
+    vocab_size=48, hidden_size=32, num_hidden_layers=4, full_attention_interval=4,
+    max_length=80, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+    linear_num_key_heads=2, linear_num_value_heads=4, linear_key_head_dim=8,
+    linear_value_head_dim=8, num_experts=4, num_experts_published=8,
+    experts_first=2, num_experts_per_tok=3, moe_intermediate_size=16,
+    shared_expert_intermediate_size=16, capacity_factor=2.0)
+T = 80              # not a multiple of the chunk of 64
+IN = it.recurrent(32, T)
+
+
+@pytest.fixture(scope="module")
+def weights():
+    return ref.init_params(CFG, 2 ** 31 + 5)
+
+
+def sub(params, prefix):
+    return {k[len(prefix):]: v for k, v in params.items() if k.startswith(prefix)}
+
+
+def to_program(p, names):
+    return {prog: p[r] for r, prog in names.items()}
+
+
+ATTN = {"wqkv": "Wqkv", "qnorm": "q_norm", "knorm": "k_norm", "wo": "Wo"}
+DELTA = {"wqkvz": "Wqkvz", "wba": "Wba", "conv": "conv", "a_log": "A_log",
+         "dt_bias": "dt_bias", "norm": "norm", "wout": "Wout"}
+MOE = {"router": "router", "wgu": "Wgu", "wd": "Wd", "shared_wgu": "shared_Wgu",
+       "shared_wd": "shared_Wd", "shared_gate": "shared_gate"}
+
+
+def layer_case(kind, weights):
+    """(program layer, its params, reference fn of (params, x [t, d]))."""
+    mm = ref.common.matmul(None)
+    if kind == "attention":
+        p = sub(weights, "l3.attn.")
+        layer = GatedAttention(n_heads=4, n_kv_heads=2, head_dim=16)
+        return layer, to_program(p, ATTN), lambda q, x: ref.attention(
+            {r: q[g] for r, g in ATTN.items()}, x, CFG, mm)
+    if kind == "delta":
+        p = sub(weights, "l0.delta.")
+        layer = GatedDeltaNet(n_key_heads=2, n_value_heads=4, key_dim=8, value_dim=8)
+        return layer, to_program(p, DELTA), lambda q, x: ref.delta(
+            {r: q[g] for r, g in DELTA.items()}, x, CFG, mm)
+    if kind == "experts":
+        p = sub(weights, "l1.moe.")
+        layer = RoutedExperts(n_experts=8, top_k=3, expert_width=16, shared_width=16,
+                              experts_held=(2, 4), capacity_factor=2.0)
+        return layer, to_program(p, MOE), lambda q, x: ref.moe(
+            {r: q[g] for r, g in MOE.items()}, x, CFG, mm)
+    if kind == "norm":
+        layer = RMSNorm()
+        return layer, {"w": weights["final_norm"]}, lambda q, x: ref.rms(
+            x, q["w"], CFG["rms_norm_eps"])
+    i = {"block_delta": 1, "block_attention": 3}[kind]
+    layer = HybridBlock(
+        mixer=kind.split("_")[1], n_heads=4, n_kv_heads=2, head_dim=16,
+        n_key_heads=2, n_value_heads=4, key_dim=8, value_dim=8, n_experts=8,
+        top_k=3, expert_width=16, shared_width=16, experts_held=(2, 4),
+        capacity_factor=2.0)
+    leaves = {k: v for k, v in ref.program_paths(CFG).items()
+              if k.startswith(f"l{i}.")}
+
+    def nest(flat):
+        out = {}
+        for name, path in leaves.items():
+            node = out
+            for key in path[1:-1]:
+                node = node.setdefault(key, {})
+            node[path[-1]] = flat[name]
+        return out
+
+    def flatten(tree):
+        out = {}
+        for name, path in leaves.items():
+            node = tree
+            for key in path[1:]:
+                node = node[key]
+            out[name] = node
+        return out
+
+    return layer, nest(weights), lambda q, x: ref.block(flatten(q), x, CFG, i)
+
+
+KINDS = ["norm", "attention", "delta", "experts", "block_delta", "block_attention"]
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_layer_matches_the_reference_forward_and_gradients(kind, weights, rng):
+    layer, params, ref_fn = layer_case(kind, weights)
+    x = jnp.asarray(rng.standard_normal((2, T, 32)), jnp.float32)
+    ct = jnp.asarray(rng.standard_normal((2, T, 32)), jnp.float32)
+    state = layer.init_state(IN)
+
+    def prog(p, x_):
+        y, _ = layer.apply(p, x_, state=state, train=True, rng=None)
+        return y
+
+    def plain(p, x_):
+        with jax.default_matmul_precision("highest"):
+            return jnp.stack([ref_fn(p, row) for row in x_])
+
+    def both(f):
+        return jax.jit(lambda p, x_: (f(p, x_), jax.grad(
+            lambda p_, x__: jnp.sum(f(p_, x__) * ct), (0, 1))(p, x_)))
+
+    (got, g_got), (want, g_want) = both(prog)(params, x), both(plain)(params, x)
+    scale = float(jnp.abs(want).max())
+    np.testing.assert_allclose(got, want, atol=2e-5 * scale, rtol=2e-4)
+    for a, b in zip(jax.tree_util.tree_leaves(g_got), jax.tree_util.tree_leaves(g_want)):
+        np.testing.assert_allclose(a, b, atol=3e-5 * float(jnp.abs(b).max()) + 1e-7,
+                                   rtol=5e-4)
+
+
+@pytest.mark.parametrize("t", [64, 128, 80, 37, 130])
+@pytest.mark.parametrize("decay", ["near_one", "fast"])
+def test_chunked_delta_rule_is_the_token_recurrence(t, decay, rng, monkeypatch):
+    b, h, dk, dv = 2, 3, 8, 8
+    q, k = (jnp.asarray(rng.standard_normal((b, t, h, dk)), jnp.float32) for _ in "qk")
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jnp.asarray(rng.standard_normal((b, t, h, dv)), jnp.float32)
+    lo, hi = (1e-4, 1e-2) if decay == "near_one" else (0.05, 1.0)
+    g = -jnp.asarray(rng.uniform(lo, hi, (b, t, h)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.0, 1.0, (b, t, h)), jnp.float32)
+
+    def plain(q, k, v, g, beta):
+        return jnp.stack([ref.delta_recurrence(*(a[i] for a in (q, k, v, g, beta)))
+                          for i in range(b)])
+
+    got = jax.jit(hybrid.chunk_gated_delta_rule)(q, k, v, g, beta)
+    want = jax.jit(plain)(q, k, v, g, beta)
+    np.testing.assert_allclose(got, want, atol=2e-5 * float(jnp.abs(want).max()))
+    if decay == "near_one":   # the state reaches the last chunk: a lost carry shows
+        step = hybrid._chunk_step
+        monkeypatch.setattr(hybrid, "_chunk_step",
+                            lambda s, ab: step(jnp.zeros_like(s), ab))
+        broken = hybrid.chunk_gated_delta_rule(q, k, v, g, beta)
+        monkeypatch.undo()
+        gap = float(jnp.abs(broken - want).max() / jnp.abs(want).max())
+        assert (gap > 0.05) == (t > 64)
+    if t not in (80, 128):
+        return
+    ct = jnp.asarray(rng.standard_normal(want.shape), jnp.float32)
+    g_got = jax.jit(jax.grad(lambda *a: jnp.sum(hybrid.chunk_gated_delta_rule(*a) * ct),
+                             (0, 1, 2, 3, 4)))(q, k, v, g, beta)
+    g_want = jax.jit(jax.grad(lambda *a: jnp.sum(plain(*a) * ct),
+                              (0, 1, 2, 3, 4)))(q, k, v, g, beta)
+    for a, b_ in zip(g_got, g_want):
+        np.testing.assert_allclose(a, b_, atol=1e-4 * float(jnp.abs(b_).max()))
+
+
+def test_reference_controls_change_the_result(weights, rng):
+    x = jnp.asarray(rng.standard_normal((130, 32)), jnp.float32)
+    blk = lambda op: jax.jit(lambda w, x_: ref.block(w, x_, CFG, 0, op))(weights, x)  # noqa: E731
+    sound = blk(None)
+    for control in ("drop_carry", "drop_expert", ref.CONTROL):
+        other = blk(control)
+        assert float(jnp.abs(other - sound).max()) > 1e-4, control
+
+
+def full_layer_weights(rng, n_experts=32, d=32, f=16):
+    draw = lambda *s: jnp.asarray(0.3 * rng.standard_normal(s), jnp.float32)  # noqa: E731
+    return {"router": draw(d, n_experts), "Wgu": draw(n_experts, d, 2 * f),
+            "Wd": draw(n_experts, f, d), "shared_Wgu": draw(d, 2 * f),
+            "shared_Wd": draw(f, d), "shared_gate": draw(d, 1)}
+
+
+def test_sixteen_shares_add_up_to_the_uncut_layer(rng):
+    """Each of 16 ranks holds 2 of 32 experts; what every rank computes
+    alike (the shared expert) is counted once."""
+    p = full_layer_weights(rng)
+    x = jnp.asarray(rng.standard_normal((2, 40, 32)), jnp.float32)
+    cfg = dict(CFG, num_experts=32, num_experts_published=32, experts_first=0,
+               num_experts_per_tok=5)
+    mm = ref.common.matmul(None)
+    whole = ref.moe({r: p[g] for r, g in MOE.items()}, x.reshape(-1, 32), cfg, mm)
+    shared = jax.nn.sigmoid(x.reshape(-1, 32) @ p["shared_gate"]) * ref.swiglu(
+        x.reshape(-1, 32), p["shared_Wgu"], p["shared_Wd"], mm)
+    total = shared
+    for rank in range(16):
+        layer = RoutedExperts(n_experts=32, top_k=5, expert_width=16, shared_width=16,
+                              experts_held=(2 * rank, 2), capacity_factor=8.0)
+        mine = dict(p, Wgu=p["Wgu"][2 * rank:2 * rank + 2], Wd=p["Wd"][2 * rank:2 * rank + 2])
+        y, st = layer.apply(mine, x, state=layer.init_state(IN), train=True, rng=None)
+        assert int(st["counters"]["dropped"]) == 0
+        total = total + (y.reshape(-1, 32) - shared)
+    np.testing.assert_allclose(total, whole, atol=2e-5 * float(jnp.abs(whole).max()))
+
+
+def routing_case(rng, kind):
+    """Weights that send every token to expert 0 first ("one"), or spread
+    them ("even"), and the tokens."""
+    p = full_layer_weights(rng, n_experts=8)
+    x = jnp.asarray(np.abs(rng.standard_normal((1, 64, 32))), jnp.float32)
+    if kind == "one":
+        p["router"] = p["router"].at[:, 0].set(5.0).at[:, 1].set(4.0)
+    p["Wgu"], p["Wd"] = p["Wgu"][:4], p["Wd"][:4]      # experts 0..3 are held
+    return p, x
+
+
+def test_expert_layer_work_is_a_function_of_shapes_alone(rng):
+    layer = RoutedExperts(n_experts=8, top_k=2, expert_width=16, shared_width=16,
+                          experts_held=(0, 4), capacity_factor=4.0)
+    state = layer.init_state(IN)
+    texts, loads = [], []
+    for kind in ("one", "even"):
+        p, x = routing_case(rng, kind)
+
+        def step(p_, x_):
+            def loss(p__):
+                y, st = layer.apply(p__, x_, state=state, train=True, rng=None)
+                return jnp.sum(y * y), st
+            return jax.value_and_grad(loss, has_aux=True)(p_)
+
+        texts.append(str(jax.make_jaxpr(step)(p, x)))
+        (_, st), _ = step(p, x)
+        loads.append(np.asarray(st["counters"]["load"]))
+        want = ref.moe({r: p[g] for r, g in MOE.items()}, x[0],
+                       dict(CFG, num_experts=4, num_experts_published=8,
+                            experts_first=0, num_experts_per_tok=2),
+                       ref.common.matmul(None))
+        y, _ = layer.apply(p, x, state=state, train=False, rng=None)
+        np.testing.assert_allclose(y[0], want, atol=2e-5 * float(jnp.abs(want).max()))
+    assert texts[0] == texts[1]                  # same program, same shapes
+    assert "while" not in texts[0] and "cond" not in texts[0]
+    assert "ragged_dot" in texts[0]
+    assert loads[0][0] == 64 and loads[0][1] == 64      # all to experts 0 and 1
+    assert loads[1].max() < 64
+
+
+def test_overflow_is_counted_and_left_out(rng):
+    p, x = routing_case(rng, "one")            # 128 assignments to experts 0, 1
+    layer = RoutedExperts(n_experts=8, top_k=2, expert_width=16, shared_width=16,
+                          experts_held=(0, 4), capacity_factor=1.0)
+    cap = layer.capacity(64)                   # 64 expected -> 128 rows at most
+    small = RoutedExperts(n_experts=8, top_k=2, expert_width=16, shared_width=16,
+                          experts_held=(0, 4), capacity_factor=0.6)
+    assert cap == 128 and small.capacity(64) == 128  # rounds up to 128 rows
+    tight = RoutedExperts(n_experts=8, top_k=2, expert_width=16, shared_width=16,
+                          experts_held=(0, 4), capacity_factor=1.0)
+    x2 = jnp.concatenate([x, x, x], axis=1)    # 192 tokens, 384 assignments, cap 256
+    y, st = tight.apply(p, x2, state=tight.init_state(IN), train=True, rng=None)
+    c = st["counters"]
+    assert tight.capacity(192) == 256
+    assert int(c["dropped"]) == 384 - 256 and int(c["capacity"]) == 256
+    assert int(c["load"].sum()) == 384
+    # sorted by expert: expert 0's 192 are kept, of expert 1's 192 the first
+    # 64 tokens'; the rest is left out
+    top, idx = tight.route(p, x2[0])
+    keep = np.ones((192, 2), bool)
+    second = np.asarray(idx) == 1
+    keep[64:][second[64:]] = False
+    cfg = dict(CFG, num_experts=4, num_experts_published=8, experts_first=0,
+               num_experts_per_tok=2)
+    mm = ref.common.matmul(None)
+    q = {r: p[g] for r, g in MOE.items()}
+    want = jax.nn.sigmoid(x2[0] @ p["shared_gate"]) * ref.swiglu(
+        x2[0], p["shared_Wgu"], p["shared_Wd"], mm)
+    for e in range(4):
+        wt = jnp.sum(jnp.where((np.asarray(idx) == e) & keep, top, 0.0), axis=-1)
+        want = want + wt[:, None] * ref.swiglu(x2[0], q["wgu"][e], q["wd"][e], mm)
+    np.testing.assert_allclose(y[0], want, atol=2e-5 * float(jnp.abs(want).max()))
+
+
+def test_counters_reach_the_fit_log_once_a_fit(rng):
+    net = zoo.HybridMoELM(**ZOO_ARGS).init()
+    ids = rng.integers(0, 48, (2, T)).astype(np.int32)
+    ds = DataSet(ids, np.roll(ids, -1, 1).astype(np.int32))
+    net.fit(ListDataSetIterator(DataSet.merge([ds, ds, ds]), batch=2))
+    net.fit(ds)
+    first, second = telemetry.fit_log()[-2:]
+    assert [e["layer"] for e in first["experts"]] == [f"layer_{i}" for i in (1, 2, 3, 4)]
+    for e3, e1 in zip(first["experts"], second["experts"]):
+        assert e3["steps"] == 3 and e1["steps"] == 1
+        assert e3["dropped_assignments"] == 0 and 0 < e3["capacity_fill"] <= 1
+        assert e3["load_max_over_mean"] >= 1.0
+        # 2 x 80 tokens x top-3, 4 of 8 experts held: about half arrive
+        assert 0.2 * 480 < e1["assignments_per_step"] < 0.8 * 480
+    total = int(np.asarray(net.state["layer_1"]["counters"]["steps"]))
+    assert total == 4                        # cumulative on the device
+
+
+@dataclasses.dataclass
+class RowCounter(Layer):
+    """A second kind of counting layer, with no summary of its own."""
+
+    def output_type(self, input_type):
+        return input_type
+
+    def has_params(self):
+        return False
+
+    def init_state(self, input_type):
+        return {"counters": {"rows": jnp.zeros((), jnp.int32),
+                             "per_feature": jnp.zeros((3,), jnp.float32)}}
+
+    def apply(self, params, x, *, state, train, rng, mask=None):
+        c = state["counters"]
+        return x, {"counters": {"rows": c["rows"] + x.shape[0],
+                                "per_feature": c["per_feature"] + 1.0}}
+
+
+@pytest.mark.parametrize("model", ["mln", "graph"])
+def test_any_layer_may_count_and_owns_its_summary(model, rng):
+    """telemetry/counters.py knows no layer's schema: a layer without a
+    `counter_summary` of its own reports its sums under `counters`, beside
+    the expert layer's `experts`."""
+    conf = NeuralNetConfiguration(seed=3, updater=updaters.Sgd(0.1))
+    experts = RoutedExperts(n_experts=4, top_k=2, expert_width=8, shared_width=8,
+                            capacity_factor=2.0)
+    head = RnnOutput(n_out=5, loss="mcxent", activation="softmax")
+    if model == "mln":
+        net = MultiLayerNetwork(conf.list([RowCounter(), experts, head])
+                                .set_input_type(it.recurrent(3, 6))).init()
+        names = ("layer_0", "layer_1")
+    else:
+        net = ComputationGraph(
+            conf.graph().add_inputs("in").add_layer("count", RowCounter(), "in")
+            .add_layer("moe", experts, "count").add_layer("out", head, "moe")
+            .set_outputs("out").set_input_types(it.recurrent(3, 6)).build()).init()
+        names = ("count", "moe")
+    x = rng.standard_normal((4, 6, 3)).astype(np.float32)
+    y = rng.integers(0, 5, (4, 6)).astype(np.int32)
+    net.fit(ListDataSetIterator(DataSet.merge([DataSet(x, y)] * 2), batch=4))
+    entry = telemetry.fit_log()[-1]
+    assert entry["counters"] == [{"layer": names[0], "rows": 8,
+                                  "per_feature": [2.0, 2.0, 2.0]}]
+    (e,) = entry["experts"]
+    assert e["layer"] == names[1] and e["steps"] == 2 and e["dropped_assignments"] == 0
+
+
+def test_zoo_class_serialises_and_round_trips(tmp_path, rng):
+    model = zoo.HybridMoELM(**ZOO_ARGS)
+    assert model.mixer_kinds() == ["delta", "delta", "delta", "attention"]
+    conf = model.conf()
+    text = conf.to_json()
+    again = MultiLayerConfiguration.from_json(text)
+    assert json.loads(again.to_json()) == json.loads(text)
+    net = MultiLayerNetwork(conf).init()
+    ids = rng.integers(0, 48, (2, T)).astype(np.int32)
+    ds = DataSet(ids, np.roll(ids, -1, 1).astype(np.int32))
+    net.fit(ds)
+    path = str(tmp_path / "hybrid.zip")
+    serialization.write_model(net, path)
+    back = serialization.restore_multi_layer_network(path)
+    np.testing.assert_array_equal(back.output(ids), net.output(ids))
+    assert back.score(ds) == net.score(ds)
+    assert int(back.state["layer_2"]["counters"]["steps"]) == 1
+    back.fit(ds)                              # the restored optimizer state steps on
+    assert np.isfinite(back.score_)
+
+
+def test_remat_per_block_changes_nothing(rng):
+    ids = rng.integers(0, 48, (2, T)).astype(np.int32)
+    ds = DataSet(ids, np.roll(ids, -1, 1).astype(np.int32))
+    scores = []
+    for remat in (None, "full"):
+        net = zoo.HybridMoELM(**ZOO_ARGS, remat=remat).init()
+        net.fit(ListDataSetIterator(DataSet.merge([ds, ds]), batch=2))
+        scores.append(net.score(ds))
+    np.testing.assert_allclose(scores[0], scores[1], rtol=1e-5)
+
+
+def test_delta_core_mapped_over_rows_is_the_whole_batch(weights, rng, monkeypatch):
+    """Past `CORE_BYTES` of float32 convolution input the rows run one
+    group at a time, each a checkpoint: same numbers, same gradients."""
+    layer, params, _ = layer_case("delta", weights)
+    x = jnp.asarray(rng.standard_normal((4, T, 32)), jnp.float32)
+    mask = jnp.asarray(rng.uniform(size=(4, T)) > 0.2, jnp.float32)
+
+    def run(m):
+        def loss(p, x_):
+            y, _ = layer.apply(p, x_, state={}, train=True, rng=None, mask=m)
+            return jnp.sum(y * y), y
+        return jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))(params, x)
+
+    for m in (None, mask):
+        whole = run(m)
+        monkeypatch.setattr(GatedDeltaNet, "CORE_BYTES", 2 * T * 64 * 4)   # 2 rows
+        text = str(jax.make_jaxpr(lambda p, x_: layer.apply(
+            p, x_, state={}, train=True, rng=None, mask=m)[0])(params, x))
+        mapped = run(m)
+        monkeypatch.undo()
+        assert f"f32[2,{T},4,8]" in text and f"f32[4,{T},4,8]" not in text
+        for a, b in zip(jax.tree_util.tree_leaves(mapped), jax.tree_util.tree_leaves(whole)):
+            np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.abs(b).max()) + 1e-8)
