@@ -19,7 +19,10 @@ state are float32.
                  depthwise convolution + silu over [q | k | v]; the gated
                  delta rule S <- exp(g) S; S += k (beta (v - S^T k))^T;
                  o = S^T q, run in chunks (`chunk_gated_delta_rule`);
-                 gated RMS norm by silu(z); Wout
+                 gated RMS norm by silu(z); Wout. Between the projections
+                 everything is chunk-major [n, b, heads, c, d]: re-tiled
+                 once in (`to_chunks`) and once out (`from_chunks`), in
+                 the projection's dtype; q and k keep their key heads
   RoutedExperts  softmax router over ALL experts, top-k renormalised; the
                  terms of the experts HELD (`experts_held` = first, count)
                  through a sorted buffer of static capacity and
@@ -166,70 +169,175 @@ class GatedAttention(Layer):
 CHUNK = 64
 
 
-def _mm(a, b):
-    return jnp.matmul(a, b, precision=ops._precision())
+def _mm(spec, a, b):
+    """A batched product named by its axes: no operand is transposed in
+    memory to fit `matmul`'s [.., i, j] x [.., j, k]."""
+    return jnp.einsum(spec, a, b, precision=ops._precision())
 
 
 def _chunk_step(s, ab):
     """The body of the scan over chunks: S' = A S + B. Emits the state the
     chunk STARTS from."""
     a_i, b_i = ab
-    return _mm(a_i, s) + b_i, s
+    return _mm("bhij,bhjv->bhiv", a_i, s) + b_i, s
+
+
+def to_chunks(a):
+    """[b, t, h, ...] -> [n, b, h, c, ...]: chunk-major, a head's chunk of
+    `CHUNK` tokens contiguous, the time zero-padded to whole chunks. With a
+    last axis of 128 this moves whole tiles (c tokens x 128 lanes of one
+    head stay together); it is the ONE re-tiling on the way into the delta
+    rule, done on the narrowest form (the bf16 projection)."""
+    b, t = a.shape[:2]
+    pad = (-t) % CHUNK
+    if pad:
+        a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+    a = a.reshape((b, (t + pad) // CHUNK, CHUNK) + a.shape[2:])
+    return a.transpose((1, 0, 3, 2) + tuple(range(4, a.ndim)))
+
+
+def from_chunks(a, t: int):
+    """[n, b, h, c, ...] -> [b, t, h, ...]: the way back, once."""
+    n, b, h, c = a.shape[:4]
+    a = a.transpose((1, 0, 3, 2) + tuple(range(4, a.ndim)))
+    return a.reshape((b, n * c, h) + a.shape[4:])[:, :t]
 
 
 def chunk_gated_delta_rule(q, k, v, g, beta):
-    """The gated delta rule in chunks of `CHUNK`. q, k [b, t, h, dk]
-    (normalised and scaled by the caller), v [b, t, h, dv], g (log decay,
-    <= 0) and beta [b, t, h], all float32 -> o [b, t, h, dv].
+    """The gated delta rule over chunks of `CHUNK` tokens, chunk-major
+    (`to_chunks`): q, k [n, b, hk, c, dk] (normalised and scaled by the
+    caller), v [n, b, hv, c, dv], g (log decay, <= 0) and beta [n, b, hv, c],
+    all float32 -> o [n, b, hv, c, dv]. Value head j reads key head
+    j // (hv / hk); q and k are never repeated to hv heads: K K^T and Q K^T
+    are computed once a key head and every array of a value head is held
+    as [.., hk, hv / hk, ..] beside them.
 
-    Per head, S_0 = 0 and for every token S <- exp(g) S;
+    Per value head, S_0 = 0 and for every token S <- exp(g) S;
     S <- S + k (beta (v - S^T k))^T; o = S^T q. Within a chunk the writes
     d_j = beta_j (v_j - ...) solve a unit lower-triangular system
     (I + A) D = U - W S_0 with A_jl = beta_j (k_j . k_l) exp(G_j - G_l),
     l < j, G the running sum of g in the chunk; a scan over chunks carries
     S (one [dk, dk] x [dk, dv] product a chunk and head). Everything else is
-    batched over all chunks; autodiff through both gives the backward in
-    chunks too."""
-    chunk, mm = CHUNK, _mm
-    b, t, h, dk = q.shape
-    dv = v.shape[-1]
-    pad = (-t) % chunk
-    if pad:  # zero keys write nothing, zero log decay keeps the state
-        q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))) for a in (q, k, v))
-        g, beta = (jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in (g, beta))
-    n = (t + pad) // chunk
+    batched over all chunks in the layout it arrives in — no array is
+    re-tiled here; autodiff through both gives the backward in chunks too."""
+    mm = _mm
+    n, b, hk, c, dk = q.shape
+    hv, dv = v.shape[2], v.shape[-1]
 
-    def chunks(a):  # [b, T, h, ...] -> [n, b, h, c, ...]
-        a = a.reshape((b, n, chunk) + a.shape[2:])
-        return jnp.moveaxis(jnp.moveaxis(a, 3, 1), 2, 0)
+    def per_key(a):   # [n, b, hv, ...] -> [n, b, hk, hv / hk, ...]: no data moves
+        return a.reshape((n, b, hk, hv // hk) + a.shape[3:])
 
-    q, k, v, g, beta = (chunks(a) for a in (q, k, v, g, beta))
-    gc = jnp.cumsum(g, axis=-1)                                  # [n, b, h, c]
-    i = jnp.arange(chunk)
+    v, g, beta = per_key(v), per_key(g), per_key(beta)
+    gc = jnp.cumsum(g, axis=-1)                                  # [n, b, hk, rep, c]
+    i = jnp.arange(c)
     lower, strict = i[:, None] >= i[None, :], i[:, None] > i[None, :]
     decay = jnp.where(lower, jnp.exp(jnp.where(
         lower, gc[..., :, None] - gc[..., None, :], 0.0)), 0.0)
-    kb = k * beta[..., None]
-    kt = jnp.swapaxes(k, -1, -2)
-    a_mat = jnp.where(strict, mm(kb, kt) * decay, 0.0) + jnp.eye(chunk, dtype=F32)
-    rhs = jnp.concatenate([v * beta[..., None], kb * jnp.exp(gc)[..., None]], -1)
+    # once a key head, shared by its value heads
+    kk = mm("nbhid,nbhjd->nbhij", k, k)[:, :, :, None]
+    qk = mm("nbhid,nbhjd->nbhij", q, k)[:, :, :, None]
+    q, k = q[:, :, :, None], k[:, :, :, None]                    # [n, b, hk, 1, c, dk]
+    a_mat = jnp.where(strict, kk * beta[..., None] * decay, 0.0) + jnp.eye(c, dtype=F32)
+    # W is solved for with its sign turned (on the small factor): what
+    # follows only ever subtracts it, and a sum's cotangent needs no pass
+    rhs = jnp.concatenate([v * beta[..., None],
+                           k * (-beta * jnp.exp(gc))[..., None]], -1)
     sol = jax.scipy.linalg.solve_triangular(a_mat, rhs, lower=True,
                                             unit_diagonal=True)
-    u, w = sol[..., :dv], sol[..., dv:]
-    qk = jnp.where(lower, mm(q, kt) * decay, 0.0)
+    u, w = sol[..., :dv], sol[..., dv:]                          # w = -W
+    qk = jnp.where(lower, qk * decay, 0.0)
     q_dec = q * jnp.exp(gc)[..., None]
-    k_dec_t = jnp.swapaxes(k * jnp.exp(gc[..., -1:] - gc)[..., None], -1, -2)
+    k_dec = k * jnp.exp(gc[..., -1:] - gc)[..., None]
     # the state across chunks is linear in itself: S' = A S + B with
     # A = exp(G_c) I - K_dec^T W, B = K_dec^T U. A and B come from batched
     # products over all chunks; the scan's body is one product and one sum
     last = jnp.exp(gc[..., -1])[..., None, None]
-    a_all = last * jnp.eye(dk, dtype=F32) - mm(k_dec_t, w)
-    b_all = mm(k_dec_t, u)
+    a_all = last * jnp.eye(dk, dtype=F32) + mm("nbhrci,nbhrcj->nbhrij", k_dec, w)
+    b_all = mm("nbhrci,nbhrcv->nbhriv", k_dec, u)
 
-    _, s_all = lax.scan(_chunk_step, jnp.zeros((b, h, dk, dv), F32), (a_all, b_all))
-    o = mm(q_dec, s_all) + mm(qk, u - mm(w, s_all))
-    o = jnp.moveaxis(jnp.moveaxis(o, 0, 2), 1, 3)             # [b, n, c, h, dv]
-    return o.reshape(b, t + pad, h, dv)[:, :t]
+    _, s_all = lax.scan(_chunk_step, jnp.zeros((b, hv, dk, dv), F32),
+                        (a_all.reshape(n, b, hv, dk, dk), b_all.reshape(n, b, hv, dk, dv)))
+    s_all = per_key(s_all)
+    o = mm("nbhrck,nbhrkv->nbhrcv", q_dec, s_all) + mm(
+        "nbhrij,nbhrjv->nbhriv", qk, u + mm("nbhrck,nbhrkv->nbhrcv", w, s_all))
+    return o.reshape(n, b, hv, c, dv)
+
+
+def _shift(a, s: int):
+    """Tokens of every chunk of a [n, r, h, c, d] moved by s places: token i
+    takes token i - s of its chunk (s < 0: a later one), zeros where the
+    chunk has none."""
+    return lax.pad(a, jnp.zeros((), a.dtype), ((0, 0, 0),) * 3 + ((s, -s, 0), (0, 0, 0)))
+
+
+def _next_chunk(a, step: int):
+    """Chunk n takes chunk n - step (step +1: the one before; -1: after);
+    zeros beyond the ends."""
+    zero = jnp.zeros_like(a[:1])
+    return jnp.concatenate([zero, a[:-1]] if step > 0 else [a[1:], zero], axis=0)
+
+
+def _rows(a, lo: int, hi: int):
+    """Zero token rows put before and after every chunk's."""
+    return jnp.pad(a, ((0, 0),) * 3 + ((lo, hi), (0, 0)))
+
+
+def _tail_before(x, cw: int):
+    """[the last cw - 1 tokens of the chunk before | as many zeros], float32:
+    what the first cw - 1 tokens of a chunk read across its border."""
+    return _rows(_next_chunk(x[..., x.shape[3] - (cw - 1):, :], 1).astype(F32), 0, cw - 1)
+
+
+def _conv_pre(x, w):
+    """The short causal depthwise convolution over the tokens of x
+    [n, r, h, c, d] (chunk-major, any float dtype), w [cw, h, 1, d], tap
+    cw - 1 - s on the token s places back -> float32. Token i of a chunk
+    reads its own chunk shifted, zeros let in (the rows are shifted in the
+    dtype they arrive in and widened after: a shift moves half the bytes),
+    plus, for i < cw - 1, the same taps over the tail of the chunk before:
+    a sliver of cw - 1 of c rows."""
+    c, cw = x.shape[3], w.shape[0]
+    taps = [w[cw - 1 - s] for s in range(cw)]
+    acc = sum(_shift(x, s).astype(F32) * taps[s] for s in range(cw))
+    tail = _tail_before(x, cw)
+    halo = sum(_shift(tail, s) * taps[s] for s in range(cw))[..., cw - 1:, :]
+    return acc + _rows(halo, 0, c - (cw - 1))
+
+
+@jax.custom_vjp
+def conv_silu(x, w):
+    """silu(`_conv_pre`(x, w)). Its backward is written out so that the
+    cotangents of the cw shifted reads of x add up in float32 and round to
+    x's dtype once (autodiff would round each and add in that dtype — x is
+    the bf16 projection under the mixed policy)."""
+    return jax.nn.silu(_conv_pre(x, w))
+
+
+def _conv_silu_fwd(x, w):
+    pre = _conv_pre(x, w)
+    return jax.nn.silu(pre), (x, w, pre)
+
+
+def _conv_silu_bwd(res, dy):
+    x, w, pre = res
+    c, cw = x.shape[3], w.shape[0]
+    taps = [w[cw - 1 - s] for s in range(cw)]
+    sg = jax.nn.sigmoid(pre)
+    d = dy * (sg * (1.0 + pre * (1.0 - sg)))
+    # token j is read by tokens j + s: of its own chunk, or (from the last
+    # cw - 1 places) by the first tokens of the chunk after
+    dx = sum(_shift(d, -s) * taps[s] for s in range(cw))
+    head = _rows(_next_chunk(d[..., :cw - 1, :], -1), cw - 1, 0)
+    into_next = sum(_shift(head, -s) * taps[s] for s in range(cw))[..., :cw - 1, :]
+    dx = dx + _rows(into_next, c - (cw - 1), 0)
+    tail = _tail_before(x, cw)
+    dw = [jnp.sum(d * _shift(x, s).astype(F32), axis=(0, 1, 3), keepdims=True)[0, 0]
+          + jnp.sum(d[..., :cw - 1, :] * _shift(tail, s)[..., cw - 1:, :],
+                    axis=(0, 1, 3), keepdims=True)[0, 0] for s in range(cw)]
+    return dx.astype(x.dtype), jnp.stack(dw[::-1])
+
+
+conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
 
 
 @register_layer
@@ -271,65 +379,75 @@ class GatedDeltaNet(Layer):
     def regularizable(self, params):
         return {k: v for k, v in params.items() if k.startswith("W")}
 
-    def _core(self, params, qkv, z, ba, mask):
-        """Everything between the projections, for rows [r, t, ...]: the
-        short convolution, the decays, the delta rule, the gated norm."""
-        r, t, _ = qkv.shape
-        hk, hv, dk, dv = self.n_key_heads, self.n_value_heads, self.key_dim, self.value_dim
-        key = hk * dk
-        cw = self.conv_width
-        padded = jnp.pad(qkv.astype(F32), ((0, 0), (cw - 1, 0), (0, 0)))
-        qkv = jax.nn.silu(sum(padded[:, j:j + t] * params["conv"][j]
-                              for j in range(cw)))
-        q, k, v = jnp.split(qkv, [key, 2 * key], axis=-1)
+    def _core(self, params, t, qk, v, ba, z, mask=None):
+        """Everything between the projections, for rows r of t tokens.
+        Chunk-major (`to_chunks`) and in the projection's dtype: qk
+        [n, r, 2 hk, c, dk], v and z [n, r, hv, c, dv], ba [n, r, 2 hv, c],
+        mask [n, r, 1, c] -> [r, t, hv dv]. The short convolution, the
+        decays, the delta rule, the gated norm — and the one re-tiling
+        back, of the result in the projection's dtype."""
+        hk, hv, cw = self.n_key_heads, self.n_value_heads, self.conv_width
+        key = hk * self.key_dim
+        qk = conv_silu(qk, params["conv"][:, :2 * key].reshape(cw, 2 * hk, 1, -1))
+        v = conv_silu(v, params["conv"][:, 2 * key:].reshape(cw, hv, 1, -1))
 
         def l2(a):
             return a * lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
 
-        q = jnp.repeat(l2(q.reshape(r, t, hk, dk)) * dk ** -0.5, hv // hk, axis=2)
-        k = jnp.repeat(l2(k.reshape(r, t, hk, dk)), hv // hk, axis=2)
         ba = ba.astype(F32)
-        beta = jax.nn.sigmoid(ba[..., :hv])
-        g = -jnp.exp(params["A_log"]) * jax.nn.softplus(ba[..., hv:] + params["dt_bias"])
+        beta = jax.nn.sigmoid(ba[:, :, :hv])
+        g = -jnp.exp(params["A_log"])[:, None] * jax.nn.softplus(
+            ba[:, :, hv:] + params["dt_bias"][:, None])
         if mask is not None:  # a padded token writes nothing, keeps the state
-            beta, g = beta * mask[..., None], g * mask[..., None]
-        o = chunk_gated_delta_rule(q, k, v.reshape(r, t, hv, dv), g, beta)
+            beta, g = beta * mask, g * mask
+        o = chunk_gated_delta_rule(l2(qk[:, :, :hk]) * self.key_dim ** -0.5,
+                                   l2(qk[:, :, hk:]), v, g, beta)
         o = rms_norm(o, params["norm"], self.eps, zero_centered=False)
-        return (o.reshape(r, t, hv * dv) * jax.nn.silu(z.astype(F32))).astype(z.dtype)
+        y = from_chunks((o * jax.nn.silu(z.astype(F32))).astype(z.dtype), t)
+        return y.reshape(y.shape[:2] + (-1,))
 
     #: float32 bytes of convolution input the core takes at a time: beyond
     #: it the rows are mapped, each a checkpoint, so that the delta rule's
-    #: working set (some 13 arrays of that size) is one group's, not the
-    #: batch's (2 x 8192 tokens x 8192 channels would hold 7 GB at once)
+    #: working set is one group's, not the batch's. At 8192 tokens x 8192
+    #: channels a row that input is 268 MB, and so is each of the solve's
+    #: right-hand side and solution and of the scan's A, B and S; q, k, v,
+    #: the decayed q and k, the output and the cotangent of each come to as
+    #: much again: some 3 GB a row while its backward runs
     CORE_BYTES = 2 ** 28
 
     def apply(self, params, x, *, state, train, rng, mask=None):
         b, t, _ = x.shape
-        val = self.n_value_heads * self.value_dim
-        width = 2 * self.n_key_heads * self.key_dim + val
+        hk, hv, dk, dv = self.n_key_heads, self.n_value_heads, self.key_dim, self.value_dim
+        key, val = hk * dk, hv * dv
         qkvz = ops.dot(x, params["Wqkvz"])
-        qkv, z = qkvz[..., :width], qkvz[..., width:]
+        qkv, z = qkvz[..., :2 * key + val], qkvz[..., 2 * key + val:]
         ba = ops.dot(x, params["Wba"])
-        m = None if mask is None else mask.astype(F32)
-        if m is not None:  # a padded token enters no convolution window
-            qkv = qkv * m[..., None].astype(qkv.dtype)
-        core = {k: params[k] for k in ("conv", "A_log", "dt_bias", "norm")}
-        rows = min(b, max(1, self.CORE_BYTES // (t * width * 4)))
+        if mask is not None:  # a padded token enters no convolution window
+            qkv = qkv * mask[..., None].astype(qkv.dtype)
+        rows = min(b, max(1, self.CORE_BYTES // (t * (2 * key + val) * 4)))
         while b % rows:        # groups of equal size
             rows -= 1
-        if rows == b:
-            y = self._core(core, qkv, z, ba, m)
-        else:
-            def group(a):
-                return a.reshape((b // rows, rows) + a.shape[1:])
 
-            args = (qkv, z, ba) + (() if m is None else (m,))
-            y = lax.map(jax.checkpoint(lambda a: self._core(core, *a, *[None] * (4 - len(a)))),
-                        tuple(group(a) for a in args))
+        def groups(a, *heads):   # [b, t, ..] -> [b / rows, rows, t, *heads]
+            return a.reshape((b // rows, rows, t) + (heads or a.shape[2:]))
+
+        # the one re-tiling in, each group's rows on their own, in the
+        # projection's dtype: [b / rows, n, rows, heads, c, ..]; z goes along
+        # so that the gate is taken where the rule's output lies
+        args = [groups(qkv[..., :2 * key], 2 * hk, dk), groups(qkv[..., 2 * key:], hv, dv),
+                groups(ba), groups(z, hv, dv)]
+        if mask is not None:
+            args.append(groups(mask.astype(F32)[..., None]))
+        args = tuple(jax.vmap(to_chunks)(a) for a in args)
+        core = {k: params[k] for k in ("conv", "A_log", "dt_bias", "norm")}
+        if rows == b:
+            y = self._core(core, t, *(a[0] for a in args))
+        else:
+            y = lax.map(jax.checkpoint(lambda a: self._core(core, t, *a)), args)
             # kept by the block's 'full' remat: the groups rerun in their
             # own backward and need not run in the block's recompute too
-            y = checkpoint_name(y.reshape(b, t, val), REMAT_KEEP)
-        y = ops.dot(y, params["Wout"])
+            y = checkpoint_name(y, REMAT_KEEP)
+        y = ops.dot(y.reshape(b, t, val), params["Wout"])
         if mask is not None:
             y = y * mask[..., None].astype(y.dtype)
         return y, state

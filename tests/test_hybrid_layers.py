@@ -153,41 +153,98 @@ def test_layer_matches_the_reference_forward_and_gradients(kind, weights, rng):
                                    rtol=5e-4)
 
 
-@pytest.mark.parametrize("t", [64, 128, 80, 37, 130])
-@pytest.mark.parametrize("decay", ["near_one", "fast"])
-def test_chunked_delta_rule_is_the_token_recurrence(t, decay, rng, monkeypatch):
-    b, h, dk, dv = 2, 3, 8, 8
-    q, k = (jnp.asarray(rng.standard_normal((b, t, h, dk)), jnp.float32) for _ in "qk")
+#: (t, decay, (key heads, value heads), masked): the ten cases at equal head
+#: counts, then fewer key than value heads — the path that repeats nothing —
+#: with and without a mask, at a length that is and is not whole chunks
+RULE_CASES = [(t, decay, (3, 3), False) for decay in ("near_one", "fast")
+              for t in (64, 128, 80, 37, 130)]
+RULE_CASES += [(t, decay, (2, 4), masked) for t in (128, 80)
+               for decay in ("near_one", "fast") for masked in (False, True)]
+
+
+@pytest.mark.parametrize("t,decay,heads,masked", RULE_CASES)
+def test_chunked_delta_rule_is_the_token_recurrence(t, decay, heads, masked, rng, monkeypatch):
+    b, (hk, hv), dk, dv = 2, heads, 8, 8
+    q, k = (jnp.asarray(rng.standard_normal((b, t, hk, dk)), jnp.float32) for _ in "qk")
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    v = jnp.asarray(rng.standard_normal((b, t, h, dv)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, t, hv, dv)), jnp.float32)
     lo, hi = (1e-4, 1e-2) if decay == "near_one" else (0.05, 1.0)
-    g = -jnp.asarray(rng.uniform(lo, hi, (b, t, h)), jnp.float32)
-    beta = jnp.asarray(rng.uniform(0.0, 1.0, (b, t, h)), jnp.float32)
+    g = -jnp.asarray(rng.uniform(lo, hi, (b, t, hv)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.0, 1.0, (b, t, hv)), jnp.float32)
+    keep = rng.uniform(size=(b, t)) > 0.3 if masked else np.ones((b, t), bool)
+    m = jnp.asarray(keep, jnp.float32)[..., None]
+
+    def chunked(q, k, v, g, beta):
+        """The program's rule, from and to [b, t, h, ...]; a masked token
+        writes nothing and keeps the state (as `GatedDeltaNet._core` has it)."""
+        o = hybrid.chunk_gated_delta_rule(*(hybrid.to_chunks(a) for a in (
+            q, k, v, g * m, beta * m)))
+        return hybrid.from_chunks(o, t)
 
     def plain(q, k, v, g, beta):
-        return jnp.stack([ref.delta_recurrence(*(a[i] for a in (q, k, v, g, beta)))
-                          for i in range(b)])
+        """The recurrence over the tokens kept, each value head with its key
+        head's q and k; zero where a token is masked."""
+        q, k = (jnp.repeat(a, hv // hk, axis=2) for a in (q, k))
+        rows = []
+        for i in range(b):
+            at = np.flatnonzero(keep[i])
+            o = ref.delta_recurrence(*(a[i][at] for a in (q, k, v, g, beta)))
+            rows.append(jnp.zeros((t, hv, dv), jnp.float32).at[at].set(o))
+        return jnp.stack(rows)
 
-    got = jax.jit(hybrid.chunk_gated_delta_rule)(q, k, v, g, beta)
+    got = jax.jit(chunked)(q, k, v, g, beta) * m[..., None]
     want = jax.jit(plain)(q, k, v, g, beta)
     np.testing.assert_allclose(got, want, atol=2e-5 * float(jnp.abs(want).max()))
     if decay == "near_one":   # the state reaches the last chunk: a lost carry shows
         step = hybrid._chunk_step
         monkeypatch.setattr(hybrid, "_chunk_step",
                             lambda s, ab: step(jnp.zeros_like(s), ab))
-        broken = hybrid.chunk_gated_delta_rule(q, k, v, g, beta)
+        broken = chunked(q, k, v, g, beta) * m[..., None]
         monkeypatch.undo()
         gap = float(jnp.abs(broken - want).max() / jnp.abs(want).max())
         assert (gap > 0.05) == (t > 64)
     if t not in (80, 128):
         return
-    ct = jnp.asarray(rng.standard_normal(want.shape), jnp.float32)
-    g_got = jax.jit(jax.grad(lambda *a: jnp.sum(hybrid.chunk_gated_delta_rule(*a) * ct),
+    ct = jnp.asarray(rng.standard_normal(want.shape), jnp.float32) * m[..., None]
+    g_got = jax.jit(jax.grad(lambda *a: jnp.sum(chunked(*a) * ct),
                              (0, 1, 2, 3, 4)))(q, k, v, g, beta)
     g_want = jax.jit(jax.grad(lambda *a: jnp.sum(plain(*a) * ct),
                               (0, 1, 2, 3, 4)))(q, k, v, g, beta)
     for a, b_ in zip(g_got, g_want):
         np.testing.assert_allclose(a, b_, atol=1e-4 * float(jnp.abs(b_).max()))
+
+
+@pytest.mark.parametrize("t", [128, 80, 200])       # two whole chunks; padded; four
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_chunked_convolution_is_the_causal_convolution(t, dtype, rng):
+    """`conv_silu` on chunk-major rows against the plain padded convolution
+    over the tokens, forward and its hand-written backward; a bf16 input's
+    cotangent is the float32 one rounded once."""
+    b, h, d, cw = 2, 3, 8, 4
+    x = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32).astype(dtype)
+    w = jnp.asarray(rng.uniform(-0.5, 0.5, (cw, h * d)), jnp.float32)
+    ct = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
+
+    def chunked(x_, w_):
+        y = hybrid.conv_silu(hybrid.to_chunks(x_), w_.reshape(cw, h, 1, d))
+        return hybrid.from_chunks(y, t)
+
+    def plain(x_, w_):
+        padded = jnp.pad(x_.astype(jnp.float32).reshape(b, t, h * d),
+                         ((0, 0), (cw - 1, 0), (0, 0)))
+        return jax.nn.silu(sum(padded[:, j:j + t] * w_[j] for j in range(cw))).reshape(b, t, h, d)
+
+    def both(f):
+        return jax.jit(lambda x_, w_: (f(x_, w_), jax.grad(
+            lambda *a: jnp.sum(f(*a) * ct), (0, 1))(x_, w_)))
+
+    (got, (gx, gw)), (want, (rx, rw)) = both(chunked)(x, w), both(plain)(x.astype(jnp.float32), w)
+    assert got.dtype == jnp.float32 and gx.dtype == x.dtype
+    np.testing.assert_allclose(got, want, atol=1e-6, rtol=1e-5)
+    np.testing.assert_allclose(gw, rw, atol=1e-5 * float(jnp.abs(rw).max()), rtol=1e-4)
+    one_rounding = 2.0 ** -8 if dtype == "bfloat16" else 1e-5
+    np.testing.assert_allclose(gx.astype(jnp.float32), rx, rtol=one_rounding,
+                               atol=1e-6 * float(jnp.abs(rx).max()))
 
 
 def test_reference_controls_change_the_result(weights, rng):
@@ -425,3 +482,59 @@ def test_delta_core_mapped_over_rows_is_the_whole_batch(weights, rng, monkeypatc
         assert f"f32[2,{T},4,8]" in text and f"f32[4,{T},4,8]" not in text
         for a, b in zip(jax.tree_util.tree_leaves(mapped), jax.tree_util.tree_leaves(whole)):
             np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.abs(b).max()) + 1e-8)
+
+
+def eqns_in_order(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs (scan, checkpoint,
+    pjit, custom calls) in place."""
+    for eqn in jaxpr.eqns:
+        subs = [v for v in eqn.params.values()
+                if hasattr(v, "eqns") or hasattr(getattr(v, "jaxpr", None), "eqns")]
+        for sub in subs:
+            yield from eqns_in_order(getattr(sub, "jaxpr", sub))
+        if not subs:
+            yield eqn
+
+
+@pytest.mark.parametrize("rows", [4, 2])        # the whole batch; mapped over rows
+def test_delta_core_holds_one_layout(rows, rng, monkeypatch):
+    """Between the projection and `Wout` nothing is repeated and nothing is
+    re-tiled twice: q and k keep their `hk` heads until the first product
+    (K K^T, Q K^T once a key head), and the chunked rule transposes no array
+    at all — `to_chunks` in, `from_chunks` out. Key and value widths differ
+    here so that a shape says whose array it is."""
+    hk, hv, dk, dv, t = 2, 6, 16, 8, T          # T = 80 pads to 128
+    layer = GatedDeltaNet(n_key_heads=hk, n_value_heads=hv, key_dim=dk, value_dim=dv)
+    params = layer.init_params(jax.random.PRNGKey(3), IN)
+    x = jnp.asarray(rng.standard_normal((4, t, 32)), jnp.float32)
+    monkeypatch.setattr(GatedDeltaNet, "CORE_BYTES", rows * t * (2 * hk * dk + hv * dv) * 4)
+    jaxpr = jax.make_jaxpr(lambda p, x_: layer.apply(
+        p, x_, state={}, train=True, rng=None)[0])(params, x)
+    eqns = list(eqns_in_order(jaxpr.jaxpr))
+    batched = [i for i, e in enumerate(eqns) if e.primitive.name == "dot_general"
+               and len(e.params["dimension_numbers"][1][0]) >= 3]
+    first = eqns[batched[0]]
+    assert [v.aval.shape[2] for v in first.invars] == [hk, hk]       # K K^T a key head
+    repeated = rows * 128 * hv * dk                  # q or k at hv heads, padded time
+    for e in eqns[:batched[0]]:
+        for v in e.outvars:
+            shape = getattr(v.aval, "shape", ())
+            assert not (dk in shape[-2:] and np.prod(shape) == repeated), (e.primitive, shape)
+    # an array is widened only from something small (a decay, a mask, a constant)
+    for e in eqns:
+        if e.primitive.name == "broadcast_in_dim":
+            (src,), (out,) = e.invars, e.outvars
+            grown = np.prod(out.aval.shape) > np.prod(getattr(src.aval, "shape", ()))
+            assert not (grown and np.prod(src.aval.shape) >= rows * 128 * hk * dk), out.aval
+
+    q = jnp.zeros((2, rows, hk, hybrid.CHUNK, dk), jnp.float32)
+    v = jnp.zeros((2, rows, hv, hybrid.CHUNK, dv), jnp.float32)
+    g = jnp.zeros((2, rows, hv, hybrid.CHUNK), jnp.float32)
+    rule = jax.make_jaxpr(hybrid.chunk_gated_delta_rule)(q, q, v, g, g)
+    names = {e.primitive.name for e in eqns_in_order(rule.jaxpr)}
+    assert "transpose" not in names and "dot_general" in names
+    # and the layer re-tiles each array once on the way in, once on the way out
+    moved = [e.outvars[0].aval.shape for e in eqns if e.primitive.name == "transpose"
+             and np.prod(e.outvars[0].aval.shape) >= 4 * t * hk * dk]   # not b | a, the mask
+    assert len(moved) == 4, moved                          # q | k, v, z; the result
+
