@@ -1145,7 +1145,7 @@ def bench_kernel_ab(on_tpu: bool) -> dict:
         "long-window in-session A/B (bench._ab_window, >=100-iter "
         "windows); flash admission boundary measured AT t=1024 in both "
         "dtypes; LSTM long-t/small-b regime probed and unreachable by "
-        "kernel design (see ops/pallas_kernels.lstm_helper_enabled); "
+        "kernel design (see ops/pallas_kernels.lstm_helper_mode); "
         "xent = fused linear+softmax-xent kernel vs XLA materialized "
         "logits at the transformer vocab-head shape (targets ride the "
         "scan carry, not the closure, which would bake a 256 MB "

@@ -12,7 +12,7 @@ Layers (all BTF [batch, time, features], the framework RNN layout):
   PositionEmbedding    — learned or fixed sinusoidal position encodings.
   MultiHeadAttention   — self-attention; causal option; key-padding masks
                          follow the [b, t] RNN mask convention. When a
-                         `parallel.ring.sequence_parallel(axis)` context is
+                         `ops.ring.sequence_parallel(axis)` context is
                          active during tracing, dispatches to ring attention
                          over the mesh axis (exact long-context attention,
                          K/V rotated over ICI).
@@ -35,12 +35,7 @@ from deeplearning4j_tpu.nn import inputs as it
 from deeplearning4j_tpu.nn.layers.base import Layer, apply_dropout, register_layer
 from deeplearning4j_tpu.ops import attention as att
 from deeplearning4j_tpu.ops import linear as ops
-
-
-def _ring():
-    # lazy: parallel.* imports models which imports nn.layers (this package)
-    from deeplearning4j_tpu.parallel import ring
-    return ring
+from deeplearning4j_tpu.ops import ring
 
 
 @register_layer
@@ -121,7 +116,7 @@ class PositionEmbedding(Layer):
 
     def apply(self, params, x, *, state, train, rng, mask=None):
         b, t, f = x.shape
-        axis = _ring().active_sequence_axis()
+        axis = ring.active_sequence_axis()
         if axis is not None:
             off = jax.lax.axis_index(axis) * t
             t_global = t * jax.lax.axis_size(axis)
@@ -215,54 +210,6 @@ class MultiHeadAttention(Layer):
     def regularizable(self, params):
         return {k: v for k, v in params.items() if k.startswith("W")}
 
-    def _use_pallas(self, b: int, t: int, d: int, mask) -> bool:
-        """Admission of the Pallas flash kernel: a rule on what the call
-        site can see — the request (`attention_impl`), the backend, the
-        shapes and the ambient mesh — and nothing else. There is no
-        compile probe: a shape this rule admits and Mosaic refuses fails
-        the step's compile with the kernel's name (which carries the
-        shape) in the error, instead of silently becoming an XLA path.
-
-        'auto' admits on TPU only, from t >= 512 (below that XLA's
-        materialized-scores path holds while the scores fit on-chip; the
-        boundary was set by builder A/Bs in July and has no driver
-        number — ROADMAP S3). Explicit attention_impl='pallas' skips the
-        backend and length gates (CPU tests run it interpreted).
-        Shape rule: no key-padding mask, block-aligned t, head dim 64 or
-        lane-aligned. Mesh rule: the kernel runs per batch shard under a
-        data mesh (parallel/mesh.py per_batch_shard): 'auto' declines when
-        the batch does not split evenly or the mesh shards anything else;
-        an explicit request there raises in per_batch_shard."""
-        if self.attention_impl not in ("pallas", "auto"):
-            return False
-        from deeplearning4j_tpu.ops import pallas_kernels as pk
-        from deeplearning4j_tpu.parallel import mesh as mesh_mod
-
-        auto = self.attention_impl == "auto"
-        on_tpu = jax.default_backend() == "tpu"
-        if auto and not (pk.helpers_enabled() and on_tpu and t >= 512):
-            return False
-        if mask is not None or not (t <= 128 or t % 128 == 0):
-            return False
-        if on_tpu and d % 128 != 0 and d != 64:
-            return False
-        return not auto or mesh_mod.per_device_batch(b) > 0
-
-    def _flash(self, q, k, v):
-        """The Pallas flash kernels on [b, h, t, d] heads that
-        `_use_pallas` admitted, per batch shard under a data mesh."""
-        from deeplearning4j_tpu.ops import pallas_kernels as pk
-        from deeplearning4j_tpu.parallel import mesh as mesh_mod
-
-        bq, bk = pk.pick_flash_blocks(q.shape[2], q.shape[3], q.dtype)
-        interpret = jax.default_backend() != "tpu"
-
-        def flash(q_, k_, v_):
-            return pk.flash_attention(q_, k_, v_, self.causal, None,
-                                      bq, bk, interpret)
-
-        return mesh_mod.per_batch_shard(flash, (q, k, v), (True, True, True))
-
     def apply(self, params, x, *, state, train, rng, mask=None):
         b, t, f = x.shape
         h = self.n_heads
@@ -273,19 +220,9 @@ class MultiHeadAttention(Layer):
         def heads(a):  # [b, t, f] -> [b, h, t, d]
             return a.reshape(b, t, h, d).transpose(0, 2, 1, 3)
 
-        q, k, v = heads(q), heads(k), heads(v)
-        axis = _ring().active_sequence_axis()
-        if axis is not None:
-            o = _ring().ring_attention_sharded(
-                q, k, v, axis_name=axis, mask=mask, causal=self.causal,
-                block_size=self.block_size)
-        elif self.attention_impl == "blockwise":
-            o = att.blockwise(q, k, v, mask=mask, causal=self.causal,
-                              block_size=self.block_size)
-        elif self._use_pallas(b, t, d, mask):
-            o = self._flash(q, k, v)
-        else:
-            o = att.sdpa(q, k, v, mask=mask, causal=self.causal)
+        o = att.attend(heads(q), heads(k), heads(v), causal=self.causal,
+                       mask=mask, impl=self.attention_impl,
+                       block_size=self.block_size)
         o = o.transpose(0, 2, 1, 3).reshape(b, t, f)
         y = ops.bias_add(ops.dot(o, params["Wo"]), params["bo"])
         y = apply_dropout(y, self.attn_dropout if train else None, train, rng)

@@ -13,8 +13,9 @@ state are float32.
   rotary         half-split pairing on the first `rotary_dim` of a head
   GatedAttention [q | g | k | v] = x Wqkv; per-head RMS norm of q and k;
                  partial rotary; each key/value head repeated to its query
-                 heads; causal softmax (the flash kernel where
-                 MultiHeadAttention admits it); o sigmoid(g) Wo
+                 heads; causal softmax through `ops.attention.attend`
+                 (the flash kernels where its rule admits them);
+                 o sigmoid(g) Wo
   GatedDeltaNet  [q | k | v | z] = x Wqkvz, [b | a] = x Wba; short causal
                  depthwise convolution + silu over [q | k | v]; the gated
                  delta rule S <- exp(g) S; S += k (beta (v - S^T k))^T;
@@ -45,7 +46,6 @@ from jax.ad_checkpoint import checkpoint_name
 
 from deeplearning4j_tpu.nn import initializers as init_mod
 from deeplearning4j_tpu.nn import inputs as it
-from deeplearning4j_tpu.nn.layers.attention import MultiHeadAttention
 from deeplearning4j_tpu.nn.layers.base import REMAT_KEEP, Layer, register_layer
 from deeplearning4j_tpu.ops import attention as att
 from deeplearning4j_tpu.ops import linear as ops
@@ -148,11 +148,7 @@ class GatedAttention(Layer):
         q = rotary(rms_norm(heads(q, h), params["q_norm"], self.eps), rot, self.rope_theta)
         k = rotary(rms_norm(heads(k, kv), params["k_norm"], self.eps), rot, self.rope_theta)
         k, v = (jnp.repeat(a, h // kv, axis=1) for a in (k, heads(v, kv)))
-        mha = MultiHeadAttention(n_heads=h, causal=True)
-        if mha._use_pallas(b, t, d, mask):
-            o = mha._flash(q, k, v)
-        else:
-            o = att.sdpa(q, k, v, mask=mask, causal=True)
+        o = att.attend(q, k, v, causal=True, mask=mask)
         o = o.transpose(0, 2, 1, 3).reshape(b, t, h * d)
         o = o * jax.nn.sigmoid(g.astype(F32)).astype(o.dtype)
         y = ops.dot(o, params["Wo"])

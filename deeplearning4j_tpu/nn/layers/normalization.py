@@ -15,11 +15,11 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn import inputs as it
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
+from deeplearning4j_tpu.ops import fused_affine_act
 
 
 @register_layer
@@ -94,36 +94,14 @@ class BatchNorm(Layer):
         return y, new_state
 
     def _affine_act(self, x, scale, shift):
-        """The memory-bound epilogue y = act(x*scale + shift). Default:
-        XLA (fused into the producing conv by the compiler). OPT-IN
-        (DL4J_TPU_PALLAS_CONVBN=1): the fused pallas conv-bn-relu
-        epilogue — one HBM read + one write for the whole normalize/
-        affine/relu tail of the ResNet conv_bn hot blocks; numerics
-        match to float rounding (<= 1 ulp) and gradients are exact wrt
-        the kernel's own forward (recompute vjp through the reference
-        epilogue). ops/pallas_kernels.bn_act; bench.py's in-session
-        conv-bn A/B records the per-round evidence — auto stays off
-        until a sustained win admits a regime."""
+        """The memory-bound epilogue y = act(x*scale + shift): the fused
+        pallas conv-bn-relu epilogue where `ops.fused_affine_act` admits
+        it (opt-in, DL4J_TPU_PALLAS_CONVBN=1), else XLA, which fuses it
+        into the producing conv."""
         act = self.activation if self.activation is not None else "identity"
-        if act in ("relu", "identity") and x.ndim >= 2:
-            from deeplearning4j_tpu.ops import pallas_kernels as pk
-
-            if pk.convbn_mode() == "forced" and pk.helpers_enabled():
-                from deeplearning4j_tpu.parallel import mesh as mesh_mod
-
-                # per-device rows under a data mesh (parallel/mesh.py)
-                b_dev = mesh_mod.per_device_batch(x.shape[0])
-                br = pk.pick_bn_block((b_dev,) + tuple(x.shape[1:]),
-                                      x.dtype) if b_dev else 0
-                if br:
-                    interp = jax.default_backend() != "tpu"
-                    # scale/shift pass through untouched (f32 in normal
-                    # runs, f64 under x64 gradient checks); the kernel
-                    # casts to x.dtype exactly as the XLA path does
-                    return mesh_mod.per_batch_shard(
-                        lambda x_, s_, h_: pk.bn_act(x_, s_, h_, act, br,
-                                                     interp),
-                        (x, scale, shift), (True, False, False))
+        y = fused_affine_act(x, scale, shift, act)
+        if y is not None:
+            return y
         y = x * scale.astype(x.dtype) + shift.astype(x.dtype)
         return self.act_fn("identity")(y)
 

@@ -22,6 +22,7 @@ from deeplearning4j_tpu.nn import inputs as it
 from deeplearning4j_tpu.nn import losses as loss_mod
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
 from deeplearning4j_tpu.nn.layers.dense import Dense, _flatten_if_needed
+from deeplearning4j_tpu.ops import fused_linear_xent
 from deeplearning4j_tpu.ops import linear as ops
 
 
@@ -56,89 +57,36 @@ class Output(Dense, BaseOutputLayer):
     def apply(self, params, x, *, state, train, rng, mask=None):
         return self._act()(self.preout(params, x)), state
 
+    def _softmax_xent(self) -> bool:
+        return (self._loss_name() in ("mcxent", "negativeloglikelihood")
+                and loss_mod._is_softmax(self._act()))
+
+    def _bias(self, params):
+        return params["b"] if self.has_bias and "b" in params else None
+
     def _fused_xent_per_example(self, params, x, labels):
-        """Fused pallas linear+softmax-xent (ops/xent_kernel.py): computes
-        per-example scores WITHOUT materializing the [.., n_out] logits in
-        HBM — the transformer profile's top non-gemm sink at LM vocab
-        sizes. Returns None (→ builtin XLA path) unless loss is mcxent on
-        softmax and `xk.plan` admits the shape (wide vocab, tileable)."""
-        if self._loss_name() not in ("mcxent", "negativeloglikelihood"):
+        """Per-example scores of mcxent on softmax against dense labels
+        WITHOUT materializing the [.., n_out] logits in HBM, where
+        `ops.fused_linear_xent` admits the shape (wide vocab, tileable);
+        None -> the builtin XLA path."""
+        if not self._softmax_xent():
             return None
-        if not loss_mod._is_softmax(self._act()):
-            return None
-        from deeplearning4j_tpu.ops import xent_kernel as xk
-
-        if not xk.xent_helper_enabled():
-            return None
-        W = params.get("W")
-        if W is None or jnp.ndim(W) != 2 or jnp.ndim(labels) < 2:
-            return None
-        x2 = _flatten_if_needed(x)
-        if (x2.shape[-1] != W.shape[0] or labels.shape[-1] != W.shape[1]
-                or x2.shape[:-1] != labels.shape[:-1]):
-            return None
-        xc, Wc = ops._mixed_cast(x2, W)
-        if xc.dtype not in (jnp.float32, jnp.bfloat16):
-            return None
-        n = 1
-        for s in x2.shape[:-1]:
-            n *= int(s)
-        # under a data mesh each device runs the kernel on its own rows
-        # (GSPMD would gather the batch around the custom call), so the
-        # block plan is for the per-device row count; a mesh that shards
-        # anything else keeps the XLA path
-        from deeplearning4j_tpu.parallel import mesh as mesh_mod
-
-        b_dev = mesh_mod.per_device_batch(x2.shape[0])
-        if not b_dev:
-            return None
-        p = xk.plan(n // x2.shape[0] * b_dev, Wc.shape[0], Wc.shape[1],
-                    xc.dtype)
-        if p is None:
-            return None
-        bias = (params["b"] if self.has_bias and "b" in params
-                else jnp.zeros((Wc.shape[1],), jnp.float32))
-        interpret = jax.default_backend() != "tpu"
-
-        def rows(x_, w_, b_, t_):
-            return xk.linear_xent_rows(x_, w_, b_, t_, p, interpret)
-
-        per_row = mesh_mod.per_batch_shard(
-            rows, (xc.reshape(n, xc.shape[-1]), Wc, bias,
-                   labels.reshape(n, labels.shape[-1])),
-            (True, False, False, True))
-        return per_row.reshape(labels.shape[:-1])
+        return fused_linear_xent(_flatten_if_needed(x), params.get("W"),
+                                 self._bias(params), labels)
 
     def _index_xent_per_example(self, params, x, labels):
         """Integer class labels ([b] or [b, t]) on a softmax head with
         mcxent: the head and the loss in row blocks
-        (`losses.sparse_xent_rows`), so no [.., n_out] label array exists.
+        (`losses.sparse_xent`), so no [.., n_out] label array exists.
         None for dense labels or another loss (-> the paths below; an
         integer label is then expanded by `losses.compute`)."""
         if (not jnp.issubdtype(jnp.result_type(labels), jnp.integer)
-                or self._loss_name() not in ("mcxent", "negativeloglikelihood")
-                or not loss_mod._is_softmax(self._act())):
+                or not self._softmax_xent()):
             return None
         x2 = x if jnp.ndim(x) == jnp.ndim(labels) + 1 else _flatten_if_needed(x)
         if x2.shape[:-1] != labels.shape:
             return None
-        bias = (params["b"],) if self.has_bias and "b" in params else ()
-
-        def rows(x_, l_, w_, *b_):
-            return loss_mod.sparse_xent_rows(x_, w_, b_[0] if b_ else None, l_)
-
-        args = (x2.reshape(-1, x2.shape[-1]), labels.reshape(-1), params["W"]) + bias
-        # under a data mesh each device loops over the blocks of its own
-        # rows: a sequential loop over the batch-sharded axis would make
-        # GSPMD gather x on every device
-        from deeplearning4j_tpu.parallel import mesh as mesh_mod
-
-        if mesh_mod.per_device_batch(x2.shape[0]):
-            per_row = mesh_mod.per_batch_shard(
-                rows, args, (True, True, False) + (False,) * len(bias))
-        else:
-            per_row = rows(*args)
-        return per_row.reshape(labels.shape)
+        return loss_mod.sparse_xent(x2, params["W"], self._bias(params), labels)
 
     def compute_loss(self, params, x, labels, *, state, mask=None, rng=None):
         per_example = self._index_xent_per_example(params, x, labels)

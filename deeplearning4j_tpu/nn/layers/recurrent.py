@@ -45,21 +45,8 @@ from deeplearning4j_tpu.nn.layers.base import (
     column_parallel_specs,
     register_layer,
 )
+from deeplearning4j_tpu.ops import fused_lstm
 from deeplearning4j_tpu.ops import linear as ops
-
-
-def chunked_lstm_auto_regime(batch: int, timesteps: int, n_hidden: int,
-                             dtype) -> bool:
-    """Measured-win regime for AUTO admission of the time-chunked LSTM
-    kernels. The round-5 A/Bs backing auto-admission were taken at
-    b=8/n=256 (1.99x at t=1024, 3.03x at t=4096 vs XLA scan, f32,
-    BENCH_DETAIL['ab']); ADVICE.md r5 flagged that admitting EVERY f32
-    t>=1024 shape extrapolates to unmeasured large-batch / narrow-cell
-    points where XLA's full-batch per-step gemms feed the MXU better. So
-    auto stays in a small-batch, wide-cell neighborhood of the measured
-    points; everything else needs the DL4J_TPU_PALLAS_LSTM=1 opt-in."""
-    return (dtype == jnp.float32 and timesteps >= 1024
-            and batch <= 16 and n_hidden >= 128)
 
 
 class BaseRecurrent(Layer):
@@ -87,87 +74,19 @@ def _lstm_scan(params, x, carry, gate_fn, act_fn, peephole: bool,
     W = params[prefix + "W"]
     R = params[prefix + "R"]
     b = params[prefix + "b"]
-    n = R.shape[0]
     # hoisted input projection: one big MXU gemm over all timesteps
     zx = ops.bias_add(ops.dot(x, W), b)  # [b, t, 4n]
     # carry dtype must match compute dtype (e.g. f64 gradient checks)
     carry = jax.tree_util.tree_map(lambda c: c.astype(zx.dtype), carry)
     # helper path (cuDNN-helper analogue, ConvolutionLayer.java:74-84
-    # discovery pattern): fused pallas scans (fwd + fused bwd kernels)
-    # for sigmoid/tanh cells, with and without Graves peepholes and
-    # sequence masks (masked steps: zero output, carry-through state —
-    # in-kernel). TWO kernel families with separate admission:
-    #   * full-t resident (lstm_scan) — OPT-IN only
-    #     (DL4J_TPU_PALLAS_LSTM=1): round-3/4 A/Bs measured XLA's scan
-    #     up to 7x faster at short-t shapes, the batch-blocked serial
-    #     grid starving the MXU (pk.lstm_helper_enabled).
-    #   * time-chunked (lstm_scan_chunked, round 5) — zx/hs stream
-    #     through VMEM with (h, c) carried across chunks, reaching the
-    #     long-t regime round 4 called unreachable. AUTO-ADMITTED for
-    #     f32 at t >= 1024 where the full-t kernel cannot fit:
-    #     measured 1.99x (t=1024) / 3.03x (t=4096) vs XLA scan at
-    #     b=8/n=256 (BENCH_DETAIL['ab']); bf16 measured 0.92x and
-    #     stays on XLA unless opted in.
-    # A reverse scan is the same recurrence on the time-flipped input
-    # (mask flipped with it).
-    if (zx.dtype in (jnp.float32, jnp.bfloat16)
-            and gate_fn is act_mod.get("sigmoid")
-            and act_fn is act_mod.get("tanh")):
-        from deeplearning4j_tpu.ops import pallas_kernels as pk
-        from deeplearning4j_tpu.parallel import mesh as mesh_mod
-
-        # under a data mesh each device runs the scan on its own rows
-        # (parallel/mesh.py per_batch_shard), so regime and block plans
-        # are judged on the per-device batch; a mesh that shards
-        # anything else keeps the lax.scan path
-        b_dev = mesh_mod.per_device_batch(zx.shape[0])
-        shape_dev = (b_dev,) + tuple(zx.shape[1:])
-        mode = pk.lstm_helper_mode()
-        forced = pk.helpers_enabled() and mode == "forced"
-        auto = (pk.helpers_enabled() and mode != "off"
-                and chunked_lstm_auto_regime(b_dev, zx.shape[1], n,
-                                             zx.dtype))
-        if b_dev and (forced or auto):
-            interp = jax.default_backend() != "tpu"
-            zk = jnp.flip(zx, axis=1) if reverse else zx
-            mk = None
-            if mask is not None:
-                mk = jnp.flip(mask, axis=1) if reverse else mask
-            # R joins the compute dtype: under the mixed policy params are
-            # f32 while activations are bf16, and the custom-vjp's scan
-            # reference needs one consistent carry dtype
-            Rk = R.astype(zx.dtype)
-            peep = None
-            if peephole:
-                peep = jnp.stack([params[prefix + "pi"],
-                                  params[prefix + "pf"],
-                                  params[prefix + "po"]]).astype(zx.dtype)
-            # the kernels own their memory models: full-t when opted in
-            # and it fits, else the chunked plan
-            bb = pk.pick_lstm_block(shape_dev, zk.dtype) if forced else 0
-            plan = pk.pick_lstm_chunk(shape_dev, zk.dtype,
-                                      masked=mk is not None)
-            if bb or plan:
-                def scan_kernel(zk_, h0_, c0_, mk_, Rk_, p_):
-                    if bb and peephole:
-                        return pk.lstm_scan_peephole(
-                            zk_, Rk_, p_, h0_, c0_, bb, interp, mk_)
-                    if bb:
-                        return pk.lstm_scan(zk_, Rk_, h0_, c0_, bb, interp,
-                                            mk_)
-                    cb, tc = plan
-                    if peephole:
-                        return pk.lstm_scan_chunked_peephole(
-                            zk_, Rk_, p_, h0_, c0_, cb, tc, interp, mk_)
-                    return pk.lstm_scan_chunked(zk_, Rk_, h0_, c0_, cb, tc,
-                                                interp, mk_)
-
-                hs, hT, cT = mesh_mod.per_batch_shard(
-                    scan_kernel, (zk, carry[0], carry[1], mk, Rk, peep),
-                    (True, True, True, True, False, False))
-                if reverse:
-                    hs = jnp.flip(hs, axis=1)
-                return hs, (hT, cT)
+    # discovery pattern): the fused kernels know sigmoid/tanh cells only;
+    # `fused_lstm` owns the rest of their admission
+    if gate_fn is act_mod.get("sigmoid") and act_fn is act_mod.get("tanh"):
+        peep = (tuple(params[prefix + k] for k in ("pi", "pf", "po"))
+                if peephole else None)
+        got = fused_lstm(zx, R, carry[0], carry[1], peep, mask, reverse)
+        if got is not None:
+            return got
 
     zx_t = jnp.swapaxes(zx, 0, 1)  # [t, b, 4n]
     if mask is not None:

@@ -25,6 +25,7 @@ from typing import Callable, Dict, Optional, Union
 import jax
 import jax.numpy as jnp
 
+from deeplearning4j_tpu.ops import kernel_call
 from deeplearning4j_tpu.ops import linear as ops
 
 EPS = 1e-7
@@ -205,6 +206,25 @@ def sparse_xent_rows(x, w, b, labels, block_rows: int = 2048):
     return per.reshape(n)
 
 
+def sparse_xent(x, w, b, labels):
+    """`sparse_xent_rows` over x [b, .., f] and integer labels [b, ..],
+    scores [b, ..]. Under a data mesh each device loops over the blocks
+    of its own rows (kernel_call.per_batch_shard): a sequential loop over
+    the batch-sharded axis would make GSPMD gather x on every device."""
+    bias = () if b is None else (b,)
+
+    def rows(x_, l_, w_, *b_):
+        return sparse_xent_rows(x_, w_, b_[0] if b_ else None, l_)
+
+    args = (x.reshape(-1, x.shape[-1]), labels.reshape(-1), w) + bias
+    if kernel_call.per_device_batch(x.shape[0]):
+        per_row = kernel_call.per_batch_shard(
+            rows, args, (True, True, False) + (False,) * len(bias))
+    else:
+        per_row = rows(*args)
+    return per_row.reshape(labels.shape)
+
+
 def compute(
     loss: Union[str, Callable],
     labels: jnp.ndarray,
@@ -252,7 +272,7 @@ def compute(
 
 def reduce_score(per_example, mask: Optional[jnp.ndarray] = None):
     """Masked-mean reduction of per-example scores — the shared tail of
-    `compute`, also used by fused loss paths (ops/xent_kernel.py) that
+    `compute`, also used by fused loss paths (`ops.fused_linear_xent`) that
     produce per-example scores without a [.., features] tensor."""
     if mask is not None:
         m = mask

@@ -6,6 +6,10 @@ truncated BPTT. Attention + ring attention are the net-new TPU-first
 capabilities the north star requires, so the primitives live here in `ops`
 next to the matmul/conv wrappers.
 
+`attend` is the one entry the layers call: it picks among the
+formulations below, ring attention (ops/ring.py) and the Pallas flash
+kernels (ops/pallas_kernels.py) by `choose_impl`'s rule.
+
 Three formulations, all numerically the softmax(QKᵀ/√d)·V contraction:
 
   sdpa           — one fused einsum chain; XLA fuses scale/mask/softmax into
@@ -14,7 +18,7 @@ Three formulations, all numerically the softmax(QKᵀ/√d)·V contraction:
   blockwise      — lax.scan over key/value chunks with an online (running
                    max/sum) softmax — the flash-attention recurrence. O(t)
                    memory instead of O(t²); also the inner loop reused by
-                   ring attention (parallel/ring.py), where the "next chunk"
+                   ring attention (ops/ring.py), where the "next chunk"
                    arrives over ICI instead of from HBM.
   online_block   — one online-softmax accumulation step, shared by blockwise
                    and ring attention.
@@ -31,7 +35,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from deeplearning4j_tpu.ops import kernel_call
 from deeplearning4j_tpu.ops import linear as ops
+from deeplearning4j_tpu.ops import pallas_kernels as pk
 
 NEG_INF = -1e30  # finite ⇒ fully-masked rows give exp(·)=0, never NaN
 
@@ -132,7 +138,7 @@ def online_chunks(acc, q, k, v, *, scale, mask=None, causal=False,
                   q_offset=0, k_offset=0, block_size: int = 512):
     """Scan K/V chunks of `block_size` into an online-softmax state —
     the shared flash inner loop behind `blockwise` and ring attention's
-    per-hop chunking (parallel/ring.py). Ragged tails are PADDED (padded
+    per-hop chunking (ops/ring.py). Ragged tails are PADDED (padded
     keys masked dead), never silently widened back to one full block:
     peak memory stays O(tq · block_size) regardless of tk. Offsets are
     the global positions of the q block and of k[0] (traced or static)."""
@@ -182,3 +188,75 @@ def blockwise(
     acc = online_chunks(online_init(q), q, k, v, scale=scale, mask=mask,
                         causal=causal, block_size=block_size)
     return online_finish(acc).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# the door: which implementation runs a layer's attention
+# ---------------------------------------------------------------------------
+
+
+def choose_impl(impl: str, b: int, t: int, d: int, masked: bool,
+                seq_axis: Optional[str] = None) -> str:
+    """'ring' | 'blockwise' | 'flash' | 'sdpa' for self-attention over
+    [b, h, t, d] heads: a rule on what the call site can see — the layer's
+    request (`attention_impl`), an active sequence axis, the backend, the
+    shapes and the ambient mesh — and nothing else. There is no compile
+    probe: a shape this rule admits and Mosaic refuses fails the step's
+    compile with the kernel's name (which carries the shape) in the error.
+
+    A sequence axis means ring attention whatever was asked for. 'auto'
+    admits the flash kernels on TPU only, from t >= 512 (below that XLA's
+    materialized-scores path holds while the scores fit on-chip; set by
+    builder A/Bs in July, no driver number — ROADMAP S3); an explicit
+    'pallas' skips the backend and length gates (CPU tests run it
+    interpreted). Shape rule: no key-padding mask, block-aligned t, head
+    dim 64 or lane-aligned. Mesh rule: the kernel runs per batch shard
+    (kernel_call.per_batch_shard): 'auto' declines when the batch does not
+    split evenly or the mesh shards anything else; an explicit request
+    there raises in per_batch_shard."""
+    if seq_axis is not None:
+        return "ring"
+    if impl == "blockwise":
+        return "blockwise"
+    if impl not in ("pallas", "auto"):
+        return "sdpa"
+    auto = impl == "auto"
+    on_tpu = jax.default_backend() == "tpu"
+    if auto and not (pk.helpers_enabled() and on_tpu and t >= 512):
+        return "sdpa"
+    if masked or not (t <= 128 or t % 128 == 0):
+        return "sdpa"
+    if on_tpu and d % 128 != 0 and d != 64:
+        return "sdpa"
+    if auto and not kernel_call.per_device_batch(b):
+        return "sdpa"
+    return "flash"
+
+
+def attend(q, k, v, *, causal: bool, mask: Optional[jnp.ndarray] = None,
+           impl: str = "auto", block_size: int = 512) -> jnp.ndarray:
+    """Self-attention o [b, h, t, d] of q, k, v [b, h, t, d] by the
+    implementation `choose_impl` names: everything between a layer's
+    heads and its output projection. `impl` is the layer's
+    `attention_impl`, `mask` its [b, t] key-padding mask; `block_size`
+    chunks the keys of the ring and blockwise recurrences."""
+    from deeplearning4j_tpu.ops import ring  # ring.py builds on this module
+
+    b, _, t, d = q.shape
+    axis = ring.active_sequence_axis()
+    how = choose_impl(impl, b, t, d, mask is not None, axis)
+    if how == "ring":
+        return ring.ring_attention_sharded(
+            q, k, v, axis_name=axis, mask=mask, causal=causal,
+            block_size=block_size)
+    if how == "blockwise":
+        return blockwise(q, k, v, mask=mask, causal=causal,
+                         block_size=block_size)
+    if how == "flash":
+        bq, bk = pk.pick_flash_blocks(t, d, q.dtype)
+        interpret = kernel_call.interpret()
+        return kernel_call.per_batch_shard(
+            lambda q_, k_, v_: pk.flash_attention(q_, k_, v_, causal, None,
+                                                  bq, bk, interpret),
+            (q, k, v), (True, True, True))
+    return sdpa(q, k, v, mask=mask, causal=causal)

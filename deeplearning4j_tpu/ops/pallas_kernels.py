@@ -26,17 +26,19 @@ q-block, dkv kernel per k-block). Numerics match the XLA formulations
 (CuDNNGradientChecks-pattern equivalence tests); an over-VMEM-budget LSTM
 bwd falls back to the XLA-recompute vjp.
 
-Admission (helpers_enabled + the per-layer shape rules in nn/layers): on
-by default on TPU backends, off on CPU (where `interpret=True` would be
+Admission: each family has ONE entry function that owns its gate, shape
+and mesh rules, block plan, per-shard mapping and fallback, and the layers
+call nothing else — `ops.attention.attend` for the flash kernels,
+`fused_lstm` and `fused_affine_act` here, `xent_kernel.fused_linear_xent`.
+On by default on TPU backends, off on CPU (where `interpret=True` would be
 slower than XLA); override with DL4J_TPU_PALLAS=1/0. The full-resident
 LSTM kernels are additionally OPT-IN via DL4J_TPU_PALLAS_LSTM=1, bn_act
 via DL4J_TPU_PALLAS_CONVBN=1. Admission is a rule on shapes, dtypes, the
 backend and the ambient mesh — never a trial compile: a kernel Mosaic
 refuses fails the enclosing step's compile, and every pallas_call is
 named for its family and shape (`kernel_name`) so the error, the HLO and a
-profiler trace all say which call it was. Under a device mesh the call
-sites run each kernel per batch shard (parallel/mesh.py
-per_batch_shard); GSPMD has no partitioning rule for the custom call.
+profiler trace all say which call it was. Under a device mesh each kernel
+runs per batch shard (ops/kernel_call.py); GSPMD cannot partition it.
 """
 from __future__ import annotations
 
@@ -49,6 +51,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deeplearning4j_tpu.ops import kernel_call
 from deeplearning4j_tpu.util import envflags
 from deeplearning4j_tpu.util.cotangent import zeros_cotangent
 
@@ -71,37 +74,14 @@ def helpers_enabled() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def lstm_helper_enabled() -> bool:
-    """Opt-in gate for the fused LSTM kernels (on top of helpers_enabled).
-
-    Round-3 long-window in-session A/B (docs/DEVNOTES.md 'Honest
-    benchmarking'): at the flagship char-RNN shape (b=64, t=64, n=256,
-    f32) the XLA lax.scan grad step measures ~0.12 ms vs ~0.81 ms for
-    the kernel fwd+bwd pair — XLA's full-batch per-step gemms with
-    cross-step pipelining beat the kernel's batch-blocked serial grid by
-    ~7x in clean conditions (round 2's opposite verdict came from short,
-    contention-noisy windows); round 4 re-measured 0.38x there. Round 5
-    RESOLVED the long-t question: the time-chunked rework
-    (lstm_scan_chunked — the full-t kernels could never fit t >= 1024)
-    reaches the regime and WINS it, 1.99x at b=8/t=1024/n=256 f32 and
-    3.03x at t=4096 (fwd+bwd A/B, BENCH_DETAIL['ab']), so the chunked
-    kernels are AUTO-admitted for f32 at t >= 1024 WITHOUT this env
-    gate (see recurrent._lstm_scan). This opt-in remains for the
-    short-t full-resident kernels (correct, gradchecked, measured
-    slower than XLA there — the cuDNN-helper-left-off contract,
-    ConvolutionLayer.java:74-84 fallthrough) and forces the chunked
-    path in unmeasured regimes (bf16: 0.92x). DL4J_TPU_PALLAS_LSTM=0
-    kills BOTH LSTM kernel families (lstm_helper_mode 'off') without
-    touching the flash/xent helpers."""
-    return lstm_helper_mode() == "forced"
-
-
 def lstm_helper_mode() -> str:
     """Tri-state DL4J_TPU_PALLAS_LSTM: 'forced' (truthy — both kernel
     families admitted wherever their plans fit), 'off' (set falsy — both
     families disabled, the LSTM-specific kill switch that leaves
-    flash/xent helpers alone), 'auto' (unset — chunked kernels in their
-    measured-win regime only)."""
+    flash/xent helpers alone), 'auto' (unset — chunked kernels in
+    `chunked_lstm_auto_regime` only). The full-t resident kernels are
+    opt-in because a builder's A/B from before the benchmark (no driver
+    number) had XLA's lax.scan ahead of them at every shape tried."""
     # only recognised truthy spellings force the kernels on;
     # "0"/"false"/"no"/garbage all mean OFF (envflags spelling contract)
     return envflags.mode("DL4J_TPU_PALLAS_LSTM")
@@ -916,10 +896,9 @@ lstm_scan.defvjp(_lstm_vjp_fwd, _lstm_vjp_bwd)
 
 
 # ---------------------------------------------------------------------------
-# time-chunked LSTM kernels — the long-sequence regime (round 5)
+# time-chunked LSTM kernels — the long-sequence regime
 # ---------------------------------------------------------------------------
-# Round 4 declared the long-t/small-b regime "unreachable by design":
-# the kernels above keep the full [bb, t, 4n] slab VMEM-resident, so at
+# The kernels above keep the full [bb, t, 4n] slab VMEM-resident, so at
 # t=1024/n=256 even one 8-row block exceeds the budget. These variants
 # shed exactly that residency: the grid gains a TIME dimension, zx/hs
 # stream through VMEM one [bb, tc, 4n] chunk at a time, and the (h, c)
@@ -929,9 +908,7 @@ lstm_scan.defvjp(_lstm_vjp_fwd, _lstm_vjp_bwd)
 # is what lets the backward revisit chunks in REVERSE grid order and
 # recompute each chunk's cell states locally (chunked-BPTT recompute, the
 # cudnnRNNBackwardData role at sequence lengths cuDNN handles with its
-# own internal streaming). Measured (BENCH_DETAIL['ab']): the fwd alone
-# wins 1.35x at b=8/t=1024/n=256 f32 and 1.88x at t=4096 vs the XLA
-# lax.scan — the regime the round-4 verdict asked to reach or retire.
+# own internal streaming).
 
 
 def _lstm_chunk_fwd_kernel(zx_ref, r_ref, *rest, tc: int, nt: int,
@@ -1361,25 +1338,94 @@ lstm_scan_chunked_peephole.defvjp(_lstm_chunked_ph_vjp_fwd,
                                   _lstm_chunked_ph_vjp_bwd)
 
 
+def chunked_lstm_auto_regime(batch: int, timesteps: int, n_hidden: int,
+                             dtype) -> bool:
+    """Where AUTO admits the time-chunked LSTM kernels: long float32
+    sequences of a small batch of wide cells — the neighbourhood of
+    b=8, n=256, t=1024 and 4096, where a builder's A/B from before the
+    benchmark (no driver number) had them ahead of XLA's scan; bf16 and
+    every other shape need the DL4J_TPU_PALLAS_LSTM=1 opt-in."""
+    return (dtype == jnp.float32 and timesteps >= 1024
+            and batch <= 16 and n_hidden >= 128)
+
+
+def fused_lstm(zx, R, h0, c0, peep=None, mask=None, reverse: bool = False):
+    """The sigmoid/tanh LSTM recurrence over the projected inputs
+    zx [b, t, 4n] through a fused kernel: (hs [b, t, n], (hT, cT)), or
+    None when none is admitted here and the caller keeps its lax.scan.
+    `peep` = (pi, pf, po) for Graves peepholes, `mask` [b, t] (masked
+    steps: zero output, carry-through state — in-kernel); a reverse scan
+    is the same recurrence on the time-flipped input and mask.
+
+    Two families (`lstm_helper_mode`): the full-t resident kernels only
+    when forced and `pick_lstm_block` fits; the time-chunked ones when
+    forced, or on their own in `chunked_lstm_auto_regime`, wherever
+    `pick_lstm_chunk` fits. Under a data mesh each device scans its own
+    rows, so regime and plans are judged on the per-device batch; a mesh
+    that shards anything else declines."""
+    if zx.dtype not in (jnp.float32, jnp.bfloat16):
+        return None
+    b_dev = kernel_call.per_device_batch(zx.shape[0])
+    mode = lstm_helper_mode()
+    forced = helpers_enabled() and mode == "forced"
+    auto = (helpers_enabled() and mode != "off"
+            and chunked_lstm_auto_regime(b_dev, zx.shape[1], R.shape[0],
+                                         zx.dtype))
+    if not (b_dev and (forced or auto)):
+        return None
+    masked = mask is not None
+    shape_dev = (b_dev,) + tuple(zx.shape[1:])
+    # the kernels own their memory models: full-t when opted in and it
+    # fits, else the chunked plan
+    bb = pick_lstm_block(shape_dev, zx.dtype) if forced else 0
+    plan = pick_lstm_chunk(shape_dev, zx.dtype, masked=masked)
+    if not (bb or plan):
+        return None
+    interp = kernel_call.interpret()
+    if reverse:
+        zx = jnp.flip(zx, axis=1)
+        mask = jnp.flip(mask, axis=1) if masked else None
+    # R joins the compute dtype: under the mixed policy params are f32
+    # while activations are bf16, and the custom-vjp's scan reference
+    # needs one consistent carry dtype
+    R = R.astype(zx.dtype)
+    if peep is not None:
+        peep = jnp.stack(peep).astype(zx.dtype)
+
+    def scan_kernel(zx_, h0_, c0_, m_, R_, p_):
+        if bb and p_ is not None:
+            return lstm_scan_peephole(zx_, R_, p_, h0_, c0_, bb, interp, m_)
+        if bb:
+            return lstm_scan(zx_, R_, h0_, c0_, bb, interp, m_)
+        cb, tc = plan
+        if p_ is not None:
+            return lstm_scan_chunked_peephole(zx_, R_, p_, h0_, c0_, cb, tc,
+                                              interp, m_)
+        return lstm_scan_chunked(zx_, R_, h0_, c0_, cb, tc, interp, m_)
+
+    hs, hT, cT = kernel_call.per_batch_shard(
+        scan_kernel, (zx, h0, c0, mask, R, peep),
+        (True, True, True, True, False, False))
+    if reverse:
+        hs = jnp.flip(hs, axis=1)
+    return hs, (hT, cT)
+
+
 def pick_flash_blocks(t: int, d: int, dtype=None) -> Tuple[int, int]:
-    """(bq, bk) for flash_attention, from the round-5 on-chip sweep (the
-    cudnnGetConvolutionForwardAlgorithm role — algorithm/tile selection
-    measured per shape class, BENCH_DETAIL['ab']). The old 128/128
-    default left 2-3x on the table: streaming K/V in 512-wide blocks
-    amortizes the serial-grid overhead that dominated, and at t <= 512
-    a whole-sequence block turns the kernel into one fused pass that
-    BEATS sdpa (1.13x measured) where 128-blocks lost (0.47x).
-    Winners at d=64 (b*h >= 32): t=512 -> (512, 512) 1.13x; t=1024 ->
-    (256, 512) bf16 2.30x / (512, 512) f32 3.44x; t=2048 -> (256, 512)
-    3.44x. The returned blocks always divide t (or t fits in one block):
-    a block that doesn't divide t would make the kernel grid silently
-    drop rows, so unaligned lengths above one block raise instead."""
+    """(bq, bk) for flash_attention: tile selection per shape class (the
+    cudnnGetConvolutionForwardAlgorithm role), from a builder's sweep at
+    d=64 before the benchmark (no driver number): K/V streamed in
+    512-wide blocks, a whole-sequence block at t <= 512, bq 256 for bf16
+    and 512 for f32 above it. The returned blocks always divide t (or t
+    fits in one block): a block that doesn't divide t would make the
+    kernel grid silently drop rows, so unaligned lengths above one block
+    raise instead."""
     if t <= 128:
         return t, t  # one block; flash_attention clamps to t
     if t % 128 != 0:
         raise ValueError(
             f"flash blocks need t % 128 == 0 (or t <= 128), got t={t}; "
-            f"pad the sequence (the layer admission gates on this)")
+            f"pad the sequence (ops.attention.choose_impl gates on this)")
     if t <= 512:
         return t, t
     bk = next(c for c in (512, 256, 128) if t % c == 0)
@@ -1409,9 +1455,8 @@ def pick_flash_blocks(t: int, d: int, dtype=None) -> Tuple[int, int]:
 # (exact gradients, nothing extra saved — the same recompute posture as
 # the chunked LSTM backward).
 #
-# Admission is OPT-IN via DL4J_TPU_PALLAS_CONVBN (bench.py's in-session
-# conv-bn A/B records the per-round evidence; auto stays off until a
-# sustained win is measured — the lstm_helper_mode precedent).
+# Admission is OPT-IN via DL4J_TPU_PALLAS_CONVBN (`fused_affine_act`): auto
+# stays off until a win is measured — the lstm_helper_mode precedent.
 
 
 def convbn_mode() -> str:
@@ -1510,3 +1555,24 @@ def _bn_act_vjp_bwd(act, block_rows, interpret, res, g):
 
 
 bn_act.defvjp(_bn_act_vjp_fwd, _bn_act_vjp_bwd)
+
+
+def fused_affine_act(x, scale, shift, act: str):
+    """y = act(x * scale + shift) through `bn_act`, or None when the
+    epilogue stays on XLA (fused into the producing conv by the
+    compiler): OPT-IN (`convbn_mode` forced), act relu or identity, a
+    block plan for the per-device rows under a data mesh. scale/shift
+    pass through untouched (f32 in normal runs, f64 under x64 gradient
+    checks); the kernel casts to x.dtype exactly as the XLA path does."""
+    if act not in ("relu", "identity") or x.ndim < 2:
+        return None
+    if not (convbn_mode() == "forced" and helpers_enabled()):
+        return None
+    b_dev = kernel_call.per_device_batch(x.shape[0])
+    br = pick_bn_block((b_dev,) + tuple(x.shape[1:]), x.dtype) if b_dev else 0
+    if not br:
+        return None
+    interp = kernel_call.interpret()
+    return kernel_call.per_batch_shard(
+        lambda x_, s_, h_: bn_act(x_, s_, h_, act, br, interp),
+        (x, scale, shift), (True, False, False))

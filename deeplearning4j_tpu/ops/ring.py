@@ -15,9 +15,9 @@ device skips (contributes zeros for) key blocks entirely in its future.
 
 Two entry points:
   ring_attention_sharded — per-shard function, call INSIDE an existing
-      shard_map whose mesh has the sequence axis. This is what the
-      MultiHeadAttention layer dispatches to when `sequence_parallel` is
-      active (see `sequence_parallel` context manager).
+      shard_map whose mesh has the sequence axis. This is what
+      `ops.attention.attend` (the attention layers' one entry) dispatches
+      to when `sequence_parallel` is active.
   ring_attention — convenience wrapper that builds the shard_map over a mesh
       for standalone use/testing.
 """
@@ -39,8 +39,8 @@ _tls = threading.local()
 
 @contextlib.contextmanager
 def sequence_parallel(axis_name: str = "seq"):
-    """While active (during tracing), MultiHeadAttention layers compute
-    ring attention over `axis_name` instead of local SDPA. The enclosing
+    """While active (during tracing), the attention layers compute ring
+    attention over `axis_name` instead of local SDPA. The enclosing
     computation must be shard_mapped over a mesh containing that axis with
     activations sharded [batch, time/axis, features]."""
     prev = getattr(_tls, "seq_axis", None)

@@ -25,12 +25,13 @@ from a [N] int32 vector and touch no [N, V] label bytes at all.
 
 Reference role parity: the cuDNN-helper pattern (ConvolutionLayer.java:
 74-84 discovery + fallthrough); the builtin path remains
-`losses.compute` on XLA. Admission is size-gated (`plan`) and measured
-per round in BENCH_DETAIL["ab"].
+`losses.compute` on XLA. `fused_linear_xent` is the one entry the output
+layer calls: gate, shape rule (`plan`), mesh rule, per-shard call.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -39,6 +40,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deeplearning4j_tpu.ops import kernel_call
+from deeplearning4j_tpu.ops import linear as ops
 from deeplearning4j_tpu.ops import pallas_kernels as pk
 from deeplearning4j_tpu.util import envflags
 from deeplearning4j_tpu.util.cotangent import zeros_cotangent
@@ -85,9 +88,9 @@ def plan(n: int, d: int, v: int, dtype) -> Optional[tuple]:
     divide N and V, a lane-aligned contracting axis, and a vocab wide
     enough that skipping the logits round-trip beats XLA's fused
     reduction (V >= 2048 — below that the [N, V] tensors ride XLA fusion
-    well enough that the builtin path wins; BENCH_DETAIL["ab"] backs the
-    cut). Preferences are the round-5 on-chip sweep winners at the bench
-    shape (N=8192, D=512, V=8192): the fwd wants the biggest row block
+    well enough that the builtin path wins). Cut and preferences are from
+    a builder's sweep before the benchmark at N=8192, D=512, V=8192 (no
+    driver number): the fwd wants the biggest row block
     that coexists with label blocks; the idx backward reads no labels, so
     it doubles the row block again to halve the serial W re-streams."""
     if v < 2048 or d % 128 != 0 or n % 8 != 0:
@@ -354,6 +357,41 @@ def _vjp_bwd(blocks, interpret, res, g):
 
 
 linear_xent_rows.defvjp(_vjp_fwd, _vjp_bwd)
+
+
+def fused_linear_xent(x, w, b, labels):
+    """Per-example softmax cross-entropy of the linear head x [b, .., d] @
+    w [d, v] (+ b, or None) against dense labels [b, .., v] through the
+    fused kernel — scores [b, ..], the logits never in HBM — or None when
+    it is not admitted here and the caller keeps `losses.compute`: the
+    gate, agreeing shapes, a float32 or bf16 head under the mixed policy,
+    a `plan` for the rows ONE device sees. Under a data mesh each device
+    runs the kernel on its own rows (GSPMD would gather the batch around
+    the custom call); a mesh that shards anything else declines."""
+    if (not xent_helper_enabled()
+            or w is None or jnp.ndim(w) != 2 or jnp.ndim(labels) < 2
+            or x.shape[-1] != w.shape[0] or labels.shape[-1] != w.shape[1]
+            or x.shape[:-1] != labels.shape[:-1]):
+        return None
+    xc, wc = ops._mixed_cast(x, w)
+    if xc.dtype not in (jnp.float32, jnp.bfloat16):
+        return None
+    n = math.prod(x.shape[:-1])
+    b_dev = kernel_call.per_device_batch(x.shape[0])
+    if not b_dev:
+        return None
+    p = plan(n // x.shape[0] * b_dev, wc.shape[0], wc.shape[1], xc.dtype)
+    if p is None:
+        return None
+    if b is None:
+        b = jnp.zeros((wc.shape[1],), jnp.float32)
+    interpret = kernel_call.interpret()
+    per_row = kernel_call.per_batch_shard(
+        lambda x_, w_, b_, t_: linear_xent_rows(x_, w_, b_, t_, p, interpret),
+        (xc.reshape(n, xc.shape[-1]), wc, b,
+         labels.reshape(n, labels.shape[-1])),
+        (True, False, False, True))
+    return per_row.reshape(labels.shape[:-1])
 
 
 def linear_xent_reference(x, w, b, labels):

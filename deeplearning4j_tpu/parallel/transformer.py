@@ -11,7 +11,7 @@ requires: one training step that composes
        hidden dim sharded; forward psum after row-split matmuls
        (g-operator), identity-fwd/psum-bwd at branch entry (f-operator),
   sp — sequence (context) parallelism over "seq": activations sharded
-       along time, exact attention via ring ppermute (parallel/ring.py),
+       along time, exact attention via ring ppermute (ops/ring.py),
        position table indexed at global offsets,
   pp — GPipe pipeline parallelism over "pipe": transformer blocks stored
        STACKED [n_layers, ...] and sharded on the layer axis; microbatches
@@ -55,8 +55,8 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.nn import updaters as upd_mod
+from deeplearning4j_tpu.ops import ring
 from deeplearning4j_tpu.parallel import layout as layout_mod
-from deeplearning4j_tpu.parallel import ring
 
 PyTree = Any
 

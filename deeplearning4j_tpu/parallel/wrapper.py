@@ -24,7 +24,7 @@ TPU-native redesign: one process, one jitted SPMD program over a Mesh.
     wrapped in jax.shard_map with activations sharded [b, t/seq, f], and
     tracing runs inside `ring.sequence_parallel('seq')` so every
     MultiHeadAttention computes exact ring attention over ICI
-    (parallel/ring.py) and PositionEmbedding indexes global offsets.
+    (ops/ring.py) and PositionEmbedding indexes global offsets.
     Gradients/losses are combined with mask-weighted psums, so the result
     equals the single-device step to f32 roundoff even with ragged masks.
     Layers that reduce over time (LSTM, pooling) declare sp_safe=False and
@@ -273,7 +273,7 @@ class ParallelWrapper:
             ComputationGraph,
         )
         from deeplearning4j_tpu.nn.layers import base as base_mod
-        from deeplearning4j_tpu.parallel import ring
+        from deeplearning4j_tpu.ops import ring
 
         tuple_args = isinstance(model, ComputationGraph)
         d_ax, s_ax = "data", "seq"
@@ -706,7 +706,7 @@ class ParallelWrapper:
     def _step_scope(self):
         """Entered around every jitted standard-step call (per-step and
         windowed): the mesh is AMBIENT for what the call traces, and Pallas
-        kernel call sites read it to run per batch shard (parallel/mesh.py
+        kernel call sites read it to run per batch shard (ops/kernel_call.py
         per_batch_shard) — GSPMD cannot partition their custom calls.
         The scope holds the jitted call only: eager work under an ambient
         mesh is placed on that mesh, which would strand host-side state

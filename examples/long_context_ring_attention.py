@@ -8,7 +8,7 @@ Run (8 virtual CPU devices; on a real slice drop the env overrides):
 A sequence far longer than one device would want to hold is sharded over
 the mesh's `seq` axis: every device keeps 1/seq_shards of the tokens, and
 exact causal attention is computed by rotating K/V blocks one ICI hop per
-ring step (parallel/ring.py) — no approximation, O(t/n) activation memory
+ring step (ops/ring.py) — no approximation, O(t/n) activation memory
 per device. The same ShardedTransformerLM composes the ring with data and
 tensor parallelism (docs/PARALLELISM.md).
 """

@@ -991,7 +991,7 @@ class TestChunkedLstmAdmission:
     def test_auto_regime_bounds(self):
         """ADVICE r5: auto-admission stays in the measured b=8/n=256
         neighborhood — small batch, wide cell, long f32 sequences."""
-        from deeplearning4j_tpu.nn.layers.recurrent import (
+        from deeplearning4j_tpu.ops.pallas_kernels import (
             chunked_lstm_auto_regime,
         )
 

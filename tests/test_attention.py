@@ -24,7 +24,7 @@ from deeplearning4j_tpu.nn.layers import (
 )
 from deeplearning4j_tpu.models import MultiLayerNetwork
 from deeplearning4j_tpu.ops import attention as att
-from deeplearning4j_tpu.parallel import ring
+from deeplearning4j_tpu.ops import ring
 
 
 def _qkv(rng, b=2, h=4, t=32, d=16, dtype=np.float32):
@@ -231,8 +231,6 @@ def test_flash_attention_d64_matches_sdpa(rng):
     """Head dim 64 (the TransformerLM bench shape) through the pallas
     kernel must match sdpa, and the TPU gate must admit exactly the
     measured shapes: d=64 and lane-aligned d."""
-    from deeplearning4j_tpu.nn.layers.attention import MultiHeadAttention
-    from deeplearning4j_tpu.ops import attention as att
     from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
 
     q, k, v = (jnp.asarray(rng.standard_normal((2, 3, 128, 64)) * 0.3,
@@ -247,33 +245,78 @@ def test_flash_attention_d64_matches_sdpa(rng):
 
     from deeplearning4j_tpu.ops import pallas_kernels as pk
 
-    mha = MultiHeadAttention(n_heads=2, attention_impl="auto")
+    def flash(impl, b, t, d, masked=False):
+        return att.choose_impl(impl, b, t, d, masked) == "flash"
+
     with mock.patch("jax.default_backend", return_value="tpu"), \
             mock.patch.object(pk, "helpers_enabled", return_value=True):
         # admission is the shape rule alone (no compile probe): auto
         # admits t >= 512; below that XLA's materialized-scores path
         # holds
-        assert mha._use_pallas(4, 1024, 64, None)       # long-context path
-        assert mha._use_pallas(4, 2048, 128, None)      # lane-aligned
-        assert mha._use_pallas(4, 512, 64, None)        # bench shape
-        assert not mha._use_pallas(4, 256, 64, None)    # short: sdpa
-        assert not mha._use_pallas(4, 1024, 96, None)   # untileable dim
-        assert not mha._use_pallas(4, 1000, 64, None)   # non-block t
-        assert not mha._use_pallas(4, 1024, 64, object())  # masked input
+        assert flash("auto", 4, 1024, 64)       # long-context path
+        assert flash("auto", 4, 2048, 128)      # lane-aligned
+        assert flash("auto", 4, 512, 64)        # bench shape
+        assert not flash("auto", 4, 256, 64)    # short: sdpa
+        assert not flash("auto", 4, 1024, 96)   # untileable dim
+        assert not flash("auto", 4, 1000, 64)   # non-block t
+        assert not flash("auto", 4, 1024, 64, masked=True)  # masked input
         # explicit request skips the length gate
-        forced = MultiHeadAttention(n_heads=2, attention_impl="pallas")
-        assert forced._use_pallas(4, 256, 64, None)
+        assert flash("pallas", 4, 256, 64)
         # under a data mesh the kernel runs per batch shard: the batch
         # must split evenly; a mesh sharding anything else declines auto
         # and refuses a forced kernel call
         from deeplearning4j_tpu.parallel import MeshSpec, build_mesh
 
         with jax.set_mesh(build_mesh(MeshSpec(data=8))):
-            assert mha._use_pallas(8, 1024, 64, None)
-            assert not mha._use_pallas(6, 1024, 64, None)
-        from deeplearning4j_tpu.parallel import mesh as mesh_mod
+            assert flash("auto", 8, 1024, 64)
+            assert not flash("auto", 6, 1024, 64)
+        from deeplearning4j_tpu.ops import kernel_call
 
         with jax.set_mesh(build_mesh(MeshSpec(data=4, model=2))):
-            assert not mha._use_pallas(8, 1024, 64, None)
+            assert not flash("auto", 8, 1024, 64)
             with pytest.raises(ValueError, match="per 'data' shard"):
-                mesh_mod.per_batch_shard(lambda a: a, (q,), (True,))
+                kernel_call.per_batch_shard(lambda a: a, (q,), (True,))
+
+
+@pytest.mark.parametrize("shape, blocks", [
+    ((8, 12, 1024, 64), (256, 512)),    # gpt2s_train_t1024, _ids
+    ((2, 16, 8192, 256), (256, 512)),   # qwen3next_train_t8192
+])
+def test_benchmark_cells_choose_flash(shape, blocks):
+    """The rule at the shapes the benchmark's cells hand it, on a TPU
+    backend: flash, with the block plan their kernel names carry — a
+    change of admission that would move a cell's numbers fails here
+    first."""
+    import unittest.mock as mock
+
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+    b, _, t, d = shape
+    with mock.patch("jax.default_backend", return_value="tpu"):
+        assert att.choose_impl("auto", b, t, d, masked=False) == "flash"
+    assert pk.pick_flash_blocks(t, d, jnp.bfloat16) == blocks
+
+
+def test_both_attention_layers_go_through_one_door(rng):
+    """MultiHeadAttention and GatedAttention hand their heads to
+    `ops.attention.attend` and to nothing else: whatever the door decides
+    for a shape, it decides for both."""
+    import unittest.mock as mock
+
+    from deeplearning4j_tpu.nn.layers import GatedAttention
+
+    x = jnp.asarray(rng.standard_normal((2, 16, 32)), jnp.float32)
+    itype = it.recurrent(32, 16)
+    seen = []
+
+    def door(q, k, v, **kw):
+        seen.append((q.shape, k.shape, v.shape, kw["causal"]))
+        return att.sdpa(q, k, v, mask=kw.get("mask"), causal=kw["causal"])
+
+    with mock.patch.object(att, "attend", side_effect=door):
+        for layer in (MultiHeadAttention(n_heads=4, causal=True),
+                      GatedAttention(n_heads=4, n_kv_heads=2, head_dim=8)):
+            params = layer.init_params(jax.random.PRNGKey(0), itype)
+            y, _ = layer.apply(params, x, state={}, train=False, rng=None)
+            assert y.shape == x.shape
+    assert seen == [((2, 4, 16, 8),) * 3 + (True,)] * 2

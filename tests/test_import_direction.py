@@ -1,0 +1,37 @@
+"""The arrows between the packages point one way: nn -> ops, parallel -> ops,
+parallel -> nn. A layer reaches a kernel through its family's entry function
+in `ops/` (`ops.attention.attend`, `ops.fused_lstm`, `ops.fused_affine_act`,
+`ops.fused_linear_xent`), never through the kernel modules or `parallel/`."""
+import ast
+import pathlib
+
+import pytest
+
+import deeplearning4j_tpu
+
+ROOT = pathlib.Path(deeplearning4j_tpu.__file__).parent
+
+
+def imported_modules(path):
+    """Every dotted name an import statement of `path` could bind:
+    `import a.b`, `from a import b` (a and a.b), at any depth of the file."""
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.Import):
+            yield from (a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom) and node.module and not node.level:
+            yield node.module
+            yield from (f"{node.module}.{a.name}" for a in node.names)
+
+
+@pytest.mark.parametrize("forbidden", [
+    "deeplearning4j_tpu.parallel",
+    "deeplearning4j_tpu.ops.pallas_kernels",
+    "deeplearning4j_tpu.ops.xent_kernel",
+])
+def test_nn_does_not_import(forbidden):
+    files = sorted((ROOT / "nn").rglob("*.py"))
+    assert len(files) > 20
+    found = [f"{f.relative_to(ROOT)}: {m}" for f in files
+             for m in imported_modules(f)
+             if m == forbidden or m.startswith(forbidden + ".")]
+    assert not found, found

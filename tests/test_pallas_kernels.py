@@ -494,9 +494,9 @@ class TestFusedBackward:
         env = dict(os.environ)
         env.pop("DL4J_TPU_PALLAS_LSTM", None)
         with mock.patch.dict(os.environ, env, clear=True):
-            assert not pk.lstm_helper_enabled()
+            assert pk.lstm_helper_mode() == "auto"
         with mock.patch.dict(os.environ, {"DL4J_TPU_PALLAS_LSTM": "1"}):
-            assert pk.lstm_helper_enabled()
+            assert pk.lstm_helper_mode() == "forced"
 
 
 def test_long_sequence_falls_back_to_scan(rng):

@@ -431,18 +431,39 @@ class TestChaosMisstepAcceptance:
 class TestEngineClosedLoop:
     def test_epoch_ticks_widen_window_from_measured_signals(
             self, monkeypatch):
+        """The loop measures, the epoch tick reads what it measured, the
+        rule's decision re-keys the window the next epoch runs at. WHICH
+        side of the threshold a CPU fit's own host share falls on depends
+        on how loaded the machine is, so the tick is handed the loop's
+        measured step time with the host's share of it pinned at 0.9."""
+        from deeplearning4j_tpu.training import engine as engine_mod
+
+        measured = []
+        real = engine_mod.WindowedFitLoop.tuning_signals
+
+        def host_bound(loop):
+            sig = real(loop)
+            measured.append(dict(sig))
+            return dict(sig, host_overhead_ms=0.9 * sig["step_ms"])
+
+        monkeypatch.setattr(engine_mod.WindowedFitLoop, "tuning_signals",
+                            host_bound)
         monkeypatch.setenv("DL4J_TPU_AUTOTUNE", "1")
         net = _net()
         net.fit(ListDataSetIterator(_iris(), batch=10), epochs=2)
         t = tuner_mod.current()
         assert t is not None and t.ticks >= 2
+        # epoch 1 at K=1: six batches, the first one's compile left out
+        assert measured[0]["window"] == 1 and measured[0]["steps"] == 5
+        assert 0 < measured[0]["host_overhead_ms"] <= measured[0]["step_ms"]
         entries = [e for e in _journal() if e["knob"] == WINDOW]
-        # CPU dispatch is synchronous: host share saturates, the window
-        # rule fires on the first epoch tick
         assert entries and entries[0]["reason"] == "window_host_bound"
-        assert entries[0]["signals"]["host_share"] >= \
+        assert entries[0]["signals"]["host_share"] == 0.9 >= \
             rules_mod.WINDOW_WIDEN_SHARE
+        assert (entries[0]["old"], entries[0]["new"]) == (1, 2)
         assert envflags.effective(WINDOW)[1] == envflags.PROV_TUNER
+        # the loop closed: epoch 2 ran at the window the tick chose
+        assert measured[1]["window"] == 2
 
     def test_gate_off_allocates_zero_tuner_state(self, tmp_path):
         net = _net()
