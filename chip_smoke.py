@@ -298,8 +298,7 @@ def phase_lm(sz: Sizes):
     bh = sz.lm_batch * sz.heads
     rows = sz.lm_batch * sz.seq
     want = [f"dl4j_flash_fwd_bh{bh}_t{sz.seq}_",
-            f"dl4j_flash_bwd_dq_bh{bh}_t{sz.seq}_",
-            f"dl4j_flash_bwd_dkv_bh{bh}_t{sz.seq}_",
+            f"dl4j_flash_bwd_bh{bh}_t{sz.seq}_",
             f"dl4j_xent_fwd_n{rows}_d{sz.d_model}_v{sz.vocab}_",
             f"dl4j_xent_bwd_idx_n{rows}_d{sz.d_model}_v{sz.vocab}_"]
     missing = [w for w in want if not any(k.startswith(w) for k in kernels)]

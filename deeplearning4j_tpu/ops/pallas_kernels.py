@@ -7,9 +7,11 @@ XLA (which fuses conv/BN/elementwise well on its own — no kernel needed),
 so pallas earns its keep only where XLA's generic lowering leaves time on
 the table:
 
-  flash_attention — fused causal/masked attention: one kernel per
-      (batch·head, q-block), online softmax in VMEM, K/V streamed block by
-      block. O(t) memory like ops.attention.blockwise but without
+  flash_attention — fused causal/masked attention: one program per
+      (batch·head, q-block) — or per batch·head at short t — online
+      softmax in VMEM, K/V streamed block by block; only the blocks the
+      diagonal crosses are masked, none that lies in the future is
+      visited. O(t) memory like ops.attention.blockwise but without
       materializing per-block intermediates in HBM; the cuDNN-fused-
       softmax-attention analogue.
   lstm_scan — the fused recurrent loop (cudnnRNNForwardTraining's role):
@@ -21,10 +23,10 @@ the table:
 Backward passes are fused pallas kernels too (round 3): the LSTM bwd runs
 the dh/dc recurrence with cell states recomputed into VMEM scratch
 (cudnnRNNBackwardData/Weights role, CudnnLSTMHelper.java:612), and the
-flash bwd rebuilds P blockwise from the saved logsumexp (dq kernel per
-q-block, dkv kernel per k-block). Numerics match the XLA formulations
-(CuDNNGradientChecks-pattern equivalence tests); an over-VMEM-budget LSTM
-bwd falls back to the XLA-recompute vjp.
+flash bwd rebuilds P blockwise from the saved logsumexp, once a block, in
+one kernel per k-block that also adds up dQ (PR 30). Numerics match the
+XLA formulations (CuDNNGradientChecks-pattern equivalence tests); an
+over-VMEM-budget LSTM bwd falls back to the XLA-recompute vjp.
 
 Admission: each family has ONE entry function that owns its gate, shape
 and mesh rules, block plan, per-shard mapping and fallback, and the layers
@@ -91,67 +93,156 @@ def lstm_helper_mode() -> str:
 _SCOPED_VMEM_DEFAULT = 16 * 2 ** 20   # what Mosaic gives a kernel unasked (v5e)
 _VMEM_CEILING = 110 * 2 ** 20         # of the chip's 128 MiB
 
-
-def _flash_vmem(t: int, d: int, dtype, whole: int, rows: int) -> dict:
-    """`compiler_params` for a flash kernel that keeps `whole` [t, d]
-    operands and `rows` [t, 1] float32 row statistics (a lane-padded
-    [t, 128] tile each) resident, double-buffered. Nothing — Mosaic's own
-    scoped limit — while they fit it with room for the blocks (every shape
-    up to this PR's: t 1024, head 64 needs 1 MiB); a raised limit for a
-    long sequence of wide heads (t 8192, head 256: 16 MiB forward, 32 MiB
-    for dK/dV), which the default refuses at compile time."""
-    need = 2 * (whole * t * d * jnp.dtype(dtype).itemsize + rows * t * 128 * 4)
-    if need <= _SCOPED_VMEM_DEFAULT // 2:
+def _flash_vmem(t: int, d: int, dtype, *, whole: int, rows: int,
+                scores: int) -> dict:
+    """`compiler_params` for a flash kernel that keeps resident,
+    double-buffered, `whole` [t, d] operands or results and `rows` float32
+    row statistics [1, t] (a sublane-padded [8, t] tile each), beside the
+    float32 temporaries of one step over `scores` score elements (S, P,
+    dP, dS). Nothing — Mosaic's own scoped limit — while that fits it
+    with room to spare (t 1024, head 64: 3 MiB); a raised limit for a
+    long sequence of wide heads (t 8192, head 256: 16 MiB of K and V
+    alone), which the default refuses at compile time."""
+    need = (2 * (whole * t * d * jnp.dtype(dtype).itemsize + rows * 8 * t * 4)
+            + 4 * scores * 4)
+    if need <= 3 * _SCOPED_VMEM_DEFAULT // 4:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
-        vmem_limit_bytes=min(need + 32 * 2 ** 20, _VMEM_CEILING))}
+        vmem_limit_bytes=min(need + _SCOPED_VMEM_DEFAULT, _VMEM_CEILING))}
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, bk: int,
-                      causal: bool, scale: float):
-    """One (batch·head, q-block) program. q_ref [bq, d]; k/v_ref [t, d].
-    lse_ref (backward-support variant): per-row logsumexp m + log(l),
-    the statistic the blockwise backward needs to rebuild P without a
-    second online softmax."""
-    bq, d = q_ref.shape
-    t = k_ref.shape[0]
-    qi = pl.program_id(1)
-    q = q_ref[:] * scale
+def _at_most(x, n: int):
+    """min(x, n) for a Python int or a traced scalar."""
+    return min(x, n) if isinstance(x, int) else lax.min(x, jnp.int32(n))
 
-    m = jnp.full((bq, 1), NEG_INF, jnp.float32)
-    l = jnp.zeros((bq, 1), jnp.float32)
-    acc = jnp.zeros((bq, d), jnp.float32)
 
-    nblk = t // bk
+def _q_major_bounds(qi, bq: int, bk: int, nk: int, causal: bool):
+    """Key blocks that the forward walks for q block `qi`: (first masked,
+    end). Blocks [0, first masked) lie wholly on the visible side of the
+    diagonal — their last key is no later than the block's first query —
+    and take no mask; [first masked, end) are the ones the diagonal
+    crosses; from `end` on every key is later than the block's last query
+    and nothing is visited. Works on Python ints and on traced scalars
+    alike (`flash_visits` and the kernels share it)."""
+    if not causal:
+        return nk, nk
+    return (qi * bq) // bk, _at_most(((qi + 1) * bq + bk - 1) // bk, nk)
 
-    def body(j, carry):
-        m, l, acc = carry
-        k_blk = k_ref[pl.ds(j * bk, bk), :]
-        v_blk = v_ref[pl.ds(j * bk, bk), :]
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
-        if causal:
-            q_pos = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            k_pos = j * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m - m_new)
-        l = l * corr + p.sum(axis=-1, keepdims=True)
-        acc = acc * corr + jnp.dot(p.astype(v_blk.dtype), v_blk,
-                                   preferred_element_type=jnp.float32)
-        return m_new, l, acc
 
-    if causal:
-        # blocks fully in the future contribute nothing: stop after the
-        # diagonal block of this q block
-        last = (qi + 1) * bq  # exclusive key bound
-        nloop = lax.min(pl.cdiv(last, jnp.int32(bk)), jnp.int32(nblk))
+def _k_major_bounds(kj, bq: int, bk: int, nq: int, causal: bool):
+    """q blocks that the backward walks for key block `kj`: (start, first
+    unmasked). Blocks before `start` end before the first key and are not
+    visited; [start, first unmasked) are the ones the diagonal crosses;
+    [first unmasked, nq) see the whole key block."""
+    if not causal:
+        return 0, 0
+    return (kj * bk) // bq, _at_most(((kj + 1) * bk + bq - 1) // bq, nq)
+
+
+def flash_visits(t: int, bq: int, bk: int, causal: bool) -> dict:
+    """Which (q block, key block) pairs each kernel visits, and whether it
+    masks them: {"q_major": [(qi, kj, masked), ...] (the forward, which
+    walks key blocks for a q block), "k_major": [...] (the backward,
+    which walks q blocks for a key block)} — the kernels' own loop
+    bounds, spelled out for the tests."""
+    nq, nk = t // bq, t // bk
+    q_major, k_major = [], []
+    for qi in range(nq):
+        first_masked, end = _q_major_bounds(qi, bq, bk, nk, causal)
+        q_major += [(qi, kj, kj >= first_masked) for kj in range(end)]
+    for kj in range(nk):
+        start, unmasked = _k_major_bounds(kj, bq, bk, nq, causal)
+        k_major += [(qi, kj, qi < unmasked) for qi in range(start, nq)]
+    return {"q_major": q_major, "k_major": k_major}
+
+
+def _dot_nt(a, b):
+    """a [m, k] · b [n, k]ᵀ -> float32 [m, n]; the MXU takes the second
+    operand transposed as it loads it, no transposed copy is built."""
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _walk(whole: bool, lo, hi, blk: int, fn, carry):
+    """carry = fn(carry, offset, width) over the blocks [lo, hi) of `blk`
+    rows. A q or key block a program (bounds traced): a fori_loop, one
+    block a step. A whole head a program (bounds static): ONE step over
+    the whole range — no loop, no rescale between blocks, every offset a
+    constant."""
+    if whole:
+        return fn(carry, lo * blk, (hi - lo) * blk) if hi > lo else carry
+    return lax.fori_loop(
+        lo, hi, lambda j, c: fn(c, pl.multiple_of(j * blk, blk), blk), carry)
+
+
+def _causal_keep(rows: int, cols: int, row0, col0, transposed=False):
+    """[rows, cols] bool: query row0 + r sees key col0 + c — or, transposed
+    (rows are keys, columns queries), key row0 + r is seen by query
+    col0 + c."""
+    rel = (lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+           - lax.broadcasted_iota(jnp.int32, (rows, cols), 1))
+    return rel <= col0 - row0 if transposed else rel >= col0 - row0
+
+
+def _whole_head(t: int, blk: int) -> bool:
+    """A head is ONE program — its blocks of `blk` rows unrolled, every
+    bound static, the unmasked part of a block's row of scores one step —
+    while that stays a small program (at most 8 blocks) whose widest step
+    of float32 scores stays small ([blk, t] within 2 MiB); else a block a
+    program with loops inside. 96 heads of t 1024 and head 64 in blocks
+    of 256, forward + dQ + dK/dV a call: 1.00 ms as ONE program a head,
+    2.31 as a block a program (PERF.md section 6, PR 30)."""
+    return t // blk <= 8 and blk * t <= 2 ** 19
+
+
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, bq: int,
+                      bk: int, whole: bool, causal: bool, scale: float):
+    """One (batch·head, q-block) program — q_ref [bq, d] — or one
+    batch·head program that walks its q blocks itself — q_ref [t, d];
+    k/v_ref [t, d]. lse_ref (backward-support variant): per-row logsumexp
+    m + log(l), the statistic the blockwise backward needs to rebuild P
+    without a second online softmax."""
+    d = q_ref.shape[1]
+    nk = k_ref.shape[0] // bk
+
+    def q_block(qi, rows):
+        q = q_ref[rows, :] * scale
+
+        def step(carry, k0, width, masked):
+            m, l, acc = carry
+            k_blk = k_ref[pl.ds(k0, width), :]
+            v_blk = v_ref[pl.ds(k0, width), :]
+            s = _dot_nt(q, k_blk)
+            if masked:
+                s = jnp.where(_causal_keep(bq, width, qi * bq, k0), s, NEG_INF)
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m - m_new)
+            l = l * corr + p.sum(axis=-1, keepdims=True)
+            acc = acc * corr + jnp.dot(p.astype(v_blk.dtype), v_blk,
+                                       preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        carry = (jnp.full((bq, 1), NEG_INF, jnp.float32),
+                 jnp.zeros((bq, 1), jnp.float32),
+                 jnp.zeros((bq, d), jnp.float32))
+        first_masked, end = _q_major_bounds(qi, bq, bk, nk, causal)
+        carry = _walk(whole, 0, first_masked, bk,
+                      functools.partial(step, masked=False), carry)
+        m, l, acc = _walk(whole, first_masked, end, bk,
+                          functools.partial(step, masked=True), carry)
+        o_ref[rows, :] = (acc / jnp.maximum(l, 1e-37)).astype(o_ref.dtype)
+        if lse_ref is not None:
+            # a row [1, bq], the layout the backward reads: the column,
+            # spread over the lanes, goes through one transpose
+            lse = m + jnp.log(jnp.maximum(l, 1e-37))
+            lse_ref[:, rows] = jnp.broadcast_to(lse, (bq, 128)).T[:1]
+
+    if whole:
+        for qi in range(q_ref.shape[0] // bq):
+            q_block(qi, pl.ds(qi * bq, bq))
     else:
-        nloop = nblk
-    m, l, acc = lax.fori_loop(0, nloop, body, (m, l, acc))
-    o_ref[:] = (acc / jnp.maximum(l, 1e-37)).astype(o_ref.dtype)
-    if lse_ref is not None:
-        lse_ref[:] = (m + jnp.log(jnp.maximum(l, 1e-37)))
+        q_block(pl.program_id(1), slice(None))
 
 
 def _flash_fwd(q, k, v, *, causal: bool, scale: float, bq: int, bk: int,
@@ -160,29 +251,33 @@ def _flash_fwd(q, k, v, *, causal: bool, scale: float, bq: int, bk: int,
     qf = q.reshape(b * h, t, d)
     kf = k.reshape(b * h, t, d)
     vf = v.reshape(b * h, t, d)
-    grid = (b * h, t // bq)
-    kernel = functools.partial(_flash_fwd_kernel, bk=bk, causal=causal,
-                               scale=scale)
+    whole = _whole_head(t, bq)
+    rows = t if whole else bq
+    grid = (b * h, t // rows)
+    kernel = functools.partial(_flash_fwd_kernel, bq=bq, bk=bk, whole=whole,
+                               causal=causal, scale=scale)
     out_shape = jax.ShapeDtypeStruct((b * h, t, d), q.dtype)
-    out_spec = pl.BlockSpec((None, bq, d), lambda i, j: (i, j, 0))
+    out_spec = pl.BlockSpec((None, rows, d), lambda i, j: (i, j, 0))
     if return_lse:
         out_shape = (out_shape,
-                     jax.ShapeDtypeStruct((b * h, t, 1), jnp.float32))
+                     jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32))
         out_spec = (out_spec,
-                    pl.BlockSpec((None, bq, 1), lambda i, j: (i, j, 0)))
+                    pl.BlockSpec((None, 1, rows), lambda i, j: (i, 0, j)))
     got = pl.pallas_call(
         kernel,
         out_shape=out_shape,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, bq, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((None, rows, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0)),
         ],
         out_specs=out_spec,
         name=kernel_name("flash_fwd", q.dtype, bh=b * h, t=t, d=d, bq=bq, bk=bk),
         interpret=interpret,
-        **_flash_vmem(t, d, q.dtype, whole=2, rows=0),
+        # K, V (+ q, o: a head a program)
+        **_flash_vmem(t, d, q.dtype, whole=4 if whole else 2,
+                      rows=int(return_lse), scores=bq * (t if whole else bk)),
     )(qf, kf, vf)
     if return_lse:
         out, lse = got
@@ -197,9 +292,9 @@ def flash_attention(q, k, v, causal: bool = True,
     """Fused attention o = softmax(qkᵀ·scale)v over [b, h, t, d].
 
     t must divide by the block sizes (pad upstream); numerics match
-    ops.attention.sdpa. Backward is the blockwise pallas pair
-    (_flash_bwd_dq_kernel / _flash_bwd_dkv_kernel) rebuilding P from the
-    logsumexp saved by the forward — O(t) memory in both directions."""
+    ops.attention.sdpa. Backward is one blockwise pallas kernel
+    (_flash_bwd_kernel) rebuilding P from the logsumexp saved by the
+    forward — O(t) memory in both directions."""
     s = (q.shape[-1] ** -0.5) if scale is None else scale
     bq = min(bq, q.shape[2])
     bk = min(bk, q.shape[2])
@@ -207,83 +302,71 @@ def flash_attention(q, k, v, causal: bool = True,
                       interpret=interpret)
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, *, bk: int, causal: bool, scale: float):
-    """dQ for one (batch·head, q-block): rebuild P blockwise from the
-    saved logsumexp, dS = P ∘ (dO Vᵀ − Δ), dQ = scale · ΣdS K."""
-    bq, d = q_ref.shape
-    t = k_ref.shape[0]
-    qi = pl.program_id(1)
-    q = q_ref[:].astype(jnp.float32) * scale
-    do = do_ref[:].astype(jnp.float32)
-    lse = lse_ref[:]          # [bq, 1] f32
-    delta = delta_ref[:]      # [bq, 1] f32
-    nblk = t // bk
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dqt_ref, *, bq: int, bk: int,
+                      whole: bool, causal: bool, scale: float):
+    """The backward for one (batch·head, k-block) program — or for a whole
+    batch·head, its key blocks walked here. P is rebuilt from the saved
+    logsumexp ONCE a block and transposed from the start (rows are keys):
+    Sᵀ = K Qᵀ [bk, bq], with the row statistics as rows (lse_ref,
+    delta_ref [1, t]), dSᵀ = Pᵀ ∘ (V dOᵀ − Δ). Then dV = ΣPᵀ dO and
+    dK = scale · ΣdSᵀ Q are plain products over the q blocks that attend
+    to this k block, and dQᵀ = scale · Kᵀ dSᵀ adds up, over a head's key
+    blocks, in the float32 scratch dqt_ref [d, t]; dq_ref [t, d] is
+    written once a head."""
+    t, d = q_ref.shape
+    nq = t // bq
 
-    def body(j, dq):
-        k_blk = k_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)
-        v_blk = v_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
-        p = jnp.exp(s - lse)
-        if causal:
-            q_pos = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            k_pos = j * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            p = jnp.where(q_pos >= k_pos, p, 0.0)
-        dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        return dq + jnp.dot(ds, k_blk, preferred_element_type=jnp.float32)
+    def k_block(kj, rows):
+        k_blk = k_ref[rows, :]
+        v_blk = v_ref[rows, :]
 
-    if causal:
-        last = (qi + 1) * bq
-        nloop = lax.min(pl.cdiv(last, jnp.int32(bk)), jnp.int32(nblk))
+        def step(carry, q0, width, masked):
+            dk, dv = carry
+            at = pl.ds(q0, width)
+            q = q_ref[at, :] * scale
+            do = do_ref[at, :]
+            pt = jnp.exp(_dot_nt(k_blk, q) - lse_ref[:, at])
+            if masked:
+                pt = jnp.where(_causal_keep(bk, width, kj * bk, q0, True),
+                               pt, 0.0)
+            dv = dv + jnp.dot(pt.astype(do.dtype), do,
+                              preferred_element_type=jnp.float32)
+            dst = (pt * (_dot_nt(v_blk, do) - delta_ref[:, at])).astype(q.dtype)
+            # q carries the scale already, so dSᵀ q is dK
+            dk = dk + jnp.dot(dst, q, preferred_element_type=jnp.float32)
+            dqt_ref[:, at] += lax.dot_general(
+                k_blk, dst, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return dk, dv
+
+        start, unmasked = _k_major_bounds(kj, bq, bk, nq, causal)
+        carry = _walk(whole, start, unmasked, bq,
+                      functools.partial(step, masked=True),
+                      (jnp.zeros((bk, d), jnp.float32),
+                       jnp.zeros((bk, d), jnp.float32)))
+        dk, dv = _walk(whole, unmasked, nq, bq,
+                       functools.partial(step, masked=False), carry)
+        dk_ref[rows, :] = dk.astype(dk_ref.dtype)
+        dv_ref[rows, :] = dv.astype(dv_ref.dtype)
+
+    def head_done():
+        dq_ref[...] = (dqt_ref[...].T * scale).astype(dq_ref.dtype)
+
+    if whole:
+        dqt_ref[...] = jnp.zeros_like(dqt_ref)
+        for kj in range(t // bk):
+            k_block(kj, pl.ds(kj * bk, bk))
+        head_done()
     else:
-        nloop = nblk
-    dq = lax.fori_loop(0, nloop, body, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[:] = (dq * scale).astype(dq_ref.dtype)
+        kj = pl.program_id(1)
 
+        @pl.when(kj == 0)
+        def _():
+            dqt_ref[...] = jnp.zeros_like(dqt_ref)
 
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, *, bq: int, causal: bool,
-                          scale: float):
-    """dK/dV for one (batch·head, k-block): dV = ΣPᵀ dO,
-    dK = scale · ΣdSᵀ Q over the q blocks that attend to this k block."""
-    bk, d = k_ref.shape
-    t = q_ref.shape[0]
-    ki = pl.program_id(1)
-    k_blk = k_ref[:].astype(jnp.float32)
-    v_blk = v_ref[:].astype(jnp.float32)
-    nblk = t // bq
-
-    def body(i, carry):
-        dk, dv = carry
-        q = q_ref[pl.ds(i * bq, bq), :].astype(jnp.float32) * scale
-        do = do_ref[pl.ds(i * bq, bq), :].astype(jnp.float32)
-        lse = lse_ref[pl.ds(i * bq, bq), :]
-        delta = delta_ref[pl.ds(i * bq, bq), :]
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
-        p = jnp.exp(s - lse)
-        if causal:
-            q_pos = i * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            k_pos = ki * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            p = jnp.where(q_pos >= k_pos, p, 0.0)
-        dv = dv + jnp.dot(p.T, do, preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dk = dk + jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
-        return dk, dv
-
-    if causal:
-        # q blocks strictly before this k block see none of it
-        start = (ki * bk) // bq
-    else:
-        start = 0
-    dk, dv = lax.fori_loop(start, nblk, body,
-                           (jnp.zeros((bk, d), jnp.float32),
-                            jnp.zeros((bk, d), jnp.float32)))
-    # dQ already carries one factor of scale; dK gets the other (s = scale·qkᵀ
-    # was computed with q pre-scaled, so dS·q here is already scaled)
-    dk_ref[:] = dk.astype(dk_ref.dtype)
-    dv_ref[:] = dv.astype(dv_ref.dtype)
+        k_block(kj, slice(None))
+        pl.when(kj == pl.num_programs(1) - 1)(head_done)
 
 
 def _flash_bwd(q, k, v, o, lse, g, *, causal: bool, scale: float, bq: int,
@@ -293,41 +376,28 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal: bool, scale: float, bq: int,
     qf, kf, vf = (a.reshape(bh, t, d) for a in (q, k, v))
     dof = g.reshape(bh, t, d)
     # Δ = rowsum(dO ∘ O): cheap fused elementwise+reduce in XLA
-    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1).reshape(bh, t, 1)
-    lsef = lse.reshape(bh, t, 1)
+    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
 
+    whole = _whole_head(t, bk)
+    krows = t if whole else bk
     seq = pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0))
-    seq1 = pl.BlockSpec((None, t, 1), lambda i, j: (i, 0, 0))
-    qblk = pl.BlockSpec((None, bq, d), lambda i, j: (i, j, 0))
-    qblk1 = pl.BlockSpec((None, bq, 1), lambda i, j: (i, j, 0))
-    kblk = pl.BlockSpec((None, bk, d), lambda i, j: (i, j, 0))
-
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, bk=bk, causal=causal,
-                          scale=scale),
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-        grid=(bh, t // bq),
-        in_specs=[qblk, seq, seq, qblk, qblk1, qblk1],
-        out_specs=qblk,
-        name=kernel_name("flash_bwd_dq", q.dtype, bh=bh, t=t, d=d, bq=bq, bk=bk),
+    row = pl.BlockSpec((None, 1, t), lambda i, j: (i, 0, 0))
+    kblk = pl.BlockSpec((None, krows, d), lambda i, j: (i, j, 0))
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, bq=bq, bk=bk, whole=whole,
+                          causal=causal, scale=scale),
+        out_shape=tuple(jax.ShapeDtypeStruct((bh, t, d), a.dtype)
+                        for a in (q, k, v)),
+        grid=(bh, t // krows),
+        in_specs=[seq, kblk, kblk, seq, row, row],
+        out_specs=(seq, kblk, kblk),
+        scratch_shapes=[pltpu.VMEM((d, t), jnp.float32)],
+        name=kernel_name("flash_bwd", q.dtype, bh=bh, t=t, d=d, bq=bq, bk=bk),
         interpret=interpret,
-        **_flash_vmem(t, d, q.dtype, whole=2, rows=0),
-    )(qf, kf, vf, dof, lsef, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, bq=bq, causal=causal,
-                          scale=scale),
-        out_shape=(jax.ShapeDtypeStruct((bh, t, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, t, d), v.dtype)),
-        grid=(bh, t // bk),
-        in_specs=[seq, kblk, kblk, seq, seq1, seq1],
-        out_specs=(kblk, kblk),
-        name=kernel_name("flash_bwd_dkv", q.dtype, bh=bh, t=t, d=d, bq=bq,
-                    bk=bk),
-        interpret=interpret,
-        **_flash_vmem(t, d, q.dtype, whole=2, rows=2),
-    )(qf, kf, vf, dof, lsef, delta)
+        # q, dO, dQ, the float32 dQᵀ (+ K, V, dK, dV: a head a program)
+        **_flash_vmem(t, d, q.dtype, whole=8 if whole else 4, rows=2,
+                      scores=bk * (t if whole else bq)),
+    )(qf, kf, vf, dof, lse.reshape(bh, 1, t), delta.reshape(bh, 1, t))
     return (dq.reshape(b, h, t, d), dk.reshape(b, h, t, d),
             dv.reshape(b, h, t, d))
 
@@ -1413,13 +1483,20 @@ def fused_lstm(zx, R, h0, c0, peep=None, mask=None, reverse: bool = False):
 
 def pick_flash_blocks(t: int, d: int, dtype=None) -> Tuple[int, int]:
     """(bq, bk) for flash_attention: tile selection per shape class (the
-    cudnnGetConvolutionForwardAlgorithm role), from a builder's sweep at
-    d=64 before the benchmark (no driver number): K/V streamed in
-    512-wide blocks, a whole-sequence block at t <= 512, bq 256 for bf16
-    and 512 for f32 above it. The returned blocks always divide t (or t
-    fits in one block): a block that doesn't divide t would make the
-    kernel grid silently drop rows, so unaligned lengths above one block
-    raise instead."""
+    cudnnGetConvolutionForwardAlgorithm role), and with it how a head is
+    cut into programs (`_whole_head`). Measured on one TPU v5e with the
+    kernels timed alone (PERF.md section 6, PR 30, has the table): one
+    whole-sequence block at t <= 512; square blocks of 256 up to t 2048,
+    where a head is one program whatever its width — head 64, t 1024:
+    0.70 ms a forward + backward call against 0.73 at 512 and 0.84 at
+    128; t 2048: 1.11 against 1.14 at 512; blocks of 512 above, where a
+    block is a program — head 256, t 8192: 26.5 ms against 28.3 at
+    (256, 512) and 30.1 at 256. Square, so that forward and backward mask
+    the same blocks; `d` and `dtype` do not move the choice today (head
+    64 and 256, bf16 and float32 were timed). The
+    returned blocks always divide t (or t fits in one block): a block
+    that doesn't divide t would make the kernel grid silently drop rows,
+    so unaligned lengths above one block raise instead."""
     if t <= 128:
         return t, t  # one block; flash_attention clamps to t
     if t % 128 != 0:
@@ -1428,12 +1505,9 @@ def pick_flash_blocks(t: int, d: int, dtype=None) -> Tuple[int, int]:
             f"pad the sequence (ops.attention.choose_impl gates on this)")
     if t <= 512:
         return t, t
-    bk = next(c for c in (512, 256, 128) if t % c == 0)
-    if dtype == jnp.float32:
-        bq = next(c for c in (512, 256, 128) if t % c == 0)
-    else:
-        bq = next(c for c in (256, 128) if t % c == 0)
-    return bq, bk
+    blk = next(c for c in ((256, 128) if t <= 2048 else (512, 256, 128))
+               if t % c == 0)
+    return blk, blk
 
 
 # ====================================================== conv-bn-relu epilogue

@@ -279,14 +279,14 @@ def test_flash_attention_d64_matches_sdpa(rng):
 
 
 @pytest.mark.parametrize("shape, blocks", [
-    ((8, 12, 1024, 64), (256, 512)),    # gpt2s_train_t1024, _ids
-    ((2, 16, 8192, 256), (256, 512)),   # qwen3next_train_t8192
+    ((8, 12, 1024, 64), (256, 256)),    # gpt2s_train_t1024, _ids
+    ((2, 16, 8192, 256), (512, 512)),   # qwen3next_train_t8192
 ])
 def test_benchmark_cells_choose_flash(shape, blocks):
     """The rule at the shapes the benchmark's cells hand it, on a TPU
-    backend: flash, with the block plan their kernel names carry — a
-    change of admission that would move a cell's numbers fails here
-    first."""
+    backend: flash, with the block plan their kernel names carry (GPT-2:
+    a head a program; Qwen3-Next: a block a program) — a change of
+    admission that would move a cell's numbers fails here first."""
     import unittest.mock as mock
 
     from deeplearning4j_tpu.ops import pallas_kernels as pk
@@ -295,6 +295,7 @@ def test_benchmark_cells_choose_flash(shape, blocks):
     with mock.patch("jax.default_backend", return_value="tpu"):
         assert att.choose_impl("auto", b, t, d, masked=False) == "flash"
     assert pk.pick_flash_blocks(t, d, jnp.bfloat16) == blocks
+    assert pk._whole_head(t, blocks[0]) == (t == 1024)
 
 
 def test_both_attention_layers_go_through_one_door(rng):

@@ -461,27 +461,43 @@ class TestFusedBackward:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                        atol=1e-4, rtol=1e-4)
 
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("plan", [
+        (16, 16),   # bq = bk, a head a program (4 blocks)
+        (8, 16),    # bq < bk
+        (16, 8),    # bq > bk
+        (64, 64),   # one block
+        (4, 4),     # 16 blocks: a q block / a key block a program
+        (4, 8),     # forward a block a program, backward a head
+        (8, 4),     # the other way round
+    ], ids=lambda p: f"{p[0]}x{p[1]}")
     @pytest.mark.parametrize("causal", [False, True])
-    def test_flash_bwd_random_cotangent(self, rng, causal):
-        """dq/dk/dv from the blockwise kernels vs the sdpa vjp under a
-        random (not all-ones) output cotangent."""
+    def test_flash_bwd_random_cotangent(self, rng, causal, plan, dtype):
+        """out and dq/dk/dv from the blockwise kernels vs the sdpa vjp
+        under a random (not all-ones) output cotangent, over square and
+        rectangular plans and both ways a head is cut into programs."""
         b, h, t, d = 2, 2, 64, 16
         q, k, v = (jnp.asarray(rng.standard_normal((b, h, t, d)) * 0.5,
-                               jnp.float32) for _ in range(3))
-        co = jnp.asarray(rng.standard_normal((b, h, t, d)), jnp.float32)
+                               dtype) for _ in range(3))
+        co = jnp.asarray(rng.standard_normal((b, h, t, d)), dtype)
+        bq, bk = plan
 
-        def lk(q, k, v):
-            return (flash_attention(q, k, v, causal, None, 16, 16, True)
-                    * co).sum()
+        def both(f, *a):
+            out, vjp = jax.vjp(f, *a[:3])
+            return (out,) + vjp(a[3].astype(out.dtype))
 
-        def lr(q, k, v):
-            return (att.sdpa(q, k, v, causal=causal) * co).sum()
-
-        gk = jax.grad(lk, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(lr, argnums=(0, 1, 2))(q, k, v)
-        for a, b_ in zip(gr, gk):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
-                                       atol=2e-4, rtol=2e-4)
+        got = both(lambda q, k, v: flash_attention(q, k, v, causal, None,
+                                                   bq, bk, True), q, k, v, co)
+        want = both(lambda q, k, v: att.sdpa(q, k, v, causal=causal),
+                    *(a.astype(jnp.float32) for a in (q, k, v, co)))
+        # float32: the tolerance this test has always had; bf16: its
+        # rounding of the results (2^-8 of values of order 1..4)
+        tol = 2e-4 if dtype == jnp.float32 else 3e-2
+        for a, b_ in zip(want, got):
+            assert b_.dtype == dtype
+            np.testing.assert_allclose(np.asarray(a),
+                                       np.asarray(b_, np.float32),
+                                       atol=tol, rtol=tol)
 
     def test_lstm_kernels_are_opt_in(self, rng):
         """Default policy: the measured-slower LSTM kernel path stays off
@@ -537,21 +553,56 @@ def test_pick_lstm_block_properties():
 
 
 def test_pick_flash_blocks_properties():
-    """Round-5 tuned block picker (pick_flash_blocks): whole-sequence
-    blocks at t <= 512, 512-wide K/V streaming above, always dividing t,
-    falling down the candidate list for odd lengths."""
+    """The block picker (pick_flash_blocks): a whole-sequence block at
+    t <= 512, square blocks of 256 up to t 2048 and of 512 above (PERF.md
+    section 6, PR 30), always dividing t, falling down the candidate list
+    for odd lengths."""
     from deeplearning4j_tpu.ops.pallas_kernels import pick_flash_blocks
 
     assert pick_flash_blocks(512, 64, jnp.bfloat16) == (512, 512)
     assert pick_flash_blocks(256, 64, jnp.bfloat16) == (256, 256)
-    assert pick_flash_blocks(1024, 64, jnp.bfloat16) == (256, 512)
-    assert pick_flash_blocks(1024, 64, jnp.float32) == (512, 512)
-    assert pick_flash_blocks(2048, 64, jnp.bfloat16) == (256, 512)
+    assert pick_flash_blocks(1024, 64, jnp.bfloat16) == (256, 256)
+    assert pick_flash_blocks(1024, 64, jnp.float32) == (256, 256)
+    assert pick_flash_blocks(2048, 64, jnp.bfloat16) == (256, 256)
+    assert pick_flash_blocks(4096, 64, jnp.bfloat16) == (512, 512)
+    assert pick_flash_blocks(8192, 256, jnp.bfloat16) == (512, 512)
+    assert pick_flash_blocks(1280, 64, jnp.bfloat16) == (256, 256)
+    assert pick_flash_blocks(4224, 64, jnp.bfloat16) == (128, 128)  # 33*128
     bq, bk = pick_flash_blocks(640, 64, jnp.float32)  # 640 = 5*128
     assert 640 % bq == 0 and 640 % bk == 0
     assert pick_flash_blocks(96, 64, jnp.float32) == (96, 96)  # one block
     with pytest.raises(ValueError, match="t % 128"):
         pick_flash_blocks(200, 64, jnp.float32)  # would drop rows
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("t, plan", [
+    (t, (bq, bk)) for t in (128, 384, 1024, 2048)
+    for bq, bk in ((128, 128), (64, 128), (128, 64), (256, 512), (512, 256),
+                   (256, 256), (128, 384), (384, 128), (2048, 512), (t, t))
+    if t % bq == 0 and t % bk == 0],
+    ids=lambda v: v if isinstance(v, int) else f"{v[0]}x{v[1]}")
+def test_flash_visits_cover_the_triangle(t, plan, causal):
+    """The (q block, key block) pairs the kernels walk — their own loop
+    bounds, listed by `flash_visits` — against the triangle: no pair
+    wholly in the future is visited, exactly the pairs the diagonal
+    crosses are masked, and every visible element lies in exactly one
+    visited pair; both for the kernel that walks key blocks (forward) and
+    for the one that walks q blocks (backward)."""
+    from deeplearning4j_tpu.ops.pallas_kernels import flash_visits
+
+    bq, bk = plan
+    rows, cols = np.arange(t)[:, None], np.arange(t)[None, :]
+    visible = (cols <= rows) if causal else np.ones((t, t), bool)
+    for order, pairs in flash_visits(t, bq, bk, causal).items():
+        assert len(set((qi, kj) for qi, kj, _ in pairs)) == len(pairs), order
+        seen = np.zeros((t, t), np.int32)
+        for qi, kj, masked in pairs:
+            block = visible[qi * bq:(qi + 1) * bq, kj * bk:(kj + 1) * bk]
+            assert block.any(), (order, qi, kj, "wholly in the future")
+            assert masked == (not block.all()), (order, qi, kj, masked)
+            seen[qi * bq:(qi + 1) * bq, kj * bk:(kj + 1) * bk] += 1
+        assert (seen[visible] == 1).all(), order
 
 
 class TestChunkedLSTM:
