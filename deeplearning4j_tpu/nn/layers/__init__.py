@@ -60,4 +60,8 @@ from deeplearning4j_tpu.nn.layers.hybrid import (  # noqa: F401
     RMSNorm,
     RoutedExperts,
 )
+from deeplearning4j_tpu.nn.layers.ssm import (  # noqa: F401
+    Mamba2Mixer,
+    SubLayerBlock,
+)
 from deeplearning4j_tpu.nn.layers.objdetect import Yolo2Output  # noqa: F401

@@ -9,13 +9,15 @@ bias-free. Under the mixed policy the projections run on bf16 operands
 (`ops.dot`); norms, the softmax over experts, decays and the delta rule's
 state are float32.
 
-  RMSNorm        y = x rsqrt(mean x^2 + eps) (1 + w)   (zero-centred weight)
+  RMSNorm        y = x rsqrt(mean x^2 + eps) (1 + w)   (zero-centred weight;
+                 or the plain form .. w)
   rotary         half-split pairing on the first `rotary_dim` of a head
   GatedAttention [q | g | k | v] = x Wqkv; per-head RMS norm of q and k;
                  partial rotary; each key/value head repeated to its query
                  heads; causal softmax through `ops.attention.attend`
                  (the flash kernels where its rule admits them);
-                 o sigmoid(g) Wo
+                 o sigmoid(g) Wo. Gate, norms and positions can each be
+                 left out (plain grouped-query attention)
   GatedDeltaNet  [q | k | v | z] = x Wqkvz, [b | a] = x Wba; short causal
                  depthwise convolution + silu over [q | k | v]; the gated
                  delta rule S <- exp(g) S; S += k (beta (v - S^T k))^T;
@@ -24,14 +26,20 @@ state are float32.
                  everything is chunk-major [n, b, heads, c, d]: re-tiled
                  once in (`to_chunks`) and once out (`from_chunks`), in
                  the projection's dtype; q and k keep their key heads
-  RoutedExperts  softmax router over ALL experts, top-k renormalised; the
-                 terms of the experts HELD (`experts_held` = first, count)
-                 through a sorted buffer of static capacity and
-                 `lax.ragged_dot`; a gated shared expert. Its device work
-                 is a function of shapes alone; overflow is counted and
-                 left out. Counters live in the layer's state (`counters`)
-                 and reach `telemetry.fit_log()` once a fit.
+  RoutedExperts  a router over ALL experts (top-k of a softmax; or sigmoid
+                 scores, chosen by score + a selection bias, weighted by
+                 the bare scores), top-k renormalised; the terms of the
+                 experts HELD (`experts_held` = first, count) through a
+                 sorted buffer of static capacity and `lax.ragged_dot`;
+                 SwiGLU or relu^2 experts; a shared expert, gated or not.
+                 Its device work is a function of shapes alone; overflow is
+                 counted and left out. Counters live in the layer's state
+                 (`counters`) and reach `telemetry.fit_log()` once a fit.
   HybridBlock    h = x + mixer(rms(x)); y = h + experts(rms(h))
+
+`to_chunks`, `conv_silu`, `from_chunks` and the row mapping
+(`rows_at_a_time`, `over_row_groups`) also serve the state-space mixer of
+`ssm.py`, which has the block of ONE sub-layer.
 """
 from __future__ import annotations
 
@@ -82,9 +90,11 @@ def rotary(x, rotary_dim: int, theta: float):
 @register_layer
 @dataclass
 class RMSNorm(Layer):
-    """y = x rsqrt(mean x^2 + eps) (1 + w), w from zero."""
+    """y = x rsqrt(mean x^2 + eps) (1 + w), w from zero; with
+    `zero_centered` off the plain form y = .. w, w from one."""
 
     eps: float = 1e-6
+    zero_centered: bool = True
 
     sp_safe = True  # normalizes the feature axis only
 
@@ -93,13 +103,13 @@ class RMSNorm(Layer):
 
     def init_params(self, rng, input_type):
         n = input_type.size if isinstance(input_type, it.Recurrent) else input_type.arity()
-        return {"w": jnp.zeros((n,), F32)}
+        return {"w": (jnp.zeros if self.zero_centered else jnp.ones)((n,), F32)}
 
     def regularizable(self, params):
         return {}
 
     def apply(self, params, x, *, state, train, rng, mask=None):
-        return rms_norm(x, params["w"], self.eps), state
+        return rms_norm(x, params["w"], self.eps, self.zero_centered), state
 
 
 # ---------------------------------------------------------------------------
@@ -110,7 +120,10 @@ class RMSNorm(Layer):
 class GatedAttention(Layer):
     """Causal softmax attention with grouped key/value heads, per-head RMS
     norm of q and k, rotary positions on part of each head and a sigmoid
-    output gate. Wqkv [f, (2 n_heads + 2 n_kv_heads) head_dim] = [q | g | k | v]."""
+    output gate. Wqkv [f, (2 n_heads + 2 n_kv_heads) head_dim] = [q | g | k | v].
+    Each of the three can be left out (`gated`, `qk_norm`, `rotary_fraction`
+    0): then Wqkv = [q | k | v] and there are no norm weights — plain
+    grouped-query attention that knows no positions."""
 
     n_heads: int = 16
     n_kv_heads: int = 2
@@ -118,6 +131,8 @@ class GatedAttention(Layer):
     rotary_fraction: float = 0.25
     rope_theta: float = 1e7
     eps: float = 1e-6
+    gated: bool = True
+    qk_norm: bool = True
 
     def output_type(self, input_type):
         return input_type
@@ -128,9 +143,11 @@ class GatedAttention(Layer):
         if h % kv:
             raise ValueError(f"n_kv_heads={kv} must divide n_heads={h}")
         r = jax.random.split(rng, 2)
-        return {"Wqkv": _w(self, r[0], (f, (2 * h + 2 * kv) * d)),
-                "q_norm": jnp.zeros((d,), F32), "k_norm": jnp.zeros((d,), F32),
-                "Wo": _w(self, r[1], (h * d, f))}
+        p = {"Wqkv": _w(self, r[0], (f, ((2 if self.gated else 1) * h + 2 * kv) * d)),
+             "Wo": _w(self, r[1], (h * d, f))}
+        if self.qk_norm:
+            p.update(q_norm=jnp.zeros((d,), F32), k_norm=jnp.zeros((d,), F32))
+        return p
 
     def regularizable(self, params):
         return {k: v for k, v in params.items() if k.startswith("W")}
@@ -139,18 +156,28 @@ class GatedAttention(Layer):
         b, t, _ = x.shape
         h, kv, d = self.n_heads, self.n_kv_heads, self.head_dim
         z = ops.dot(x, params["Wqkv"])
-        q, g, k, v = jnp.split(z, [h * d, 2 * h * d, (2 * h + kv) * d], axis=-1)
+        if self.gated:
+            q, g, k, v = jnp.split(z, [h * d, 2 * h * d, (2 * h + kv) * d], axis=-1)
+        else:
+            q, k, v = jnp.split(z, [h * d, (h + kv) * d], axis=-1)
 
         def heads(a, n):  # [b, t, n d] -> [b, n, t, d]
             return a.reshape(b, t, n, d).transpose(0, 2, 1, 3)
 
         rot = int(d * self.rotary_fraction)
-        q = rotary(rms_norm(heads(q, h), params["q_norm"], self.eps), rot, self.rope_theta)
-        k = rotary(rms_norm(heads(k, kv), params["k_norm"], self.eps), rot, self.rope_theta)
+
+        def prepared(a, n, norm):  # a head's norm, then its positions
+            a = heads(a, n)
+            if self.qk_norm:
+                a = rms_norm(a, params[norm], self.eps)
+            return rotary(a, rot, self.rope_theta) if rot else a
+
+        q, k = prepared(q, h, "q_norm"), prepared(k, kv, "k_norm")
         k, v = (jnp.repeat(a, h // kv, axis=1) for a in (k, heads(v, kv)))
         o = att.attend(q, k, v, causal=True, mask=mask)
         o = o.transpose(0, 2, 1, 3).reshape(b, t, h * d)
-        o = o * jax.nn.sigmoid(g.astype(F32)).astype(o.dtype)
+        if self.gated:
+            o = o * jax.nn.sigmoid(g.astype(F32)).astype(o.dtype)
         y = ops.dot(o, params["Wo"])
         if mask is not None:
             y = y * mask[..., None].astype(y.dtype)
@@ -178,17 +205,17 @@ def _chunk_step(s, ab):
     return _mm("bhij,bhjv->bhiv", a_i, s) + b_i, s
 
 
-def to_chunks(a):
+def to_chunks(a, chunk: int = CHUNK):
     """[b, t, h, ...] -> [n, b, h, c, ...]: chunk-major, a head's chunk of
-    `CHUNK` tokens contiguous, the time zero-padded to whole chunks. With a
+    `chunk` tokens contiguous, the time zero-padded to whole chunks. With a
     last axis of 128 this moves whole tiles (c tokens x 128 lanes of one
-    head stay together); it is the ONE re-tiling on the way into the delta
-    rule, done on the narrowest form (the bf16 projection)."""
+    head stay together); it is the ONE re-tiling on the way into a chunked
+    recurrence, done on the narrowest form (the bf16 projection)."""
     b, t = a.shape[:2]
-    pad = (-t) % CHUNK
+    pad = (-t) % chunk
     if pad:
         a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-    a = a.reshape((b, (t + pad) // CHUNK, CHUNK) + a.shape[2:])
+    a = a.reshape((b, (t + pad) // chunk, chunk) + a.shape[2:])
     return a.transpose((1, 0, 3, 2) + tuple(range(4, a.ndim)))
 
 
@@ -284,10 +311,11 @@ def _tail_before(x, cw: int):
     return _rows(_next_chunk(x[..., x.shape[3] - (cw - 1):, :], 1).astype(F32), 0, cw - 1)
 
 
-def _conv_pre(x, w):
+def _conv_pre(x, w, b=None):
     """The short causal depthwise convolution over the tokens of x
     [n, r, h, c, d] (chunk-major, any float dtype), w [cw, h, 1, d], tap
-    cw - 1 - s on the token s places back -> float32. Token i of a chunk
+    cw - 1 - s on the token s places back, plus the bias b [h, 1, d] where
+    there is one -> float32. Token i of a chunk
     reads its own chunk shifted, zeros let in (the rows are shifted in the
     dtype they arrive in and widened after: a shift moves half the bytes),
     plus, for i < cw - 1, the same taps over the tail of the chunk before:
@@ -297,25 +325,30 @@ def _conv_pre(x, w):
     acc = sum(_shift(x, s).astype(F32) * taps[s] for s in range(cw))
     tail = _tail_before(x, cw)
     halo = sum(_shift(tail, s) * taps[s] for s in range(cw))[..., cw - 1:, :]
-    return acc + _rows(halo, 0, c - (cw - 1))
+    out = acc + _rows(halo, 0, c - (cw - 1))
+    return out if b is None else out + b
 
 
-@jax.custom_vjp
-def conv_silu(x, w):
-    """silu(`_conv_pre`(x, w)). Its backward is written out so that the
+def conv_silu(x, w, b=None):
+    """silu(`_conv_pre`(x, w, b)). Its backward is written out so that the
     cotangents of the cw shifted reads of x add up in float32 and round to
     x's dtype once (autodiff would round each and add in that dtype — x is
     the bf16 projection under the mixed policy)."""
-    return jax.nn.silu(_conv_pre(x, w))
+    return _conv_silu(x, w, b)
 
 
-def _conv_silu_fwd(x, w):
-    pre = _conv_pre(x, w)
-    return jax.nn.silu(pre), (x, w, pre)
+@jax.custom_vjp
+def _conv_silu(x, w, b):
+    return jax.nn.silu(_conv_pre(x, w, b))
+
+
+def _conv_silu_fwd(x, w, b):
+    pre = _conv_pre(x, w, b)
+    return jax.nn.silu(pre), (x, w, b, pre)
 
 
 def _conv_silu_bwd(res, dy):
-    x, w, pre = res
+    x, w, b, pre = res
     c, cw = x.shape[3], w.shape[0]
     taps = [w[cw - 1 - s] for s in range(cw)]
     sg = jax.nn.sigmoid(pre)
@@ -330,10 +363,45 @@ def _conv_silu_bwd(res, dy):
     dw = [jnp.sum(d * _shift(x, s).astype(F32), axis=(0, 1, 3), keepdims=True)[0, 0]
           + jnp.sum(d[..., :cw - 1, :] * _shift(tail, s)[..., cw - 1:, :],
                     axis=(0, 1, 3), keepdims=True)[0, 0] for s in range(cw)]
-    return dx.astype(x.dtype), jnp.stack(dw[::-1])
+    db = None if b is None else jnp.sum(d, axis=(0, 1, 3), keepdims=True)[0, 0]
+    return dx.astype(x.dtype), jnp.stack(dw[::-1]), db
 
 
-conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+_conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+#: float32 bytes of convolution input a chunked core takes at a time: beyond
+#: it the rows are mapped, each group a checkpoint, so that the core's
+#: working set is one group's, not the batch's
+CORE_BYTES = 2 ** 28
+
+
+def rows_at_a_time(b: int, row_bytes: int, limit: int = CORE_BYTES) -> int:
+    """The largest divisor of the batch whose rows stay within `limit`."""
+    rows = min(b, max(1, limit // row_bytes))
+    while b % rows:        # groups of equal size
+        rows -= 1
+    return rows
+
+
+def over_row_groups(core, arrays, rows: int, chunk: int = CHUNK):
+    """`core` between a layer's projections, a group of `rows` rows at a
+    time. `arrays`: pairs (a [b, t, ..], the shape a token's features are
+    split into — () as they are). Each is re-tiled ONCE, each group's rows
+    on their own, in the dtype it arrives in: [b / rows, n, rows, heads, c,
+    ..] (`to_chunks`), and `core(*group)` runs on the whole batch when it is
+    one group, else mapped over the groups, each a checkpoint. What comes
+    back then carries the groups in front and is tagged `REMAT_KEEP`: kept
+    by a block's 'full' remat, because the groups rerun in their own
+    backward and need not run in the block's recompute too."""
+    b, t = arrays[0][0].shape[:2]
+    args = [a.reshape((b // rows, rows, t) + (tuple(heads) or a.shape[2:]))
+            for a, heads in arrays]
+    args = tuple(jax.vmap(lambda g: to_chunks(g, chunk))(a) for a in args)
+    if rows == b:
+        return core(*(a[0] for a in args))
+    out = lax.map(jax.checkpoint(lambda a: core(*a)), args)
+    return jax.tree_util.tree_map(lambda y: checkpoint_name(y, REMAT_KEEP), out)
 
 
 @register_layer
@@ -402,14 +470,12 @@ class GatedDeltaNet(Layer):
         y = from_chunks((o * jax.nn.silu(z.astype(F32))).astype(z.dtype), t)
         return y.reshape(y.shape[:2] + (-1,))
 
-    #: float32 bytes of convolution input the core takes at a time: beyond
-    #: it the rows are mapped, each a checkpoint, so that the delta rule's
-    #: working set is one group's, not the batch's. At 8192 tokens x 8192
-    #: channels a row that input is 268 MB, and so is each of the solve's
-    #: right-hand side and solution and of the scan's A, B and S; q, k, v,
-    #: the decayed q and k, the output and the cotangent of each come to as
-    #: much again: some 3 GB a row while its backward runs
-    CORE_BYTES = 2 ** 28
+    #: `CORE_BYTES` for this layer. At 8192 tokens x 8192 channels a row the
+    #: convolution input is 268 MB, and so is each of the solve's right-hand
+    #: side and solution and of the scan's A, B and S; q, k, v, the decayed
+    #: q and k, the output and the cotangent of each come to as much again:
+    #: some 3 GB a row while its backward runs
+    CORE_BYTES = CORE_BYTES
 
     def apply(self, params, x, *, state, train, rng, mask=None):
         b, t, _ = x.shape
@@ -420,29 +486,14 @@ class GatedDeltaNet(Layer):
         ba = ops.dot(x, params["Wba"])
         if mask is not None:  # a padded token enters no convolution window
             qkv = qkv * mask[..., None].astype(qkv.dtype)
-        rows = min(b, max(1, self.CORE_BYTES // (t * (2 * key + val) * 4)))
-        while b % rows:        # groups of equal size
-            rows -= 1
-
-        def groups(a, *heads):   # [b, t, ..] -> [b / rows, rows, t, *heads]
-            return a.reshape((b // rows, rows, t) + (heads or a.shape[2:]))
-
-        # the one re-tiling in, each group's rows on their own, in the
-        # projection's dtype: [b / rows, n, rows, heads, c, ..]; z goes along
-        # so that the gate is taken where the rule's output lies
-        args = [groups(qkv[..., :2 * key], 2 * hk, dk), groups(qkv[..., 2 * key:], hv, dv),
-                groups(ba), groups(z, hv, dv)]
+        rows = rows_at_a_time(b, t * (2 * key + val) * 4, self.CORE_BYTES)
+        # z goes along so that the gate is taken where the rule's output lies
+        args = [(qkv[..., :2 * key], (2 * hk, dk)), (qkv[..., 2 * key:], (hv, dv)),
+                (ba, ()), (z, (hv, dv))]
         if mask is not None:
-            args.append(groups(mask.astype(F32)[..., None]))
-        args = tuple(jax.vmap(to_chunks)(a) for a in args)
+            args.append((mask.astype(F32)[..., None], ()))
         core = {k: params[k] for k in ("conv", "A_log", "dt_bias", "norm")}
-        if rows == b:
-            y = self._core(core, t, *(a[0] for a in args))
-        else:
-            y = lax.map(jax.checkpoint(lambda a: self._core(core, t, *a)), args)
-            # kept by the block's 'full' remat: the groups rerun in their
-            # own backward and need not run in the block's recompute too
-            y = checkpoint_name(y, REMAT_KEEP)
+        y = over_row_groups(lambda *a: self._core(core, t, *a), args, rows)
         y = ops.dot(y.reshape(b, t, val), params["Wout"])
         if mask is not None:
             y = y * mask[..., None].astype(y.dtype)
@@ -452,9 +503,20 @@ class GatedDeltaNet(Layer):
 # ---------------------------------------------------------------------------
 # routed experts
 # ---------------------------------------------------------------------------
-def _swiglu(x, wgu, wd):
-    gate, up = jnp.split(ops.dot(x, wgu), 2, axis=-1)
-    return ops.dot(jax.nn.silu(gate) * up, wd)
+def _swiglu(h):
+    gate, up = jnp.split(h, 2, axis=-1)
+    return jax.nn.silu(gate) * up
+
+
+def _relu2(h):
+    return jnp.square(jax.nn.relu(h))
+
+
+#: an expert's non-linearity between its two products -> (the function, how
+#: many times the expert's width its first matrix is wide, that matrix's
+#: name): "swiglu" silu(x Wg) (x Wu) Wd with [gate | up] one matrix;
+#: "relu2" relu(x Wu)^2 Wd
+EXPERT_ACTS = {"swiglu": (_swiglu, 2, "Wgu"), "relu2": (_relu2, 1, "Wu")}
 
 
 def _grouped(x, w, sizes):
@@ -523,13 +585,21 @@ _from_buffer.defvjp(_from_buffer_fwd, _from_buffer_bwd)
 @register_layer
 @dataclass
 class RoutedExperts(Layer):
-    """SwiGLU experts behind a softmax router, for a rank that holds
-    `experts_held` = (first, count) of `n_experts` (default: all), plus a
-    gated shared expert. The router scores all `n_experts`, keeps the
-    `top_k` largest and renormalises them over the chosen wherever they
-    live; this layer adds the terms of its own experts and leaves the
-    others' out (on one chip it runs without the exchange that would bring
-    other ranks' tokens).
+    """Experts behind a router, for a rank that holds `experts_held` =
+    (first, count) of `n_experts` (default: all), plus a shared expert. The
+    router scores all `n_experts`, keeps the `top_k` largest and
+    renormalises them over the chosen wherever they live; this layer adds
+    the terms of its own experts and leaves the others' out (on one chip it
+    runs without the exchange that would bring other ranks' tokens).
+
+    Two recipes share everything below the scores. `scoring` "softmax":
+    the weights are the top-k of the softmax. "sigmoid": every expert is
+    scored sigmoid(x Wr) on its own; the k are CHOSEN by score +
+    `select_bias` (a leaf no gradient reaches: it chooses, it does not
+    weigh), weighted by the bare scores, renormalised, and scaled by
+    `routed_scale`. `expert_act` (`EXPERT_ACTS`) is the non-linearity of
+    routed and shared experts alike; `shared_gated` multiplies the shared
+    expert by sigmoid(x w) or adds it as it is.
 
     Device work is a function of shapes alone: the (token, expert)
     assignments of the held experts are sorted by expert into a buffer of
@@ -551,9 +621,18 @@ class RoutedExperts(Layer):
     experts_held: Optional[Sequence[int]] = None
     capacity_factor: float = 1.25
     norm_topk: bool = True
+    scoring: str = "softmax"
+    routed_scale: float = 1.0
+    expert_act: str = "swiglu"
+    shared_gated: bool = True
 
     def held(self):
         return tuple(self.experts_held) if self.experts_held else (0, self.n_experts)
+
+    def _act(self):
+        if self.expert_act not in EXPERT_ACTS:
+            raise ValueError(f"expert_act={self.expert_act!r}: one of {sorted(EXPERT_ACTS)}")
+        return EXPERT_ACTS[self.expert_act]
 
     def capacity(self, rows: int) -> int:
         """Buffer rows for `rows` tokens: the factor times the expected
@@ -571,14 +650,21 @@ class RoutedExperts(Layer):
         first, count = self.held()
         if first < 0 or first + count > self.n_experts:
             raise ValueError(f"experts_held={self.experts_held} outside 0..{self.n_experts}")
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring={self.scoring!r}: 'softmax' or 'sigmoid'")
         e, s = self.expert_width, self.shared_width
+        _, wide, up = self._act()
         r = jax.random.split(rng, 6)
-        return {"router": _w(self, r[0], (f, self.n_experts)),
-                "Wgu": _w(self, r[1], (count, f, 2 * e)),
-                "Wd": _w(self, r[2], (count, e, f)),
-                "shared_Wgu": _w(self, r[3], (f, 2 * s)),
-                "shared_Wd": _w(self, r[4], (s, f)),
-                "shared_gate": _w(self, r[5], (f, 1))}
+        p = {"router": _w(self, r[0], (f, self.n_experts)),
+             up: _w(self, r[1], (count, f, wide * e)),
+             "Wd": _w(self, r[2], (count, e, f)),
+             "shared_" + up: _w(self, r[3], (f, wide * s)),
+             "shared_Wd": _w(self, r[4], (s, f))}
+        if self.shared_gated:
+            p["shared_gate"] = _w(self, r[5], (f, 1))
+        if self.scoring == "sigmoid":
+            p["select_bias"] = jnp.zeros((self.n_experts,), F32)
+        return p
 
     def init_state(self, input_type):
         _, count = self.held()
@@ -606,6 +692,13 @@ class RoutedExperts(Layer):
         """(weights [n, top_k] float32, expert ids [n, top_k])."""
         logits = jnp.matmul(xf.astype(F32), params["router"],
                             precision=lax.Precision.HIGHEST)
+        if self.scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            _, idx = lax.top_k(scores + lax.stop_gradient(params["select_bias"]), self.top_k)
+            top = jnp.take_along_axis(scores, idx, axis=-1)
+            if self.norm_topk:
+                top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+            return top * self.routed_scale, idx
         top, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), self.top_k)
         if self.norm_topk:
             top = top / jnp.sum(top, axis=-1, keepdims=True)
@@ -627,8 +720,8 @@ class RoutedExperts(Layer):
         sizes = bounds[1:] - bounds[:-1]
         sizes = sizes.at[-1].add(cap - bounds[-1])   # the padding is computed
         xs = _to_buffer(xf, order, inv, cap)
-        gate, up = jnp.split(_grouped(xs, params["Wgu"], sizes), 2, axis=-1)
-        ys = _grouped(jax.nn.silu(gate) * up, params["Wd"], sizes)
+        act, _, up = self._act()
+        ys = _grouped(act(_grouped(xs, params[up], sizes)), params["Wd"], sizes)
         # a slot counts when its expert is held and its position is inside the
         # buffer; the rows of the others (the last group's padding) weigh 0
         kept = (key < count) & (inv < cap)
@@ -643,8 +736,10 @@ class RoutedExperts(Layer):
         xf = x.reshape(-1, shape[-1])
         top, idx = self.route(params, xf)
         out, load, dropped = self.routed(params, xf, top, idx)
-        gate = jax.nn.sigmoid(ops.dot(xf, params["shared_gate"]).astype(F32))
-        shared = _swiglu(xf, params["shared_Wgu"], params["shared_Wd"])
+        act, _, up = self._act()
+        gate = (jax.nn.sigmoid(ops.dot(xf, params["shared_gate"]).astype(F32))
+                if self.shared_gated else 1.0)
+        shared = ops.dot(act(ops.dot(xf, params["shared_" + up])), params["shared_Wd"])
         y = (out + gate * shared.astype(F32)).astype(x.dtype).reshape(shape)
         if mask is not None:
             y = y * mask[..., None].astype(y.dtype)

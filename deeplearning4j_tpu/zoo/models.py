@@ -548,6 +548,91 @@ class HybridMoELM(ZooModel):
 
 
 @dataclass
+class PatternHybridLM(ZooModel):
+    """Decoder-only LM whose layers are named by a PATTERN string, one
+    character a layer, each ONE sub-layer behind a pre-norm and a residual
+    (`SubLayerBlock`): `M` a Mamba-2 state-space mixer, `*` grouped-query
+    softmax attention without positions, `E` sigmoid-routed relu^2 experts
+    beside an ungated shared expert; plain RMS norms, an untied bias-free
+    head (the `nemotron_h` shape). The arguments are the keys of the
+    published `config.json`; `num_experts` is the count this rank HOLDS of
+    `num_experts_published` (`n_routed_experts`; default: all of them),
+    starting at `experts_first`. Input: [b, t] token ids; labels: [b, t]
+    integer next-token ids (or dense one-hot)."""
+
+    vocab_size: int = 1000
+    hidden_size: int = 256
+    hybrid_override_pattern: str = "MEM*E"
+    layer_norm_epsilon: float = 1e-5
+    max_length: int = 128
+    # softmax attention
+    num_attention_heads: int = 4
+    num_key_value_heads: int = 2
+    head_dim: int = 64
+    # state-space mixer
+    mamba_num_heads: int = 8
+    mamba_head_dim: int = 32
+    n_groups: int = 2
+    ssm_state_size: int = 16
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    time_step_min: float = 1e-3
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    # routed experts
+    num_experts: int = 8
+    num_experts_published: Optional[int] = None
+    experts_first: int = 0
+    num_experts_per_tok: int = 2
+    moe_intermediate_size: int = 64
+    moe_shared_expert_intermediate_size: int = 128
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    capacity_factor: float = 1.25
+    # per-block activation-checkpoint policy (parallel/layout.py)
+    remat: Optional[str] = None
+
+    def conf(self):
+        from deeplearning4j_tpu.nn.layers import (
+            EmbeddingSequence,
+            RMSNorm,
+            SubLayerBlock,
+        )
+        from deeplearning4j_tpu.nn.layers.ssm import pattern_kinds
+
+        blocks = [
+            SubLayerBlock(
+                kind=kind, eps=self.layer_norm_epsilon,
+                ssm_heads=self.mamba_num_heads, ssm_head_dim=self.mamba_head_dim,
+                ssm_groups=self.n_groups, ssm_state=self.ssm_state_size,
+                conv_width=self.conv_kernel, chunk=self.chunk_size,
+                dt_min=self.time_step_min, dt_max=self.time_step_max,
+                dt_floor=self.time_step_floor,
+                n_heads=self.num_attention_heads,
+                n_kv_heads=self.num_key_value_heads, head_dim=self.head_dim,
+                n_experts=self.num_experts_published or self.num_experts,
+                top_k=self.num_experts_per_tok,
+                expert_width=self.moe_intermediate_size,
+                shared_width=self.moe_shared_expert_intermediate_size,
+                experts_held=(self.experts_first, self.num_experts),
+                capacity_factor=self.capacity_factor,
+                norm_topk=self.norm_topk_prob,
+                routed_scale=self.routed_scaling_factor, remat=self.remat)
+            for kind in pattern_kinds(self.hybrid_override_pattern)
+        ]
+        return NeuralNetConfiguration(
+            seed=self.seed, updater=updaters.Adam(learning_rate=3e-4),
+            weight_init="xavier",
+        ).list([
+            EmbeddingSequence(n_in=self.vocab_size, n_out=self.hidden_size),
+            *blocks,
+            RMSNorm(eps=self.layer_norm_epsilon, zero_centered=False),
+            RnnOutput(n_out=self.vocab_size, loss="mcxent",
+                      activation="softmax", has_bias=False),
+        ]).set_input_type(it.recurrent(self.vocab_size, self.max_length))
+
+
+@dataclass
 class VisionTransformer(ZooModel):
     """ViT-style image classifier — net-new 14th zoo architecture (the
     reference zoo is pre-transformer). Patch embedding via a stride=patch
