@@ -57,6 +57,43 @@ def dot_general(x, w, dims, **kw):
     return lax.dot_general(x, w, dims, precision=_precision(), **kw)
 
 
+#: the largest tile libtpu gives a width of its grouped product
+GROUPED_TILE = 512
+
+
+def grouped_width(n: int) -> int:
+    """The width at which `grouped_dot` runs an operand or a result of width n:
+    the next multiple of 512 where that adds at most a quarter, else n itself.
+    libtpu tiles each width of its grouped product by the largest of 512, 256
+    and 128 that divides it, and the tile sets the rate: on a v5e 133-148
+    TFLOP/s at 512 x 512 (3072 x 2048), 96-127 at 256 x 512, 25-33 at
+    128 x 128 (2688 x 1856, or 2688 x 1920) — PERF.md section 6, PR 32."""
+    padded = -(-n // GROUPED_TILE) * GROUPED_TILE
+    return padded if 4 * (padded - n) <= n else n
+
+
+def grouped_dot(x: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray, blocks: int = 1) -> jnp.ndarray:
+    """Rows of x [rows, K], sorted by group (`sizes` rows each), times their
+    group's matrix w [g, k, blocks * n] -> [rows, blocks * grouped_width(n)]:
+    `lax.ragged_dot` (XLA's grouped product) on widths it runs well.
+
+    Padding is with zeros and is exact: x may already be wider than k (its
+    columns beyond k must be zero; they meet zero rows of w), and each of the
+    `blocks` column blocks of w is padded to `grouped_width(n)`, so the result
+    has zero columns there. They stay: the next product's matrix gets zero
+    rows for them, and whoever reduces the rows to tokens slices once, there.
+    Only the matrices are padded here, after the cast, never the rows. At
+    widths that are their own `grouped_width` this is `lax.ragged_dot` on the
+    operands as they came."""
+    x, w = _mixed_cast(x, w)
+    g, k, n = w.shape[0], w.shape[1], w.shape[2] // blocks
+    wide, n_pad = x.shape[-1], grouped_width(n)
+    if (wide, n_pad) != (k, n):
+        w = jnp.pad(w.reshape(g, k, blocks, n),
+                    ((0, 0), (0, wide - k), (0, 0), (0, n_pad - n))).reshape(g, wide, blocks * n_pad)
+    return lax.ragged_dot(x, w, sizes, precision=_precision())
+
+
 def conv2d(
     x: jnp.ndarray,
     kernel: jnp.ndarray,
