@@ -56,7 +56,10 @@ from deeplearning4j_tpu.nn.layers.attention import (  # noqa: F401
 from deeplearning4j_tpu.nn.layers.hybrid import (  # noqa: F401
     GatedAttention,
     GatedDeltaNet,
+    GatedMLP,
     HybridBlock,
+    KimiDeltaAttention,
+    LatentAttention,
     RMSNorm,
     RoutedExperts,
 )

@@ -1,8 +1,9 @@
 """Hybrid decoder layers: RMS norm, rotary positions, gated softmax
-attention with grouped key/value heads, gated-delta-rule linear attention,
-routed experts that are told which experts they hold, and the block that
-stacks a mixer of either kind on the expert layer (the Qwen3-Next shape;
-ROADMAP R3, R4, R8).
+attention with grouped key/value heads, gated-delta-rule linear attention
+with one decay a head or one a key channel, latent attention, routed
+experts that are told which experts they hold, a dense gated feed-forward,
+and the block that stacks a mixer of either kind on the expert layer (the
+Qwen3-Next shape; ROADMAP R3, R4, R5, R8).
 
 All BTF [batch, time, features] like `attention.py`; weights [n_in, n_out],
 bias-free. Under the mixed policy the projections run on bf16 operands
@@ -26,6 +27,17 @@ state are float32.
                  everything is chunk-major [n, b, heads, c, d]: re-tiled
                  once in (`to_chunks`) and once out (`from_chunks`), in
                  the projection's dtype; q and k keep their key heads
+  KimiDeltaAttention  the delta rule with a decay of its own a key CHANNEL
+                 (KDA): S <- Diag(exp(g)) S, g through a low-rank
+                 bottleneck; `chunk_channel_gated_delta_rule`, whose chunk
+                 scores are exact and finite at any decay (`_decayed`) and
+                 whose solve, scan and read-out are the scalar rule's;
+                 per-head RMS norm gated by a sigmoid through a second
+                 bottleneck; Wo
+  LatentAttention  keys and values through a normalised bottleneck plus one
+                 key part shared by all heads (MLA), no positions; keys
+                 wider than values through `ops.attention.attend`
+  GatedMLP       act(x W1) Wd with an expert's non-linearity, dense
   RoutedExperts  a router over ALL experts (top-k of a softmax; or sigmoid
                  scores, chosen by score + a selection bias, weighted by
                  the bare scores), top-k renormalised; the terms of the
@@ -38,13 +50,16 @@ state are float32.
                  (`counters`) and reach `telemetry.fit_log()` once a fit.
   HybridBlock    h = x + mixer(rms(x)); y = h + experts(rms(h))
 
-`to_chunks`, `conv_silu`, `from_chunks` and the row mapping
-(`rows_at_a_time`, `over_row_groups`) also serve the state-space mixer of
-`ssm.py`, which has the block of ONE sub-layer.
+`to_chunks`, `conv_silu`, `from_chunks`, the row mapping (`rows_at_a_time`,
+`over_row_groups`) and the decay counters (`decay_counters`, ..) also serve
+the state-space mixer of `ssm.py`, which has the block of ONE sub-layer
+(`SubLayerBlock`: every kind of mixer and feed-forward here but the
+Qwen3-Next pair, which `HybridBlock` stacks).
 """
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
@@ -199,6 +214,12 @@ def _mm(spec, a, b):
     return jnp.einsum(spec, a, b, precision=ops._precision())
 
 
+def l2_normalised(a):
+    """a / |a| over the last axis (1e-6 under the root): the delta rules'
+    q and k."""
+    return a * lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+
+
 def _chunk_step(s, ab):
     """The body of the scan over chunks: S' = A S + B. Emits the state the
     chunk STARTS from."""
@@ -266,25 +287,180 @@ def chunk_gated_delta_rule(q, k, v, g, beta):
     # follows only ever subtracts it, and a sum's cotangent needs no pass
     rhs = jnp.concatenate([v * beta[..., None],
                            k * (-beta * jnp.exp(gc))[..., None]], -1)
-    sol = jax.scipy.linalg.solve_triangular(a_mat, rhs, lower=True,
-                                            unit_diagonal=True)
-    u, w = sol[..., :dv], sol[..., dv:]                          # w = -W
+    u, w = _solve_writes(a_mat, rhs, dv)
     qk = jnp.where(lower, qk * decay, 0.0)
     q_dec = q * jnp.exp(gc)[..., None]
     k_dec = k * jnp.exp(gc[..., -1:] - gc)[..., None]
-    # the state across chunks is linear in itself: S' = A S + B with
-    # A = exp(G_c) I - K_dec^T W, B = K_dec^T U. A and B come from batched
-    # products over all chunks; the scan's body is one product and one sum
     last = jnp.exp(gc[..., -1])[..., None, None]
-    a_all = last * jnp.eye(dk, dtype=F32) + mm("nbhrci,nbhrcj->nbhrij", k_dec, w)
-    b_all = mm("nbhrci,nbhrcv->nbhriv", k_dec, u)
+    o, _ = _scan_read(u, w, qk, q_dec, k_dec, last * jnp.eye(dk, dtype=F32))
+    return o.reshape(n, b, hv, c, dv)
 
+
+# What every chunked delta rule does once its chunk's scores are made, the
+# decay one scalar a head and token or a vector over the key channels alike.
+def _solve_writes(a_mat, rhs, dv: int):
+    """The chunk's writes: (I + A) [U | -W] = rhs, unit lower-triangular;
+    a_mat [n, b, *heads, c, c], rhs [.., c, dv + dk] -> (U [.., c, dv],
+    -W [.., c, dk])."""
+    sol = jax.scipy.linalg.solve_triangular(a_mat, rhs, lower=True,
+                                            unit_diagonal=True)
+    return sol[..., :dv], sol[..., dv:]
+
+
+def _scan_read(u, w, qk, q_dec, k_dec, a_diag):
+    """Carry the state across chunks and read it: u, w (= -W) from
+    `_solve_writes`, qk the decayed Q K^T [.., c, c], q_dec (q times the
+    decay since the chunk's start) and k_dec (k times the decay up to its
+    end) [.., c, dk], a_diag the state's own decay over the chunk as a
+    matrix [.., dk, dk] -> (o [.., c, dv], the states the chunks start from
+    [n, b, heads, dk, dv]).
+
+    The state across chunks is linear in itself: S' = A S + B with
+    A = a_diag - K_dec^T W, B = K_dec^T U. A and B come from batched
+    products over all chunks; the scan's body is one product and one sum."""
+    mm = _mm
+    dk, dv = w.shape[-1], u.shape[-1]
+    a_all = a_diag + mm("...ci,...cj->...ij", k_dec, w)
+    b_all = mm("...ci,...cv->...iv", k_dec, u)
+    n, b = a_all.shape[:2]
+    heads = a_all.shape[2:-2]
+    hv = math.prod(heads)
     _, s_all = lax.scan(_chunk_step, jnp.zeros((b, hv, dk, dv), F32),
                         (a_all.reshape(n, b, hv, dk, dk), b_all.reshape(n, b, hv, dk, dv)))
-    s_all = per_key(s_all)
-    o = mm("nbhrck,nbhrkv->nbhrcv", q_dec, s_all) + mm(
-        "nbhrij,nbhrjv->nbhriv", qk, u + mm("nbhrck,nbhrkv->nbhrcv", w, s_all))
-    return o.reshape(n, b, hv, c, dv)
+    s_in = s_all.reshape((n, b) + heads + (dk, dv))
+    o = mm("...ck,...kv->...cv", q_dec, s_in) + mm(
+        "...ij,...jv->...iv", qk, u + mm("...ck,...kv->...cv", w, s_in))
+    return o, s_all
+
+
+#: tokens a sub-block of a chunk whose decayed terms are formed directly
+#: (`_decayed`)
+SUB = 16
+
+
+def _pair_decay(gc):
+    """E_jld = exp(G_jd - G_ld) for l <= j, 0 above the diagonal: gc
+    [.., s, d] -> [.., s, s, d]. The exponent is <= 0 wherever it counts."""
+    s = gc.shape[-2]
+    i = jnp.arange(s)
+    lower = (i[:, None] >= i[None, :])[..., None]
+    return jnp.where(lower, jnp.exp(jnp.where(
+        lower, gc[..., :, None, :] - gc[..., None, :, :], 0.0)), 0.0)
+
+
+def _halves(x):
+    """[.., c, m] -> [.., 2, c / 2, m]: the earlier and the later half."""
+    return x.reshape(x.shape[:-2] + (2, x.shape[-2] // 2, x.shape[-1]))
+
+
+def _decayed(what: str, x, y, gc):
+    """One of three sums over the pairs l <= j of a chunk's tokens, each
+    term carrying E_jld = exp(G_jd - G_ld), gc = G the running log decay
+    [.., c, d], falling along the tokens:
+
+      "scores"  x = a, y = k [.., c, d] -> P_jl = sum_d a_jd k_ld E_jld
+                                           [.., c, c], 0 above the diagonal
+      "rows"    x = W [.., c, c], y = k -> sum_{l <= j} W_jl k_ld E_jld  [.., c, d]
+      "cols"    x = W, y = a            -> sum_{j >= l} W_jl a_jd E_jld  [.., c, d]
+
+    With a decay of its own a channel the exponent sits INSIDE the
+    contraction, and the factorisation (a exp(G)) (k exp(-G))^T overflows
+    float32 once a channel has decayed by e^-88 inside the chunk. Exact and
+    finite for ANY decay instead, by halves: the later half's rows against
+    the earlier half's are referred to the later half's FIRST row r,
+
+        E_jld = exp(G_jd - r_d) exp(r_d - G_ld),   l < first <= j
+
+    both exponents <= 0 (a factor that underflows to 0 stands for a product
+    that is smaller still), one product on the MXU; the two diagonal blocks
+    the same way again, down to `SUB` tokens, where the [s, s, d] terms are
+    formed and summed as they are."""
+    c = gc.shape[-2]
+    if c <= SUB:
+        e = _pair_decay(gc)
+        if what == "scores":
+            return jnp.sum(x[..., :, None, :] * y[..., None, :, :] * e, axis=-1)
+        if what == "rows":
+            return jnp.sum(x[..., :, :, None] * y[..., None, :, :] * e, axis=-2)
+        return jnp.sum(x[..., :, :, None] * y[..., :, None, :] * e, axis=-3)
+    p = c // 2
+    g2, y2 = _halves(gc), _halves(y)
+    r = g2[..., 1, :1, :]                                          # the later half's first row
+    later, earlier = jnp.exp(g2[..., 1, :, :] - r), jnp.exp(r - g2[..., 0, :, :])
+    if what == "scores":
+        x2 = _halves(x)
+        diag = _decayed(what, x2, y2, g2)                          # [.., 2, p, p]
+        off = _mm("...id,...jd->...ij", x2[..., 1, :, :] * later, y2[..., 0, :, :] * earlier)
+        return jnp.concatenate([
+            jnp.concatenate([diag[..., 0, :, :], jnp.zeros_like(off)], -1),
+            jnp.concatenate([off, diag[..., 1, :, :]], -1)], -2)
+    # the weights' two diagonal blocks [.., 2, p, p] and the block below them
+    w = x.reshape(x.shape[:-2] + (2, p, 2, p))
+    diag = _decayed(what, jnp.stack([w[..., 0, :, 0, :], w[..., 1, :, 1, :]], -3), y2, g2)
+    off = w[..., 1, :, 0, :]                                       # rows later, columns earlier
+    zero = jnp.zeros_like(diag[..., 0, :, :])
+    if what == "rows":
+        cross = later * _mm("...ij,...jd->...id", off, y2[..., 0, :, :] * earlier)
+        return (diag + jnp.stack([zero, cross], -3)).reshape(gc.shape)
+    cross = earlier * _mm("...ij,...id->...jd", off, y2[..., 1, :, :] * later)
+    return (diag + jnp.stack([cross, zero], -3)).reshape(gc.shape)
+
+
+@jax.custom_vjp
+def _decayed_scores(a, k, gc):
+    """P_jl = sum_d a_jd k_ld exp(G_jd - G_ld) for l <= j, 0 above the
+    diagonal: a, k, gc [.., c, d] -> [.., c, c] (`_decayed`). The backward
+    is written out: da and dk are the two weighted sums of the same pairs,
+    and since every term is a_jd k_ld E_jld, dG = a da - k dk — no third
+    and fourth pass over the pairs, and nothing kept but a, k and G."""
+    return _decayed("scores", a, k, gc)
+
+
+def _decayed_scores_fwd(a, k, gc):
+    return _decayed("scores", a, k, gc), (a, k, gc)
+
+
+def _decayed_scores_bwd(res, dp):
+    a, k, gc = res
+    da = _decayed("rows", dp, k, gc)
+    dk = _decayed("cols", dp, a, gc)
+    return da, dk, a * da - k * dk
+
+
+_decayed_scores.defvjp(_decayed_scores_fwd, _decayed_scores_bwd)
+
+
+def chunk_channel_gated_delta_rule(q, k, v, g, beta):
+    """The delta rule with a decay of its own a key CHANNEL (KDA), over
+    chunks of `CHUNK` tokens, chunk-major (`to_chunks`): q, k [n, b, h, c,
+    dk] (normalised and scaled by the caller), v [n, b, h, c, dv], g (log
+    decay, <= 0) [n, b, h, c, dk], beta [n, b, h, c], all float32 ->
+    (o [n, b, h, c, dv], the states the chunks start from [n, b, h, dk, dv]).
+
+    Per head, S_0 = 0 and for every token S <- Diag(exp(g)) S;
+    S <- S + k (beta (v - S^T k))^T; o = S^T q. Within a chunk the writes
+    solve (I + A) D = U - W S_0 as in `chunk_gated_delta_rule`, with
+    A_jl = beta_j sum_d k_jd k_ld exp(G_jd - G_ld), l < j: the decay no
+    longer factors out of K K^T, so A and the read-out's Q K^T come from
+    `_decayed_scores`; every other decay is a product with exp of a
+    non-positive number (since the chunk's start, or up to its end); the
+    solve, the scan over chunks and the read-out are `_solve_writes` and
+    `_scan_read`, shared with the scalar rule. The backward is autodiff
+    through all of it but the decayed scores, whose own is written out; in
+    chunks, and as finite as the forward."""
+    c, dk = q.shape[-2:]
+    dv = v.shape[-1]
+    gc = jnp.cumsum(g, axis=-2)
+    i = jnp.arange(c)
+    strict = i[:, None] > i[None, :]
+    a_mat = jnp.where(strict, _decayed_scores(k, k, gc) * beta[..., None], 0.0) \
+        + jnp.eye(c, dtype=F32)
+    since, last = jnp.exp(gc), gc[..., -1:, :]
+    rhs = jnp.concatenate([v * beta[..., None], k * since * -beta[..., None]], -1)
+    u, w = _solve_writes(a_mat, rhs, dv)
+    a_diag = jnp.exp(last)[..., 0, :, None] * jnp.eye(dk, dtype=F32)
+    return _scan_read(u, w, _decayed_scores(q, k, gc), q * since,
+                      k * jnp.exp(last - gc), a_diag)
 
 
 def _shift(a, s: int):
@@ -456,17 +632,14 @@ class GatedDeltaNet(Layer):
         qk = conv_silu(qk, params["conv"][:, :2 * key].reshape(cw, 2 * hk, 1, -1))
         v = conv_silu(v, params["conv"][:, 2 * key:].reshape(cw, hv, 1, -1))
 
-        def l2(a):
-            return a * lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
-
         ba = ba.astype(F32)
         beta = jax.nn.sigmoid(ba[:, :, :hv])
         g = -jnp.exp(params["A_log"])[:, None] * jax.nn.softplus(
             ba[:, :, hv:] + params["dt_bias"][:, None])
         if mask is not None:  # a padded token writes nothing, keeps the state
             beta, g = beta * mask, g * mask
-        o = chunk_gated_delta_rule(l2(qk[:, :, :hk]) * self.key_dim ** -0.5,
-                                   l2(qk[:, :, hk:]), v, g, beta)
+        o = chunk_gated_delta_rule(l2_normalised(qk[:, :, :hk]) * self.key_dim ** -0.5,
+                                   l2_normalised(qk[:, :, hk:]), v, g, beta)
         o = rms_norm(o, params["norm"], self.eps, zero_centered=False)
         y = from_chunks((o * jax.nn.silu(z.astype(F32))).astype(z.dtype), t)
         return y.reshape(y.shape[:2] + (-1,))
@@ -502,6 +675,208 @@ class GatedDeltaNet(Layer):
 
 
 # ---------------------------------------------------------------------------
+# counters of a recurrence's decay and state, shared by the mixers that keep them
+# ---------------------------------------------------------------------------
+def decay_counters():
+    """The state a recurrent mixer counts in: `steps` and float32 sums over
+    the steps of the mean and of the smallest per-token decay and of the
+    largest |state| a chunk starts from."""
+    zero = lambda dtype: jnp.zeros((), dtype)  # noqa: E731 — a buffer each: state is donated
+    return {"counters": {"steps": zero(jnp.int32), "decay_sum": zero(F32),
+                         "decay_min_sum": zero(F32), "state_max_sum": zero(F32)}}
+
+
+def count_decay(state, mean, low, high):
+    """`decay_counters` after one more step whose row groups read `mean`,
+    `low`, `high` (a scalar, or one a group)."""
+    c = state["counters"]
+    return {"counters": {
+        "steps": c["steps"] + 1, "decay_sum": c["decay_sum"] + jnp.mean(mean),
+        "decay_min_sum": c["decay_min_sum"] + jnp.min(low),
+        "state_max_sum": c["state_max_sum"] + jnp.max(high)}}
+
+
+def decay_summary(key: str, added):
+    """Per-step means of `decay_counters` over a fit, under `key`."""
+    steps = max(int(added["steps"][0]), 1)
+    return key, {
+        "steps": int(added["steps"][0]),
+        "decay_mean": float(added["decay_sum"][0]) / steps,
+        "decay_min": float(added["decay_min_sum"][0]) / steps,
+        "state_abs_max": float(added["state_max_sum"][0]) / steps,
+    }
+
+
+def decay_stats(decay, states):
+    """(mean, smallest) of the per-token decays and the largest |state|."""
+    decay, states = lax.stop_gradient(decay), lax.stop_gradient(states)
+    return jnp.mean(decay), jnp.min(decay), jnp.max(jnp.abs(states))
+
+
+@register_layer
+@dataclass
+class KimiDeltaAttention(Layer):
+    """Delta-rule linear attention whose decay is a vector over a head's
+    key channels (KDA) over [b, t, f]; n_heads heads, keys and values
+    head_dim wide. Wqkv [f, 3 n_heads head_dim] = [q | k | v] with a short
+    causal depthwise convolution + silu over all three (no bias); Wlow
+    [f, 2 head_dim + n_heads] = [fa | ga | b]: the decay and the output
+    gate each through a bottleneck as wide as a head, beta a head;
+
+      q = l2(q) head_dim^-0.5, k = l2(k), beta = sigmoid(x Wb)
+      g = -exp(A_log[h]) softplus(x Wfa Wfb + dt_bias)      [n_heads x head_dim]
+      S <- Diag(exp(g)) S;  S <- S + k (beta (v - S^T k))^T;  o = S^T q
+      y = (rms(o; norm) sigmoid(x Wga Wgb)) Wo,  the norm over a head
+
+    run in chunks (`chunk_channel_gated_delta_rule`), chunk-major between
+    the projections like `GatedDeltaNet`. `A_log` one a head, `dt_bias`
+    one a channel. State `counters` (`decay_counters`; `telemetry.fit_log()`
+    reports them under `kda`): the decay's mean and its smallest value over
+    tokens and channels, the largest |state| a chunk starts from."""
+
+    n_heads: int = 32
+    head_dim: int = 128
+    conv_width: int = 4
+    eps: float = 1e-5
+
+    #: `CORE_BYTES` for this layer: at 8192 tokens x 12288 convolved
+    #: channels a row is 403 MB, so the rows are mapped one at a time
+    CORE_BYTES = CORE_BYTES
+
+    def output_type(self, input_type):
+        return input_type
+
+    def init_params(self, rng, input_type):
+        f = input_type.size
+        h, d = self.n_heads, self.head_dim
+        r = jax.random.split(rng, 8)
+        # dt log-uniform over [1e-3, 0.1], dt_bias its inverse softplus; A ~ U(1, 16)
+        dt = jnp.exp(jax.random.uniform(r[5], (h * d,), F32) * jnp.log(100.0) + jnp.log(1e-3))
+        return {
+            "Wqkv": _w(self, r[0], (f, 3 * h * d)),
+            "conv": jax.random.uniform(r[1], (self.conv_width, 3 * h * d), F32,
+                                       -1.0, 1.0) * self.conv_width ** -0.5,
+            "Wlow": _w(self, r[2], (f, 2 * d + h)),
+            "Wfb": _w(self, r[3], (d, h * d)),
+            "Wgb": _w(self, r[4], (d, h * d)),
+            "A_log": jnp.log(jax.random.uniform(r[6], (h,), F32, 1.0, 16.0)),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "norm": jnp.ones((d,), F32),
+            "Wo": _w(self, r[7], (h * d, f)),
+        }
+
+    def init_state(self, input_type):
+        return decay_counters()
+
+    def counter_summary(self, added):
+        return decay_summary("kda", added)
+
+    def regularizable(self, params):
+        return {k: v for k, v in params.items() if k.startswith("W")}
+
+    def _core(self, params, t, qkv, f, ba, z, mask=None):
+        """Everything between the projections, for rows r of t tokens.
+        Chunk-major (`to_chunks`) and in the projection's dtype: qkv
+        [n, r, 3 h, c, d], f (the decay before its softplus) and z (the
+        gate before its sigmoid) [n, r, h, c, d], ba [n, r, h, c], mask
+        [n, r, 1, c] -> ([r, t, h d], the step's counters)."""
+        h, cw = self.n_heads, self.conv_width
+        qkv = conv_silu(qkv, params["conv"].reshape(cw, 3 * h, 1, -1))
+
+        beta = jax.nn.sigmoid(ba.astype(F32))
+        g = -jnp.exp(params["A_log"])[:, None, None] * jax.nn.softplus(
+            f.astype(F32) + params["dt_bias"].reshape(h, 1, -1))
+        if mask is not None:  # a padded token writes nothing, keeps the state
+            beta, g = beta * mask, g * mask[..., None]
+        o, states = chunk_channel_gated_delta_rule(
+            l2_normalised(qkv[:, :, :h]) * self.head_dim ** -0.5,
+            l2_normalised(qkv[:, :, h:2 * h]), qkv[:, :, 2 * h:], g, beta)
+        o = rms_norm(o, params["norm"], self.eps, zero_centered=False)
+        y = from_chunks((o * jax.nn.sigmoid(z.astype(F32))).astype(z.dtype), t)
+        return y.reshape(y.shape[:2] + (-1,)), decay_stats(jnp.exp(g), states)
+
+    def apply(self, params, x, *, state, train, rng, mask=None):
+        b, t, _ = x.shape
+        h, d = self.n_heads, self.head_dim
+        qkv = ops.dot(x, params["Wqkv"])
+        low = ops.dot(x, params["Wlow"])
+        f = ops.dot(low[..., :d], params["Wfb"])
+        z = ops.dot(low[..., d:2 * d], params["Wgb"])
+        if mask is not None:  # a padded token enters no convolution window
+            qkv = qkv * mask[..., None].astype(qkv.dtype)
+        rows = rows_at_a_time(b, t * 3 * h * d * 4, self.CORE_BYTES)
+        args = [(qkv, (3 * h, d)), (f, (h, d)), (low[..., 2 * d:], ()), (z, (h, d))]
+        if mask is not None:
+            args.append((mask.astype(F32)[..., None], ()))
+        core = {k: params[k] for k in ("conv", "A_log", "dt_bias", "norm")}
+        y, stats = over_row_groups(lambda *a: self._core(core, t, *a), args, rows)
+        y = ops.dot(y.reshape(b, t, h * d), params["Wo"])
+        if mask is not None:
+            y = y * mask[..., None].astype(y.dtype)
+        return y, count_decay(state, *stats) if train else state
+
+
+# ---------------------------------------------------------------------------
+# latent attention
+# ---------------------------------------------------------------------------
+@register_layer
+@dataclass
+class LatentAttention(Layer):
+    """Causal softmax attention whose keys and values come through a
+    normalised bottleneck (MLA) and that knows no positions. Wq
+    [f, n_heads (nope_dim + rope_dim)]; Wkva [f, kv_rank + rope_dim] =
+    [c | kr]; [k_nope | v] = rms(c; kv_norm) Wkvb, n_heads heads of
+    [nope_dim | v_dim]; a head's key is [k_nope | kr], the rope_dim-wide
+    part kr ONE for all heads (the part that would carry rotary positions:
+    here it carries none); scores q k^T (nope_dim + rope_dim)^-0.5 through
+    `ops.attention.attend`, whose flash kernels take a key width that
+    differs from the value width; Wo [n_heads v_dim, f]. No bias."""
+
+    n_heads: int = 32
+    kv_rank: int = 512
+    nope_dim: int = 128
+    rope_dim: int = 64
+    v_dim: int = 128
+    eps: float = 1e-5
+
+    def output_type(self, input_type):
+        return input_type
+
+    def init_params(self, rng, input_type):
+        f = input_type.size
+        h = self.n_heads
+        r = jax.random.split(rng, 4)
+        return {"Wq": _w(self, r[0], (f, h * (self.nope_dim + self.rope_dim))),
+                "Wkva": _w(self, r[1], (f, self.kv_rank + self.rope_dim)),
+                "kv_norm": jnp.ones((self.kv_rank,), F32),
+                "Wkvb": _w(self, r[2], (self.kv_rank, h * (self.nope_dim + self.v_dim))),
+                "Wo": _w(self, r[3], (h * self.v_dim, f))}
+
+    def regularizable(self, params):
+        return {k: v for k, v in params.items() if k.startswith("W")}
+
+    def apply(self, params, x, *, state, train, rng, mask=None):
+        b, t, _ = x.shape
+        h, nope = self.n_heads, self.nope_dim
+
+        def heads(a):  # [b, t, h d] -> [b, h, t, d]
+            return a.reshape(b, t, h, -1).transpose(0, 2, 1, 3)
+
+        q = heads(ops.dot(x, params["Wq"]))
+        ckr = ops.dot(x, params["Wkva"])
+        c = rms_norm(ckr[..., :self.kv_rank], params["kv_norm"], self.eps,
+                     zero_centered=False)
+        kv = heads(ops.dot(c, params["Wkvb"]))
+        kr = jnp.broadcast_to(ckr[:, None, :, self.kv_rank:], (b, h, t, self.rope_dim))
+        k = jnp.concatenate([kv[..., :nope], kr], axis=-1)
+        o = att.attend(q, k, kv[..., nope:], causal=True, mask=mask)
+        y = ops.dot(o.transpose(0, 2, 1, 3).reshape(b, t, h * self.v_dim), params["Wo"])
+        if mask is not None:
+            y = y * mask[..., None].astype(y.dtype)
+        return y, state
+
+
+# ---------------------------------------------------------------------------
 # routed experts
 # ---------------------------------------------------------------------------
 def _swiglu(h):
@@ -518,6 +893,35 @@ def _relu2(h):
 #: name): "swiglu" silu(x Wg) (x Wu) Wd with [gate | up] one matrix;
 #: "relu2" relu(x Wu)^2 Wd
 EXPERT_ACTS = {"swiglu": (_swiglu, 2, "Wgu"), "relu2": (_relu2, 1, "Wu")}
+
+
+@register_layer
+@dataclass
+class GatedMLP(Layer):
+    """A dense feed-forward of `width` with an expert's non-linearity
+    (`EXPERT_ACTS`): "swiglu" (silu(x Wg) (x Wu)) Wd with Wgu = [gate | up]
+    one matrix; "relu2" relu(x Wu)^2 Wd. No bias. The leading dense layer
+    of an otherwise routed stack."""
+
+    width: int = 1024
+    act: str = "swiglu"
+
+    def output_type(self, input_type):
+        return input_type
+
+    def init_params(self, rng, input_type):
+        f = input_type.size
+        _, wide, up = EXPERT_ACTS[self.act]
+        r = jax.random.split(rng, 2)
+        return {up: _w(self, r[0], (f, wide * self.width)),
+                "Wd": _w(self, r[1], (self.width, f))}
+
+    def apply(self, params, x, *, state, train, rng, mask=None):
+        act, _, up = EXPERT_ACTS[self.act]
+        y = ops.dot(act(ops.dot(x, params[up])), params["Wd"])
+        if mask is not None:
+            y = y * mask[..., None].astype(y.dtype)
+        return y, state
 
 
 # The sorted buffer is a permutation of the (slot, token) assignments cut to

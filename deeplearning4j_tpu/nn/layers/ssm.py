@@ -141,22 +141,14 @@ class Mamba2Mixer(Layer):
         }
 
     def init_state(self, input_type):
-        zero = lambda dtype: jnp.zeros((), dtype)  # noqa: E731 — a buffer each: state is donated
-        return {"counters": {"steps": zero(jnp.int32), "decay_sum": zero(F32),
-                             "decay_min_sum": zero(F32), "state_max_sum": zero(F32)}}
+        return hy.decay_counters()
 
     def regularizable(self, params):
         return {k: v for k, v in params.items() if k.startswith("W")}
 
     def counter_summary(self, added):
         """Per-step means of the counters over a fit, under `ssm`."""
-        steps = max(int(added["steps"][0]), 1)
-        return "ssm", {
-            "steps": int(added["steps"][0]),
-            "decay_mean": float(added["decay_sum"][0]) / steps,
-            "decay_min": float(added["decay_min_sum"][0]) / steps,
-            "state_abs_max": float(added["state_max_sum"][0]) / steps,
-        }
+        return hy.decay_summary("ssm", added)
 
     def _core(self, params, t, x, bc, dt, z, mask=None):
         """Everything between the projections, for rows r of t tokens.
@@ -184,8 +176,7 @@ class Mamba2Mixer(Layer):
         y = y * lax.rsqrt(jnp.mean(y * y, axis=(3, 5), keepdims=True) + self.eps)
         y = y.reshape(n, r, h, c, p) * params["norm"].reshape(h, 1, p)
         y = hy.from_chunks(y.astype(z.dtype), t)
-        decay = lax.stop_gradient(jnp.exp(dt * a[:, None]))
-        stats = (jnp.mean(decay), jnp.min(decay), jnp.max(jnp.abs(lax.stop_gradient(states))))
+        stats = hy.decay_stats(jnp.exp(dt * a[:, None]), states)
         return y.reshape(y.shape[:2] + (-1,)), stats
 
     def apply(self, params, x, *, state, train, rng, mask=None):
@@ -203,18 +194,12 @@ class Mamba2Mixer(Layer):
         if mask is not None:
             args.append((mask.astype(F32)[..., None], ()))
         core = {k: params[k] for k in ("conv", "conv_b", "A_log", "D", "dt_bias", "norm")}
-        y, (mean, low, high) = hy.over_row_groups(
+        y, stats = hy.over_row_groups(
             lambda *a: self._core(core, t, *a), args, rows, self.chunk)
         y = ops.dot(y.reshape(b, t, inner), params["Wout"])
         if mask is not None:
             y = y * mask[..., None].astype(y.dtype)
-        if train:
-            c = state["counters"]
-            state = {"counters": {
-                "steps": c["steps"] + 1, "decay_sum": c["decay_sum"] + jnp.mean(mean),
-                "decay_min_sum": c["decay_min_sum"] + jnp.min(low),
-                "state_max_sum": c["state_max_sum"] + jnp.max(high)}}
-        return y, state
+        return y, hy.count_decay(state, *stats) if train else state
 
 
 # ---------------------------------------------------------------------------
@@ -222,6 +207,9 @@ class Mamba2Mixer(Layer):
 # ---------------------------------------------------------------------------
 #: sub-layer kinds by the character a layer pattern names them with
 PATTERN_KINDS = {"M": "mamba", "*": "attention", "E": "experts"}
+#: every kind a `SubLayerBlock` builds: a pattern's, and those only a model
+#: with a per-layer mixer list names (`zoo.DeltaLatentMoELM`)
+KINDS = (*PATTERN_KINDS.values(), "kda", "latent", "dense")
 
 
 def pattern_kinds(pattern: str):
@@ -237,10 +225,12 @@ def pattern_kinds(pattern: str):
 @dataclass
 class SubLayerBlock(Layer):
     """y = x + sublayer(rms(x; w)), `kind` "mamba" (Mamba2Mixer),
-    "attention" (GatedAttention without gate, q/k norms and positions) or
-    "experts" (RoutedExperts). One Layer so networks stay flat lists and
-    `remat` wraps a whole block; params nest the sub-layer's (`norm`,
-    `sub`), state and counters are the sub-layer's own."""
+    "attention" (GatedAttention without gate, q/k norms and positions),
+    "experts" (RoutedExperts), "kda" (KimiDeltaAttention), "latent"
+    (LatentAttention) or "dense" (GatedMLP with the experts'
+    non-linearity). One Layer so networks stay flat lists and `remat` wraps
+    a whole block; params nest the sub-layer's (`norm`, `sub`), state and
+    counters are the sub-layer's own."""
 
     kind: str = "mamba"
     eps: float = 1e-5
@@ -258,6 +248,14 @@ class SubLayerBlock(Layer):
     n_heads: int = 32
     n_kv_heads: int = 2
     head_dim: int = 128
+    # delta rule with a decay a channel: n_heads heads of head_dim
+    # latent attention: n_heads heads, keys [nope_dim | rope_dim], values v_dim
+    kv_rank: int = 512
+    nope_dim: int = 128
+    rope_dim: int = 64
+    v_dim: int = 128
+    # dense feed-forward
+    dense_width: int = 1024
     # routed experts
     n_experts: int = 128
     top_k: int = 6
@@ -295,7 +293,19 @@ class SubLayerBlock(Layer):
                 norm_topk=self.norm_topk, scoring=self.scoring,
                 routed_scale=self.routed_scale, expert_act=self.expert_act,
                 shared_gated=self.shared_gated, weight_init=self.weight_init)
-        raise ValueError(f"kind={self.kind!r}: one of {sorted(PATTERN_KINDS.values())}")
+        if self.kind == "kda":
+            return hy.KimiDeltaAttention(
+                n_heads=self.n_heads, head_dim=self.head_dim,
+                conv_width=self.conv_width, eps=self.eps, weight_init=self.weight_init)
+        if self.kind == "latent":
+            return hy.LatentAttention(
+                n_heads=self.n_heads, kv_rank=self.kv_rank, nope_dim=self.nope_dim,
+                rope_dim=self.rope_dim, v_dim=self.v_dim, eps=self.eps,
+                weight_init=self.weight_init)
+        if self.kind == "dense":
+            return hy.GatedMLP(width=self.dense_width, act=self.expert_act,
+                               weight_init=self.weight_init)
+        raise ValueError(f"kind={self.kind!r}: one of {sorted(KINDS)}")
 
     def init_params(self, rng, input_type):
         return {"norm": {"w": jnp.ones((input_type.size,), F32)},

@@ -23,7 +23,8 @@ Three formulations, all numerically the softmax(QKᵀ/√d)·V contraction:
   online_block   — one online-softmax accumulation step, shared by blockwise
                    and ring attention.
 
-Shapes: q [b, h, tq, d], k/v [b, h, tk, d]. Masks are key-padding masks
+Shapes: q [b, h, tq, d], k [b, h, tk, d], v [b, h, tk, dv] (dv = d unless a
+head's keys carry a part its values lack). Masks are key-padding masks
 [b, tk] (1 = attend) — the BTF mask convention the RNN layers use; `causal`
 adds the lower-triangular constraint.
 """
@@ -119,11 +120,13 @@ def online_block(
     return o_new, l_new, m_new
 
 
-def online_init(q):
+def online_init(q, dv: Optional[int] = None):
+    """The empty online-softmax state for queries q and values `dv` wide
+    (default: as wide as q)."""
     b, h, tq, d = q.shape
     acc_dtype = jnp.float32 if q.dtype == jnp.bfloat16 else q.dtype
     return (
-        jnp.zeros((b, h, tq, d), acc_dtype),
+        jnp.zeros((b, h, tq, dv or d), acc_dtype),
         jnp.zeros((b, h, tq), acc_dtype),
         jnp.full((b, h, tq), NEG_INF, acc_dtype),
     )
@@ -151,7 +154,7 @@ def online_chunks(acc, q, k, v, *, scale, mask=None, causal=False,
         base = jnp.ones((b, tk), q.dtype) if mask is None else mask
         mask = jnp.pad(base, ((0, 0), (0, pad)))
     kb = k.reshape(b, h, nblk, block_size, d).transpose(2, 0, 1, 3, 4)
-    vb = v.reshape(b, h, nblk, block_size, d).transpose(2, 0, 1, 3, 4)
+    vb = v.reshape(b, h, nblk, block_size, -1).transpose(2, 0, 1, 3, 4)
     mb = (mask.reshape(b, nblk, block_size).transpose(1, 0, 2)
           if mask is not None else None)
 
@@ -185,7 +188,7 @@ def blockwise(
     scale = (d ** -0.5) if scale is None else scale
     if k.shape[2] <= block_size:
         return sdpa(q, k, v, mask=mask, causal=causal, scale=scale)
-    acc = online_chunks(online_init(q), q, k, v, scale=scale, mask=mask,
+    acc = online_chunks(online_init(q, v.shape[-1]), q, k, v, scale=scale, mask=mask,
                         causal=causal, block_size=block_size)
     return online_finish(acc).astype(q.dtype)
 
@@ -195,25 +198,61 @@ def blockwise(
 # ---------------------------------------------------------------------------
 
 
-def choose_impl(impl: str, b: int, t: int, d: int, masked: bool,
-                seq_axis: Optional[str] = None) -> str:
+#: float32 score bytes [b, h, t, t] that `sdpa` may be handed when the caller
+#: asked for a kernel and the shape rule declines it: beyond this the call
+#: raises instead (2 x 32 heads at t 8192 are 17 GB)
+SDPA_SCORE_BYTES = 2 ** 32
+
+
+def _head_widths(d) -> Tuple[int, int]:
+    """(key width, value width) from a pair, or from one width for both."""
+    return (d, d) if isinstance(d, int) else (int(d[0]), int(d[1]))
+
+
+def _kernel_admits(dk: int, dv: int, on_tpu: bool) -> bool:
+    """The flash kernels' rule on a head's widths. Interpreted (off TPU)
+    any widths run. On TPU a [t, d] operand is tiled in lanes of 128: the
+    value width, which is the width of the accumulator and of the output
+    block, is 64 or lane-aligned; the key width is only ever contracted
+    over (K Qᵀ) or the sublane side of dQᵀ, so a multiple of 64 does —
+    192 = 128 + 64 of a latent-attention head runs as it is: 64 heads of
+    t 8192, forward + backward, 43.5 ms a call on one v5e against 46.2 with
+    q and k zero-padded to 256 in the door (the pads and slices cost more
+    than the narrower operands save; 41.7 were they born 256 wide; PERF.md
+    section 6, PR 33)."""
+    if not on_tpu:
+        return True
+    return (dv == 64 or dv % 128 == 0) and (dk == dv or dk % 64 == 0)
+
+
+def choose_impl(impl: str, b: int, t: int, d, masked: bool,
+                seq_axis: Optional[str] = None, h: int = 1) -> str:
     """'ring' | 'blockwise' | 'flash' | 'sdpa' for self-attention over
-    [b, h, t, d] heads: a rule on what the call site can see — the layer's
-    request (`attention_impl`), an active sequence axis, the backend, the
-    shapes and the ambient mesh — and nothing else. There is no compile
-    probe: a shape this rule admits and Mosaic refuses fails the step's
-    compile with the kernel's name (which carries the shape) in the error.
+    [b, h, t, .] heads whose keys are dk and whose values dv wide — `d` is
+    the pair (dk, dv), or one int for both: a rule on what the call site
+    can see — the layer's request (`attention_impl`), an active sequence
+    axis, the backend, the shapes and the ambient mesh — and nothing else.
+    There is no compile probe: a shape this rule admits and Mosaic refuses
+    fails the step's compile with the kernel's name (which carries the
+    shape) in the error.
 
     A sequence axis means ring attention whatever was asked for. 'auto'
     admits the flash kernels on TPU only, from t >= 512 (below that XLA's
     materialized-scores path holds while the scores fit on-chip; set by
     builder A/Bs in July, no driver number — ROADMAP S3); an explicit
     'pallas' skips the backend and length gates (CPU tests run it
-    interpreted). Shape rule: no key-padding mask, block-aligned t, head
-    dim 64 or lane-aligned. Mesh rule: the kernel runs per batch shard
+    interpreted). Shape rule: no key-padding mask, block-aligned t, and
+    head widths `_kernel_admits` (dv 64 or lane-aligned; dk = dv, or a
+    multiple of 64). Mesh rule: the kernel runs per batch shard
     (kernel_call.per_batch_shard): 'auto' declines when the batch does not
     split evenly or the mesh shards anything else; an explicit request
-    there raises in per_batch_shard."""
+    there raises in per_batch_shard.
+
+    Where a kernel was wanted (the gates passed) and only the head widths
+    decline it, the fallback is `sdpa`'s materialised [b, h, t, t] float32
+    scores: beyond `SDPA_SCORE_BYTES` of them this raises, with the shape,
+    rather than hand XLA an array no chip holds."""
+    dk, dv = _head_widths(d)
     if seq_axis is not None:
         return "ring"
     if impl == "blockwise":
@@ -226,7 +265,15 @@ def choose_impl(impl: str, b: int, t: int, d: int, masked: bool,
         return "sdpa"
     if masked or not (t <= 128 or t % 128 == 0):
         return "sdpa"
-    if on_tpu and d % 128 != 0 and d != 64:
+    if not _kernel_admits(dk, dv, on_tpu):
+        if 4 * b * h * t * t > SDPA_SCORE_BYTES:
+            raise ValueError(
+                f"attention over q, k [{b}, {h}, {t}, {dk}], v [.., {dv}]: the flash "
+                f"kernels admit a value width of 64 or a multiple of 128 and a key "
+                f"width equal to it or a multiple of 64, and the materialised "
+                f"float32 scores [{b}, {h}, {t}, {t}] are "
+                f"{4 * b * h * t * t / 2 ** 30:.1f} GiB; pad the head or request "
+                f"attention_impl='blockwise'")
         return "sdpa"
     if auto and not kernel_call.per_device_batch(b):
         return "sdpa"
@@ -235,16 +282,17 @@ def choose_impl(impl: str, b: int, t: int, d: int, masked: bool,
 
 def attend(q, k, v, *, causal: bool, mask: Optional[jnp.ndarray] = None,
            impl: str = "auto", block_size: int = 512) -> jnp.ndarray:
-    """Self-attention o [b, h, t, d] of q, k, v [b, h, t, d] by the
-    implementation `choose_impl` names: everything between a layer's
-    heads and its output projection. `impl` is the layer's
-    `attention_impl`, `mask` its [b, t] key-padding mask; `block_size`
-    chunks the keys of the ring and blockwise recurrences."""
+    """Self-attention o [b, h, t, dv] of q, k [b, h, t, dk] and v
+    [b, h, t, dv] by the implementation `choose_impl` names: everything
+    between a layer's heads and its output projection, scaled by
+    dk^-0.5. `impl` is the layer's `attention_impl`, `mask` its [b, t]
+    key-padding mask; `block_size` chunks the keys of the ring and
+    blockwise recurrences."""
     from deeplearning4j_tpu.ops import ring  # ring.py builds on this module
 
-    b, _, t, d = q.shape
+    b, h, t, dk = q.shape
     axis = ring.active_sequence_axis()
-    how = choose_impl(impl, b, t, d, mask is not None, axis)
+    how = choose_impl(impl, b, t, (dk, v.shape[-1]), mask is not None, axis, h)
     if how == "ring":
         return ring.ring_attention_sharded(
             q, k, v, axis_name=axis, mask=mask, causal=causal,
@@ -253,7 +301,7 @@ def attend(q, k, v, *, causal: bool, mask: Optional[jnp.ndarray] = None,
         return blockwise(q, k, v, mask=mask, causal=causal,
                          block_size=block_size)
     if how == "flash":
-        bq, bk = pk.pick_flash_blocks(t, d, q.dtype)
+        bq, bk = pk.pick_flash_blocks(t, dk, q.dtype)
         interpret = kernel_call.interpret()
         return kernel_call.per_batch_shard(
             lambda q_, k_, v_: pk.flash_attention(q_, k_, v_, causal, None,

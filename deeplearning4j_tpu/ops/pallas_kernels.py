@@ -93,17 +93,23 @@ def lstm_helper_mode() -> str:
 _SCOPED_VMEM_DEFAULT = 16 * 2 ** 20   # what Mosaic gives a kernel unasked (v5e)
 _VMEM_CEILING = 110 * 2 ** 20         # of the chip's 128 MiB
 
-def _flash_vmem(t: int, d: int, dtype, *, whole: int, rows: int,
-                scores: int) -> dict:
+def _lanes(*widths: int) -> int:
+    """Columns that [., d] operands of these widths occupy in VMEM
+    together: each in whole tiles of 128."""
+    return sum(-(-d // 128) * 128 for d in widths)
+
+
+def _flash_vmem(t: int, columns: int, dtype, *, rows: int, scores: int) -> dict:
     """`compiler_params` for a flash kernel that keeps resident,
-    double-buffered, `whole` [t, d] operands or results and `rows` float32
-    row statistics [1, t] (a sublane-padded [8, t] tile each), beside the
-    float32 temporaries of one step over `scores` score elements (S, P,
-    dP, dS). Nothing — Mosaic's own scoped limit — while that fits it
-    with room to spare (t 1024, head 64: 3 MiB); a raised limit for a
-    long sequence of wide heads (t 8192, head 256: 16 MiB of K and V
-    alone), which the default refuses at compile time."""
-    need = (2 * (whole * t * d * jnp.dtype(dtype).itemsize + rows * 8 * t * 4)
+    double-buffered, [t, .] operands or results of `columns` columns in
+    all (`_lanes` of their widths) and `rows` float32 row statistics
+    [1, t] (a sublane-padded [8, t] tile each), beside the float32
+    temporaries of one step over `scores` score elements (S, P, dP, dS).
+    Nothing — Mosaic's own scoped limit — while that fits it with room to
+    spare (t 1024, head 64: 5 MiB); a raised limit for a long sequence of
+    wide heads (t 8192, head 256: 16 MiB of K and V alone), which the
+    default refuses at compile time."""
+    need = (2 * (columns * t * jnp.dtype(dtype).itemsize + rows * 8 * t * 4)
             + 4 * scores * 4)
     if need <= 3 * _SCOPED_VMEM_DEFAULT // 4:
         return {}
@@ -197,12 +203,13 @@ def _whole_head(t: int, blk: int) -> bool:
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, bq: int,
                       bk: int, whole: bool, causal: bool, scale: float):
-    """One (batch·head, q-block) program — q_ref [bq, d] — or one
-    batch·head program that walks its q blocks itself — q_ref [t, d];
-    k/v_ref [t, d]. lse_ref (backward-support variant): per-row logsumexp
-    m + log(l), the statistic the blockwise backward needs to rebuild P
-    without a second online softmax."""
-    d = q_ref.shape[1]
+    """One (batch·head, q-block) program — q_ref [bq, dk] — or one
+    batch·head program that walks its q blocks itself — q_ref [t, dk];
+    k_ref [t, dk], v_ref [t, dv] (the value width may differ from the key
+    width; o_ref is as wide as v). lse_ref (backward-support variant):
+    per-row logsumexp m + log(l), the statistic the blockwise backward
+    needs to rebuild P without a second online softmax."""
+    dv = v_ref.shape[1]
     nk = k_ref.shape[0] // bk
 
     def q_block(qi, rows):
@@ -225,7 +232,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, bq: int,
 
         carry = (jnp.full((bq, 1), NEG_INF, jnp.float32),
                  jnp.zeros((bq, 1), jnp.float32),
-                 jnp.zeros((bq, d), jnp.float32))
+                 jnp.zeros((bq, dv), jnp.float32))
         first_masked, end = _q_major_bounds(qi, bq, bk, nk, causal)
         carry = _walk(whole, 0, first_masked, bk,
                       functools.partial(step, masked=False), carry)
@@ -245,19 +252,27 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, bq: int,
         q_block(pl.program_id(1), slice(None))
 
 
+def _flash_widths(d: int, dv: int) -> dict:
+    """The head widths as a kernel's name carries them: `d` alone where
+    keys and values are equally wide (the names every reader knows), else
+    the key width `d` and the value width `dv`."""
+    return {"d": d} if d == dv else {"d": d, "dv": dv}
+
+
 def _flash_fwd(q, k, v, *, causal: bool, scale: float, bq: int, bk: int,
                interpret: bool, return_lse: bool = False):
     b, h, t, d = q.shape
+    dv = v.shape[-1]
     qf = q.reshape(b * h, t, d)
     kf = k.reshape(b * h, t, d)
-    vf = v.reshape(b * h, t, d)
+    vf = v.reshape(b * h, t, dv)
     whole = _whole_head(t, bq)
     rows = t if whole else bq
     grid = (b * h, t // rows)
     kernel = functools.partial(_flash_fwd_kernel, bq=bq, bk=bk, whole=whole,
                                causal=causal, scale=scale)
-    out_shape = jax.ShapeDtypeStruct((b * h, t, d), q.dtype)
-    out_spec = pl.BlockSpec((None, rows, d), lambda i, j: (i, j, 0))
+    out_shape = jax.ShapeDtypeStruct((b * h, t, dv), q.dtype)
+    out_spec = pl.BlockSpec((None, rows, dv), lambda i, j: (i, j, 0))
     if return_lse:
         out_shape = (out_shape,
                      jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32))
@@ -270,26 +285,29 @@ def _flash_fwd(q, k, v, *, causal: bool, scale: float, bq: int, bk: int,
         in_specs=[
             pl.BlockSpec((None, rows, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, t, dv), lambda i, j: (i, 0, 0)),
         ],
         out_specs=out_spec,
-        name=kernel_name("flash_fwd", q.dtype, bh=b * h, t=t, d=d, bq=bq, bk=bk),
+        name=kernel_name("flash_fwd", q.dtype, bh=b * h, t=t,
+                         **_flash_widths(d, dv), bq=bq, bk=bk),
         interpret=interpret,
         # K, V (+ q, o: a head a program)
-        **_flash_vmem(t, d, q.dtype, whole=4 if whole else 2,
+        **_flash_vmem(t, _lanes(d, dv, *((d, dv) if whole else ())), q.dtype,
                       rows=int(return_lse), scores=bq * (t if whole else bk)),
     )(qf, kf, vf)
     if return_lse:
         out, lse = got
-        return out.reshape(b, h, t, d), lse.reshape(b, h, t)
-    return got.reshape(b, h, t, d)
+        return out.reshape(b, h, t, dv), lse.reshape(b, h, t)
+    return got.reshape(b, h, t, dv)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = True,
                     scale: Optional[float] = None, bq: int = 128,
                     bk: int = 128, interpret: bool = False):
-    """Fused attention o = softmax(qkᵀ·scale)v over [b, h, t, d].
+    """Fused attention o = softmax(qkᵀ·scale)v over q, k [b, h, t, d] and
+    v [b, h, t, dv] -> [b, h, t, dv] (dv = d for most models; a latent-
+    attention head carries a positional part in its keys only).
 
     t must divide by the block sizes (pad upstream); numerics match
     ops.attention.sdpa. Backward is one blockwise pallas kernel
@@ -313,8 +331,10 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dK = scale · ΣdSᵀ Q are plain products over the q blocks that attend
     to this k block, and dQᵀ = scale · Kᵀ dSᵀ adds up, over a head's key
     blocks, in the float32 scratch dqt_ref [d, t]; dq_ref [t, d] is
-    written once a head."""
+    written once a head. q, k, dq, dk are d wide, v, dO, dv may be another
+    width."""
     t, d = q_ref.shape
+    dv_ = v_ref.shape[1]
     nq = t // bq
 
     def k_block(kj, rows):
@@ -344,7 +364,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         carry = _walk(whole, start, unmasked, bq,
                       functools.partial(step, masked=True),
                       (jnp.zeros((bk, d), jnp.float32),
-                       jnp.zeros((bk, d), jnp.float32)))
+                       jnp.zeros((bk, dv_), jnp.float32)))
         dk, dv = _walk(whole, unmasked, nq, bq,
                        functools.partial(step, masked=False), carry)
         dk_ref[rows, :] = dk.astype(dk_ref.dtype)
@@ -372,34 +392,41 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bwd(q, k, v, o, lse, g, *, causal: bool, scale: float, bq: int,
                bk: int, interpret: bool):
     b, h, t, d = q.shape
+    dv = v.shape[-1]
     bh = b * h
-    qf, kf, vf = (a.reshape(bh, t, d) for a in (q, k, v))
-    dof = g.reshape(bh, t, d)
+    qf, kf = (a.reshape(bh, t, d) for a in (q, k))
+    vf, dof = (a.reshape(bh, t, dv) for a in (v, g))
     # Δ = rowsum(dO ∘ O): cheap fused elementwise+reduce in XLA
     delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
 
     whole = _whole_head(t, bk)
     krows = t if whole else bk
-    seq = pl.BlockSpec((None, t, d), lambda i, j: (i, 0, 0))
+
+    def seq(w):
+        return pl.BlockSpec((None, t, w), lambda i, j: (i, 0, 0))
+
+    def kblk(w):
+        return pl.BlockSpec((None, krows, w), lambda i, j: (i, j, 0))
+
     row = pl.BlockSpec((None, 1, t), lambda i, j: (i, 0, 0))
-    kblk = pl.BlockSpec((None, krows, d), lambda i, j: (i, j, 0))
-    dq, dk, dv = pl.pallas_call(
+    dq, dk, dv_out = pl.pallas_call(
         functools.partial(_flash_bwd_kernel, bq=bq, bk=bk, whole=whole,
                           causal=causal, scale=scale),
-        out_shape=tuple(jax.ShapeDtypeStruct((bh, t, d), a.dtype)
-                        for a in (q, k, v)),
+        out_shape=tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
+                        for a in (qf, kf, vf)),
         grid=(bh, t // krows),
-        in_specs=[seq, kblk, kblk, seq, row, row],
-        out_specs=(seq, kblk, kblk),
+        in_specs=[seq(d), kblk(d), kblk(dv), seq(dv), row, row],
+        out_specs=(seq(d), kblk(d), kblk(dv)),
         scratch_shapes=[pltpu.VMEM((d, t), jnp.float32)],
-        name=kernel_name("flash_bwd", q.dtype, bh=bh, t=t, d=d, bq=bq, bk=bk),
+        name=kernel_name("flash_bwd", q.dtype, bh=bh, t=t,
+                         **_flash_widths(d, dv), bq=bq, bk=bk),
         interpret=interpret,
         # q, dO, dQ, the float32 dQᵀ (+ K, V, dK, dV: a head a program)
-        **_flash_vmem(t, d, q.dtype, whole=8 if whole else 4, rows=2,
-                      scores=bk * (t if whole else bq)),
+        **_flash_vmem(t, _lanes(d, dv, d, d, *((d, dv, d, dv) if whole else ())),
+                      q.dtype, rows=2, scores=bk * (t if whole else bq)),
     )(qf, kf, vf, dof, lse.reshape(bh, 1, t), delta.reshape(bh, 1, t))
     return (dq.reshape(b, h, t, d), dk.reshape(b, h, t, d),
-            dv.reshape(b, h, t, d))
+            dv_out.reshape(b, h, t, dv))
 
 
 def _flash_vjp_fwd(q, k, v, causal, scale, bq, bk, interpret):

@@ -105,7 +105,7 @@ def ring_attention_sharded(
     perm = [(j, (j + 1) % n) for j in range(n)]
 
     q_off = idx * t_loc
-    acc = att.online_init(q)
+    acc = att.online_init(q, v.shape[-1])
     k_cur, v_cur = k, v
     m_cur = mask
     # n is a static mesh-axis size: a Python loop unrolls into n ppermute +

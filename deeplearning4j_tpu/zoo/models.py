@@ -633,6 +633,103 @@ class PatternHybridLM(ZooModel):
 
 
 @dataclass
+class DeltaLatentMoELM(ZooModel):
+    """Decoder-only LM with a per-layer MIXER LIST and a leading dense
+    layer: layer i (from 1) mixes with delta-rule linear attention whose
+    decay is a vector over the key channels (KDA) if i is in
+    `linear_attn_config["kda_layers"]`, else with latent attention that
+    knows no positions (MLA, `mla_use_nope`); its feed-forward is a dense
+    swiglu of `intermediate_size` for i <= `first_k_dense_replace`, else
+    sigmoid-routed swiglu experts beside ungated shared experts. Each
+    sub-layer sits behind a plain RMS pre-norm and a residual
+    (`SubLayerBlock`: two blocks a layer); final norm, untied bias-free head
+    (the `kimi_linear` shape). The arguments are the keys of the published
+    `config.json`; `num_experts` is the count this rank HOLDS of
+    `num_experts_published` (default: all of them), starting at
+    `experts_first`. Input: [b, t] token ids; labels: [b, t] integer
+    next-token ids (or dense one-hot)."""
+
+    vocab_size: int = 1000
+    hidden_size: int = 256
+    num_hidden_layers: int = 5
+    rms_norm_eps: float = 1e-5
+    max_length: int = 128
+    # which layer mixes how: {"kda_layers": [1, 2, 3, 5, ...], "num_heads",
+    # "head_dim", "short_conv_kernel_size"}; the layers it does not list
+    # are latent attention
+    linear_attn_config: Optional[dict] = None
+    # latent attention
+    num_attention_heads: int = 4
+    kv_lora_rank: int = 64
+    qk_nope_head_dim: int = 32
+    qk_rope_head_dim: int = 16
+    v_head_dim: int = 32
+    # feed-forward
+    first_k_dense_replace: int = 1
+    intermediate_size: int = 512
+    # routed experts
+    num_experts: int = 8
+    num_experts_published: Optional[int] = None
+    experts_first: int = 0
+    num_experts_per_token: int = 2
+    moe_intermediate_size: int = 64
+    num_shared_experts: int = 1
+    routed_scaling_factor: float = 2.446
+    moe_renormalize: bool = True
+    capacity_factor: float = 1.25
+    # per-block activation-checkpoint policy (parallel/layout.py)
+    remat: Optional[str] = None
+
+    def sublayer_kinds(self):
+        """The per-layer (mixer, feed-forward) kinds."""
+        linear = self.linear_attn_config or {"kda_layers": [
+            i for i in range(1, self.num_hidden_layers + 1) if i % 4]}
+        return [("kda" if i in linear["kda_layers"] else "latent",
+                 "dense" if i <= self.first_k_dense_replace else "experts")
+                for i in range(1, self.num_hidden_layers + 1)]
+
+    def conf(self):
+        from deeplearning4j_tpu.nn.layers import (
+            EmbeddingSequence,
+            RMSNorm,
+            SubLayerBlock,
+        )
+
+        linear = self.linear_attn_config or {}
+
+        def block(kind):
+            kda = kind == "kda"
+            return SubLayerBlock(
+                kind=kind, eps=self.rms_norm_eps,
+                n_heads=linear.get("num_heads", 4) if kda else self.num_attention_heads,
+                head_dim=linear.get("head_dim", 32),
+                conv_width=linear.get("short_conv_kernel_size", 4),
+                kv_rank=self.kv_lora_rank, nope_dim=self.qk_nope_head_dim,
+                rope_dim=self.qk_rope_head_dim, v_dim=self.v_head_dim,
+                dense_width=self.intermediate_size,
+                n_experts=self.num_experts_published or self.num_experts,
+                top_k=self.num_experts_per_token,
+                expert_width=self.moe_intermediate_size,
+                shared_width=self.num_shared_experts * self.moe_intermediate_size,
+                experts_held=(self.experts_first, self.num_experts),
+                capacity_factor=self.capacity_factor,
+                norm_topk=self.moe_renormalize, scoring="sigmoid",
+                routed_scale=self.routed_scaling_factor, expert_act="swiglu",
+                shared_gated=False, remat=self.remat)
+
+        return NeuralNetConfiguration(
+            seed=self.seed, updater=updaters.Adam(learning_rate=3e-4),
+            weight_init="xavier",
+        ).list([
+            EmbeddingSequence(n_in=self.vocab_size, n_out=self.hidden_size),
+            *(block(kind) for pair in self.sublayer_kinds() for kind in pair),
+            RMSNorm(eps=self.rms_norm_eps, zero_centered=False),
+            RnnOutput(n_out=self.vocab_size, loss="mcxent",
+                      activation="softmax", has_bias=False),
+        ]).set_input_type(it.recurrent(self.vocab_size, self.max_length))
+
+
+@dataclass
 class VisionTransformer(ZooModel):
     """ViT-style image classifier — net-new 14th zoo architecture (the
     reference zoo is pre-transformer). Patch embedding via a stride=patch
