@@ -72,6 +72,7 @@ class Sizes:
     lstm_chunk_bt: tuple = (8, 1024)
     lstm_full_bt: tuple = (64, 64)
     lstm_n: int = 256
+    kda_nrh: tuple = (128, 1, 32)  # chunks, rows, heads: kimilinear_train_t8192's row
     bn_batch: int = 128
     bn_shapes: tuple = ((112, 112, 64), (28, 28, 128), (56, 56, 256),
                         (28, 28, 512), (14, 14, 1024), (7, 7, 2048))
@@ -559,6 +560,38 @@ def phase_kernels(sz: Sizes):
 
     for hwc in sz.bn_shapes:
         run(f"bn_act {hwc} (opt-in family)", lambda hwc=hwc: bn_case(hwc))
+
+    # ---- the per-channel delta rule's chunk kernels fwd+bwd, through their door
+    def kda_case():
+        from deeplearning4j_tpu.nn.layers import hybrid
+        from deeplearning4j_tpu.ops import delta
+
+        (n, r, h), c, d = sz.kda_nrh, 64, 128
+        unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
+        q = unit(rnd((n, r, h, c, d), jnp.float32)) * d ** -0.5
+        k, v = unit(rnd((n, r, h, c, d), jnp.float32)), rnd((n, r, h, c, d), jnp.float32)
+        # log decays down to -1.6 a token: the fastest channels fall by e^-100 in a chunk
+        g = -jnp.exp(jnp.asarray(rng.uniform(np.log(1e-3), np.log(1.6), (n, r, h, c, d)), jnp.float32))
+        beta = jnp.asarray(rng.uniform(0.0, 1.0, (n, r, h, c)), jnp.float32)
+        ct = rnd((n, r, h, c, d), jnp.float32)
+        assert delta.kda_impl("auto", q, v) == "pallas" or interpret
+
+        def both(f):
+            def run(*a):
+                (o, states), vjp = jax.vjp(f, *a)
+                return (o, states) + tuple(vjp((ct, jnp.zeros_like(states))))
+            return jax.jit(run)
+
+        def ref(*a):
+            with highest:
+                return hybrid.chunk_channel_gated_delta_rule(*a)
+
+        got = both(lambda *a: delta.kda_chunks(*a, impl="pallas"))(q, k, v, g, beta)
+        want = both(ref)(q, k, v, g, beta)
+        _compare(f"kda chunks n={n} r={r} h={h} c={c} d={d} float32 "
+                 f"o,states,dq,dk,dv,dg,dbeta", jnp.float32, got, want, failures)
+
+    run("kda chunks", kda_case)
 
     assert not failures, "kernels: " + "; ".join(failures)
 

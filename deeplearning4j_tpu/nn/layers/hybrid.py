@@ -72,6 +72,7 @@ from deeplearning4j_tpu.nn import initializers as init_mod
 from deeplearning4j_tpu.nn import inputs as it
 from deeplearning4j_tpu.nn.layers.base import REMAT_KEEP, Layer, register_layer
 from deeplearning4j_tpu.ops import attention as att
+from deeplearning4j_tpu.ops import delta
 from deeplearning4j_tpu.ops import linear as ops
 
 F32 = jnp.float32
@@ -788,9 +789,10 @@ class KimiDeltaAttention(Layer):
             f.astype(F32) + params["dt_bias"].reshape(h, 1, -1))
         if mask is not None:  # a padded token writes nothing, keeps the state
             beta, g = beta * mask, g * mask[..., None]
-        o, states = chunk_channel_gated_delta_rule(
-            l2_normalised(qkv[:, :, :h]) * self.head_dim ** -0.5,
-            l2_normalised(qkv[:, :, h:2 * h]), qkv[:, :, 2 * h:], g, beta)
+        rule = (l2_normalised(qkv[:, :, :h]) * self.head_dim ** -0.5,
+                l2_normalised(qkv[:, :, h:2 * h]), qkv[:, :, 2 * h:], g, beta)
+        # the kernel pair where `ops.delta.kda_impl` admits it, else the XLA form
+        o, states = delta.kda_chunks(*rule) or chunk_channel_gated_delta_rule(*rule)
         o = rms_norm(o, params["norm"], self.eps, zero_centered=False)
         y = from_chunks((o * jax.nn.sigmoid(z.astype(F32))).astype(z.dtype), t)
         return y.reshape(y.shape[:2] + (-1,)), decay_stats(jnp.exp(g), states)

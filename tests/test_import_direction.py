@@ -1,7 +1,8 @@
 """The arrows between the packages point one way: nn -> ops, parallel -> ops,
 parallel -> nn. A layer reaches a kernel through its family's entry function
 in `ops/` (`ops.attention.attend`, `ops.fused_lstm`, `ops.fused_affine_act`,
-`ops.fused_linear_xent`), never through the kernel modules or `parallel/`."""
+`ops.fused_linear_xent`, `ops.delta.kda_chunks`), never through the kernel
+modules or `parallel/`."""
 import ast
 import pathlib
 
@@ -27,6 +28,7 @@ def imported_modules(path):
     "deeplearning4j_tpu.parallel",
     "deeplearning4j_tpu.ops.pallas_kernels",
     "deeplearning4j_tpu.ops.xent_kernel",
+    "deeplearning4j_tpu.ops.kda_kernels",
 ])
 def test_nn_does_not_import(forbidden):
     files = sorted((ROOT / "nn").rglob("*.py"))
