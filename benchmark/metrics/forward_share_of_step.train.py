@@ -1,0 +1,14 @@
+"""Per cent of the train step's device time in the PRIMAL forward pass:
+operations under any device scope whose name stack has no `transpose(`
+wrapper (`benchmark/scope_reduce.py`). Under remat 'full' the backward
+region runs about as much again as recompute (the printed table's
+`(recompute)` column: what sits under `rematted_computation`). Left out for
+a program without scopes."""
+from benchmark import scope_reduce
+
+
+def read(run):
+    acct = scope_reduce.scope_account(run)
+    if acct is None or not acct.step_s or not acct.scoped_s:
+        return None
+    return 100.0 * acct.forward_s / acct.step_s

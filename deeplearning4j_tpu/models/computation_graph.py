@@ -35,6 +35,7 @@ from deeplearning4j_tpu.nn.graph_vertices import LayerVertex
 from deeplearning4j_tpu.nn.layers import base as base_mod
 from deeplearning4j_tpu.nn.layers.output import BaseOutputLayer
 from deeplearning4j_tpu.nn.regularization import apply_constraints
+from deeplearning4j_tpu.telemetry.trace import device_scope
 
 PyTree = Any
 
@@ -185,21 +186,27 @@ class ComputationGraph:
                 acts[name] = vin[0] if len(vin) == 1 else vin
                 mask_map[name] = vmasks[0] if vmasks else None
                 continue
+            # the vertex's device scope (telemetry/trace.py), opened
+            # INSIDE what remat wraps: the recompute carries it too
+            kind = type(v.layer if isinstance(v, LayerVertex) else v).__name__
             if (new_carries is not None and isinstance(v, LayerVertex)
                     and isinstance(v.layer, BaseRecurrent)):
-                p = (params[name] if fsdp is None
-                     else fsdp.gather(name, params[name]))
-                p = wn_mod.maybe_transform(v.layer, p, rngs[i], train)
-                y, c_out = v.layer.scan(p, vin[0], new_carries[name],
-                                        mask=vmasks[0] if vmasks else None,
-                                        train=train, rng=rngs[i])
+                with device_scope(kind=kind, layer=name):
+                    p = (params[name] if fsdp is None
+                         else fsdp.gather(name, params[name]))
+                    p = wn_mod.maybe_transform(v.layer, p, rngs[i], train)
+                    y, c_out = v.layer.scan(
+                        p, vin[0], new_carries[name],
+                        mask=vmasks[0] if vmasks else None,
+                        train=train, rng=rngs[i])
                 new_carries[name] = c_out
             else:
-                def run(p_raw, xin, st, r, ms, _v=v, _name=name):
-                    p_g = (p_raw if fsdp is None
-                           else fsdp.gather(_name, p_raw))
-                    return _v.apply(p_g, xin, state=st, train=train,
-                                    rng=r, masks=ms)
+                def run(p_raw, xin, st, r, ms, _v=v, _name=name, _kind=kind):
+                    with device_scope(kind=_kind, layer=_name):
+                        p_g = (p_raw if fsdp is None
+                               else fsdp.gather(_name, p_raw))
+                        return _v.apply(p_g, xin, state=st, train=train,
+                                        rng=r, masks=ms)
 
                 layer = v.layer if isinstance(v, LayerVertex) else None
                 pol = getattr(layer, "remat", None) if layer else None
@@ -242,29 +249,31 @@ class ComputationGraph:
             params, state, inputs, train=train, rng=rng, masks=fmasks,
             carries=carries
         )
-        total = jnp.zeros(())
-        for oi, oname in enumerate(self.conf.network_outputs):
-            v = self.conf.vertices[oname]
-            assert isinstance(v, LayerVertex) and isinstance(v.layer, BaseOutputLayer), (
-                f"output vertex '{oname}' must wrap an output layer"
-            )
-            x_in = acts[oname]
-            lmask = None
-            if lmasks is not None:
-                lmask = lmasks[oi]
-            if lmask is None:
-                lmask = mask_map.get(oname)
-            fsdp = getattr(self, "_fsdp_layout", None)
-            p_out = (params[oname] if fsdp is None
-                     else fsdp.gather(oname, params[oname]))
-            p_out = wn_mod.maybe_transform(v.layer, p_out, rng, train)
-            score, per_ex, out_state = v.layer.compute_loss(
-                p_out, x_in, labels[oi], state=state[oname],
-                mask=lmask, rng=rng,
-            )
-            new_state[oname] = out_state
-            total = total + score
-        return total + self._reg_score(params), (new_state, new_carries)
+        with device_scope(kind="loss"):  # heads, losses and the penalty
+            total = jnp.zeros(())
+            for oi, oname in enumerate(self.conf.network_outputs):
+                v = self.conf.vertices[oname]
+                assert isinstance(v, LayerVertex) and isinstance(v.layer, BaseOutputLayer), (
+                    f"output vertex '{oname}' must wrap an output layer"
+                )
+                x_in = acts[oname]
+                lmask = None
+                if lmasks is not None:
+                    lmask = lmasks[oi]
+                if lmask is None:
+                    lmask = mask_map.get(oname)
+                fsdp = getattr(self, "_fsdp_layout", None)
+                p_out = (params[oname] if fsdp is None
+                         else fsdp.gather(oname, params[oname]))
+                p_out = wn_mod.maybe_transform(v.layer, p_out, rng, train)
+                score, per_ex, out_state = v.layer.compute_loss(
+                    p_out, x_in, labels[oi], state=state[oname],
+                    mask=lmask, rng=rng,
+                )
+                new_state[oname] = out_state
+                total = total + score
+            total = total + self._reg_score(params)
+        return total, (new_state, new_carries)
 
     def _check_policy(self):
         """Invalidate cached jitted fns when the global precision policy
@@ -280,35 +289,36 @@ class ComputationGraph:
     def _apply_updates(self, params, grads, opt_state, iteration):
         """Per-vertex gradient-normalization + updater + constraints —
         shared by the standard and tBPTT train steps."""
-        d = self.conf.defaults
-        new_params, new_opt = {}, {}
-        for name in self.topo:
-            g = grads[name]
-            if not g:
-                new_params[name] = params[name]
-                new_opt[name] = opt_state[name]
-                continue
-            v = self.conf.vertices[name]
-            layer = v.layer if isinstance(v, LayerVertex) else None
-            gn = (layer.gradient_normalization if layer is not None and
-                  layer.gradient_normalization is not None
-                  else d.gradient_normalization)
-            thr = (layer.gradient_normalization_threshold
-                   if layer is not None and
-                   layer.gradient_normalization_threshold is not None
-                   else d.gradient_normalization_threshold)
-            g = upd_mod.normalize_gradients(g, gn, thr)
-            u = self._updaters[name]
-            lr = (d.lr_schedule(u.learning_rate, iteration)
-                  if d.lr_schedule else u.learning_rate)
-            steps_tree, o_new = u.apply(g, opt_state[name], lr)
-            p_new = jax.tree_util.tree_map(lambda p_, s_: p_ - s_,
-                                           params[name], steps_tree)
-            if layer is not None and layer.constraints:
-                p_new = apply_constraints(p_new, layer.constraints)
-            new_params[name] = p_new
-            new_opt[name] = o_new
-        return new_params, new_opt
+        with device_scope(kind="update"):
+            d = self.conf.defaults
+            new_params, new_opt = {}, {}
+            for name in self.topo:
+                g = grads[name]
+                if not g:
+                    new_params[name] = params[name]
+                    new_opt[name] = opt_state[name]
+                    continue
+                v = self.conf.vertices[name]
+                layer = v.layer if isinstance(v, LayerVertex) else None
+                gn = (layer.gradient_normalization if layer is not None and
+                      layer.gradient_normalization is not None
+                      else d.gradient_normalization)
+                thr = (layer.gradient_normalization_threshold
+                       if layer is not None and
+                       layer.gradient_normalization_threshold is not None
+                       else d.gradient_normalization_threshold)
+                g = upd_mod.normalize_gradients(g, gn, thr)
+                u = self._updaters[name]
+                lr = (d.lr_schedule(u.learning_rate, iteration)
+                      if d.lr_schedule else u.learning_rate)
+                steps_tree, o_new = u.apply(g, opt_state[name], lr)
+                p_new = jax.tree_util.tree_map(lambda p_, s_: p_ - s_,
+                                               params[name], steps_tree)
+                if layer is not None and layer.constraints:
+                    p_new = apply_constraints(p_new, layer.constraints)
+                new_params[name] = p_new
+                new_opt[name] = o_new
+            return new_params, new_opt
 
     def _train_step_fn(self):
         """The RAW (unjitted) single train step — `_build_train_step` wraps

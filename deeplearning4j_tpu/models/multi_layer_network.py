@@ -43,6 +43,7 @@ from deeplearning4j_tpu.nn.layers.base import Layer
 from deeplearning4j_tpu.nn.layers.output import BaseOutputLayer
 from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrent
 from deeplearning4j_tpu.nn.regularization import apply_constraints
+from deeplearning4j_tpu.telemetry.trace import device_scope
 from deeplearning4j_tpu.datasets.dataset import DataSet
 from deeplearning4j_tpu.training import engine as engine_mod
 from deeplearning4j_tpu.datasets.iterators import (
@@ -198,19 +199,24 @@ class MultiLayerNetwork:
             if i in self.conf.input_preprocessors:
                 x = self.conf.input_preprocessors[i].transform(x, cur_mask)
             k = _key(i)
+            # the layer's device scope (telemetry/trace.py) is opened
+            # INSIDE what remat wraps: the recompute carries it too
             if carries is not None and isinstance(layer, BaseRecurrent):
-                p_i = params[k] if fsdp is None else fsdp.gather(k, params[k])
-                p_i = wn_mod.maybe_transform(layer, p_i, rngs[i], train)
-                x, c_out = layer.scan(p_i, x, carries[i], mask=cur_mask,
-                                      train=train, rng=rngs[i])
+                with device_scope(kind=type(layer).__name__, layer=i):
+                    p_i = (params[k] if fsdp is None
+                           else fsdp.gather(k, params[k]))
+                    p_i = wn_mod.maybe_transform(layer, p_i, rngs[i], train)
+                    x, c_out = layer.scan(p_i, x, carries[i], mask=cur_mask,
+                                          train=train, rng=rngs[i])
                 new_carries[i] = c_out
             else:
-                def run(p_raw, xx, st, r, m, _layer=layer, _k=k):
-                    p_g = (p_raw if fsdp is None
-                           else fsdp.gather(_k, p_raw))
-                    p_g = wn_mod.maybe_transform(_layer, p_g, r, train)
-                    return _layer.apply(p_g, xx, state=st, train=train,
-                                        rng=r, mask=m)
+                def run(p_raw, xx, st, r, m, _layer=layer, _k=k, _i=i):
+                    with device_scope(kind=type(_layer).__name__, layer=_i):
+                        p_g = (p_raw if fsdp is None
+                               else fsdp.gather(_k, p_raw))
+                        p_g = wn_mod.maybe_transform(_layer, p_g, r, train)
+                        return _layer.apply(p_g, xx, state=st, train=train,
+                                            rng=r, mask=m)
 
                 pol = getattr(layer, "remat", None)
                 if train and pol:
@@ -267,13 +273,14 @@ class MultiLayerNetwork:
         k = _key(len(self.layers) - 1)
         eff_mask = lmask if lmask is not None else cur_mask
         fsdp = getattr(self, "_fsdp_layout", None)
-        p_out = params[k] if fsdp is None else fsdp.gather(k, params[k])
-        p_out = wn_mod.maybe_transform(out_layer, p_out, rng, train)
-        score, per_ex, out_state = out_layer.compute_loss(
-            p_out, h, y, state=state[k], mask=eff_mask, rng=rng
-        )
+        with device_scope(kind="loss"):  # head, loss and the penalty
+            p_out = params[k] if fsdp is None else fsdp.gather(k, params[k])
+            p_out = wn_mod.maybe_transform(out_layer, p_out, rng, train)
+            score, per_ex, out_state = out_layer.compute_loss(
+                p_out, h, y, state=state[k], mask=eff_mask, rng=rng
+            )
+            score = score + self._reg_score(params)
         new_state[k] = out_state
-        score = score + self._reg_score(params)
         return score, new_state
 
     def _apply_updates(self, params, grads, opt_state, iteration):
@@ -281,36 +288,37 @@ class MultiLayerNetwork:
         shared by the standard train step, the tBPTT step, and
         ParallelWrapper's sequence-parallel step (which computes grads
         under shard_map and applies them here)."""
-        d = self.conf.defaults
-        schedule = d.lr_schedule
-        new_params, new_opt = {}, []
-        for i in range(len(self.layers)):
-            k = _key(i)
-            g = grads[k]
-            layer = self.layers[i]
-            if not g or getattr(layer, "frozen", False):
-                new_params[k] = params[k]
-                new_opt.append(opt_state[i])
-                continue
-            gn = (layer.gradient_normalization
-                  if layer.gradient_normalization is not None
-                  else d.gradient_normalization)
-            thr = (layer.gradient_normalization_threshold
-                   if layer.gradient_normalization_threshold is not None
-                   else d.gradient_normalization_threshold)
-            g = upd_mod.normalize_gradients(g, gn, thr)
-            u = self._updaters[i]
-            base_lr = u.learning_rate
-            lr = schedule(base_lr, iteration) if schedule else base_lr
-            steps_tree, new_ou = u.apply(g, opt_state[i], lr)
-            p = jax.tree_util.tree_map(
-                lambda p_, s_: p_ - s_, params[k], steps_tree
-            )
-            if layer.constraints:
-                p = apply_constraints(p, layer.constraints)
-            new_params[k] = p
-            new_opt.append(new_ou)
-        return new_params, new_opt
+        with device_scope(kind="update"):
+            d = self.conf.defaults
+            schedule = d.lr_schedule
+            new_params, new_opt = {}, []
+            for i in range(len(self.layers)):
+                k = _key(i)
+                g = grads[k]
+                layer = self.layers[i]
+                if not g or getattr(layer, "frozen", False):
+                    new_params[k] = params[k]
+                    new_opt.append(opt_state[i])
+                    continue
+                gn = (layer.gradient_normalization
+                      if layer.gradient_normalization is not None
+                      else d.gradient_normalization)
+                thr = (layer.gradient_normalization_threshold
+                       if layer.gradient_normalization_threshold is not None
+                       else d.gradient_normalization_threshold)
+                g = upd_mod.normalize_gradients(g, gn, thr)
+                u = self._updaters[i]
+                base_lr = u.learning_rate
+                lr = schedule(base_lr, iteration) if schedule else base_lr
+                steps_tree, new_ou = u.apply(g, opt_state[i], lr)
+                p = jax.tree_util.tree_map(
+                    lambda p_, s_: p_ - s_, params[k], steps_tree
+                )
+                if layer.constraints:
+                    p = apply_constraints(p, layer.constraints)
+                new_params[k] = p
+                new_opt.append(new_ou)
+            return new_params, new_opt
 
     def _train_step_fn(self):
         """The RAW (unjitted) single train step — `_build_train_step` wraps
@@ -575,11 +583,13 @@ class MultiLayerNetwork:
             )
             k = _key(n_layers - 1)
             eff_mask = lmask if lmask is not None else cur_mask
-            score, per_ex, out_state = out_layer.compute_loss(
-                params[k], h, y, state=state[k], mask=eff_mask, rng=rng
-            )
+            with device_scope(kind="loss"):
+                score, per_ex, out_state = out_layer.compute_loss(
+                    params[k], h, y, state=state[k], mask=eff_mask, rng=rng
+                )
+                score = score + self._reg_score(params)
             new_state[k] = out_state
-            return score + self._reg_score(params), (new_state, new_carries)
+            return score, (new_state, new_carries)
 
         def step(params, state, opt_state, carries, iteration, rng, x, y,
                  fmask, lmask):

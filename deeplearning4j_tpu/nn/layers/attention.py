@@ -36,6 +36,7 @@ from deeplearning4j_tpu.nn.layers.base import Layer, apply_dropout, register_lay
 from deeplearning4j_tpu.ops import attention as att
 from deeplearning4j_tpu.ops import linear as ops
 from deeplearning4j_tpu.ops import ring
+from deeplearning4j_tpu.telemetry.trace import device_scope
 
 
 @register_layer
@@ -214,18 +215,19 @@ class MultiHeadAttention(Layer):
         b, t, f = x.shape
         h = self.n_heads
         d = f // h
-        qkv = ops.bias_add(ops.dot(x, params["Wqkv"]), params["bqkv"])  # [b, t, 3f]
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-
         def heads(a):  # [b, t, f] -> [b, h, t, d]
             return a.reshape(b, t, h, d).transpose(0, 2, 1, 3)
 
-        o = att.attend(heads(q), heads(k), heads(v), causal=self.causal,
-                       mask=mask, impl=self.attention_impl,
-                       block_size=self.block_size)
-        o = o.transpose(0, 2, 1, 3).reshape(b, t, f)
-        y = ops.bias_add(ops.dot(o, params["Wo"]), params["bo"])
-        y = apply_dropout(y, self.attn_dropout if train else None, train, rng)
+        with device_scope("proj"):
+            qkv = ops.bias_add(ops.dot(x, params["Wqkv"]), params["bqkv"])  # [b, t, 3f]
+            q, k, v = (heads(a) for a in jnp.split(qkv, 3, axis=-1))
+        with device_scope("attend"):
+            o = att.attend(q, k, v, causal=self.causal, mask=mask,
+                           impl=self.attention_impl, block_size=self.block_size)
+        with device_scope("out"):
+            o = o.transpose(0, 2, 1, 3).reshape(b, t, f)
+            y = ops.bias_add(ops.dot(o, params["Wo"]), params["bo"])
+            y = apply_dropout(y, self.attn_dropout if train else None, train, rng)
         # zero padded query positions like the RNN layers do
         if mask is not None:
             y = y * mask[..., None].astype(y.dtype)
@@ -313,13 +315,18 @@ class TransformerBlock(Layer):
     def apply(self, params, x, *, state, train, rng, mask=None):
         f = x.shape[-1]
         mha = self._sub(f)
-        a, _ = mha.apply(params["attn"], self._ln(params["ln1"], x),
-                         state={}, train=train, rng=rng, mask=mask)
+        with device_scope("norm"):
+            xn = self._ln(params["ln1"], x)
+        with device_scope(kind=type(mha).__name__):
+            a, _ = mha.apply(params["attn"], xn, state={}, train=train, rng=rng,
+                             mask=mask)
         x = x + a
-        hminus = self._ln(params["ln2"], x)
-        hid = self.act_fn("gelu")(ops.bias_add(ops.dot(hminus, params["W1"]), params["b1"]))
-        hid = apply_dropout(hid, self.dropout if train else None, train, rng)
-        y = x + ops.bias_add(ops.dot(hid, params["W2"]), params["b2"])
+        with device_scope("norm"):
+            hminus = self._ln(params["ln2"], x)
+        with device_scope("mlp"):
+            hid = self.act_fn("gelu")(ops.bias_add(ops.dot(hminus, params["W1"]), params["b1"]))
+            hid = apply_dropout(hid, self.dropout if train else None, train, rng)
+            y = x + ops.bias_add(ops.dot(hid, params["W2"]), params["b2"])
         if mask is not None:
             y = y * mask[..., None].astype(y.dtype)
         return y, state

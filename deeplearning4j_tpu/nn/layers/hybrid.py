@@ -74,6 +74,7 @@ from deeplearning4j_tpu.nn.layers.base import REMAT_KEEP, Layer, register_layer
 from deeplearning4j_tpu.ops import attention as att
 from deeplearning4j_tpu.ops import delta
 from deeplearning4j_tpu.ops import linear as ops
+from deeplearning4j_tpu.telemetry.trace import device_scope
 
 F32 = jnp.float32
 
@@ -172,7 +173,8 @@ class GatedAttention(Layer):
     def apply(self, params, x, *, state, train, rng, mask=None):
         b, t, _ = x.shape
         h, kv, d = self.n_heads, self.n_kv_heads, self.head_dim
-        z = ops.dot(x, params["Wqkv"])
+        with device_scope("proj"):
+            z = ops.dot(x, params["Wqkv"])
         if self.gated:
             q, g, k, v = jnp.split(z, [h * d, 2 * h * d, (2 * h + kv) * d], axis=-1)
         else:
@@ -189,13 +191,17 @@ class GatedAttention(Layer):
                 a = rms_norm(a, params[norm], self.eps)
             return rotary(a, rot, self.rope_theta) if rot else a
 
-        q, k = prepared(q, h, "q_norm"), prepared(k, kv, "k_norm")
-        k, v = (jnp.repeat(a, h // kv, axis=1) for a in (k, heads(v, kv)))
-        o = att.attend(q, k, v, causal=True, mask=mask)
+        with device_scope("gates"):
+            q, k = prepared(q, h, "q_norm"), prepared(k, kv, "k_norm")
+            k, v = (jnp.repeat(a, h // kv, axis=1) for a in (k, heads(v, kv)))
+        with device_scope("attend"):
+            o = att.attend(q, k, v, causal=True, mask=mask)
         o = o.transpose(0, 2, 1, 3).reshape(b, t, h * d)
         if self.gated:
-            o = o * jax.nn.sigmoid(g.astype(F32)).astype(o.dtype)
-        y = ops.dot(o, params["Wo"])
+            with device_scope("gates"):
+                o = o * jax.nn.sigmoid(g.astype(F32)).astype(o.dtype)
+        with device_scope("out"):
+            y = ops.dot(o, params["Wo"])
         if mask is not None:
             y = y * mask[..., None].astype(y.dtype)
         return y, state
@@ -288,12 +294,14 @@ def chunk_gated_delta_rule(q, k, v, g, beta):
     # follows only ever subtracts it, and a sum's cotangent needs no pass
     rhs = jnp.concatenate([v * beta[..., None],
                            k * (-beta * jnp.exp(gc))[..., None]], -1)
-    u, w = _solve_writes(a_mat, rhs, dv)
+    with device_scope("solve"):
+        u, w = _solve_writes(a_mat, rhs, dv)
     qk = jnp.where(lower, qk * decay, 0.0)
     q_dec = q * jnp.exp(gc)[..., None]
     k_dec = k * jnp.exp(gc[..., -1:] - gc)[..., None]
     last = jnp.exp(gc[..., -1])[..., None, None]
-    o, _ = _scan_read(u, w, qk, q_dec, k_dec, last * jnp.eye(dk, dtype=F32))
+    with device_scope("scan"):
+        o, _ = _scan_read(u, w, qk, q_dec, k_dec, last * jnp.eye(dk, dtype=F32))
     return o.reshape(n, b, hv, c, dv)
 
 
@@ -458,10 +466,12 @@ def chunk_channel_gated_delta_rule(q, k, v, g, beta):
         + jnp.eye(c, dtype=F32)
     since, last = jnp.exp(gc), gc[..., -1:, :]
     rhs = jnp.concatenate([v * beta[..., None], k * since * -beta[..., None]], -1)
-    u, w = _solve_writes(a_mat, rhs, dv)
+    with device_scope("solve"):
+        u, w = _solve_writes(a_mat, rhs, dv)
     a_diag = jnp.exp(last)[..., 0, :, None] * jnp.eye(dk, dtype=F32)
-    return _scan_read(u, w, _decayed_scores(q, k, gc), q * since,
-                      k * jnp.exp(last - gc), a_diag)
+    qk = _decayed_scores(q, k, gc)
+    with device_scope("scan"):
+        return _scan_read(u, w, qk, q * since, k * jnp.exp(last - gc), a_diag)
 
 
 def _shift(a, s: int):
@@ -573,9 +583,10 @@ def over_row_groups(core, arrays, rows: int, chunk: int = CHUNK):
     by a block's 'full' remat, because the groups rerun in their own
     backward and need not run in the block's recompute too."""
     b, t = arrays[0][0].shape[:2]
-    args = [a.reshape((b // rows, rows, t) + (tuple(heads) or a.shape[2:]))
-            for a, heads in arrays]
-    args = tuple(jax.vmap(lambda g: to_chunks(g, chunk))(a) for a in args)
+    with device_scope("retile"):
+        args = [a.reshape((b // rows, rows, t) + (tuple(heads) or a.shape[2:]))
+                for a, heads in arrays]
+        args = tuple(jax.vmap(lambda g: to_chunks(g, chunk))(a) for a in args)
     if rows == b:
         return core(*(a[0] for a in args))
     out = lax.map(jax.checkpoint(lambda a: core(*a)), args)
@@ -630,20 +641,27 @@ class GatedDeltaNet(Layer):
         back, of the result in the projection's dtype."""
         hk, hv, cw = self.n_key_heads, self.n_value_heads, self.conv_width
         key = hk * self.key_dim
-        qk = conv_silu(qk, params["conv"][:, :2 * key].reshape(cw, 2 * hk, 1, -1))
-        v = conv_silu(v, params["conv"][:, 2 * key:].reshape(cw, hv, 1, -1))
+        with device_scope("conv"):
+            qk = conv_silu(qk, params["conv"][:, :2 * key].reshape(cw, 2 * hk, 1, -1))
+            v = conv_silu(v, params["conv"][:, 2 * key:].reshape(cw, hv, 1, -1))
 
-        ba = ba.astype(F32)
-        beta = jax.nn.sigmoid(ba[:, :, :hv])
-        g = -jnp.exp(params["A_log"])[:, None] * jax.nn.softplus(
-            ba[:, :, hv:] + params["dt_bias"][:, None])
-        if mask is not None:  # a padded token writes nothing, keeps the state
-            beta, g = beta * mask, g * mask
-        o = chunk_gated_delta_rule(l2_normalised(qk[:, :, :hk]) * self.key_dim ** -0.5,
-                                   l2_normalised(qk[:, :, hk:]), v, g, beta)
-        o = rms_norm(o, params["norm"], self.eps, zero_centered=False)
-        y = from_chunks((o * jax.nn.silu(z.astype(F32))).astype(z.dtype), t)
-        return y.reshape(y.shape[:2] + (-1,))
+        with device_scope("gates"):
+            ba = ba.astype(F32)
+            beta = jax.nn.sigmoid(ba[:, :, :hv])
+            g = -jnp.exp(params["A_log"])[:, None] * jax.nn.softplus(
+                ba[:, :, hv:] + params["dt_bias"][:, None])
+            if mask is not None:  # a padded token writes nothing, keeps the state
+                beta, g = beta * mask, g * mask
+            q = l2_normalised(qk[:, :, :hk]) * self.key_dim ** -0.5
+            k = l2_normalised(qk[:, :, hk:])
+        with device_scope("rule"):
+            o = chunk_gated_delta_rule(q, k, v, g, beta)
+        with device_scope("norm_gate"):
+            o = rms_norm(o, params["norm"], self.eps, zero_centered=False)
+            o = (o * jax.nn.silu(z.astype(F32))).astype(z.dtype)
+        with device_scope("retile"):
+            y = from_chunks(o, t)
+            return y.reshape(y.shape[:2] + (-1,))
 
     #: `CORE_BYTES` for this layer. At 8192 tokens x 8192 channels a row the
     #: convolution input is 268 MB, and so is each of the solve's right-hand
@@ -656,9 +674,10 @@ class GatedDeltaNet(Layer):
         b, t, _ = x.shape
         hk, hv, dk, dv = self.n_key_heads, self.n_value_heads, self.key_dim, self.value_dim
         key, val = hk * dk, hv * dv
-        qkvz = ops.dot(x, params["Wqkvz"])
-        qkv, z = qkvz[..., :2 * key + val], qkvz[..., 2 * key + val:]
-        ba = ops.dot(x, params["Wba"])
+        with device_scope("proj"):
+            qkvz = ops.dot(x, params["Wqkvz"])
+            qkv, z = qkvz[..., :2 * key + val], qkvz[..., 2 * key + val:]
+            ba = ops.dot(x, params["Wba"])
         if mask is not None:  # a padded token enters no convolution window
             qkv = qkv * mask[..., None].astype(qkv.dtype)
         rows = rows_at_a_time(b, t * (2 * key + val) * 4, self.CORE_BYTES)
@@ -669,7 +688,8 @@ class GatedDeltaNet(Layer):
             args.append((mask.astype(F32)[..., None], ()))
         core = {k: params[k] for k in ("conv", "A_log", "dt_bias", "norm")}
         y = over_row_groups(lambda *a: self._core(core, t, *a), args, rows)
-        y = ops.dot(y.reshape(b, t, val), params["Wout"])
+        with device_scope("proj"):
+            y = ops.dot(y.reshape(b, t, val), params["Wout"])
         if mask is not None:
             y = y * mask[..., None].astype(y.dtype)
         return y, state
@@ -782,28 +802,37 @@ class KimiDeltaAttention(Layer):
         gate before its sigmoid) [n, r, h, c, d], ba [n, r, h, c], mask
         [n, r, 1, c] -> ([r, t, h d], the step's counters)."""
         h, cw = self.n_heads, self.conv_width
-        qkv = conv_silu(qkv, params["conv"].reshape(cw, 3 * h, 1, -1))
+        with device_scope("conv"):
+            qkv = conv_silu(qkv, params["conv"].reshape(cw, 3 * h, 1, -1))
 
-        beta = jax.nn.sigmoid(ba.astype(F32))
-        g = -jnp.exp(params["A_log"])[:, None, None] * jax.nn.softplus(
-            f.astype(F32) + params["dt_bias"].reshape(h, 1, -1))
-        if mask is not None:  # a padded token writes nothing, keeps the state
-            beta, g = beta * mask, g * mask[..., None]
-        rule = (l2_normalised(qkv[:, :, :h]) * self.head_dim ** -0.5,
-                l2_normalised(qkv[:, :, h:2 * h]), qkv[:, :, 2 * h:], g, beta)
-        # the kernel pair where `ops.delta.kda_impl` admits it, else the XLA form
-        o, states = delta.kda_chunks(*rule) or chunk_channel_gated_delta_rule(*rule)
-        o = rms_norm(o, params["norm"], self.eps, zero_centered=False)
-        y = from_chunks((o * jax.nn.sigmoid(z.astype(F32))).astype(z.dtype), t)
-        return y.reshape(y.shape[:2] + (-1,)), decay_stats(jnp.exp(g), states)
+        with device_scope("gates"):
+            beta = jax.nn.sigmoid(ba.astype(F32))
+            g = -jnp.exp(params["A_log"])[:, None, None] * jax.nn.softplus(
+                f.astype(F32) + params["dt_bias"].reshape(h, 1, -1))
+            if mask is not None:  # a padded token writes nothing, keeps the state
+                beta, g = beta * mask, g * mask[..., None]
+            rule = (l2_normalised(qkv[:, :, :h]) * self.head_dim ** -0.5,
+                    l2_normalised(qkv[:, :, h:2 * h]), qkv[:, :, 2 * h:], g, beta)
+        with device_scope("rule"):
+            # the kernel pair where `ops.delta.kda_impl` admits it, else the XLA form
+            o, states = delta.kda_chunks(*rule) or chunk_channel_gated_delta_rule(*rule)
+        with device_scope("norm_gate"):
+            o = rms_norm(o, params["norm"], self.eps, zero_centered=False)
+            o = (o * jax.nn.sigmoid(z.astype(F32))).astype(z.dtype)
+        with device_scope("retile"):
+            y = from_chunks(o, t)
+            y = y.reshape(y.shape[:2] + (-1,))
+        with device_scope("counters"):
+            return y, decay_stats(jnp.exp(g), states)
 
     def apply(self, params, x, *, state, train, rng, mask=None):
         b, t, _ = x.shape
         h, d = self.n_heads, self.head_dim
-        qkv = ops.dot(x, params["Wqkv"])
-        low = ops.dot(x, params["Wlow"])
-        f = ops.dot(low[..., :d], params["Wfb"])
-        z = ops.dot(low[..., d:2 * d], params["Wgb"])
+        with device_scope("proj"):
+            qkv = ops.dot(x, params["Wqkv"])
+            low = ops.dot(x, params["Wlow"])
+            f = ops.dot(low[..., :d], params["Wfb"])
+            z = ops.dot(low[..., d:2 * d], params["Wgb"])
         if mask is not None:  # a padded token enters no convolution window
             qkv = qkv * mask[..., None].astype(qkv.dtype)
         rows = rows_at_a_time(b, t * 3 * h * d * 4, self.CORE_BYTES)
@@ -812,10 +841,12 @@ class KimiDeltaAttention(Layer):
             args.append((mask.astype(F32)[..., None], ()))
         core = {k: params[k] for k in ("conv", "A_log", "dt_bias", "norm")}
         y, stats = over_row_groups(lambda *a: self._core(core, t, *a), args, rows)
-        y = ops.dot(y.reshape(b, t, h * d), params["Wo"])
+        with device_scope("proj"):
+            y = ops.dot(y.reshape(b, t, h * d), params["Wo"])
         if mask is not None:
             y = y * mask[..., None].astype(y.dtype)
-        return y, count_decay(state, *stats) if train else state
+        with device_scope("counters"):
+            return y, count_decay(state, *stats) if train else state
 
 
 # ---------------------------------------------------------------------------
@@ -864,15 +895,20 @@ class LatentAttention(Layer):
         def heads(a):  # [b, t, h d] -> [b, h, t, d]
             return a.reshape(b, t, h, -1).transpose(0, 2, 1, 3)
 
-        q = heads(ops.dot(x, params["Wq"]))
-        ckr = ops.dot(x, params["Wkva"])
-        c = rms_norm(ckr[..., :self.kv_rank], params["kv_norm"], self.eps,
-                     zero_centered=False)
-        kv = heads(ops.dot(c, params["Wkvb"]))
-        kr = jnp.broadcast_to(ckr[:, None, :, self.kv_rank:], (b, h, t, self.rope_dim))
-        k = jnp.concatenate([kv[..., :nope], kr], axis=-1)
-        o = att.attend(q, k, kv[..., nope:], causal=True, mask=mask)
-        y = ops.dot(o.transpose(0, 2, 1, 3).reshape(b, t, h * self.v_dim), params["Wo"])
+        with device_scope("proj"):
+            q = heads(ops.dot(x, params["Wq"]))
+            ckr = ops.dot(x, params["Wkva"])
+        with device_scope("gates"):  # the bottleneck's norm
+            c = rms_norm(ckr[..., :self.kv_rank], params["kv_norm"], self.eps,
+                         zero_centered=False)
+        with device_scope("proj"):
+            kv = heads(ops.dot(c, params["Wkvb"]))
+            kr = jnp.broadcast_to(ckr[:, None, :, self.kv_rank:], (b, h, t, self.rope_dim))
+            k = jnp.concatenate([kv[..., :nope], kr], axis=-1)
+        with device_scope("attend"):
+            o = att.attend(q, k, kv[..., nope:], causal=True, mask=mask)
+        with device_scope("out"):
+            y = ops.dot(o.transpose(0, 2, 1, 3).reshape(b, t, h * self.v_dim), params["Wo"])
         if mask is not None:
             y = y * mask[..., None].astype(y.dtype)
         return y, state
@@ -1121,54 +1157,63 @@ class RoutedExperts(Layer):
         k = self.top_k
         first, count = self.held()
         cap = self.capacity(n)
-        local = idx.T - first                   # [k, n]: assignment = slot * n + token
-        key = jnp.where((local >= 0) & (local < count), local, count).reshape(-1)
-        order = jnp.argsort(key, stable=True)   # by expert; what is held comes first
-        inv = jnp.argsort(order)
-        starts = jnp.searchsorted(key[order], jnp.arange(count + 1), side="left")
-        bounds = jnp.minimum(starts, cap)
-        sizes = bounds[1:] - bounds[:-1]
-        sizes = sizes.at[-1].add(cap - bounds[-1])   # the padding is computed
+        with device_scope("sort"):
+            local = idx.T - first               # [k, n]: assignment = slot * n + token
+            key = jnp.where((local >= 0) & (local < count), local, count).reshape(-1)
+            order = jnp.argsort(key, stable=True)   # by expert; what is held comes first
+            inv = jnp.argsort(order)
+            starts = jnp.searchsorted(key[order], jnp.arange(count + 1), side="left")
+            bounds = jnp.minimum(starts, cap)
+            sizes = bounds[1:] - bounds[:-1]
+            sizes = sizes.at[-1].add(cap - bounds[-1])   # the padding is computed
         # the buffer is born at the width the grouped product runs well at:
         # zero columns added to the TOKENS here and cut from the tokens below.
         # The barrier keeps XLA from moving the pad behind the gather, where it
         # is a pass over the buffer (1.83 ms, 8 a step at Nemotron's size)
         padded = ops.grouped_width(f)
-        if padded != f:
-            xf = lax.optimization_barrier(jnp.pad(xf, ((0, 0), (0, padded - f))))
-        xs = _to_buffer(xf, order, inv, cap)
+        with device_scope("gather"):
+            if padded != f:
+                xf = lax.optimization_barrier(jnp.pad(xf, ((0, 0), (0, padded - f))))
+            xs = _to_buffer(xf, order, inv, cap)
         act, wide, up = self._act()
-        ys = ops.grouped_dot(act(ops.grouped_dot(xs, params[up], sizes, wide)),
-                             params["Wd"], sizes)
-        # a slot counts when its expert is held and its position is inside the
-        # buffer; the rows of the others (the last group's padding) weigh 0
-        kept = (key < count) & (inv < cap)
-        out = _from_buffer(ys, jnp.where(kept, top.T.reshape(-1), 0.0).reshape(k, n),
-                           order, inv)[:, :f]
-        load = (starts[1:] - starts[:-1]).astype(jnp.int32)
-        dropped = jnp.maximum(starts[-1] - cap, 0).astype(jnp.int32)
+        with device_scope("product"):
+            ys = ops.grouped_dot(act(ops.grouped_dot(xs, params[up], sizes, wide)),
+                                 params["Wd"], sizes)
+        with device_scope("combine"):
+            # a slot counts when its expert is held and its position is inside the
+            # buffer; the rows of the others (the last group's padding) weigh 0
+            kept = (key < count) & (inv < cap)
+            out = _from_buffer(ys, jnp.where(kept, top.T.reshape(-1), 0.0).reshape(k, n),
+                               order, inv)[:, :f]
+        with device_scope("counters"):
+            load = (starts[1:] - starts[:-1]).astype(jnp.int32)
+            dropped = jnp.maximum(starts[-1] - cap, 0).astype(jnp.int32)
         return out, load, dropped
 
     def apply(self, params, x, *, state, train, rng, mask=None):
         shape = x.shape
         xf = x.reshape(-1, shape[-1])
-        top, idx = self.route(params, xf)
+        with device_scope("route"):
+            top, idx = self.route(params, xf)
         out, load, dropped = self.routed(params, xf, top, idx)
         act, _, up = self._act()
-        gate = (jax.nn.sigmoid(ops.dot(xf, params["shared_gate"]).astype(F32))
-                if self.shared_gated else 1.0)
-        shared = ops.dot(act(ops.dot(xf, params["shared_" + up])), params["shared_Wd"])
-        y = (out + gate * shared.astype(F32)).astype(x.dtype).reshape(shape)
+        with device_scope("shared"):
+            gate = (jax.nn.sigmoid(ops.dot(xf, params["shared_gate"]).astype(F32))
+                    if self.shared_gated else 1.0)
+            shared = ops.dot(act(ops.dot(xf, params["shared_" + up])), params["shared_Wd"])
+        with device_scope("combine"):
+            y = (out + gate * shared.astype(F32)).astype(x.dtype).reshape(shape)
         if mask is not None:
             y = y * mask[..., None].astype(y.dtype)
         if train:
-            c = state["counters"]
-            mean = jnp.maximum(jnp.mean(load.astype(F32)), 1e-9)
-            state = {"counters": {
-                "steps": c["steps"] + 1, "load": c["load"] + load,
-                "dropped": c["dropped"] + dropped,
-                "capacity": c["capacity"] + self.capacity(xf.shape[0]),
-                "ratio_sum": c["ratio_sum"] + jnp.max(load.astype(F32)) / mean}}
+            with device_scope("counters"):
+                c = state["counters"]
+                mean = jnp.maximum(jnp.mean(load.astype(F32)), 1e-9)
+                state = {"counters": {
+                    "steps": c["steps"] + 1, "load": c["load"] + load,
+                    "dropped": c["dropped"] + dropped,
+                    "capacity": c["capacity"] + self.capacity(xf.shape[0]),
+                    "ratio_sum": c["ratio_sum"] + jnp.max(load.astype(F32)) / mean}}
         return y, state
 
 
@@ -1251,11 +1296,16 @@ class HybridBlock(Layer):
         return out
 
     def apply(self, params, x, *, state, train, rng, mask=None):
-        a, _ = self._mixer().apply(
-            params["mixer"], rms_norm(x, params["norm1"]["w"], self.eps),
-            state={}, train=train, rng=rng, mask=mask)
+        mixer, moe = self._mixer(), self._moe()
+        with device_scope("norm"):
+            xn = rms_norm(x, params["norm1"]["w"], self.eps)
+        with device_scope(kind=type(mixer).__name__):
+            a, _ = mixer.apply(params["mixer"], xn, state={}, train=train, rng=rng,
+                               mask=mask)
         h = x + a
-        m, state = self._moe().apply(
-            params["moe"], rms_norm(h, params["norm2"]["w"], self.eps),
-            state=state, train=train, rng=rng, mask=mask)
+        with device_scope("norm"):
+            hn = rms_norm(h, params["norm2"]["w"], self.eps)
+        with device_scope(kind=type(moe).__name__):
+            m, state = moe.apply(params["moe"], hn, state=state, train=train, rng=rng,
+                                 mask=mask)
         return h + m, state

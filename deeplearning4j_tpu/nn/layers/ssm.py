@@ -31,6 +31,7 @@ from jax import lax
 from deeplearning4j_tpu.nn.layers import hybrid as hy
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
 from deeplearning4j_tpu.ops import linear as ops
+from deeplearning4j_tpu.telemetry.trace import device_scope
 
 F32 = jnp.float32
 
@@ -77,8 +78,9 @@ def ssd_chunked(x, dt, a, b, c):
     xd = x * dt[..., None]
     y = mm("nrgeij,nrgejp->nrgeip", cb * decay, xd)
     b_all = mm("nrgejp,nrgjs->nrgeps", xd * jnp.exp(gc[..., -1:] - gc)[..., None], b)
-    _, s_all = lax.scan(_ssd_step, jnp.zeros((r, h, p, s), F32),
-                        (jnp.exp(gc[..., -1]).reshape(n, r, h), b_all.reshape(n, r, h, p, s)))
+    with device_scope("scan"):
+        _, s_all = lax.scan(_ssd_step, jnp.zeros((r, h, p, s), F32),
+                            (jnp.exp(gc[..., -1]).reshape(n, r, h), b_all.reshape(n, r, h, p, s)))
     y = y + jnp.exp(gc)[..., None] * mm("nrgis,nrgeps->nrgeip", c, per_group(s_all))
     return y.reshape(n, r, h, cl, p), s_all
 
@@ -160,30 +162,38 @@ class Mamba2Mixer(Layer):
         dtype."""
         h, p, g, cw = self.n_heads, self.head_dim, self.n_groups, self.conv_width
         inner = h * p
-        x = hy.conv_silu(x, params["conv"][:, :inner].reshape(cw, h, 1, p),
-                         params["conv_b"][:inner].reshape(h, 1, p))
-        bc = hy.conv_silu(bc, params["conv"][:, inner:].reshape(cw, 2 * g, 1, -1),
-                          params["conv_b"][inner:].reshape(2 * g, 1, -1))
-        dt = jax.nn.softplus(dt.astype(F32) + params["dt_bias"][:, None])
-        if mask is not None:  # a padded token writes nothing, keeps the state
-            dt = dt * mask
-        a = -jnp.exp(params["A_log"])
-        y, states = ssd_chunked(x, dt, a, bc[:, :, :g], bc[:, :, g:])
-        y = (y + params["D"][:, None, None] * x) * jax.nn.silu(z.astype(F32))
-        # one mean a group of h / g heads' channels
-        n, r, _, c, _ = y.shape
-        y = y.reshape(n, r, g, h // g, c, p)
-        y = y * lax.rsqrt(jnp.mean(y * y, axis=(3, 5), keepdims=True) + self.eps)
-        y = y.reshape(n, r, h, c, p) * params["norm"].reshape(h, 1, p)
-        y = hy.from_chunks(y.astype(z.dtype), t)
-        stats = hy.decay_stats(jnp.exp(dt * a[:, None]), states)
+        with device_scope("conv"):
+            x = hy.conv_silu(x, params["conv"][:, :inner].reshape(cw, h, 1, p),
+                             params["conv_b"][:inner].reshape(h, 1, p))
+            bc = hy.conv_silu(bc, params["conv"][:, inner:].reshape(cw, 2 * g, 1, -1),
+                              params["conv_b"][inner:].reshape(2 * g, 1, -1))
+        with device_scope("gates"):
+            dt = jax.nn.softplus(dt.astype(F32) + params["dt_bias"][:, None])
+            if mask is not None:  # a padded token writes nothing, keeps the state
+                dt = dt * mask
+            a = -jnp.exp(params["A_log"])
+        with device_scope("rule"):
+            y, states = ssd_chunked(x, dt, a, bc[:, :, :g], bc[:, :, g:])
+        with device_scope("norm_gate"):
+            y = (y + params["D"][:, None, None] * x) * jax.nn.silu(z.astype(F32))
+            # one mean a group of h / g heads' channels
+            n, r, _, c, _ = y.shape
+            y = y.reshape(n, r, g, h // g, c, p)
+            y = y * lax.rsqrt(jnp.mean(y * y, axis=(3, 5), keepdims=True) + self.eps)
+            y = y.reshape(n, r, h, c, p) * params["norm"].reshape(h, 1, p)
+            y = y.astype(z.dtype)
+        with device_scope("retile"):
+            y = hy.from_chunks(y, t)
+        with device_scope("counters"):
+            stats = hy.decay_stats(jnp.exp(dt * a[:, None]), states)
         return y.reshape(y.shape[:2] + (-1,)), stats
 
     def apply(self, params, x, *, state, train, rng, mask=None):
         b, t, _ = x.shape
         h, p, g, s = self.n_heads, self.head_dim, self.n_groups, self.state_dim
         inner, bc = self._widths()
-        zxbcdt = ops.dot(x, params["Win"])
+        with device_scope("proj"):
+            zxbcdt = ops.dot(x, params["Win"])
         z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + bc], axis=-1)
         if mask is not None:  # a padded token enters no convolution window
             xbc = xbc * mask[..., None].astype(xbc.dtype)
@@ -196,10 +206,12 @@ class Mamba2Mixer(Layer):
         core = {k: params[k] for k in ("conv", "conv_b", "A_log", "D", "dt_bias", "norm")}
         y, stats = hy.over_row_groups(
             lambda *a: self._core(core, t, *a), args, rows, self.chunk)
-        y = ops.dot(y.reshape(b, t, inner), params["Wout"])
+        with device_scope("proj"):
+            y = ops.dot(y.reshape(b, t, inner), params["Wout"])
         if mask is not None:
             y = y * mask[..., None].astype(y.dtype)
-        return y, hy.count_decay(state, *stats) if train else state
+        with device_scope("counters"):
+            return y, hy.count_decay(state, *stats) if train else state
 
 
 # ---------------------------------------------------------------------------
@@ -321,7 +333,13 @@ class SubLayerBlock(Layer):
         return {"sub/" + k: v for k, v in self._sub().regularizable(params["sub"]).items()}
 
     def apply(self, params, x, *, state, train, rng, mask=None):
-        a, state = self._sub().apply(
-            params["sub"], hy.rms_norm(x, params["norm"]["w"], self.eps, zero_centered=False),
-            state=state, train=train, rng=rng, mask=mask)
+        sub = self._sub()
+        with device_scope("norm"):
+            xn = hy.rms_norm(x, params["norm"]["w"], self.eps, zero_centered=False)
+        # a dense feed-forward is the block's `mlp`; a mixer or the experts
+        # open parts of their own under their kind
+        with (device_scope("mlp") if self.kind == "dense"
+              else device_scope(kind=type(sub).__name__)):
+            a, state = sub.apply(params["sub"], xn, state=state, train=train, rng=rng,
+                                 mask=mask)
         return x + a, state

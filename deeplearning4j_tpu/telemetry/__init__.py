@@ -102,6 +102,7 @@ from deeplearning4j_tpu.telemetry.trace import (  # noqa: F401
     TELEMETRY_GATE,
     Tracer,
     configure,
+    device_scope,
     fit_log,
     traced,
     tracer,

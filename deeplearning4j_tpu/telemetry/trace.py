@@ -37,6 +37,7 @@ from __future__ import annotations
 import functools
 import json
 import os
+import re
 import statistics
 import threading
 import time
@@ -696,6 +697,62 @@ def fit_log() -> List[Dict[str, Any]]:
     counted in. docs/TELEMETRY.md "Reading a fit's phases"."""
     with _lock:
         return list(_fits)
+
+
+# ---------------------------------------------------------------------------
+# device scopes
+# ---------------------------------------------------------------------------
+
+SCOPE_PREFIX = "dl4j."
+
+#: the words a layer may name a part of itself with (docs/TELEMETRY.md
+#: "Device scopes" says what each covers)
+SCOPE_PARTS = frozenset({
+    # a block around its sub-layers
+    "norm", "mlp",
+    # recurrent mixers
+    "proj", "retile", "conv", "gates", "rule", "solve", "scan", "norm_gate",
+    "counters",
+    # attention
+    "attend", "out",
+    # routed experts
+    "route", "sort", "gather", "product", "combine", "shared",
+})
+
+
+def device_scope(part: Optional[str] = None, *, kind: Optional[str] = None,
+                 layer=None):
+    """The seam beside ``tracer().span()`` for the DEVICE's timeline: a
+    ``jax.named_scope`` that puts one name on JAX's name stack while a
+    step is traced. The stack is every operation's HLO ``op_name``, which
+    the profiler's device trace carries as ``tf_op`` — metadata alone, so
+    there is no gate: the compiled program is the same with or without.
+
+        device_scope(kind="kimideltaattention", layer=3)   dl4j.L3.kimideltaattention
+        device_scope(kind="loss")                          dl4j.loss
+        device_scope("proj")                               proj
+
+    A KIND takes the prefix: with ``layer`` (the index of the layer in
+    its network, or a graph vertex's name) it is the scope a MODEL opens
+    around the one call that applies that layer; without, a model's
+    ``loss`` / ``update`` or the scope a block opens around a layer nested
+    in it. A PART is a plain word of ``SCOPE_PARTS`` that a LAYER opens
+    around a piece of its own work, nested under its kind; parts nest
+    (``rule`` holds ``solve`` and ``scan``). Characters a path cannot hold
+    become ``_``. Grammar and readers: docs/TELEMETRY.md "Device scopes"."""
+    import jax
+
+    if (part is None) == (kind is None):
+        raise ValueError("device_scope takes a part or a kind")
+    if kind is None:
+        if layer is not None or part not in SCOPE_PARTS:
+            raise ValueError(f"device scope part {part!r}: one of "
+                             f"{sorted(SCOPE_PARTS)}, and no layer")
+        return jax.named_scope(part)
+    name = re.sub(r"[^a-z0-9_]", "_", kind.lower())
+    if layer is not None:
+        name = "L" + re.sub(r"[^A-Za-z0-9_-]", "_", str(layer)) + "." + name
+    return jax.named_scope(SCOPE_PREFIX + name)
 
 
 def traced(name: Optional[str] = None, category: str = ""):
