@@ -73,6 +73,7 @@ class Sizes:
     lstm_full_bt: tuple = (64, 64)
     lstm_n: int = 256
     kda_nrh: tuple = (128, 1, 32)  # chunks, rows, heads: kimilinear_train_t8192's row
+    gdn_nrhh: tuple = (128, 1, 16, 32)  # chunks, rows, key and value heads: qwen3next_train_t8192's row
     bn_batch: int = 128
     bn_shapes: tuple = ((112, 112, 64), (28, 28, 128), (56, 56, 256),
                         (28, 28, 512), (14, 14, 1024), (7, 7, 2048))
@@ -592,6 +593,38 @@ def phase_kernels(sz: Sizes):
                  f"o,states,dq,dk,dv,dg,dbeta", jnp.float32, got, want, failures)
 
     run("kda chunks", kda_case)
+
+    # ---- the scalar delta rule's chunk kernels fwd+bwd, through their door
+    def gdn_case():
+        from deeplearning4j_tpu.nn.layers import hybrid
+        from deeplearning4j_tpu.ops import delta
+
+        (n, r, hk, hv), c, d = sz.gdn_nrhh, 64, 128
+        unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
+        q = unit(rnd((n, r, hk, c, d), jnp.float32)) * d ** -0.5
+        k, v = unit(rnd((n, r, hk, c, d), jnp.float32)), rnd((n, r, hv, c, d), jnp.float32)
+        # per-token decays from 0.9999 down to 0.2: the fastest heads fall by e^-100 in a chunk
+        g = -jnp.exp(jnp.asarray(rng.uniform(np.log(1e-4), np.log(1.6), (n, r, hv, c)), jnp.float32))
+        beta = jnp.asarray(rng.uniform(0.0, 1.0, (n, r, hv, c)), jnp.float32)
+        ct = rnd((n, r, hv, c, d), jnp.float32)
+        assert delta.gdn_impl("auto", q, v) == "pallas" or interpret
+
+        def both(f):
+            def run(*a):
+                o, vjp = jax.vjp(f, *a)
+                return (o,) + tuple(vjp(ct))
+            return jax.jit(run)
+
+        def ref(*a):
+            with highest:
+                return hybrid.chunk_gated_delta_rule(*a)
+
+        got = both(lambda *a: delta.gdn_chunks(*a, impl="pallas"))(q, k, v, g, beta)
+        want = both(ref)(q, k, v, g, beta)
+        _compare(f"gdn chunks n={n} r={r} hk={hk} hv={hv} c={c} d={d} float32 "
+                 f"o,dq,dk,dv,dg,dbeta", jnp.float32, got, want, failures)
+
+    run("gdn chunks", gdn_case)
 
     assert not failures, "kernels: " + "; ".join(failures)
 

@@ -22,7 +22,8 @@ state are float32.
   GatedDeltaNet  [q | k | v | z] = x Wqkvz, [b | a] = x Wba; short causal
                  depthwise convolution + silu over [q | k | v]; the gated
                  delta rule S <- exp(g) S; S += k (beta (v - S^T k))^T;
-                 o = S^T q, run in chunks (`chunk_gated_delta_rule`);
+                 o = S^T q, run in chunks (`ops.delta.gdn_chunks`: a
+                 kernel pair on a TPU; `chunk_gated_delta_rule` elsewhere);
                  gated RMS norm by silu(z); Wout. Between the projections
                  everything is chunk-major [n, b, heads, c, d]: re-tiled
                  once in (`to_chunks`) and once out (`from_chunks`), in
@@ -655,7 +656,10 @@ class GatedDeltaNet(Layer):
             q = l2_normalised(qk[:, :, :hk]) * self.key_dim ** -0.5
             k = l2_normalised(qk[:, :, hk:])
         with device_scope("rule"):
-            o = chunk_gated_delta_rule(q, k, v, g, beta)
+            # the kernel pair where `ops.delta.gdn_impl` admits it, else the XLA form
+            o = delta.gdn_chunks(q, k, v, g, beta)
+            if o is None:
+                o = chunk_gated_delta_rule(q, k, v, g, beta)
         with device_scope("norm_gate"):
             o = rms_norm(o, params["norm"], self.eps, zero_centered=False)
             o = (o * jax.nn.silu(z.astype(F32))).astype(z.dtype)
@@ -664,10 +668,14 @@ class GatedDeltaNet(Layer):
             return y.reshape(y.shape[:2] + (-1,))
 
     #: `CORE_BYTES` for this layer. At 8192 tokens x 8192 channels a row the
-    #: convolution input is 268 MB, and so is each of the solve's right-hand
-    #: side and solution and of the scan's A, B and S; q, k, v, the decayed
-    #: q and k, the output and the cotangent of each come to as much again:
-    #: some 3 GB a row while its backward runs
+    #: convolution input is 268 MB, and so are q with k, v, the output and the
+    #: cotangent of each. With the chunk rule as kernels a row's backward
+    #: holds besides them the chunk-start states (268 MB) and what the rerun
+    #: keeps a chunk (scores 134, inverse 67, [U | W] 268): 2.06 GB a row
+    #: while its backward runs, by `memory_analysis()` of one layer alone at
+    #: [1, 8192, 2048] for a described v5e. The XLA form takes 3.28 GB: the
+    #: solve's right-hand side and solution and the scan's A, B and S are
+    #: 268 MB each, and so are their cotangents
     CORE_BYTES = CORE_BYTES
 
     def apply(self, params, x, *, state, train, rng, mask=None):

@@ -1,7 +1,7 @@
 """The arrows between the packages point one way: nn -> ops, parallel -> ops,
 parallel -> nn. A layer reaches a kernel through its family's entry function
 in `ops/` (`ops.attention.attend`, `ops.fused_lstm`, `ops.fused_affine_act`,
-`ops.fused_linear_xent`, `ops.delta.kda_chunks`), never through the kernel
+`ops.fused_linear_xent`, `ops.delta.kda_chunks` / `gdn_chunks`), never through the kernel
 modules or `parallel/`."""
 import ast
 import pathlib
@@ -29,6 +29,8 @@ def imported_modules(path):
     "deeplearning4j_tpu.ops.pallas_kernels",
     "deeplearning4j_tpu.ops.xent_kernel",
     "deeplearning4j_tpu.ops.kda_kernels",
+    "deeplearning4j_tpu.ops.gdn_kernels",
+    "deeplearning4j_tpu.ops.chunk_kernels",
 ])
 def test_nn_does_not_import(forbidden):
     files = sorted((ROOT / "nn").rglob("*.py"))
@@ -36,4 +38,15 @@ def test_nn_does_not_import(forbidden):
     found = [f"{f.relative_to(ROOT)}: {m}" for f in files
              for m in imported_modules(f)
              if m == forbidden or m.startswith(forbidden + ".")]
+    assert not found, found
+
+
+@pytest.mark.parametrize("module,forbidden", [
+    ("gdn_kernels", "kda_kernels"), ("kda_kernels", "gdn_kernels"),
+    ("chunk_kernels", "kda_kernels"), ("chunk_kernels", "gdn_kernels"),
+])
+def test_the_delta_rules_kernels_share_the_skeleton_and_not_each_other(module, forbidden):
+    """`ops/chunk_kernels.py` is what both rules' kernel pairs are built on;
+    neither rule imports the other's arithmetic, nor the skeleton a rule's."""
+    found = [m for m in imported_modules(ROOT / "ops" / f"{module}.py") if forbidden in m]
     assert not found, found
