@@ -261,11 +261,14 @@ class SubLayerBlock(Layer):
     n_kv_heads: int = 2
     head_dim: int = 128
     # delta rule with a decay a channel: n_heads heads of head_dim
-    # latent attention: n_heads heads, keys [nope_dim | rope_dim], values v_dim
+    # latent attention: n_heads heads, keys [nope_dim | rope_dim], values v_dim;
+    # rotary positions on the rope_dim parts where rope_theta is given
     kv_rank: int = 512
     nope_dim: int = 128
     rope_dim: int = 64
     v_dim: int = 128
+    rope_theta: Optional[float] = None
+    rope_interleave: bool = True
     # dense feed-forward
     dense_width: int = 1024
     # routed experts
@@ -313,6 +316,7 @@ class SubLayerBlock(Layer):
             return hy.LatentAttention(
                 n_heads=self.n_heads, kv_rank=self.kv_rank, nope_dim=self.nope_dim,
                 rope_dim=self.rope_dim, v_dim=self.v_dim, eps=self.eps,
+                rope_theta=self.rope_theta, rope_interleave=self.rope_interleave,
                 weight_init=self.weight_init)
         if self.kind == "dense":
             return hy.GatedMLP(width=self.dense_width, act=self.expert_act,

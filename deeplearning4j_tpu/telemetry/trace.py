@@ -714,7 +714,7 @@ SCOPE_PARTS = frozenset({
     "proj", "retile", "conv", "gates", "rule", "solve", "scan", "norm_gate",
     "counters",
     # attention
-    "attend", "out",
+    "rope", "attend", "out",
     # routed experts
     "route", "sort", "gather", "product", "combine", "shared",
 })

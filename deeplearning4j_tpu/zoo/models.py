@@ -637,17 +637,20 @@ class DeltaLatentMoELM(ZooModel):
     """Decoder-only LM with a per-layer MIXER LIST and a leading dense
     layer: layer i (from 1) mixes with delta-rule linear attention whose
     decay is a vector over the key channels (KDA) if i is in
-    `linear_attn_config["kda_layers"]`, else with latent attention that
-    knows no positions (MLA, `mla_use_nope`); its feed-forward is a dense
-    swiglu of `intermediate_size` for i <= `first_k_dense_replace`, else
-    sigmoid-routed swiglu experts beside ungated shared experts. Each
-    sub-layer sits behind a plain RMS pre-norm and a residual
-    (`SubLayerBlock`: two blocks a layer); final norm, untied bias-free head
-    (the `kimi_linear` shape). The arguments are the keys of the published
-    `config.json`; `num_experts` is the count this rank HOLDS of
-    `num_experts_published` (default: all of them), starting at
-    `experts_first`. Input: [b, t] token ids; labels: [b, t] integer
-    next-token ids (or dense one-hot)."""
+    `linear_attn_config["kda_layers"]`, else with latent attention (MLA);
+    its feed-forward is a dense swiglu of `intermediate_size` for i <=
+    `first_k_dense_replace`, else sigmoid-routed swiglu experts beside
+    ungated shared experts. Each sub-layer sits behind a plain RMS pre-norm
+    and a residual (`SubLayerBlock`: two blocks a layer); final norm, untied
+    bias-free head. Two published shapes: `kimi_linear` — KDA layers carry
+    the positions and the latent layers know none (`mla_use_nope`) — and
+    `deepseek_v3` — `kda_layers` empty, EVERY layer latent attention with
+    rotary positions (`rope_theta`, `rope_interleave`) on the rope part of
+    its queries and on the key part all heads share (`mla_use_nope` false).
+    The arguments are the keys of the published `config.json`; `num_experts`
+    is the count this rank HOLDS of `num_experts_published` (default: all of
+    them), starting at `experts_first`. Input: [b, t] token ids; labels:
+    [b, t] integer next-token ids (or dense one-hot)."""
 
     vocab_size: int = 1000
     hidden_size: int = 256
@@ -658,12 +661,16 @@ class DeltaLatentMoELM(ZooModel):
     # "head_dim", "short_conv_kernel_size"}; the layers it does not list
     # are latent attention
     linear_attn_config: Optional[dict] = None
-    # latent attention
+    # latent attention; without `mla_use_nope`, rotary positions on its
+    # qk_rope_head_dim parts
     num_attention_heads: int = 4
     kv_lora_rank: int = 64
     qk_nope_head_dim: int = 32
     qk_rope_head_dim: int = 16
     v_head_dim: int = 32
+    mla_use_nope: bool = True
+    rope_theta: float = 10000.0
+    rope_interleave: bool = True
     # feed-forward
     first_k_dense_replace: int = 1
     intermediate_size: int = 512
@@ -706,6 +713,8 @@ class DeltaLatentMoELM(ZooModel):
                 conv_width=linear.get("short_conv_kernel_size", 4),
                 kv_rank=self.kv_lora_rank, nope_dim=self.qk_nope_head_dim,
                 rope_dim=self.qk_rope_head_dim, v_dim=self.v_head_dim,
+                rope_theta=None if self.mla_use_nope else float(self.rope_theta),
+                rope_interleave=self.rope_interleave,
                 dense_width=self.intermediate_size,
                 n_experts=self.num_experts_published or self.num_experts,
                 top_k=self.num_experts_per_token,
