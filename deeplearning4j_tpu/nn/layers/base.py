@@ -31,16 +31,17 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nn import activations as act_mod
 from deeplearning4j_tpu.nn import inputs as it
 from deeplearning4j_tpu.nn import updaters as upd_mod
+from deeplearning4j_tpu.util import jaxcompat
 
 PyTree = Any
 
 _LAYER_TYPES: Dict[str, type] = {}
 
-#: `jax.ad_checkpoint.checkpoint_name` tag for a value the 'full' remat
-#: policy keeps although it recomputes everything else: the output of a
-#: sub-computation that is ITSELF a checkpoint (it reruns in its own
-#: backward, and would run a third time in the block's recompute).
-REMAT_KEEP = "dl4j_remat_keep"
+#: the tag for a value the 'full' remat policy keeps: one that costs more to
+#: compute again than to keep (the row groups' outputs, `hybrid.over_row_groups`;
+#: the flash forward's output and logsumexp, in `ops/`). Defined where `ops/`
+#: can import it too.
+REMAT_KEEP = jaxcompat.REMAT_KEEP
 
 
 def register_layer(cls):

@@ -50,12 +50,14 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.ops import kernel_call
 from deeplearning4j_tpu.util import envflags
 from deeplearning4j_tpu.util.cotangent import zeros_cotangent
+from deeplearning4j_tpu.util.jaxcompat import REMAT_KEEP
 
 NEG_INF = -1e30
 
@@ -435,6 +437,9 @@ def _flash_vjp_fwd(q, k, v, causal, scale, bq, bk, interpret):
     bk_ = min(bk, q.shape[2])
     out, lse = _flash_fwd(q, k, v, causal=causal, scale=s, bq=bq_, bk=bk_,
                           interpret=interpret, return_lse=True)
+    # all the backward kernel needs beside q, k, v: kept by a block's 'full'
+    # remat, whose recompute then does not call the forward kernel again
+    out, lse = (checkpoint_name(a, REMAT_KEEP) for a in (out, lse))
     return out, (q, k, v, out, lse)
 
 

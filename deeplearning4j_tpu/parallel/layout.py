@@ -42,7 +42,8 @@ policy BY NAME — names lower to jax.checkpoint policies here:
 
     'none'            no checkpointing: full activation stash
     'dots_saveable'   save matmul outputs, recompute elementwise
-    'full'            save nothing (but a layer's REMAT_KEEP tags), recompute the block
+    'full'            recompute the block; save nothing but what is tagged REMAT_KEEP
+                      (a value that costs more to compute again than to keep)
     'offload'         save dot outputs to host memory (pinned_host)
 
 Booleans stay accepted where the old single `remat: bool` flag lived
@@ -86,10 +87,13 @@ def canonical_policy(name: Any) -> str:
 
 def remat_policy(name: Any):
     """The jax.checkpoint `policy=` object for a canonical name ('full'
-    saves nothing but what a layer tags `base.REMAT_KEEP` — the output of an
-    inner checkpoint, so that it is not run a third time). Cached so the
-    same name always returns the SAME callable: a fresh policy closure
-    per call would defeat the jit trace cache."""
+    saves nothing but what is tagged `base.REMAT_KEEP` — a value that costs
+    more to compute again than to keep: the output of an inner checkpoint
+    (`hybrid.over_row_groups`), so that it is not run a third time, and the
+    flash forward kernel's output and logsumexp (`pallas_kernels`), so that
+    it is not run a second time). Cached so the same name always returns
+    the SAME callable: a fresh policy closure per call would defeat the jit
+    trace cache."""
     n = canonical_policy(name)
     if n in _POLICY_CACHE:
         return _POLICY_CACHE[n]

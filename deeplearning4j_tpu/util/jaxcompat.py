@@ -1,13 +1,26 @@
 """The jit seam every hot-path entry point binds through.
 
 ``jit`` is ``jax.jit`` plus the compile watcher (telemetry/introspect.py)
-and the donation metadata the analyzer audits.
+and the donation metadata the analyzer audits. ``REMAT_KEEP`` is the one
+name the 'full' remat policy reads; it stands here because `ops/` and `nn/`
+both tag with it and `ops/` imports nothing of `nn/`.
 """
 from __future__ import annotations
 
 import functools
 
 import jax
+
+#: `jax.ad_checkpoint.checkpoint_name` tag for a value the 'full' remat
+#: policy keeps although it recomputes everything else: a value that costs
+#: more to compute again than to keep. Two uses, one rule: what comes back
+#: from a sub-computation that is ITSELF a checkpoint (`hybrid.over_row_groups`:
+#: it reruns in its own backward, and would run a third time in the block's
+#: recompute), and the flash forward kernel's output and logsumexp
+#: (`pallas_kernels._flash_vjp_fwd`: all its backward kernel needs beside
+#: q, k, v, so the block's recompute does not call the forward kernel again).
+#: Outside a `jax.checkpoint` the tag lowers to nothing.
+REMAT_KEEP = "dl4j_remat_keep"
 
 
 def jit(fn, *, watch_name=None, **jit_kwargs):

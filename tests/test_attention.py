@@ -321,3 +321,154 @@ def test_both_attention_layers_go_through_one_door(rng):
             y, _ = layer.apply(params, x, state={}, train=False, rng=None)
             assert y.shape == x.shape
     assert seen == [((2, 4, 16, 8),) * 3 + (True,)] * 2
+
+
+# ---------------------------------------------------------------------------
+# remat 'full' keeps the flash forward's output and logsumexp (REMAT_KEEP)
+# ---------------------------------------------------------------------------
+FLASH_B, FLASH_T, FLASH_F, FLASH_H, FLASH_DV = 2, 128, 32, 2, 16
+
+
+@pytest.fixture(params=["latent", "gated", "mha"])
+def flash_stack(request, rng):
+    """Two attention blocks, each behind `maybe_remat(., policy)`, whose heads
+    `attend` hands to the flash kernels (interpreted here): `loss(policy)`
+    is a function of (params, x), `block` one residual block."""
+    import functools
+    import unittest.mock as mock
+
+    from deeplearning4j_tpu.nn.layers import GatedAttention, LatentAttention
+    from deeplearning4j_tpu.parallel.layout import maybe_remat
+
+    layer = {
+        # keys 192 = 128 + 64 and values 128 (kanana2, kimi-linear), an eighth the size
+        "latent": LatentAttention(n_heads=FLASH_H, kv_rank=16, nope_dim=16, rope_dim=8,
+                                  v_dim=FLASH_DV),
+        "gated": GatedAttention(n_heads=FLASH_H, n_kv_heads=1, head_dim=FLASH_DV),
+        "mha": MultiHeadAttention(n_heads=FLASH_H, causal=True),
+    }[request.param]
+    itype = it.recurrent(FLASH_F, FLASH_T)
+    params = [layer.init_params(jax.random.PRNGKey(i), itype) for i in range(2)]
+    x = jnp.asarray(rng.standard_normal((FLASH_B, FLASH_T, FLASH_F)), jnp.float32)
+
+    def block(p, h):
+        return h + layer.apply(p, h, state={}, train=True, rng=None)[0]
+
+    def loss(params, x, policy):
+        for p in params:
+            # a function object a trace: a checkpoint's jaxpr is cached by it
+            x = maybe_remat(lambda p, h: block(p, h), policy)(p, x)
+        return jnp.sum(x * x)
+
+    with mock.patch.object(att, "choose_impl", return_value="flash"):
+        yield (lambda policy: functools.partial(loss, policy=policy)), block, params, x
+
+
+def _untagged():
+    """The flash forward rule as the parent commit had it: nothing named."""
+    import unittest.mock as mock
+
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+    return mock.patch.object(pk, "checkpoint_name", lambda a, name: a)
+
+
+def _kernel_calls(jaxpr):
+    """The family of every `pallas_call` in a jaxpr, at any depth, in order."""
+    found = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            found.append(e.params["name"].split("_bh")[0])
+        for inner in jax.core.jaxprs_in_params(e.params):
+            found += _kernel_calls(inner)
+    return found
+
+
+def test_full_remat_calls_the_flash_forward_once_a_layer(flash_stack):
+    """The gradient of two blocks under 'full' holds ONE forward kernel call
+    a layer and one backward: the recompute drops the call whose two results
+    are kept. Untagged, the forward kernel runs twice a layer."""
+    loss, _, params, x = flash_stack
+
+    def calls():
+        jaxpr = jax.make_jaxpr(jax.grad(loss("full")))(params, x)
+        return sorted(_kernel_calls(jaxpr.jaxpr))
+
+    assert calls() == ["dl4j_flash_bwd"] * 2 + ["dl4j_flash_fwd"] * 2
+    with _untagged():
+        assert calls() == ["dl4j_flash_bwd"] * 2 + ["dl4j_flash_fwd"] * 4
+
+
+def test_full_remat_gradient_is_the_unrematted_gradient(flash_stack):
+    """One forward result used twice in place of two equal results: the
+    gradient under 'full' is the gradient under 'none', bit for bit."""
+    loss, _, params, x = flash_stack
+    full = jax.jit(jax.grad(loss("full"), argnums=(0, 1)))(params, x)
+    none = jax.jit(jax.grad(loss("none"), argnums=(0, 1)))(params, x)
+    for a, b in zip(jax.tree_util.tree_leaves(full), jax.tree_util.tree_leaves(none)):
+        assert float(jnp.abs(b).max()) > 0
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_full_remat_saves_the_inputs_the_output_and_the_logsumexp(flash_stack, capsys):
+    """What a checkpointed block keeps for its backward: its arguments, o
+    [b, h, t, dv] and the logsumexp [b, h, t] in float32 — and nothing else;
+    untagged, its arguments alone."""
+    from deeplearning4j_tpu.parallel.layout import maybe_remat
+
+    _, block, params, x = flash_stack
+
+    def kept():
+        # a function object of its own: a checkpoint's trace is cached by it
+        jax.ad_checkpoint.print_saved_residuals(
+            maybe_remat(lambda p, h: block(p, h), "full"), params[0], x)
+        lines = capsys.readouterr().out.strip().splitlines()
+        assert any("from the argument h" in ln for ln in lines)
+        return sorted(ln.split()[0] for ln in lines if "from the argument" not in ln)
+
+    o = f"f32[{FLASH_B},{FLASH_H},{FLASH_T},{FLASH_DV}]"
+    lse = f"f32[{FLASH_B},{FLASH_H},{FLASH_T}]"
+    assert kept() == sorted([o, lse])
+    with _untagged():
+        assert kept() == []
+
+
+def test_the_flash_tag_lowers_to_nothing_outside_a_checkpoint(flash_stack):
+    """No `jax.checkpoint` around it (remat 'none', every forward-only call):
+    the step lowers to the untagged step's text, but for the number the
+    module's symbol table hands a private function (`@_where_58` / `_57`)."""
+    import re
+
+    loss, block, params, x = flash_stack
+
+    def lowered():
+        texts = (jax.jit(jax.grad(loss("none"))).lower(params, x).as_text(),
+                 jax.jit(lambda p, h: block(p, h)).lower(params[0], x).as_text())
+        return [re.sub(r"@(\w+?)_\d+\b", r"@\1", t) for t in texts]
+
+    tagged = lowered()
+    assert "dl4j_remat_keep" not in "".join(tagged)
+    with _untagged():
+        assert lowered() == tagged
+
+
+def test_full_remat_keeps_the_flash_results_under_a_data_mesh(flash_stack):
+    """Under a two-device `data` mesh the kernels run inside
+    `kernel_call.per_batch_shard`'s `shard_map`; the name is carried through
+    it: one forward kernel call a layer there too, each on one device's row."""
+    from deeplearning4j_tpu.parallel import MeshSpec, build_mesh
+
+    loss, _, params, x = flash_stack
+
+    def calls():
+        jaxpr = jax.make_jaxpr(jax.grad(loss("full")))(params, x)
+        assert "shard_map" in str(jaxpr)
+        return sorted(_kernel_calls(jaxpr.jaxpr))
+
+    with jax.set_mesh(build_mesh(MeshSpec(data=2), devices=jax.devices()[:2])):
+        assert calls() == ["dl4j_flash_bwd"] * 2 + ["dl4j_flash_fwd"] * 2
+        with _untagged():
+            assert calls() == ["dl4j_flash_bwd"] * 2 + ["dl4j_flash_fwd"] * 4
+        full, none = (jax.jit(jax.grad(loss(p)))(params, x) for p in ("full", "none"))
+    for a, b in zip(jax.tree_util.tree_leaves(full), jax.tree_util.tree_leaves(none)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

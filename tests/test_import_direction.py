@@ -1,5 +1,6 @@
 """The arrows between the packages point one way: nn -> ops, parallel -> ops,
-parallel -> nn. A layer reaches a kernel through its family's entry function
+parallel -> nn; what `ops/` and `nn/` both need (the `REMAT_KEEP` tag) stands
+in `util/`. A layer reaches a kernel through its family's entry function
 in `ops/` (`ops.attention.attend`, `ops.fused_lstm`, `ops.fused_affine_act`,
 `ops.fused_linear_xent`, `ops.delta.kda_chunks` / `gdn_chunks`), never through the kernel
 modules or `parallel/`."""
@@ -24,6 +25,16 @@ def imported_modules(path):
             yield from (f"{node.module}.{a.name}" for a in node.names)
 
 
+def imports_of(package, forbidden, at_least):
+    """`file: module` for every import of `forbidden`, or of a module under
+    it, in the files of `package` (more than `at_least` of them)."""
+    files = sorted((ROOT / package).rglob("*.py"))
+    assert len(files) > at_least
+    return [f"{f.relative_to(ROOT)}: {m}" for f in files
+            for m in imported_modules(f)
+            if m == forbidden or m.startswith(forbidden + ".")]
+
+
 @pytest.mark.parametrize("forbidden", [
     "deeplearning4j_tpu.parallel",
     "deeplearning4j_tpu.ops.pallas_kernels",
@@ -33,12 +44,21 @@ def imported_modules(path):
     "deeplearning4j_tpu.ops.chunk_kernels",
 ])
 def test_nn_does_not_import(forbidden):
-    files = sorted((ROOT / "nn").rglob("*.py"))
-    assert len(files) > 20
-    found = [f"{f.relative_to(ROOT)}: {m}" for f in files
-             for m in imported_modules(f)
-             if m == forbidden or m.startswith(forbidden + ".")]
-    assert not found, found
+    assert not imports_of("nn", forbidden, at_least=20)
+
+
+@pytest.mark.parametrize("forbidden", ["deeplearning4j_tpu.nn", "deeplearning4j_tpu.parallel"])
+def test_ops_does_not_import(forbidden):
+    assert not imports_of("ops", forbidden, at_least=8)
+
+
+def test_one_remat_keep_tag():
+    """`nn/` and `parallel/` read the tag off `base`, `ops/` off `util/`: one string."""
+    from deeplearning4j_tpu.nn.layers import base
+    from deeplearning4j_tpu.ops import pallas_kernels
+    from deeplearning4j_tpu.util import jaxcompat
+
+    assert base.REMAT_KEEP is jaxcompat.REMAT_KEEP is pallas_kernels.REMAT_KEEP
 
 
 @pytest.mark.parametrize("module,forbidden", [
