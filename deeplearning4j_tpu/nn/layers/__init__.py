@@ -57,6 +57,7 @@ from deeplearning4j_tpu.nn.layers.hybrid import (  # noqa: F401
     GatedAttention,
     GatedDeltaNet,
     GatedMLP,
+    GatedShortConv,
     HybridBlock,
     KimiDeltaAttention,
     LatentAttention,

@@ -19,7 +19,8 @@ state are float32.
                  heads; causal softmax through `ops.attention.attend`
                  (the flash kernels where its rule admits them);
                  o sigmoid(g) Wo. Gate, norms and positions can each be
-                 left out (plain grouped-query attention)
+                 left out (plain grouped-query attention); the norms'
+                 weights zero-centred (1 + w) or plain (w)
   GatedDeltaNet  [q | k | v | z] = x Wqkvz, [b | a] = x Wba; short causal
                  depthwise convolution + silu over [q | k | v]; the gated
                  delta rule S <- exp(g) S; S += k (beta (v - S^T k))^T;
@@ -41,6 +42,9 @@ state are float32.
                  that part and on the queries' matching part alone, or no
                  positions; keys wider than values through
                  `ops.attention.attend`
+  GatedShortConv [B | C | z] = x Win; y = (C conv(B z)) Wout, conv a short
+                 causal depthwise convolution with no activation: a mixer
+                 with no recurrence and no state beyond its last taps
   GatedMLP       act(x W1) Wd with an expert's non-linearity, dense
   RoutedExperts  a router over ALL experts (top-k of a softmax; or sigmoid
                  scores, chosen by score + a selection bias, weighted by
@@ -48,7 +52,8 @@ state are float32.
                  experts HELD (`experts_held` = first, count) through a
                  sorted buffer of static capacity and XLA's grouped
                  product (`ops.linear.grouped_dot`, which pads widths);
-                 SwiGLU or relu^2 experts; a shared expert, gated or not.
+                 SwiGLU or relu^2 experts; a shared expert, gated or not,
+                 or none.
                  Its device work is a function of shapes alone; overflow is
                  counted and left out. Counters live in the layer's state
                  (`counters`) and reach `telemetry.fit_log()` once a fit.
@@ -174,7 +179,8 @@ class GatedAttention(Layer):
     output gate. Wqkv [f, (2 n_heads + 2 n_kv_heads) head_dim] = [q | g | k | v].
     Each of the three can be left out (`gated`, `qk_norm`, `rotary_fraction`
     0): then Wqkv = [q | k | v] and there are no norm weights — plain
-    grouped-query attention that knows no positions."""
+    grouped-query attention that knows no positions. The norms multiply by
+    1 + w, w from zero (`qk_norm_zero_centered`), or by w from one."""
 
     n_heads: int = 16
     n_kv_heads: int = 2
@@ -184,6 +190,7 @@ class GatedAttention(Layer):
     eps: float = 1e-6
     gated: bool = True
     qk_norm: bool = True
+    qk_norm_zero_centered: bool = True
 
     def output_type(self, input_type):
         return input_type
@@ -197,7 +204,8 @@ class GatedAttention(Layer):
         p = {"Wqkv": _w(self, r[0], (f, ((2 if self.gated else 1) * h + 2 * kv) * d)),
              "Wo": _w(self, r[1], (h * d, f))}
         if self.qk_norm:
-            p.update(q_norm=jnp.zeros((d,), F32), k_norm=jnp.zeros((d,), F32))
+            start = jnp.zeros if self.qk_norm_zero_centered else jnp.ones
+            p.update(q_norm=start((d,), F32), k_norm=start((d,), F32))
         return p
 
     def regularizable(self, params):
@@ -221,7 +229,7 @@ class GatedAttention(Layer):
         def prepared(a, n, norm):  # a head's norm, then its positions
             a = heads(a, n)
             if self.qk_norm:
-                a = rms_norm(a, params[norm], self.eps)
+                a = rms_norm(a, params[norm], self.eps, self.qk_norm_zero_centered)
             return rotary(a, rot, self.rope_theta) if rot else a
 
         with device_scope("gates"):
@@ -966,6 +974,63 @@ class LatentAttention(Layer):
 
 
 # ---------------------------------------------------------------------------
+# double-gated short convolution
+# ---------------------------------------------------------------------------
+def short_conv(u, w):
+    """The causal depthwise convolution of u [b, t, f] (float32) with the
+    taps w [cw, f], tap cw - 1 - s on the token s places back, zeros before
+    the sequence; no bias, no activation. Plain [b, t, f]: a mixer with no
+    recurrence needs no chunks. In float32, so that the cw shifted reads —
+    and in the backward their cotangents — add up there and round once."""
+    t, cw = u.shape[1], w.shape[0]
+    return sum(jnp.pad(u, ((0, 0), (s, 0), (0, 0)))[:, :t] * w[cw - 1 - s] for s in range(cw))
+
+
+@register_layer
+@dataclass
+class GatedShortConv(Layer):
+    """A mixer of two gates around a short convolution (the LFM2 `conv`
+    operator): [B | C | z] = x Win, Win [f, 3 f]; u = B z; c = `short_conv`
+    (u; conv [conv_width, f]); y = (C c) Wout, Wout [f, f]. No bias, no
+    activation, no recurrence: two products around three elementwise passes
+    over [b, t, f], which run in float32 between the projections' dtype."""
+
+    conv_width: int = 3
+
+    def output_type(self, input_type):
+        return input_type
+
+    def init_params(self, rng, input_type):
+        f = input_type.size
+        r = jax.random.split(rng, 3)
+        return {"Win": _w(self, r[0], (f, 3 * f)),
+                "conv": jax.random.uniform(r[1], (self.conv_width, f), F32,
+                                           -1.0, 1.0) * self.conv_width ** -0.5,
+                "Wout": _w(self, r[2], (f, f))}
+
+    def regularizable(self, params):
+        return {k: v for k, v in params.items() if k.startswith("W")}
+
+    def apply(self, params, x, *, state, train, rng, mask=None):
+        with device_scope("proj"):
+            bcz = ops.dot(x, params["Win"])
+        with device_scope("gates"):
+            b_, c_, z = (a.astype(F32) for a in jnp.split(bcz, 3, axis=-1))
+            u = b_ * z
+            if mask is not None:  # a padded token enters no convolution window
+                u = u * mask[..., None].astype(F32)
+        with device_scope("conv"):
+            c = short_conv(u, params["conv"])
+        with device_scope("gates"):
+            y = (c_ * c).astype(bcz.dtype)
+        with device_scope("out"):
+            y = ops.dot(y, params["Wout"])
+        if mask is not None:
+            y = y * mask[..., None].astype(y.dtype)
+        return y, state
+
+
+# ---------------------------------------------------------------------------
 # routed experts
 # ---------------------------------------------------------------------------
 def _swiglu(h):
@@ -1074,7 +1139,8 @@ _from_buffer.defvjp(_from_buffer_fwd, _from_buffer_bwd)
 @dataclass
 class RoutedExperts(Layer):
     """Experts behind a router, for a rank that holds `experts_held` =
-    (first, count) of `n_experts` (default: all), plus a shared expert. The
+    (first, count) of `n_experts` (default: all), plus a shared expert
+    (`shared_width` 0: none, no `shared_*` leaf, nothing added). The
     router scores all `n_experts`, keeps the `top_k` largest and
     renormalises them over the chosen wherever they live; this layer adds
     the terms of its own experts and leaves the others' out (on one chip it
@@ -1084,10 +1150,10 @@ class RoutedExperts(Layer):
     the weights are the top-k of the softmax. "sigmoid": every expert is
     scored sigmoid(x Wr) on its own; the k are CHOSEN by score +
     `select_bias` (a leaf no gradient reaches: it chooses, it does not
-    weigh), weighted by the bare scores, renormalised, and scaled by
-    `routed_scale`. `expert_act` (`EXPERT_ACTS`) is the non-linearity of
-    routed and shared experts alike; `shared_gated` multiplies the shared
-    expert by sigmoid(x w) or adds it as it is.
+    weigh), weighted by the bare scores, renormalised (over their sum +
+    `norm_eps`) and scaled by `routed_scale`. `expert_act` (`EXPERT_ACTS`) is
+    the non-linearity of routed and shared experts alike; `shared_gated`
+    multiplies the shared expert by sigmoid(x w) or adds it as it is.
 
     Device work is a function of shapes alone: the (token, expert)
     assignments of the held experts are sorted by expert into a buffer of
@@ -1122,6 +1188,7 @@ class RoutedExperts(Layer):
     routed_scale: float = 1.0
     expert_act: str = "swiglu"
     shared_gated: bool = True
+    norm_eps: float = 1e-20
 
     def held(self):
         return tuple(self.experts_held) if self.experts_held else (0, self.n_experts)
@@ -1154,11 +1221,12 @@ class RoutedExperts(Layer):
         r = jax.random.split(rng, 6)
         p = {"router": _w(self, r[0], (f, self.n_experts)),
              up: _w(self, r[1], (count, f, wide * e)),
-             "Wd": _w(self, r[2], (count, e, f)),
-             "shared_" + up: _w(self, r[3], (f, wide * s)),
-             "shared_Wd": _w(self, r[4], (s, f))}
-        if self.shared_gated:
-            p["shared_gate"] = _w(self, r[5], (f, 1))
+             "Wd": _w(self, r[2], (count, e, f))}
+        if s:
+            p.update({"shared_" + up: _w(self, r[3], (f, wide * s)),
+                      "shared_Wd": _w(self, r[4], (s, f))})
+            if self.shared_gated:
+                p["shared_gate"] = _w(self, r[5], (f, 1))
         if self.scoring == "sigmoid":
             p["select_bias"] = jnp.zeros((self.n_experts,), F32)
         return p
@@ -1194,7 +1262,7 @@ class RoutedExperts(Layer):
             _, idx = lax.top_k(scores + lax.stop_gradient(params["select_bias"]), self.top_k)
             top = jnp.take_along_axis(scores, idx, axis=-1)
             if self.norm_topk:
-                top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+                top = top / (jnp.sum(top, axis=-1, keepdims=True) + self.norm_eps)
             return top * self.routed_scale, idx
         top, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), self.top_k)
         if self.norm_topk:
@@ -1247,13 +1315,16 @@ class RoutedExperts(Layer):
         with device_scope("route"):
             top, idx = self.route(params, xf)
         out, load, dropped = self.routed(params, xf, top, idx)
-        act, _, up = self._act()
-        with device_scope("shared"):
-            gate = (jax.nn.sigmoid(ops.dot(xf, params["shared_gate"]).astype(F32))
-                    if self.shared_gated else 1.0)
-            shared = ops.dot(act(ops.dot(xf, params["shared_" + up])), params["shared_Wd"])
+        if self.shared_width:
+            act, _, up = self._act()
+            with device_scope("shared"):
+                gate = (jax.nn.sigmoid(ops.dot(xf, params["shared_gate"]).astype(F32))
+                        if self.shared_gated else 1.0)
+                shared = ops.dot(act(ops.dot(xf, params["shared_" + up])), params["shared_Wd"])
+            with device_scope("combine"):
+                out = out + gate * shared.astype(F32)
         with device_scope("combine"):
-            y = (out + gate * shared.astype(F32)).astype(x.dtype).reshape(shape)
+            y = out.astype(x.dtype).reshape(shape)
         if mask is not None:
             y = y * mask[..., None].astype(y.dtype)
         if train:

@@ -220,8 +220,8 @@ class Mamba2Mixer(Layer):
 #: sub-layer kinds by the character a layer pattern names them with
 PATTERN_KINDS = {"M": "mamba", "*": "attention", "E": "experts"}
 #: every kind a `SubLayerBlock` builds: a pattern's, and those only a model
-#: with a per-layer mixer list names (`zoo.DeltaLatentMoELM`)
-KINDS = (*PATTERN_KINDS.values(), "kda", "latent", "dense")
+#: with a per-layer mixer list names (`zoo.DeltaLatentMoELM`, `zoo.ShortConvMoELM`)
+KINDS = (*PATTERN_KINDS.values(), "kda", "latent", "dense", "shortconv")
 
 
 def pattern_kinds(pattern: str):
@@ -237,9 +237,11 @@ def pattern_kinds(pattern: str):
 @dataclass
 class SubLayerBlock(Layer):
     """y = x + sublayer(rms(x; w)), `kind` "mamba" (Mamba2Mixer),
-    "attention" (GatedAttention without gate, q/k norms and positions),
-    "experts" (RoutedExperts), "kda" (KimiDeltaAttention), "latent"
-    (LatentAttention) or "dense" (GatedMLP with the experts'
+    "attention" (GatedAttention without gate; without q/k norms and
+    positions too unless `qk_norm` — plain weights — and `rotary_fraction`
+    with `rope_theta` ask for them), "experts" (RoutedExperts), "kda"
+    (KimiDeltaAttention), "latent" (LatentAttention), "shortconv"
+    (GatedShortConv) or "dense" (GatedMLP with the experts'
     non-linearity). One Layer so networks stay flat lists and `remat` wraps
     a whole block; params nest the sub-layer's (`norm`, `sub`), state and
     counters are the sub-layer's own."""
@@ -260,9 +262,12 @@ class SubLayerBlock(Layer):
     n_heads: int = 32
     n_kv_heads: int = 2
     head_dim: int = 128
+    qk_norm: bool = False
+    rotary_fraction: float = 0.0
     # delta rule with a decay a channel: n_heads heads of head_dim
     # latent attention: n_heads heads, keys [nope_dim | rope_dim], values v_dim;
-    # rotary positions on the rope_dim parts where rope_theta is given
+    # rotary positions on the rope_dim parts where rope_theta is given (softmax
+    # attention: on `rotary_fraction` of a head)
     kv_rank: int = 512
     nope_dim: int = 128
     rope_dim: int = 64
@@ -283,6 +288,7 @@ class SubLayerBlock(Layer):
     routed_scale: float = 2.5
     expert_act: str = "relu2"
     shared_gated: bool = False
+    norm_eps: float = 1e-20
 
     def output_type(self, input_type):
         return input_type
@@ -296,10 +302,12 @@ class SubLayerBlock(Layer):
                 dt_min=self.dt_min, dt_max=self.dt_max, dt_floor=self.dt_floor,
                 weight_init=self.weight_init)
         if self.kind == "attention":
+            rope = {"rope_theta": self.rope_theta} if self.rotary_fraction else {}
             return hy.GatedAttention(
                 n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
-                head_dim=self.head_dim, rotary_fraction=0.0, gated=False,
-                qk_norm=False, weight_init=self.weight_init)
+                head_dim=self.head_dim, rotary_fraction=self.rotary_fraction, gated=False,
+                qk_norm=self.qk_norm, qk_norm_zero_centered=False, eps=self.eps,
+                weight_init=self.weight_init, **rope)
         if self.kind == "experts":
             return hy.RoutedExperts(
                 n_experts=self.n_experts, top_k=self.top_k,
@@ -307,7 +315,8 @@ class SubLayerBlock(Layer):
                 experts_held=self.experts_held, capacity_factor=self.capacity_factor,
                 norm_topk=self.norm_topk, scoring=self.scoring,
                 routed_scale=self.routed_scale, expert_act=self.expert_act,
-                shared_gated=self.shared_gated, weight_init=self.weight_init)
+                shared_gated=self.shared_gated, norm_eps=self.norm_eps,
+                weight_init=self.weight_init)
         if self.kind == "kda":
             return hy.KimiDeltaAttention(
                 n_heads=self.n_heads, head_dim=self.head_dim,
@@ -318,6 +327,8 @@ class SubLayerBlock(Layer):
                 rope_dim=self.rope_dim, v_dim=self.v_dim, eps=self.eps,
                 rope_theta=self.rope_theta, rope_interleave=self.rope_interleave,
                 weight_init=self.weight_init)
+        if self.kind == "shortconv":
+            return hy.GatedShortConv(conv_width=self.conv_width, weight_init=self.weight_init)
         if self.kind == "dense":
             return hy.GatedMLP(width=self.dense_width, act=self.expert_act,
                                weight_init=self.weight_init)

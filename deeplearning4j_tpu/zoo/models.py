@@ -739,6 +739,103 @@ class DeltaLatentMoELM(ZooModel):
 
 
 @dataclass
+class ShortConvMoELM(ZooModel):
+    """Decoder-only LM whose mixers are named by `layer_types`, one entry a
+    published layer: "conv" a double-gated short convolution
+    (`GatedShortConv`, taps `conv_L_cache`, no bias), "full_attention"
+    grouped-query softmax attention with plain-weight RMS norms on every
+    head of q and k and rotary positions over the whole head
+    (`rope_parameters["rope_theta"]`, half-split pairs). The feed-forward of
+    published layer i (from 0) is a dense swiglu of `intermediate_size` for
+    i < `num_dense_layers`, else sigmoid-routed swiglu experts chosen by
+    score + a selection bias, renormalised over the chosen (+ 1e-6), with
+    NO shared expert. Each sub-layer sits behind a plain RMS pre-norm and a
+    residual (`SubLayerBlock`: two blocks a layer); final norm, untied
+    bias-free head (the `lfm2_moe` shape). The arguments are the keys of
+    the published `config.json`; `num_hidden_layers` layers are BUILT, the
+    published layers `layers_first` .. (a pipeline stage's share), and
+    `num_experts` is the count this rank HOLDS of `num_experts_published`
+    (default: all of them), starting at `experts_first`. Input: [b, t]
+    token ids; labels: [b, t] integer next-token ids (or dense one-hot)."""
+
+    vocab_size: int = 1000
+    hidden_size: int = 256
+    num_hidden_layers: int = 4
+    layers_first: int = 0
+    norm_eps: float = 1e-5
+    max_length: int = 128
+    # which published layer mixes how; default: attention every fourth
+    # layer from the third on
+    layer_types: Optional[Sequence[str]] = None
+    conv_L_cache: int = 3
+    num_attention_heads: int = 4
+    num_key_value_heads: int = 2
+    rope_parameters: Optional[dict] = None
+    # feed-forward
+    num_dense_layers: int = 2
+    intermediate_size: int = 512
+    # routed experts
+    num_experts: int = 8
+    num_experts_published: Optional[int] = None
+    experts_first: int = 0
+    num_experts_per_tok: int = 2
+    moe_intermediate_size: int = 64
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    capacity_factor: float = 1.25
+    # per-block activation-checkpoint policy (parallel/layout.py)
+    remat: Optional[str] = None
+
+    def sublayer_kinds(self):
+        """The (mixer, feed-forward) kinds of the layers built."""
+        held = range(self.layers_first, self.layers_first + self.num_hidden_layers)
+        types = self.layer_types or [
+            "full_attention" if i % 4 == 2 else "conv" for i in range(held.stop)]
+        mixers = {"conv": "shortconv", "full_attention": "attention"}
+        bad = sorted(set(types) - set(mixers))
+        if bad or held.stop > len(types):
+            raise ValueError(f"layer_types: {len(types)} entries for layers {held}, "
+                             f"of them {bad} none of {sorted(mixers)}")
+        return [(mixers[types[i]], "dense" if i < self.num_dense_layers else "experts")
+                for i in held]
+
+    def conf(self):
+        from deeplearning4j_tpu.nn.layers import (
+            EmbeddingSequence,
+            RMSNorm,
+            SubLayerBlock,
+        )
+
+        def block(kind):
+            return SubLayerBlock(
+                kind=kind, eps=self.norm_eps, conv_width=self.conv_L_cache,
+                n_heads=self.num_attention_heads, n_kv_heads=self.num_key_value_heads,
+                head_dim=self.hidden_size // self.num_attention_heads,
+                qk_norm=True, rotary_fraction=1.0,
+                rope_theta=float((self.rope_parameters or {}).get("rope_theta", 1e6)),
+                dense_width=self.intermediate_size,
+                n_experts=self.num_experts_published or self.num_experts,
+                top_k=self.num_experts_per_tok,
+                expert_width=self.moe_intermediate_size, shared_width=0,
+                experts_held=(self.experts_first, self.num_experts),
+                capacity_factor=self.capacity_factor,
+                norm_topk=self.norm_topk_prob, scoring="sigmoid",
+                routed_scale=self.routed_scaling_factor, expert_act="swiglu",
+                norm_eps=1e-6, remat=self.remat)
+
+        return NeuralNetConfiguration(
+            seed=self.seed, updater=updaters.Adam(learning_rate=3e-4),
+            weight_init="xavier",
+        ).list([
+            EmbeddingSequence(n_in=self.vocab_size, n_out=self.hidden_size),
+            *(block(kind) for pair in self.sublayer_kinds() for kind in pair),
+            RMSNorm(eps=self.norm_eps, zero_centered=False),
+            RnnOutput(n_out=self.vocab_size, loss="mcxent",
+                      activation="softmax", has_bias=False),
+        ]).set_input_type(it.recurrent(self.vocab_size, self.max_length))
+
+
+@dataclass
 class VisionTransformer(ZooModel):
     """ViT-style image classifier — net-new 14th zoo architecture (the
     reference zoo is pre-transformer). Patch embedding via a stride=patch
