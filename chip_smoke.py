@@ -74,6 +74,9 @@ class Sizes:
     lstm_n: int = 256
     kda_nrh: tuple = (128, 1, 32)  # chunks, rows, heads: kimilinear_train_t8192's row
     gdn_nrhh: tuple = (128, 1, 16, 32)  # chunks, rows, key and value heads: qwen3next_train_t8192's row
+    # the short convolution's operands [n, r, h, c, d] and whether a bias: Kimi-Linear's, Nemotron's two
+    conv_shapes: tuple = (((128, 1, 96, 64, 128), False), ((64, 1, 64, 128, 64), True),
+                          ((64, 1, 16, 128, 128), True))
     bn_batch: int = 128
     bn_shapes: tuple = ((112, 112, 64), (28, 28, 128), (56, 56, 256),
                         (28, 28, 512), (14, 14, 1024), (7, 7, 2048))
@@ -625,6 +628,35 @@ def phase_kernels(sz: Sizes):
                  f"o,dq,dk,dv,dg,dbeta", jnp.float32, got, want, failures)
 
     run("gdn chunks", gdn_case)
+
+    # ---- the recurrent mixers' short convolution + silu fwd+bwd, through its door:
+    # tokens on the sublanes (d 128) and on the lanes (d 64, with a bias)
+    def conv_case(shape, bias):
+        from deeplearning4j_tpu.nn.layers import hybrid
+        from deeplearning4j_tpu.ops import delta
+
+        h, d = shape[2], shape[4]
+        x, ct = rnd(shape, jnp.bfloat16), rnd(shape, jnp.float32)
+        w = rnd((4, h, 1, d), jnp.float32, 0.5)
+        b = rnd((h, 1, d), jnp.float32, 0.5) if bias else None
+        assert delta.conv_silu_impl("auto", x, w) == "pallas" or interpret
+
+        def both(f):
+            def run(*a):
+                y, vjp = jax.vjp(f, *a)
+                return (y,) + tuple(vjp(ct))
+            return jax.jit(run)
+
+        args = (x, w) + ((b,) if bias else ())
+        got = both(lambda *a: delta.conv_silu_chunks(*a, impl="pallas"))(*args)
+        want = both(hybrid._conv_silu if bias else lambda x_, w_: hybrid._conv_silu(x_, w_, None))(*args)
+        # dx is bfloat16 on both sides: a unit in its last place apart where the float32 sums differ
+        _compare(f"conv silu {shape} bias={bias} y,dw" + (",db" if bias else ""), jnp.float32,
+                 got[:1] + got[2:], want[:1] + want[2:], failures)
+        _compare(f"conv silu {shape} bias={bias} dx", jnp.bfloat16, got[1:2], want[1:2], failures)
+
+    for shape, bias in sz.conv_shapes:
+        run(f"conv silu {shape}", lambda shape=shape, bias=bias: conv_case(shape, bias))
 
     assert not failures, "kernels: " + "; ".join(failures)
 

@@ -59,11 +59,13 @@ state are float32.
                  (`counters`) and reach `telemetry.fit_log()` once a fit.
   HybridBlock    h = x + mixer(rms(x)); y = h + experts(rms(h))
 
-`to_chunks`, `conv_silu`, `from_chunks`, the row mapping (`rows_at_a_time`,
-`over_row_groups`) and the decay counters (`decay_counters`, ..) also serve
-the state-space mixer of `ssm.py`, which has the block of ONE sub-layer
-(`SubLayerBlock`: every kind of mixer and feed-forward here but the
-Qwen3-Next pair, which `HybridBlock` stacks).
+`to_chunks`, `conv_silu` (on a TPU the kernel pair `dl4j_convsilu_fwd` /
+`dl4j_convsilu_bwd` behind `ops.delta.conv_silu_chunks`, wherever its rule
+admits the operands; XLA elsewhere), `from_chunks`, the row mapping
+(`rows_at_a_time`, `over_row_groups`) and the decay counters
+(`decay_counters`, ..) also serve the state-space mixer of `ssm.py`, which
+has the block of ONE sub-layer (`SubLayerBlock`: every kind of mixer and
+feed-forward here but the Qwen3-Next pair, which `HybridBlock` stacks).
 """
 from __future__ import annotations
 
@@ -562,8 +564,13 @@ def conv_silu(x, w, b=None):
     """silu(`_conv_pre`(x, w, b)). Its backward is written out so that the
     cotangents of the cw shifted reads of x add up in float32 and round to
     x's dtype once (autodiff would round each and add in that dtype — x is
-    the bf16 projection under the mixed policy)."""
-    return _conv_silu(x, w, b)
+    the bf16 projection under the mixed policy). On a TPU it is the kernel
+    pair `dl4j_convsilu_fwd` / `dl4j_convsilu_bwd` wherever
+    `ops.delta.conv_silu_impl` admits the operands (one pass over the bytes
+    a direction, no `pre` in HBM); the XLA form `_conv_silu` everywhere
+    else, and the tests' oracle."""
+    y = delta.conv_silu_chunks(x, w, b)
+    return _conv_silu(x, w, b) if y is None else y
 
 
 @jax.custom_vjp

@@ -5,7 +5,10 @@ a model is a string of sub-layer kinds (ROADMAP R3, R4, R8).
 
 BTF [batch, time, features], weights [n_in, n_out], like `hybrid.py`, whose
 chunk-major layout, short convolution and row mapping this file uses as
-they are (`to_chunks`, `conv_silu`, `from_chunks`, `over_row_groups`).
+they are (`to_chunks`, `conv_silu`, `from_chunks`, `over_row_groups`): on
+a TPU the convolution of x [.., 128, 64] and of B | C [.., 128, 128] runs as
+the kernel pair `dl4j_convsilu_fwd` / `dl4j_convsilu_bwd`, the 64-wide heads
+with their tokens on the lanes (`ops/convsilu_kernels.py`).
 
   Mamba2Mixer   [z | x B C | dt] = u Win; [x B C] <- silu(causal depthwise
                 conv + bias); x in H heads of P channels, B and C in G

@@ -8,11 +8,22 @@ import jax.numpy as jnp
 from jax import lax
 
 from deeplearning4j_tpu.ops import chunk_kernels
+from deeplearning4j_tpu.ops import convsilu_kernels
 from deeplearning4j_tpu.ops import gdn_kernels
 from deeplearning4j_tpu.ops import kda_kernels
 from deeplearning4j_tpu.ops import kernel_call
 from deeplearning4j_tpu.ops import linear
 from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+
+def _which(impl: str, fits: bool, rows: int) -> str:
+    """'pallas' where the kernels fit the operands and `impl` asks for them:
+    'auto' also wants a TPU backend with the helpers on and rows that split
+    evenly over an ambient data mesh; an explicit 'pallas' skips those gates."""
+    if impl == "auto":
+        fits = (fits and pk.helpers_enabled() and jax.default_backend() == "tpu"
+                and bool(kernel_call.per_device_batch(rows)))
+    return "pallas" if fits and impl in ("auto", "pallas") else "xla"
 
 
 def kda_impl(impl: str, q, v) -> str:
@@ -27,10 +38,7 @@ def kda_impl(impl: str, q, v) -> str:
     n, r, h, c, dk = q.shape
     fits = (q.dtype == v.dtype == jnp.float32 and c == chunk_kernels.CHUNK
             and dk == v.shape[-1] and dk % 128 == 0)
-    if impl == "auto":
-        fits = (fits and pk.helpers_enabled() and jax.default_backend() == "tpu"
-                and bool(kernel_call.per_device_batch(r)))
-    return "pallas" if fits and impl in ("auto", "pallas") else "xla"
+    return _which(impl, fits, r)
 
 
 def kda_chunks(q, k, v, g, beta, impl: str = "auto"):
@@ -65,10 +73,7 @@ def gdn_impl(impl: str, q, v) -> str:
     n, r, hk, c, dk = q.shape
     fits = (q.dtype == v.dtype == jnp.float32 and c == chunk_kernels.CHUNK
             and dk == v.shape[-1] and dk % 128 == 0 and v.shape[2] % hk == 0)
-    if impl == "auto":
-        fits = (fits and pk.helpers_enabled() and jax.default_backend() == "tpu"
-                and bool(kernel_call.per_device_batch(r)))
-    return "pallas" if fits and impl in ("auto", "pallas") else "xla"
+    return _which(impl, fits, r)
 
 
 def gdn_chunks(q, k, v, g, beta, impl: str = "auto"):
@@ -89,3 +94,36 @@ def gdn_chunks(q, k, v, g, beta, impl: str = "auto"):
 
     return kernel_call.per_batch_shard(
         rows_first, tuple(x.swapaxes(0, 1) for x in (q, k, v, g, beta)), (True,) * 5).swapaxes(0, 1)
+
+
+def conv_silu_impl(impl: str, x, w) -> str:
+    """'pallas' | 'xla' for the short causal convolution + silu over
+    chunk-major x [n, r, h, c, d] with taps w [cw, h, 1, d]: the kernels take
+    bfloat16 or float32 rows and at most 9 taps, d whole lane tiles (a
+    multiple of 128) over chunks of a multiple of 16 tokens, or a head
+    narrower than 128 (a multiple of 16) over chunks of exactly 128
+    (`convsilu_kernels.fits`). 'auto' and an explicit 'pallas' as for
+    `kda_impl`."""
+    n, r, h, c, d = x.shape
+    fits = convsilu_kernels.fits(c, d, w.shape[0], x.dtype)
+    return _which(impl, fits, r)
+
+
+def conv_silu_chunks(x, w, b=None, impl: str = "auto"):
+    """silu(the short causal depthwise convolution of chunk-major x
+    [n, r, h, c, d] with w [cw, h, 1, d] + b [h, 1, d]) float32 through the
+    kernel pair `dl4j_convsilu_fwd` / `dl4j_convsilu_bwd` — or None where
+    `conv_silu_impl` declines and the caller keeps its XLA form
+    (`hybrid._conv_silu`). Under a data mesh each device runs its own rows,
+    w and b arrive whole and their cotangents are summed over the devices."""
+    if conv_silu_impl(impl, x, w) != "pallas":
+        return None
+    interpret = kernel_call.interpret()
+
+    def rows_first(x_, w_, *b_):    # the shard mapping splits axis 0: rows in front, and back
+        return convsilu_kernels.conv_silu_kernels(
+            x_.swapaxes(0, 1), w_, b_[0] if b_ else None, interpret).swapaxes(0, 1)
+
+    args = (x.swapaxes(0, 1), w) + (() if b is None else (b,))
+    return kernel_call.per_batch_shard(
+        rows_first, args, (True,) + (False,) * (len(args) - 1)).swapaxes(0, 1)

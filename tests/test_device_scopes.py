@@ -309,3 +309,8 @@ def test_kda_kernels_sit_under_rule():
     kda = [scope_reduce.parse(n) for n in calls if "dl4j_kda_" in n]
     assert kda and all(s.kind == "kimideltaattention" and s.parts == ("rule",) for s in kda)
     assert {s.backward for s in kda} == {False, True}
+    # the short convolution's kernels in the same step: under `conv`, the
+    # backward with the stack of its call site
+    conv = [scope_reduce.parse(n) for n in calls if "dl4j_convsilu_" in n]
+    assert conv and all(s.kind == "kimideltaattention" and s.parts == ("conv",) for s in conv)
+    assert {s.backward for s in conv} == {False, True}
