@@ -74,6 +74,7 @@ class Sizes:
     lstm_n: int = 256
     kda_nrh: tuple = (128, 1, 32)  # chunks, rows, heads: kimilinear_train_t8192's row
     gdn_nrhh: tuple = (128, 1, 16, 32)  # chunks, rows, key and value heads: qwen3next_train_t8192's row
+    ssd_nrhg: tuple = (64, 1, 64, 8)  # chunks, rows, heads, groups: nemotron3nano_train_t8192's row
     # the short convolution's operands [n, r, h, c, d] and whether a bias: Kimi-Linear's, Nemotron's two
     conv_shapes: tuple = (((128, 1, 96, 64, 128), False), ((64, 1, 64, 128, 64), True),
                           ((64, 1, 16, 128, 128), True))
@@ -628,6 +629,36 @@ def phase_kernels(sz: Sizes):
                  f"o,dq,dk,dv,dg,dbeta", jnp.float32, got, want, failures)
 
     run("gdn chunks", gdn_case)
+
+    # ---- the state-space rule's chunk kernels fwd+bwd, through their door
+    def ssd_case():
+        from deeplearning4j_tpu.nn.layers import ssm
+        from deeplearning4j_tpu.ops import delta
+
+        (n, r, h, g), c, p, s = sz.ssd_nrhg, 128, 64, 128
+        x, ct = rnd((n, r, h, c, p), jnp.float32), rnd((n, r, h, c, p), jnp.float32)
+        b, cm = rnd((n, r, g, c, s), jnp.float32, 0.3), rnd((n, r, g, c, s), jnp.float32, 0.3)
+        # per-token decays from 0.9999 down to 0.2: the fastest heads fall by e^-200 in a chunk
+        a = -jnp.asarray(rng.uniform(1.0, 16.0, (h,)), jnp.float32)
+        dt = jnp.exp(jnp.asarray(rng.uniform(np.log(1e-4), np.log(1.6), (n, r, h, c)), jnp.float32)) / -a[:, None]
+        assert delta.ssd_impl("auto", x, b) == "pallas" or interpret
+
+        def both(f):
+            def run(*q):
+                (y, states), vjp = jax.vjp(f, *q)
+                return (y, states) + tuple(vjp((ct, jnp.zeros_like(states))))
+            return jax.jit(run)
+
+        def ref(*q):
+            with highest:
+                return ssm.ssd_chunked(*q)
+
+        got = both(lambda *q: delta.ssd_chunks(*q, impl="pallas"))(x, dt, a, b, cm)
+        want = both(ref)(x, dt, a, b, cm)
+        _compare(f"ssd chunks n={n} r={r} h={h} g={g} c={c} p={p} s={s} float32 "
+                 f"y,states,dx,ddt,da,db,dc", jnp.float32, got, want, failures)
+
+    run("ssd chunks", ssd_case)
 
     # ---- the recurrent mixers' short convolution + silu fwd+bwd, through its door:
     # tokens on the sublanes (d 128) and on the lanes (d 64, with a bias)

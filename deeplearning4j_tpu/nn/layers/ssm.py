@@ -8,7 +8,11 @@ chunk-major layout, short convolution and row mapping this file uses as
 they are (`to_chunks`, `conv_silu`, `from_chunks`, `over_row_groups`): on
 a TPU the convolution of x [.., 128, 64] and of B | C [.., 128, 128] runs as
 the kernel pair `dl4j_convsilu_fwd` / `dl4j_convsilu_bwd`, the 64-wide heads
-with their tokens on the lanes (`ops/convsilu_kernels.py`).
+with their tokens on the lanes (`ops/convsilu_kernels.py`), and the chunked
+recurrence itself as the pair `dl4j_ssd_fwd` / `dl4j_ssd_bwd` wherever
+`ops.delta.ssd_impl` admits the operands (`ops/ssd_kernels.py`: a chunk's
+decays, scores and the carried state stay on the chip); `ssd_chunked` below
+is the form everywhere else, and the tests' oracle.
 
   Mamba2Mixer   [z | x B C | dt] = u Win; [x B C] <- silu(causal depthwise
                 conv + bias); x in H heads of P channels, B and C in G
@@ -17,9 +21,9 @@ with their tokens on the lanes (`ops/convsilu_kernels.py`).
                 float32, S [P x N] from 0:
                   S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t
                   y_t = S_t C_t + D x_t
-                run in chunks (`ssd_chunked`); y <- group-wise RMS norm of
-                y silu(z) (the gate BEFORE the norm, one mean a group of
-                H / G heads); Wout. No bias but the convolution's.
+                run in chunks (`ssd_chunked`, or its kernels); y <- group-wise
+                RMS norm of y silu(z) (the gate BEFORE the norm, one mean a
+                group of H / G heads); Wout. No bias but the convolution's.
   SubLayerBlock y = x + sublayer(rms(x; w)), plain weight from one
 """
 from __future__ import annotations
@@ -33,6 +37,7 @@ from jax import lax
 
 from deeplearning4j_tpu.nn.layers import hybrid as hy
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
+from deeplearning4j_tpu.ops import delta
 from deeplearning4j_tpu.ops import linear as ops
 from deeplearning4j_tpu.telemetry.trace import device_scope
 
@@ -113,8 +118,11 @@ class Mamba2Mixer(Layer):
     dt_floor: float = 1e-4
 
     #: `hybrid.CORE_BYTES` for this layer: at 8192 tokens a row the
-    #: convolution input [x | B | C] is 201 MB in float32 and the chunk's
-    #: decays and scores [n, h, 128, 128] 268 MB each
+    #: convolution input [x | B | C] is 201 MB in float32; x, the rule's
+    #: output, the chunk-start states and the cotangent of each 134 MB. With
+    #: the rule as kernels nothing else of the rule reaches HBM; the XLA form
+    #: also writes a chunk's decayed scores [n, h, 128, 128], 268 MB, for its
+    #: backward
     CORE_BYTES = hy.CORE_BYTES
 
     def output_type(self, input_type):
@@ -176,7 +184,10 @@ class Mamba2Mixer(Layer):
                 dt = dt * mask
             a = -jnp.exp(params["A_log"])
         with device_scope("rule"):
-            y, states = ssd_chunked(x, dt, a, bc[:, :, :g], bc[:, :, g:])
+            # the kernel pair where `ops.delta.ssd_impl` admits it, else the XLA form
+            rule = (x, dt, a, bc[:, :, :g], bc[:, :, g:])
+            got = delta.ssd_chunks(*rule)
+            y, states = ssd_chunked(*rule) if got is None else got
         with device_scope("norm_gate"):
             y = (y + params["D"][:, None, None] * x) * jax.nn.silu(z.astype(F32))
             # one mean a group of h / g heads' channels

@@ -1,4 +1,5 @@
-"""The door to the delta rules' kernels: what a layer calls between its
+"""The door to the recurrent mixers' kernels — the delta rules', the
+state-space rule's, their short convolution's: what a layer calls between its
 projections, and the rule that says where a kernel runs (one entry a kernel
 family; a rule on what the call site can see, and nothing else)."""
 from __future__ import annotations
@@ -14,6 +15,7 @@ from deeplearning4j_tpu.ops import kda_kernels
 from deeplearning4j_tpu.ops import kernel_call
 from deeplearning4j_tpu.ops import linear
 from deeplearning4j_tpu.ops import pallas_kernels as pk
+from deeplearning4j_tpu.ops import ssd_kernels
 
 
 def _which(impl: str, fits: bool, rows: int) -> str:
@@ -94,6 +96,43 @@ def gdn_chunks(q, k, v, g, beta, impl: str = "auto"):
 
     return kernel_call.per_batch_shard(
         rows_first, tuple(x.swapaxes(0, 1) for x in (q, k, v, g, beta)), (True,) * 5).swapaxes(0, 1)
+
+
+def ssd_impl(impl: str, x, b) -> str:
+    """'pallas' | 'xla' for the state-space rule over chunk-major
+    x [n, r, h, c, p] and b [n, r, g, c, s]: the kernels take float32 arrays
+    in chunks of `ssd_kernels.CHUNK` tokens (one lane tile), a state of whole
+    lane tiles (s a multiple of 128), heads of a multiple of 16 channels, g
+    dividing h with at most `ssd_kernels.HEADS` heads a group
+    (`ssd_kernels.fits`). 'auto' and an explicit 'pallas' as for `kda_impl`."""
+    n, r, h, c, p = x.shape
+    fits = (x.dtype == b.dtype == jnp.float32
+            and ssd_kernels.fits(c, p, b.shape[-1], h, b.shape[2]))
+    return _which(impl, fits, r)
+
+
+def ssd_chunks(x, dt, a, b, c, impl: str = "auto"):
+    """The state-space rule over chunk-major x [n, r, h, c, p], dt
+    [n, r, h, c], a [h], b and c [n, r, g, c, s] through the kernel pair
+    `dl4j_ssd_fwd` / `dl4j_ssd_bwd`: (y [n, r, h, c, p] without the skip,
+    the states the chunks start from [n, r, h, p, s]; no cotangent flows
+    through the states) — or None where `ssd_impl` declines and the caller
+    keeps its XLA form (`ssm.ssd_chunked`). Products run at
+    `linear._precision()`. Under a data mesh each device runs its own rows, a
+    arrives whole and its cotangent is summed over the devices."""
+    if ssd_impl(impl, x, b) != "pallas":
+        return None
+    highest = linear._precision() is not None
+    interpret = kernel_call.interpret()
+
+    def rows_first(a_, *rows):    # the shard mapping splits axis 0: rows in front, and back
+        x_, dt_, b_, c_ = (t.swapaxes(0, 1) for t in rows)
+        y, st = ssd_kernels.ssd_chunk_kernels(x_, dt_, a_, b_, c_, highest, interpret)
+        return y.swapaxes(0, 1), st.swapaxes(0, 1)
+
+    y, st = kernel_call.per_batch_shard(
+        rows_first, (a,) + tuple(t.swapaxes(0, 1) for t in (x, dt, b, c)), (False,) + (True,) * 4)
+    return y.swapaxes(0, 1), lax.stop_gradient(st.swapaxes(0, 1))
 
 
 def conv_silu_impl(impl: str, x, w) -> str:

@@ -7,6 +7,7 @@ formulas; the sigmoid router and the relu^2 experts; the expert layer's
 shares; the pattern string; the counters; and the zoo class through
 `ParallelWrapper.fit` against the reference's three Adam steps."""
 import json
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -493,14 +494,23 @@ def test_remat_per_block_changes_nothing(rng):
 
 
 @pytest.mark.parametrize("masked", [False, True])
-def test_ssm_core_mapped_over_rows_is_the_whole_batch(masked, weights, rng, monkeypatch):
+@pytest.mark.parametrize("kernels", [False, True])
+def test_ssm_core_mapped_over_rows_is_the_whole_batch(kernels, masked, weights, rng, monkeypatch):
     """Past `CORE_BYTES` of float32 convolution input the rows run one
     group at a time, each a checkpoint: same numbers, same gradients, same
-    counters."""
-    layer = mixer()
-    params = renamed(sub(weights, "l0.mamba."), "mamba.")
-    x = jnp.asarray(rng.standard_normal((4, T, 32)), jnp.float32)
-    mask = jnp.asarray(rng.uniform(size=(4, T)) > 0.2, jnp.float32) if masked else None
+    counters. `kernels`: a mixer of the shape the rule's kernel pair takes
+    (chunks of 128, a state of 128), the kernels requested the way a TPU
+    requests them ('auto') and run interpreted — against the XLA form too."""
+    from deeplearning4j_tpu.ops import kernel_call, ssd_kernels
+
+    t, width = (160, 32 + 2 * 128) if kernels else (T, 96)
+    if kernels:
+        layer = Mamba2Mixer(n_heads=2, head_dim=16, n_groups=1, state_dim=128, chunk=128)
+        params = layer.init_params(jax.random.PRNGKey(5), IN)
+    else:
+        layer, params = mixer(), renamed(sub(weights, "l0.mamba."), "mamba.")
+    x = jnp.asarray(rng.standard_normal((4, t, 32)), jnp.float32)
+    mask = jnp.asarray(rng.uniform(size=(4, t)) > 0.2, jnp.float32) if masked else None
 
     def run():
         def loss(p, x_):
@@ -509,11 +519,21 @@ def test_ssm_core_mapped_over_rows_is_the_whole_batch(masked, weights, rng, monk
             return jnp.sum(y * y), (y, st)
         return jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))(params, x)
 
-    whole = run()
-    monkeypatch.setattr(Mamba2Mixer, "CORE_BYTES", 2 * T * 96 * 4)   # 2 rows
-    text = str(jax.make_jaxpr(lambda p, x_: layer.apply(
-        p, x_, state=layer.init_state(IN), train=True, rng=None, mask=mask)[0])(params, x))
-    mapped = run()
-    assert f"f32[2,{T},4,8]" in text and f"f32[4,{T},4,8]" not in text
+    if kernels:
+        xla = run()
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(kernel_call, "interpret", lambda: True)
+    with mock.patch.object(ssd_kernels, "ssd_chunk_kernels", wraps=ssd_kernels.ssd_chunk_kernels) as ran:
+        whole = run()
+        monkeypatch.setattr(Mamba2Mixer, "CORE_BYTES", 2 * t * width * 4)   # 2 rows
+        text = str(jax.make_jaxpr(lambda p, x_: layer.apply(
+            p, x_, state=layer.init_state(IN), train=True, rng=None, mask=mask)[0])(params, x))
+        mapped = run()
+    assert ran.call_count == (3 if kernels else 0)
+    heads = "2,16" if kernels else "4,8"
+    assert f"f32[2,{t},{heads}]" in text and f"f32[4,{t},{heads}]" not in text
     for a, b in zip(jax.tree_util.tree_leaves(mapped), jax.tree_util.tree_leaves(whole)):
         np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.abs(b).max()) + 1e-8)
+    if kernels:
+        for b, c in zip(jax.tree_util.tree_leaves(whole), jax.tree_util.tree_leaves(xla)):
+            np.testing.assert_allclose(b, c, atol=1e-4 * float(jnp.abs(c).max()) + 1e-8)
