@@ -53,19 +53,19 @@ from deeplearning4j_tpu.nn.layers.attention import (  # noqa: F401
     PositionEmbedding,
     TransformerBlock,
 )
+from deeplearning4j_tpu.nn.layers.blocks import (  # noqa: F401
+    HybridBlock,
+    SubLayerBlock,
+)
 from deeplearning4j_tpu.nn.layers.hybrid import (  # noqa: F401
     GatedAttention,
     GatedDeltaNet,
     GatedMLP,
     GatedShortConv,
-    HybridBlock,
     KimiDeltaAttention,
     LatentAttention,
     RMSNorm,
     RoutedExperts,
 )
-from deeplearning4j_tpu.nn.layers.ssm import (  # noqa: F401
-    Mamba2Mixer,
-    SubLayerBlock,
-)
+from deeplearning4j_tpu.nn.layers.ssm import Mamba2Mixer  # noqa: F401
 from deeplearning4j_tpu.nn.layers.objdetect import Yolo2Output  # noqa: F401

@@ -197,6 +197,19 @@ class Layer:
         return obj
 
 
+def nested_layer(value) -> Optional[Layer]:
+    """A field that holds a LAYER (a wrapper's `underlying`, a block's `sub`),
+    as a constructor is handed it: the Layer itself, or the dict its
+    `to_json` wrote, which is how `Layer.from_json` passes it on (`to_json`
+    writes any field that has a `to_json` through it). None stays None;
+    anything else is refused."""
+    if isinstance(value, dict):
+        return Layer.from_json(value)
+    if value is None or isinstance(value, Layer):
+        return value
+    raise TypeError(f"a Layer or its to_json dict, not {value!r}")
+
+
 def column_parallel_specs(params: PyTree, model_axis: str,
                           model_size: int) -> PyTree:
     """Megatron column-parallel rule for W[..., n_out]/b[n_out] param dicts

@@ -1,9 +1,9 @@
 """Hybrid decoder layers: RMS norm, rotary positions, gated softmax
 attention with grouped key/value heads, gated-delta-rule linear attention
 with one decay a head or one a key channel, latent attention, routed
-experts that are told which experts they hold, a dense gated feed-forward,
-and the block that stacks a mixer of either kind on the expert layer (the
-Qwen3-Next shape; ROADMAP R3, R4, R5, R8).
+experts that are told which experts they hold and a dense gated
+feed-forward (ROADMAP R3, R4, R5, R8). The residual blocks that wrap them
+are `blocks.py`'s.
 
 All BTF [batch, time, features] like `attention.py`; weights [n_in, n_out],
 bias-free. Under the mixed policy the projections run on bf16 operands
@@ -57,15 +57,12 @@ state are float32.
                  Its device work is a function of shapes alone; overflow is
                  counted and left out. Counters live in the layer's state
                  (`counters`) and reach `telemetry.fit_log()` once a fit.
-  HybridBlock    h = x + mixer(rms(x)); y = h + experts(rms(h))
 
 `to_chunks`, `conv_silu` (on a TPU the kernel pair `dl4j_convsilu_fwd` /
 `dl4j_convsilu_bwd` behind `ops.delta.conv_silu_chunks`, wherever its rule
 admits the operands; XLA elsewhere), `from_chunks`, the row mapping
 (`rows_at_a_time`, `over_row_groups`) and the decay counters
-(`decay_counters`, ..) also serve the state-space mixer of `ssm.py`, which
-has the block of ONE sub-layer (`SubLayerBlock`: every kind of mixer and
-feed-forward here but the Qwen3-Next pair, which `HybridBlock` stacks).
+(`decay_counters`, ..) also serve the state-space mixer of `ssm.py`.
 """
 from __future__ import annotations
 
@@ -1344,97 +1341,3 @@ class RoutedExperts(Layer):
                     "capacity": c["capacity"] + self.capacity(xf.shape[0]),
                     "ratio_sum": c["ratio_sum"] + jnp.max(load.astype(F32)) / mean}}
         return y, state
-
-
-# ---------------------------------------------------------------------------
-# the block
-# ---------------------------------------------------------------------------
-@register_layer
-@dataclass
-class HybridBlock(Layer):
-    """h = x + mixer(rms(x)); y = h + experts(rms(h)), `mixer` "delta"
-    (GatedDeltaNet) or "attention" (GatedAttention). One Layer so networks
-    stay flat lists and `remat` wraps a whole block; params nest the
-    sublayers' (`norm1`, `mixer`, `norm2`, `moe`), state is the experts'."""
-
-    mixer: str = "delta"
-    eps: float = 1e-6
-    # gated softmax attention
-    n_heads: int = 16
-    n_kv_heads: int = 2
-    head_dim: int = 256
-    rotary_fraction: float = 0.25
-    rope_theta: float = 1e7
-    # gated delta rule
-    n_key_heads: int = 16
-    n_value_heads: int = 32
-    key_dim: int = 128
-    value_dim: int = 128
-    conv_width: int = 4
-    # routed experts
-    n_experts: int = 512
-    top_k: int = 10
-    expert_width: int = 512
-    shared_width: int = 512
-    experts_held: Optional[Sequence[int]] = None
-    capacity_factor: float = 1.25
-    norm_topk: bool = True
-
-    def output_type(self, input_type):
-        return input_type
-
-    def _mixer(self):
-        if self.mixer == "attention":
-            return GatedAttention(
-                n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
-                head_dim=self.head_dim, rotary_fraction=self.rotary_fraction,
-                rope_theta=self.rope_theta, eps=self.eps,
-                weight_init=self.weight_init)
-        if self.mixer == "delta":
-            return GatedDeltaNet(
-                n_key_heads=self.n_key_heads, n_value_heads=self.n_value_heads,
-                key_dim=self.key_dim, value_dim=self.value_dim,
-                conv_width=self.conv_width, eps=self.eps,
-                weight_init=self.weight_init)
-        raise ValueError(f"mixer={self.mixer!r}: 'delta' or 'attention'")
-
-    def _moe(self):
-        return RoutedExperts(
-            n_experts=self.n_experts, top_k=self.top_k,
-            expert_width=self.expert_width, shared_width=self.shared_width,
-            experts_held=self.experts_held, capacity_factor=self.capacity_factor,
-            norm_topk=self.norm_topk, weight_init=self.weight_init)
-
-    def init_params(self, rng, input_type):
-        f = input_type.size
-        r = jax.random.split(rng, 2)
-        return {"norm1": {"w": jnp.zeros((f,), F32)},
-                "mixer": self._mixer().init_params(r[0], input_type),
-                "norm2": {"w": jnp.zeros((f,), F32)},
-                "moe": self._moe().init_params(r[1], input_type)}
-
-    def init_state(self, input_type):
-        return self._moe().init_state(input_type)
-
-    def counter_summary(self, added):
-        return self._moe().counter_summary(added)
-
-    def regularizable(self, params):
-        out = {"mixer/" + k: v for k, v in self._mixer().regularizable(params["mixer"]).items()}
-        out.update({"moe/" + k: v for k, v in self._moe().regularizable(params["moe"]).items()})
-        return out
-
-    def apply(self, params, x, *, state, train, rng, mask=None):
-        mixer, moe = self._mixer(), self._moe()
-        with device_scope("norm"):
-            xn = rms_norm(x, params["norm1"]["w"], self.eps)
-        with device_scope(kind=type(mixer).__name__):
-            a, _ = mixer.apply(params["mixer"], xn, state={}, train=train, rng=rng,
-                               mask=mask)
-        h = x + a
-        with device_scope("norm"):
-            hn = rms_norm(h, params["norm2"]["w"], self.eps)
-        with device_scope(kind=type(moe).__name__):
-            m, state = moe.apply(params["moe"], hn, state=state, train=train, rng=rng,
-                                 mask=mask)
-        return h + m, state

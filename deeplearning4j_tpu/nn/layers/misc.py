@@ -8,7 +8,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional, Union
 
-from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
+from deeplearning4j_tpu.nn.layers.base import Layer, nested_layer, register_layer
 
 
 @register_layer
@@ -17,12 +17,7 @@ class Frozen(Layer):
     underlying: Optional[Union[dict, Layer]] = None
 
     def __post_init__(self):
-        if isinstance(self.underlying, Layer):
-            self._inner = self.underlying
-        elif isinstance(self.underlying, dict):
-            self._inner = Layer.from_json(self.underlying)
-        else:
-            self._inner = None
+        self._inner = nested_layer(self.underlying)
 
     @property
     def inner(self) -> Layer:

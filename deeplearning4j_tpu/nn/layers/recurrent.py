@@ -43,6 +43,7 @@ from deeplearning4j_tpu.nn.layers.base import (
     Layer,
     apply_dropout,
     column_parallel_specs,
+    nested_layer,
     register_layer,
 )
 from deeplearning4j_tpu.ops import fused_lstm
@@ -379,12 +380,7 @@ class LastTimeStep(Layer):
     underlying: Optional[dict] = None  # serialized wrapped layer config
 
     def __post_init__(self):
-        if isinstance(self.underlying, Layer):
-            self._inner = self.underlying
-        elif isinstance(self.underlying, dict):
-            self._inner = Layer.from_json(self.underlying)
-        else:
-            self._inner = None
+        self._inner = nested_layer(self.underlying)
 
     def _wrapped(self):
         return self._inner

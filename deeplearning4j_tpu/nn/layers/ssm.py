@@ -1,7 +1,6 @@
 """A state-space mixer with a scalar decay per head (Mamba-2, in its chunked
-"SSD" form) and the block that places ONE sub-layer — that mixer, softmax
-attention or routed experts — behind one pre-norm and one residual, so that
-a model is a string of sub-layer kinds (ROADMAP R3, R4, R8).
+"SSD" form; ROADMAP R3, R4, R8). The block that places it behind a pre-norm
+and a residual is `blocks.SubLayerBlock`.
 
 BTF [batch, time, features], weights [n_in, n_out], like `hybrid.py`, whose
 chunk-major layout, short convolution and row mapping this file uses as
@@ -24,12 +23,10 @@ is the form everywhere else, and the tests' oracle.
                 run in chunks (`ssd_chunked`, or its kernels); y <- group-wise
                 RMS norm of y silu(z) (the gate BEFORE the norm, one mean a
                 group of H / G heads); Wout. No bias but the convolution's.
-  SubLayerBlock y = x + sublayer(rms(x; w)), plain weight from one
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -226,149 +223,3 @@ class Mamba2Mixer(Layer):
             y = y * mask[..., None].astype(y.dtype)
         with device_scope("counters"):
             return y, hy.count_decay(state, *stats) if train else state
-
-
-# ---------------------------------------------------------------------------
-# the block
-# ---------------------------------------------------------------------------
-#: sub-layer kinds by the character a layer pattern names them with
-PATTERN_KINDS = {"M": "mamba", "*": "attention", "E": "experts"}
-#: every kind a `SubLayerBlock` builds: a pattern's, and those only a model
-#: with a per-layer mixer list names (`zoo.DeltaLatentMoELM`, `zoo.ShortConvMoELM`)
-KINDS = (*PATTERN_KINDS.values(), "kda", "latent", "dense", "shortconv")
-
-
-def pattern_kinds(pattern: str):
-    """"MEM*E" -> ["mamba", "experts", "mamba", "attention", "experts"]."""
-    bad = sorted(set(pattern) - set(PATTERN_KINDS))
-    if bad or not pattern:
-        raise ValueError(f"layer pattern {pattern!r}: characters {bad} are none of "
-                         f"{sorted(PATTERN_KINDS)}")
-    return [PATTERN_KINDS[ch] for ch in pattern]
-
-
-@register_layer
-@dataclass
-class SubLayerBlock(Layer):
-    """y = x + sublayer(rms(x; w)), `kind` "mamba" (Mamba2Mixer),
-    "attention" (GatedAttention without gate; without q/k norms and
-    positions too unless `qk_norm` — plain weights — and `rotary_fraction`
-    with `rope_theta` ask for them), "experts" (RoutedExperts), "kda"
-    (KimiDeltaAttention), "latent" (LatentAttention), "shortconv"
-    (GatedShortConv) or "dense" (GatedMLP with the experts'
-    non-linearity). One Layer so networks stay flat lists and `remat` wraps
-    a whole block; params nest the sub-layer's (`norm`, `sub`), state and
-    counters are the sub-layer's own."""
-
-    kind: str = "mamba"
-    eps: float = 1e-5
-    # state-space mixer
-    ssm_heads: int = 64
-    ssm_head_dim: int = 64
-    ssm_groups: int = 8
-    ssm_state: int = 128
-    conv_width: int = 4
-    chunk: int = 128
-    dt_min: float = 1e-3
-    dt_max: float = 0.1
-    dt_floor: float = 1e-4
-    # softmax attention
-    n_heads: int = 32
-    n_kv_heads: int = 2
-    head_dim: int = 128
-    qk_norm: bool = False
-    rotary_fraction: float = 0.0
-    # delta rule with a decay a channel: n_heads heads of head_dim
-    # latent attention: n_heads heads, keys [nope_dim | rope_dim], values v_dim;
-    # rotary positions on the rope_dim parts where rope_theta is given (softmax
-    # attention: on `rotary_fraction` of a head)
-    kv_rank: int = 512
-    nope_dim: int = 128
-    rope_dim: int = 64
-    v_dim: int = 128
-    rope_theta: Optional[float] = None
-    rope_interleave: bool = True
-    # dense feed-forward
-    dense_width: int = 1024
-    # routed experts
-    n_experts: int = 128
-    top_k: int = 6
-    expert_width: int = 1856
-    shared_width: int = 3712
-    experts_held: Optional[Sequence[int]] = None
-    capacity_factor: float = 1.25
-    norm_topk: bool = True
-    scoring: str = "sigmoid"
-    routed_scale: float = 2.5
-    expert_act: str = "relu2"
-    shared_gated: bool = False
-    norm_eps: float = 1e-20
-
-    def output_type(self, input_type):
-        return input_type
-
-    def _sub(self):
-        if self.kind == "mamba":
-            return Mamba2Mixer(
-                n_heads=self.ssm_heads, head_dim=self.ssm_head_dim,
-                n_groups=self.ssm_groups, state_dim=self.ssm_state,
-                conv_width=self.conv_width, chunk=self.chunk, eps=self.eps,
-                dt_min=self.dt_min, dt_max=self.dt_max, dt_floor=self.dt_floor,
-                weight_init=self.weight_init)
-        if self.kind == "attention":
-            rope = {"rope_theta": self.rope_theta} if self.rotary_fraction else {}
-            return hy.GatedAttention(
-                n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
-                head_dim=self.head_dim, rotary_fraction=self.rotary_fraction, gated=False,
-                qk_norm=self.qk_norm, qk_norm_zero_centered=False, eps=self.eps,
-                weight_init=self.weight_init, **rope)
-        if self.kind == "experts":
-            return hy.RoutedExperts(
-                n_experts=self.n_experts, top_k=self.top_k,
-                expert_width=self.expert_width, shared_width=self.shared_width,
-                experts_held=self.experts_held, capacity_factor=self.capacity_factor,
-                norm_topk=self.norm_topk, scoring=self.scoring,
-                routed_scale=self.routed_scale, expert_act=self.expert_act,
-                shared_gated=self.shared_gated, norm_eps=self.norm_eps,
-                weight_init=self.weight_init)
-        if self.kind == "kda":
-            return hy.KimiDeltaAttention(
-                n_heads=self.n_heads, head_dim=self.head_dim,
-                conv_width=self.conv_width, eps=self.eps, weight_init=self.weight_init)
-        if self.kind == "latent":
-            return hy.LatentAttention(
-                n_heads=self.n_heads, kv_rank=self.kv_rank, nope_dim=self.nope_dim,
-                rope_dim=self.rope_dim, v_dim=self.v_dim, eps=self.eps,
-                rope_theta=self.rope_theta, rope_interleave=self.rope_interleave,
-                weight_init=self.weight_init)
-        if self.kind == "shortconv":
-            return hy.GatedShortConv(conv_width=self.conv_width, weight_init=self.weight_init)
-        if self.kind == "dense":
-            return hy.GatedMLP(width=self.dense_width, act=self.expert_act,
-                               weight_init=self.weight_init)
-        raise ValueError(f"kind={self.kind!r}: one of {sorted(KINDS)}")
-
-    def init_params(self, rng, input_type):
-        return {"norm": {"w": jnp.ones((input_type.size,), F32)},
-                "sub": self._sub().init_params(rng, input_type)}
-
-    def init_state(self, input_type):
-        return self._sub().init_state(input_type)
-
-    def counter_summary(self, added):
-        return self._sub().counter_summary(added)
-
-    def regularizable(self, params):
-        return {"sub/" + k: v for k, v in self._sub().regularizable(params["sub"]).items()}
-
-    def apply(self, params, x, *, state, train, rng, mask=None):
-        sub = self._sub()
-        with device_scope("norm"):
-            xn = hy.rms_norm(x, params["norm"]["w"], self.eps, zero_centered=False)
-        # a dense feed-forward is the block's `mlp`; a mixer or the experts
-        # open parts of their own under their kind
-        with (device_scope("mlp") if self.kind == "dense"
-              else device_scope(kind=type(sub).__name__)):
-            a, state = sub.apply(params["sub"], xn, state=state, train=train, rng=rng,
-                                 mask=mask)
-        return x + a, state

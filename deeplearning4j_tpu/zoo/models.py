@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import os
 from dataclasses import dataclass, field
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 from deeplearning4j_tpu.models import ComputationGraph, MultiLayerNetwork
 from deeplearning4j_tpu.nn import inputs as it
@@ -34,11 +34,23 @@ from deeplearning4j_tpu.nn.layers import (
     Conv2D,
     Dense,
     DropoutLayer,
+    EmbeddingSequence,
+    GatedAttention,
+    GatedDeltaNet,
+    GatedMLP,
+    GatedShortConv,
     GlobalPooling,
     GravesLSTM,
+    HybridBlock,
+    KimiDeltaAttention,
+    LatentAttention,
+    Mamba2Mixer,
     Output,
+    RMSNorm,
     RnnOutput,
+    RoutedExperts,
     SeparableConv2D,
+    SubLayerBlock,
     Subsampling2D,
     ZeroPadding2D,
 )
@@ -462,22 +474,75 @@ class TransformerLM(ZooModel):
 
 
 @dataclass
-class HybridMoELM(ZooModel):
-    """Decoder-only LM of `HybridBlock`s: gated-delta-rule linear attention
-    with a gated softmax-attention layer every `full_attention_interval`-th
-    block, routed experts beside a shared expert in every block, RMS norms,
-    an untied bias-free head (the Qwen3-Next shape). The arguments are the
-    keys of the published `config.json`; `num_experts` is the count this
-    rank HOLDS of `num_experts_published` (default: all of them), starting
-    at `experts_first`. Input: [b, t] token ids; labels: [b, t] integer
-    next-token ids (or dense one-hot)."""
+class _DecoderLM(ZooModel):
+    """The ONE decoder skeleton: token ids -> `EmbeddingSequence` -> a residual
+    block around each entry of `sublayers()` -> final RMS norm -> an untied
+    bias-free head; Adam 3e-4, xavier, `remat` on every block. A model is the
+    keys of its published `config.json` as fields, `NORM`, and `sublayers()`:
+    the nested layers in network order, each built with every argument the
+    model means — a Layer is wrapped in a `SubLayerBlock` (plain pre-norm),
+    a (mixer, experts) pair in ONE `HybridBlock` (zero-centred pre-norms).
+    `num_experts` is the count this rank HOLDS of `num_experts_published`
+    (default: all of them), starting at `experts_first` (`_experts`). Input:
+    [b, t] token ids; labels: [b, t] integer next-token ids (or dense
+    one-hot)."""
 
     vocab_size: int = 1000
     hidden_size: int = 256
+    max_length: int = 128
+    # routed experts: held of published
+    num_experts: int = 8
+    num_experts_published: Optional[int] = None
+    experts_first: int = 0
+    capacity_factor: float = 1.25
+    # per-block activation-checkpoint policy (parallel/layout.py)
+    remat: Optional[str] = None
+
+    #: the published key of every norm's eps; is the final norm's weight
+    #: zero-centred (1 + w)
+    NORM = ("rms_norm_eps", False)
+
+    def sublayers(self):
+        raise NotImplementedError
+
+    def _experts(self, **recipe):
+        """`RoutedExperts` over the experts this rank holds of the published
+        count; `recipe` is everything else, spelled out by the model."""
+        return RoutedExperts(
+            n_experts=self.num_experts_published or self.num_experts,
+            experts_held=(self.experts_first, self.num_experts),
+            capacity_factor=self.capacity_factor, **recipe)
+
+    def conf(self):
+        key, zero_centered = self.NORM
+        eps = getattr(self, key)
+        blocks = [
+            HybridBlock(mixer=sub[0], moe=sub[1], eps=eps, remat=self.remat)
+            if isinstance(sub, tuple) else SubLayerBlock(sub=sub, eps=eps, remat=self.remat)
+            for sub in self.sublayers()
+        ]
+        return NeuralNetConfiguration(
+            seed=self.seed, updater=updaters.Adam(learning_rate=3e-4),
+            weight_init="xavier",
+        ).list([
+            EmbeddingSequence(n_in=self.vocab_size, n_out=self.hidden_size),
+            *blocks,
+            RMSNorm(eps=eps, zero_centered=zero_centered),
+            RnnOutput(n_out=self.vocab_size, loss="mcxent",
+                      activation="softmax", has_bias=False),
+        ]).set_input_type(it.recurrent(self.vocab_size, self.max_length))
+
+
+@dataclass
+class HybridMoELM(_DecoderLM):
+    """Decoder-only LM of `HybridBlock`s: gated-delta-rule linear attention
+    with a gated softmax-attention layer every `full_attention_interval`-th
+    block, softmax-routed swiglu experts beside a gated shared expert in
+    every block, zero-centred RMS norms (the Qwen3-Next shape)."""
+
     num_hidden_layers: int = 4
     full_attention_interval: int = 4
     rms_norm_eps: float = 1e-6
-    max_length: int = 128
     # gated softmax attention
     num_attention_heads: int = 4
     num_key_value_heads: int = 2
@@ -491,80 +556,63 @@ class HybridMoELM(ZooModel):
     linear_value_head_dim: int = 32
     linear_conv_kernel_dim: int = 4
     # routed experts
-    num_experts: int = 8
-    num_experts_published: Optional[int] = None
-    experts_first: int = 0
     num_experts_per_tok: int = 2
     moe_intermediate_size: int = 64
     shared_expert_intermediate_size: int = 64
     norm_topk_prob: bool = True
-    capacity_factor: float = 1.25
-    # per-block activation-checkpoint policy (parallel/layout.py)
-    remat: Optional[str] = None
+
+    NORM = ("rms_norm_eps", True)
 
     def mixer_kinds(self):
         """The per-layer list of mixer kinds."""
         return ["attention" if (i + 1) % self.full_attention_interval == 0
                 else "delta" for i in range(self.num_hidden_layers)]
 
-    def conf(self):
-        from deeplearning4j_tpu.nn.layers import (
-            EmbeddingSequence,
-            HybridBlock,
-            RMSNorm,
-        )
-
-        n_all = self.num_experts_published or self.num_experts
-        blocks = [
-            HybridBlock(
-                mixer=kind, eps=self.rms_norm_eps,
-                n_heads=self.num_attention_heads,
-                n_kv_heads=self.num_key_value_heads, head_dim=self.head_dim,
-                rotary_fraction=self.partial_rotary_factor,
-                rope_theta=self.rope_theta,
+    def sublayers(self):
+        eps = self.rms_norm_eps
+        mixer = {
+            "attention": lambda: GatedAttention(
+                n_heads=self.num_attention_heads, n_kv_heads=self.num_key_value_heads,
+                head_dim=self.head_dim, rotary_fraction=self.partial_rotary_factor,
+                rope_theta=self.rope_theta, eps=eps, gated=True, qk_norm=True,
+                qk_norm_zero_centered=True),
+            "delta": lambda: GatedDeltaNet(
                 n_key_heads=self.linear_num_key_heads,
                 n_value_heads=self.linear_num_value_heads,
-                key_dim=self.linear_key_head_dim,
-                value_dim=self.linear_value_head_dim,
-                conv_width=self.linear_conv_kernel_dim,
-                n_experts=n_all, top_k=self.num_experts_per_tok,
-                expert_width=self.moe_intermediate_size,
-                shared_width=self.shared_expert_intermediate_size,
-                experts_held=(self.experts_first, self.num_experts),
-                capacity_factor=self.capacity_factor,
-                norm_topk=self.norm_topk_prob, remat=self.remat)
-            for kind in self.mixer_kinds()
-        ]
-        return NeuralNetConfiguration(
-            seed=self.seed, updater=updaters.Adam(learning_rate=3e-4),
-            weight_init="xavier",
-        ).list([
-            EmbeddingSequence(n_in=self.vocab_size, n_out=self.hidden_size),
-            *blocks,
-            RMSNorm(eps=self.rms_norm_eps),
-            RnnOutput(n_out=self.vocab_size, loss="mcxent",
-                      activation="softmax", has_bias=False),
-        ]).set_input_type(it.recurrent(self.vocab_size, self.max_length))
+                key_dim=self.linear_key_head_dim, value_dim=self.linear_value_head_dim,
+                conv_width=self.linear_conv_kernel_dim, eps=eps),
+        }
+        return [(mixer[kind](), self._experts(
+            top_k=self.num_experts_per_tok, expert_width=self.moe_intermediate_size,
+            shared_width=self.shared_expert_intermediate_size,
+            norm_topk=self.norm_topk_prob, scoring="softmax", routed_scale=1.0,
+            expert_act="swiglu", shared_gated=True, norm_eps=1e-20))
+            for kind in self.mixer_kinds()]
+
+
+#: sub-layer kinds by the character a layer pattern names them with
+PATTERN_KINDS = {"M": "mamba", "*": "attention", "E": "experts"}
+
+
+def pattern_kinds(pattern: str):
+    """"MEM*E" -> ["mamba", "experts", "mamba", "attention", "experts"]."""
+    bad = sorted(set(pattern) - set(PATTERN_KINDS))
+    if bad or not pattern:
+        raise ValueError(f"layer pattern {pattern!r}: characters {bad} are none of "
+                         f"{sorted(PATTERN_KINDS)}")
+    return [PATTERN_KINDS[ch] for ch in pattern]
 
 
 @dataclass
-class PatternHybridLM(ZooModel):
+class PatternHybridLM(_DecoderLM):
     """Decoder-only LM whose layers are named by a PATTERN string, one
-    character a layer, each ONE sub-layer behind a pre-norm and a residual
-    (`SubLayerBlock`): `M` a Mamba-2 state-space mixer, `*` grouped-query
-    softmax attention without positions, `E` sigmoid-routed relu^2 experts
-    beside an ungated shared expert; plain RMS norms, an untied bias-free
-    head (the `nemotron_h` shape). The arguments are the keys of the
-    published `config.json`; `num_experts` is the count this rank HOLDS of
-    `num_experts_published` (`n_routed_experts`; default: all of them),
-    starting at `experts_first`. Input: [b, t] token ids; labels: [b, t]
-    integer next-token ids (or dense one-hot)."""
+    character a layer, each ONE sub-layer: `M` a Mamba-2 state-space mixer,
+    `*` grouped-query softmax attention without gate, q/k norms or positions,
+    `E` sigmoid-routed relu^2 experts beside an ungated shared expert (the
+    `nemotron_h` shape; `num_experts_published` is its `n_routed_experts`)."""
 
-    vocab_size: int = 1000
-    hidden_size: int = 256
     hybrid_override_pattern: str = "MEM*E"
     layer_norm_epsilon: float = 1e-5
-    max_length: int = 128
     # softmax attention
     num_attention_heads: int = 4
     num_key_value_heads: int = 2
@@ -580,83 +628,53 @@ class PatternHybridLM(ZooModel):
     time_step_max: float = 0.1
     time_step_floor: float = 1e-4
     # routed experts
-    num_experts: int = 8
-    num_experts_published: Optional[int] = None
-    experts_first: int = 0
     num_experts_per_tok: int = 2
     moe_intermediate_size: int = 64
     moe_shared_expert_intermediate_size: int = 128
     routed_scaling_factor: float = 2.5
     norm_topk_prob: bool = True
-    capacity_factor: float = 1.25
-    # per-block activation-checkpoint policy (parallel/layout.py)
-    remat: Optional[str] = None
 
-    def conf(self):
-        from deeplearning4j_tpu.nn.layers import (
-            EmbeddingSequence,
-            RMSNorm,
-            SubLayerBlock,
-        )
-        from deeplearning4j_tpu.nn.layers.ssm import pattern_kinds
+    NORM = ("layer_norm_epsilon", False)
 
-        blocks = [
-            SubLayerBlock(
-                kind=kind, eps=self.layer_norm_epsilon,
-                ssm_heads=self.mamba_num_heads, ssm_head_dim=self.mamba_head_dim,
-                ssm_groups=self.n_groups, ssm_state=self.ssm_state_size,
-                conv_width=self.conv_kernel, chunk=self.chunk_size,
+    def sublayers(self):
+        eps = self.layer_norm_epsilon
+        sub = {
+            "mamba": lambda: Mamba2Mixer(
+                n_heads=self.mamba_num_heads, head_dim=self.mamba_head_dim,
+                n_groups=self.n_groups, state_dim=self.ssm_state_size,
+                conv_width=self.conv_kernel, chunk=self.chunk_size, eps=eps,
                 dt_min=self.time_step_min, dt_max=self.time_step_max,
-                dt_floor=self.time_step_floor,
-                n_heads=self.num_attention_heads,
-                n_kv_heads=self.num_key_value_heads, head_dim=self.head_dim,
-                n_experts=self.num_experts_published or self.num_experts,
-                top_k=self.num_experts_per_tok,
-                expert_width=self.moe_intermediate_size,
+                dt_floor=self.time_step_floor),
+            "attention": lambda: GatedAttention(
+                n_heads=self.num_attention_heads, n_kv_heads=self.num_key_value_heads,
+                head_dim=self.head_dim, rotary_fraction=0.0, eps=eps, gated=False,
+                qk_norm=False, qk_norm_zero_centered=False),
+            "experts": lambda: self._experts(
+                top_k=self.num_experts_per_tok, expert_width=self.moe_intermediate_size,
                 shared_width=self.moe_shared_expert_intermediate_size,
-                experts_held=(self.experts_first, self.num_experts),
-                capacity_factor=self.capacity_factor,
-                norm_topk=self.norm_topk_prob,
-                routed_scale=self.routed_scaling_factor, remat=self.remat)
-            for kind in pattern_kinds(self.hybrid_override_pattern)
-        ]
-        return NeuralNetConfiguration(
-            seed=self.seed, updater=updaters.Adam(learning_rate=3e-4),
-            weight_init="xavier",
-        ).list([
-            EmbeddingSequence(n_in=self.vocab_size, n_out=self.hidden_size),
-            *blocks,
-            RMSNorm(eps=self.layer_norm_epsilon, zero_centered=False),
-            RnnOutput(n_out=self.vocab_size, loss="mcxent",
-                      activation="softmax", has_bias=False),
-        ]).set_input_type(it.recurrent(self.vocab_size, self.max_length))
+                norm_topk=self.norm_topk_prob, scoring="sigmoid",
+                routed_scale=self.routed_scaling_factor, expert_act="relu2",
+                shared_gated=False, norm_eps=1e-20),
+        }
+        return [sub[kind]() for kind in pattern_kinds(self.hybrid_override_pattern)]
 
 
 @dataclass
-class DeltaLatentMoELM(ZooModel):
+class DeltaLatentMoELM(_DecoderLM):
     """Decoder-only LM with a per-layer MIXER LIST and a leading dense
-    layer: layer i (from 1) mixes with delta-rule linear attention whose
-    decay is a vector over the key channels (KDA) if i is in
-    `linear_attn_config["kda_layers"]`, else with latent attention (MLA);
-    its feed-forward is a dense swiglu of `intermediate_size` for i <=
+    layer, two sub-layers a layer: layer i (from 1) mixes with delta-rule
+    linear attention whose decay is a vector over the key channels (KDA) if
+    i is in `linear_attn_config["kda_layers"]`, else with latent attention
+    (MLA); its feed-forward is a dense swiglu of `intermediate_size` for i <=
     `first_k_dense_replace`, else sigmoid-routed swiglu experts beside
-    ungated shared experts. Each sub-layer sits behind a plain RMS pre-norm
-    and a residual (`SubLayerBlock`: two blocks a layer); final norm, untied
-    bias-free head. Two published shapes: `kimi_linear` — KDA layers carry
-    the positions and the latent layers know none (`mla_use_nope`) — and
-    `deepseek_v3` — `kda_layers` empty, EVERY layer latent attention with
+    ungated shared experts. Two published shapes: `kimi_linear` — KDA layers
+    carry the positions and the latent layers know none (`mla_use_nope`) —
+    and `deepseek_v3` — `kda_layers` empty, EVERY layer latent attention with
     rotary positions (`rope_theta`, `rope_interleave`) on the rope part of
-    its queries and on the key part all heads share (`mla_use_nope` false).
-    The arguments are the keys of the published `config.json`; `num_experts`
-    is the count this rank HOLDS of `num_experts_published` (default: all of
-    them), starting at `experts_first`. Input: [b, t] token ids; labels:
-    [b, t] integer next-token ids (or dense one-hot)."""
+    its queries and on the key part all heads share (`mla_use_nope` false)."""
 
-    vocab_size: int = 1000
-    hidden_size: int = 256
     num_hidden_layers: int = 5
     rms_norm_eps: float = 1e-5
-    max_length: int = 128
     # which layer mixes how: {"kda_layers": [1, 2, 3, 5, ...], "num_heads",
     # "head_dim", "short_conv_kernel_size"}; the layers it does not list
     # are latent attention
@@ -675,17 +693,11 @@ class DeltaLatentMoELM(ZooModel):
     first_k_dense_replace: int = 1
     intermediate_size: int = 512
     # routed experts
-    num_experts: int = 8
-    num_experts_published: Optional[int] = None
-    experts_first: int = 0
     num_experts_per_token: int = 2
     moe_intermediate_size: int = 64
     num_shared_experts: int = 1
     routed_scaling_factor: float = 2.446
     moe_renormalize: bool = True
-    capacity_factor: float = 1.25
-    # per-block activation-checkpoint policy (parallel/layout.py)
-    remat: Optional[str] = None
 
     def sublayer_kinds(self):
         """The per-layer (mixer, feed-forward) kinds."""
@@ -695,75 +707,47 @@ class DeltaLatentMoELM(ZooModel):
                  "dense" if i <= self.first_k_dense_replace else "experts")
                 for i in range(1, self.num_hidden_layers + 1)]
 
-    def conf(self):
-        from deeplearning4j_tpu.nn.layers import (
-            EmbeddingSequence,
-            RMSNorm,
-            SubLayerBlock,
-        )
-
-        linear = self.linear_attn_config or {}
-
-        def block(kind):
-            kda = kind == "kda"
-            return SubLayerBlock(
-                kind=kind, eps=self.rms_norm_eps,
-                n_heads=linear.get("num_heads", 4) if kda else self.num_attention_heads,
-                head_dim=linear.get("head_dim", 32),
-                conv_width=linear.get("short_conv_kernel_size", 4),
-                kv_rank=self.kv_lora_rank, nope_dim=self.qk_nope_head_dim,
-                rope_dim=self.qk_rope_head_dim, v_dim=self.v_head_dim,
+    def sublayers(self):
+        eps, linear = self.rms_norm_eps, self.linear_attn_config or {}
+        sub = {
+            "kda": lambda: KimiDeltaAttention(
+                n_heads=linear.get("num_heads", 4), head_dim=linear.get("head_dim", 32),
+                conv_width=linear.get("short_conv_kernel_size", 4), eps=eps),
+            "latent": lambda: LatentAttention(
+                n_heads=self.num_attention_heads, kv_rank=self.kv_lora_rank,
+                nope_dim=self.qk_nope_head_dim, rope_dim=self.qk_rope_head_dim,
+                v_dim=self.v_head_dim, eps=eps,
                 rope_theta=None if self.mla_use_nope else float(self.rope_theta),
-                rope_interleave=self.rope_interleave,
-                dense_width=self.intermediate_size,
-                n_experts=self.num_experts_published or self.num_experts,
-                top_k=self.num_experts_per_token,
-                expert_width=self.moe_intermediate_size,
+                rope_interleave=self.rope_interleave),
+            "dense": lambda: GatedMLP(width=self.intermediate_size, act="swiglu"),
+            "experts": lambda: self._experts(
+                top_k=self.num_experts_per_token, expert_width=self.moe_intermediate_size,
                 shared_width=self.num_shared_experts * self.moe_intermediate_size,
-                experts_held=(self.experts_first, self.num_experts),
-                capacity_factor=self.capacity_factor,
                 norm_topk=self.moe_renormalize, scoring="sigmoid",
                 routed_scale=self.routed_scaling_factor, expert_act="swiglu",
-                shared_gated=False, remat=self.remat)
-
-        return NeuralNetConfiguration(
-            seed=self.seed, updater=updaters.Adam(learning_rate=3e-4),
-            weight_init="xavier",
-        ).list([
-            EmbeddingSequence(n_in=self.vocab_size, n_out=self.hidden_size),
-            *(block(kind) for pair in self.sublayer_kinds() for kind in pair),
-            RMSNorm(eps=self.rms_norm_eps, zero_centered=False),
-            RnnOutput(n_out=self.vocab_size, loss="mcxent",
-                      activation="softmax", has_bias=False),
-        ]).set_input_type(it.recurrent(self.vocab_size, self.max_length))
+                shared_gated=False, norm_eps=1e-20),
+        }
+        return [sub[kind]() for pair in self.sublayer_kinds() for kind in pair]
 
 
 @dataclass
-class ShortConvMoELM(ZooModel):
+class ShortConvMoELM(_DecoderLM):
     """Decoder-only LM whose mixers are named by `layer_types`, one entry a
-    published layer: "conv" a double-gated short convolution
-    (`GatedShortConv`, taps `conv_L_cache`, no bias), "full_attention"
-    grouped-query softmax attention with plain-weight RMS norms on every
-    head of q and k and rotary positions over the whole head
+    published layer, two sub-layers a layer: "conv" a double-gated short
+    convolution (`GatedShortConv`, taps `conv_L_cache`, no bias),
+    "full_attention" grouped-query softmax attention with plain-weight RMS
+    norms on every head of q and k and rotary positions over the whole head
     (`rope_parameters["rope_theta"]`, half-split pairs). The feed-forward of
     published layer i (from 0) is a dense swiglu of `intermediate_size` for
     i < `num_dense_layers`, else sigmoid-routed swiglu experts chosen by
     score + a selection bias, renormalised over the chosen (+ 1e-6), with
-    NO shared expert. Each sub-layer sits behind a plain RMS pre-norm and a
-    residual (`SubLayerBlock`: two blocks a layer); final norm, untied
-    bias-free head (the `lfm2_moe` shape). The arguments are the keys of
-    the published `config.json`; `num_hidden_layers` layers are BUILT, the
-    published layers `layers_first` .. (a pipeline stage's share), and
-    `num_experts` is the count this rank HOLDS of `num_experts_published`
-    (default: all of them), starting at `experts_first`. Input: [b, t]
-    token ids; labels: [b, t] integer next-token ids (or dense one-hot)."""
+    NO shared expert (the `lfm2_moe` shape). `num_hidden_layers` layers are
+    BUILT, the published layers `layers_first` .. (a pipeline stage's
+    share)."""
 
-    vocab_size: int = 1000
-    hidden_size: int = 256
     num_hidden_layers: int = 4
     layers_first: int = 0
     norm_eps: float = 1e-5
-    max_length: int = 128
     # which published layer mixes how; default: attention every fourth
     # layer from the third on
     layer_types: Optional[Sequence[str]] = None
@@ -775,16 +759,12 @@ class ShortConvMoELM(ZooModel):
     num_dense_layers: int = 2
     intermediate_size: int = 512
     # routed experts
-    num_experts: int = 8
-    num_experts_published: Optional[int] = None
-    experts_first: int = 0
     num_experts_per_tok: int = 2
     moe_intermediate_size: int = 64
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
-    capacity_factor: float = 1.25
-    # per-block activation-checkpoint policy (parallel/layout.py)
-    remat: Optional[str] = None
+
+    NORM = ("norm_eps", False)
 
     def sublayer_kinds(self):
         """The (mixer, feed-forward) kinds of the layers built."""
@@ -799,40 +779,22 @@ class ShortConvMoELM(ZooModel):
         return [(mixers[types[i]], "dense" if i < self.num_dense_layers else "experts")
                 for i in held]
 
-    def conf(self):
-        from deeplearning4j_tpu.nn.layers import (
-            EmbeddingSequence,
-            RMSNorm,
-            SubLayerBlock,
-        )
-
-        def block(kind):
-            return SubLayerBlock(
-                kind=kind, eps=self.norm_eps, conv_width=self.conv_L_cache,
+    def sublayers(self):
+        sub = {
+            "shortconv": lambda: GatedShortConv(conv_width=self.conv_L_cache),
+            "attention": lambda: GatedAttention(
                 n_heads=self.num_attention_heads, n_kv_heads=self.num_key_value_heads,
-                head_dim=self.hidden_size // self.num_attention_heads,
-                qk_norm=True, rotary_fraction=1.0,
+                head_dim=self.hidden_size // self.num_attention_heads, rotary_fraction=1.0,
                 rope_theta=float((self.rope_parameters or {}).get("rope_theta", 1e6)),
-                dense_width=self.intermediate_size,
-                n_experts=self.num_experts_published or self.num_experts,
-                top_k=self.num_experts_per_tok,
-                expert_width=self.moe_intermediate_size, shared_width=0,
-                experts_held=(self.experts_first, self.num_experts),
-                capacity_factor=self.capacity_factor,
-                norm_topk=self.norm_topk_prob, scoring="sigmoid",
+                eps=self.norm_eps, gated=False, qk_norm=True, qk_norm_zero_centered=False),
+            "dense": lambda: GatedMLP(width=self.intermediate_size, act="swiglu"),
+            "experts": lambda: self._experts(
+                top_k=self.num_experts_per_tok, expert_width=self.moe_intermediate_size,
+                shared_width=0, norm_topk=self.norm_topk_prob, scoring="sigmoid",
                 routed_scale=self.routed_scaling_factor, expert_act="swiglu",
-                norm_eps=1e-6, remat=self.remat)
-
-        return NeuralNetConfiguration(
-            seed=self.seed, updater=updaters.Adam(learning_rate=3e-4),
-            weight_init="xavier",
-        ).list([
-            EmbeddingSequence(n_in=self.vocab_size, n_out=self.hidden_size),
-            *(block(kind) for pair in self.sublayer_kinds() for kind in pair),
-            RMSNorm(eps=self.norm_eps, zero_centered=False),
-            RnnOutput(n_out=self.vocab_size, loss="mcxent",
-                      activation="softmax", has_bias=False),
-        ]).set_input_type(it.recurrent(self.vocab_size, self.max_length))
+                shared_gated=False, norm_eps=1e-6),
+        }
+        return [sub[kind]() for pair in self.sublayer_kinds() for kind in pair]
 
 
 @dataclass

@@ -94,11 +94,8 @@ def layer_case(kind, weights):
         return layer, {"w": weights["final_norm"]}, lambda q, x: ref.rms(
             x, q["w"], CFG["rms_norm_eps"])
     i = {"block_delta": 1, "block_attention": 3}[kind]
-    layer = HybridBlock(
-        mixer=kind.split("_")[1], n_heads=4, n_kv_heads=2, head_dim=16,
-        n_key_heads=2, n_value_heads=4, key_dim=8, value_dim=8, n_experts=8,
-        top_k=3, expert_width=16, shared_width=16, experts_held=(2, 4),
-        capacity_factor=2.0)
+    layer = HybridBlock(mixer=layer_case(kind.split("_")[1], weights)[0],
+                        moe=layer_case("experts", weights)[0])
     leaves = {k: v for k, v in ref.program_paths(CFG).items()
               if k.startswith(f"l{i}.")}
 

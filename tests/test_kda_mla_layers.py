@@ -404,8 +404,10 @@ def test_the_mixer_list_and_the_dense_first_layer():
     model = zoo.DeltaLatentMoELM(**ZOO_ARGS)
     assert model.sublayer_kinds() == [("kda", "dense"), ("kda", "experts"), ("kda", "experts"),
                                       ("latent", "experts"), ("kda", "experts")]
-    kinds = [l.kind for l in model.conf().layers if isinstance(l, SubLayerBlock)]
-    assert kinds == [k for pair in model.sublayer_kinds() for k in pair]
+    wraps = {"kda": KimiDeltaAttention, "latent": LatentAttention, "dense": GatedMLP,
+             "experts": RoutedExperts}
+    subs = [type(l.sub) for l in model.conf().layers if isinstance(l, SubLayerBlock)]
+    assert subs == [wraps[k] for pair in model.sublayer_kinds() for k in pair]
     assert ref.kinds(CFG) == [(m.replace("latent", "mla"), f.replace("experts", "moe"))
                               for m, f in model.sublayer_kinds()]
     # the published lists, whole: 20 KDA layers and 7 latent ones of 27
@@ -415,8 +417,10 @@ def test_the_mixer_list_and_the_dense_first_layer():
     assert [i + 1 for i, (m, _) in enumerate(all_kinds) if m == "latent"] == \
         CFG["linear_attn_config"]["full_attn_layers"]
     assert [f for _, f in all_kinds].count("dense") == 1
-    with pytest.raises(ValueError):
-        SubLayerBlock(kind="rwkv").init_params(jax.random.PRNGKey(0), IN)
+    # a `sub` that is no layer is refused
+    for no_layer in ("rwkv", None, 3):
+        with pytest.raises(TypeError):
+            SubLayerBlock(sub=no_layer)
 
 
 def batches(n=3, rows=2, t=T):

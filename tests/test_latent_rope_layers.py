@@ -22,7 +22,7 @@ from deeplearning4j_tpu import telemetry, zoo
 from deeplearning4j_tpu.models import MultiLayerNetwork, serialization
 from deeplearning4j_tpu.nn import inputs as it
 from deeplearning4j_tpu.nn.conf import MultiLayerConfiguration
-from deeplearning4j_tpu.nn.layers import LatentAttention, RoutedExperts, SubLayerBlock
+from deeplearning4j_tpu.nn.layers import GatedMLP, LatentAttention, RoutedExperts, SubLayerBlock
 from deeplearning4j_tpu.nn.layers import hybrid
 from deeplearning4j_tpu.ops import attention as att
 from deeplearning4j_tpu.ops import linear as ops
@@ -315,11 +315,12 @@ def test_every_layer_is_latent_and_the_first_is_dense():
     model = zoo.DeltaLatentMoELM(**ZOO_ARGS)
     assert model.sublayer_kinds() == [("latent", "dense"), ("latent", "experts"),
                                       ("latent", "experts")]
-    blocks = [l for l in model.conf().layers if isinstance(l, SubLayerBlock)]
-    assert [b.kind for b in blocks] == ["latent", "dense", "latent", "experts", "latent", "experts"]
-    assert all(b.rope_theta == 1e6 and b.rope_interleave for b in blocks)
+    subs = [l.sub for l in model.conf().layers if isinstance(l, SubLayerBlock)]
+    assert [type(s) for s in subs] == [LatentAttention, GatedMLP, LatentAttention, RoutedExperts,
+                                       LatentAttention, RoutedExperts]
+    assert all(s.rope_theta == 1e6 and s.rope_interleave for s in subs[::2])
     assert ref.kinds(CFG) == ["dense", "moe", "moe"]
-    shared = [b.shared_width for b in blocks if b.kind == "experts"]
+    shared = [s.shared_width for s in subs if isinstance(s, RoutedExperts)]
     assert shared == [2 * CFG["moe_intermediate_size"]] * 2        # two shared experts, one swiglu
     # the published depth: 48 latent layers, one dense
     kinds = zoo.DeltaLatentMoELM(**dict(ZOO_ARGS, num_hidden_layers=48)).sublayer_kinds()
@@ -328,8 +329,9 @@ def test_every_layer_is_latent_and_the_first_is_dense():
     # `mla_use_nope` (the kimi_linear shape) hands no theta on, whatever rope_theta says
     kimi = {k: v for k, v in tiny_kimi.kimi_linear()["program"]["args"].items() if k != "remat"}
     for args in (kimi, dict(ZOO_ARGS, mla_use_nope=True)):
-        assert all(l.rope_theta is None for l in zoo.DeltaLatentMoELM(**args).conf().layers
-                   if isinstance(l, SubLayerBlock))
+        latent = [l.sub for l in zoo.DeltaLatentMoELM(**args).conf().layers
+                  if isinstance(l, SubLayerBlock) and isinstance(l.sub, LatentAttention)]
+        assert latent and all(s.rope_theta is None for s in latent)
 
 
 def batches(n=3, rows=2):

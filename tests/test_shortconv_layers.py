@@ -8,6 +8,8 @@ layer; the experts without `shared_*` leaves and with the published epsilon;
 the 4 shares of an expert layer against the uncut layer; zoo -> config DSL ->
 `ParallelWrapper.fit` against the reference's three Adam steps; what every new
 argument's default leaves as it was; the mixer's scopes in the lowered step."""
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,6 +27,7 @@ from deeplearning4j_tpu.nn import inputs as it
 from deeplearning4j_tpu.nn.conf import MultiLayerConfiguration, NeuralNetConfiguration
 from deeplearning4j_tpu.nn.layers import (
     GatedAttention,
+    GatedMLP,
     GatedShortConv,
     RnnOutput,
     RoutedExperts,
@@ -232,17 +235,25 @@ def test_plain_and_zero_centred_norm_weights_start_at_the_same_layer(rng):
 
 
 def test_sublayer_attention_defaults_are_the_parents():
-    """Kind "attention" without the new arguments builds what it built:
-    no gate, no q/k norm, no positions (the Nemotron step must not move)."""
-    old = SubLayerBlock(kind="attention", n_heads=4, n_kv_heads=2, head_dim=8)._sub()
+    """The attention a pattern model wraps is what it was: no gate, no q/k
+    norm, no positions (the Nemotron step must not move); this model's says
+    every argument it means itself, and a block wraps whatever it is given."""
+    def wrapped(model, kind):
+        return next(l.sub for l in model.conf().layers
+                    if isinstance(l, SubLayerBlock) and isinstance(l.sub, kind))
+
+    old = wrapped(zoo.PatternHybridLM(hybrid_override_pattern="M*E", hidden_size=32,
+                                      num_attention_heads=4, num_key_value_heads=2, head_dim=8),
+                  GatedAttention)
     assert (old.gated, old.qk_norm, old.rotary_fraction) == (False, False, 0.0)
     assert set(old.init_params(jax.random.PRNGKey(0), IN)) == {"Wqkv", "Wo"}
-    new = SubLayerBlock(kind="attention", n_heads=4, n_kv_heads=2, head_dim=8, qk_norm=True,
-                        rotary_fraction=1.0, rope_theta=1e6, eps=1e-5)._sub()
+    new = wrapped(zoo.ShortConvMoELM(hidden_size=32, num_attention_heads=4, num_key_value_heads=2,
+                                     norm_eps=1e-5, rope_parameters={"rope_theta": 1e6}),
+                  GatedAttention)
     assert (new.qk_norm, new.qk_norm_zero_centered, new.rotary_fraction, new.rope_theta,
             new.eps) == (True, False, 1.0, 1e6, 1e-5)
-    assert "shortconv" in ssm.KINDS
-    assert isinstance(SubLayerBlock(kind="shortconv", conv_width=3)._sub(), GatedShortConv)
+    assert not hasattr(SubLayerBlock, "kind") and not hasattr(ssm, "KINDS")
+    assert isinstance(SubLayerBlock(sub=GatedShortConv(conv_width=3)).sub, GatedShortConv)
 
 
 # ---------------------------------------------------------------------------
@@ -356,17 +367,17 @@ def test_sublayer_kinds_follow_the_published_layer_types():
 
 def test_the_blocks_carry_the_published_recipe():
     model = zoo.ShortConvMoELM(**ZOO_ARGS)
-    blocks = [l for l in model.conf().layers if isinstance(l, SubLayerBlock)]
-    assert [b.kind for b in blocks] == ["shortconv", "dense", "attention", "experts",
-                                        "shortconv", "experts", "shortconv", "experts"]
-    e = next(b for b in blocks if b.kind == "experts")._sub()
+    subs = [l.sub for l in model.conf().layers if isinstance(l, SubLayerBlock)]
+    assert [type(s) for s in subs] == [GatedShortConv, GatedMLP, GatedAttention, RoutedExperts,
+                                       GatedShortConv, RoutedExperts, GatedShortConv, RoutedExperts]
+    e = next(s for s in subs if isinstance(s, RoutedExperts))
     assert (e.n_experts, e.top_k, e.held(), e.shared_width, e.scoring, e.norm_topk, e.norm_eps,
             e.routed_scale, e.expert_act) == (8, 3, (2, 4), 0, "sigmoid", True, 1e-6, 1.0, "swiglu")
-    a = next(b for b in blocks if b.kind == "attention")._sub()
+    a = next(s for s in subs if isinstance(s, GatedAttention))
     assert (a.n_heads, a.n_kv_heads, a.head_dim, a.rotary_fraction, a.rope_theta, a.qk_norm,
             a.qk_norm_zero_centered, a.gated, a.eps) == (4, 2, 8, 1.0, 1e6, True, False, False, 1e-5)
-    assert next(b for b in blocks if b.kind == "shortconv")._sub().conv_width == 3
-    assert next(b for b in blocks if b.kind == "dense")._sub().width == 64
+    assert next(s for s in subs if isinstance(s, GatedShortConv)).conv_width == 3
+    assert next(s for s in subs if isinstance(s, GatedMLP)).width == 64
 
 
 def batches(n=3, rows=2):
@@ -435,7 +446,9 @@ def test_new_layer_and_zoo_class_round_trip(tmp_path, rng):
     conf = zoo.ShortConvMoELM(**ZOO_ARGS).conf()
     again = MultiLayerConfiguration.from_json(conf.to_json())
     assert again.to_json() == conf.to_json()
-    assert '"kind": "shortconv"' in conf.to_json() and '"norm_eps": 1e-06' in conf.to_json()
+    nested = [l["sub"] for l in json.loads(conf.to_json())["layers"] if l["type"] == "SubLayerBlock"]
+    assert nested[0] == {"type": "GatedShortConv", "conv_width": 3}
+    assert nested[3]["type"] == "RoutedExperts" and nested[3]["norm_eps"] == 1e-06
     small = NeuralNetConfiguration(seed=3).list([
         layer, RnnOutput(n_out=5, loss="mcxent", activation="softmax")]).set_input_type(IN)
     assert '"conv_width": 3' in small.to_json()

@@ -36,6 +36,7 @@ from deeplearning4j_tpu.nn.layers import (
 from deeplearning4j_tpu.nn.layers import hybrid, ssm
 from deeplearning4j_tpu.parallel import MeshSpec, ParallelWrapper
 from deeplearning4j_tpu.parallel.mesh import build_mesh
+from deeplearning4j_tpu.zoo.models import pattern_kinds
 
 CFG = tiny_nemotron.nemotron_h()
 ZOO_ARGS = {k: v for k, v in CFG["program"]["args"].items() if k != "remat"}
@@ -94,8 +95,10 @@ def layer_case(kind, weights):
                 lambda q, x: ref.rms(x, q["w"], 1e-5))
     i = {"block_mamba": 2, "block_experts": 3, "block_attention": 7}[kind]
     prefix = {"block_mamba": "mamba.", "block_experts": "moe.", "block_attention": "attn."}[kind]
+    wraps = {"block_mamba": Mamba2Mixer, "block_experts": RoutedExperts,
+             "block_attention": GatedAttention}[kind]
     layer = next(l for l in zoo.PatternHybridLM(**ZOO_ARGS).conf().layers
-                 if isinstance(l, SubLayerBlock) and l.kind == kind.split("_")[1])
+                 if isinstance(l, SubLayerBlock) and isinstance(l.sub, wraps))
     p = sub(weights, f"l{i}.")
 
     def plain(q, x):
@@ -378,20 +381,21 @@ def test_reference_controls_change_the_result(weights, rng):
 
 
 def test_pattern_string_names_the_layers():
-    assert ssm.pattern_kinds("MEMEMEM*E") == [
+    assert pattern_kinds("MEMEMEM*E") == [
         "mamba", "experts", "mamba", "experts", "mamba", "experts", "mamba",
         "attention", "experts"]
     for bad in ("MEX", "", "M-E"):
         with pytest.raises(ValueError):
-            ssm.pattern_kinds(bad)
+            pattern_kinds(bad)
     layers = zoo.PatternHybridLM(**ZOO_ARGS).conf().layers
-    assert [l.kind for l in layers if isinstance(l, SubLayerBlock)] == ssm.pattern_kinds(
-        "MEMEMEM*E")
+    wraps = {"mamba": Mamba2Mixer, "attention": GatedAttention, "experts": RoutedExperts}
+    assert [type(l.sub) for l in layers if isinstance(l, SubLayerBlock)] == [
+        wraps[k] for k in pattern_kinds("MEMEMEM*E")]
     assert [ref.KINDS[ch] for ch in "M*E"] == ["mamba", "attn", "moe"]
     with pytest.raises(ValueError):
         zoo.PatternHybridLM(**dict(ZOO_ARGS, hybrid_override_pattern="MEQ")).conf()
     published = tiny.config("nemotron-3-nano-30b-a3b-l9")["published"]
-    kinds = ssm.pattern_kinds(published["hybrid_override_pattern"])
+    kinds = pattern_kinds(published["hybrid_override_pattern"])
     assert len(kinds) == published["num_hidden_layers"] == 52
     assert [kinds.count(k) for k in ("mamba", "experts", "attention")] == [23, 23, 6]
     assert published["hybrid_override_pattern"][35:44] == "MEMEMEM*E"
