@@ -69,6 +69,22 @@ class Recurrent(InputType):
 
 
 @dataclass(repr=False)
+class RecurrentPasses(Recurrent):
+    """The states of `passes` passes of a looped stack over one sequence,
+    [batch, passes, timesteps, size] — the batch axis stays leading (a data
+    mesh shards axis 0). What `LoopedStack` hands `LoopExitOutput`."""
+
+    kind: str = "rnn_passes"
+    passes: int = 1
+
+    def shape(self, batch=-1):
+        return (batch, self.passes, self.timesteps, self.size)
+
+    def arity(self):
+        return self.passes * super().arity()
+
+
+@dataclass(repr=False)
 class Convolutional(InputType):
     height: int
     width: int
@@ -115,6 +131,7 @@ def convolutional_flat(height: int, width: int, channels: int) -> ConvolutionalF
 _KINDS = {
     "ff": FeedForward,
     "rnn": Recurrent,
+    "rnn_passes": RecurrentPasses,
     "cnn": Convolutional,
     "cnn_flat": ConvolutionalFlat,
 }

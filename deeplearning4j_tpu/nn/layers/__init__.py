@@ -15,6 +15,7 @@ from deeplearning4j_tpu.nn.layers.dense import (  # noqa: F401
 from deeplearning4j_tpu.nn.layers.output import (  # noqa: F401
     BaseOutputLayer,
     CenterLossOutput,
+    LoopExitOutput,
     LossLayer,
     Output,
     RnnOutput,
@@ -55,6 +56,7 @@ from deeplearning4j_tpu.nn.layers.attention import (  # noqa: F401
 )
 from deeplearning4j_tpu.nn.layers.blocks import (  # noqa: F401
     HybridBlock,
+    LoopedStack,
     SubLayerBlock,
 )
 from deeplearning4j_tpu.nn.layers.hybrid import (  # noqa: F401

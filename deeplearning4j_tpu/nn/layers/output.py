@@ -1,5 +1,6 @@
 """Output / loss-bearing layers: OutputLayer, RnnOutputLayer, LossLayer,
-CenterLossOutputLayer.
+CenterLossOutputLayer, and `LoopExitOutput` (the exit-weighted loss over the
+passes of a `LoopedStack`; no DL4J counterpart).
 
 Reference: nn/conf/layers/{OutputLayer,RnnOutputLayer,LossLayer}.java,
 nn/conf/layers/CenterLossOutputLayer.java; runtime BaseOutputLayer
@@ -20,10 +21,14 @@ import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn import inputs as it
 from deeplearning4j_tpu.nn import losses as loss_mod
-from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
+from deeplearning4j_tpu.nn import initializers as init_mod
+from deeplearning4j_tpu.nn.layers.base import Layer, column_parallel_specs, register_layer
 from deeplearning4j_tpu.nn.layers.dense import Dense, _flatten_if_needed
 from deeplearning4j_tpu.ops import fused_linear_xent
 from deeplearning4j_tpu.ops import linear as ops
+from deeplearning4j_tpu.telemetry.trace import device_scope
+
+F32 = jnp.float32
 
 
 class BaseOutputLayer(Layer):
@@ -119,6 +124,132 @@ class RnnOutput(Output):
         if self.has_bias:
             z = ops.bias_add(z, params["b"])
         return z
+
+
+def exit_pdf(lam):
+    """The exit gate's lam [.., steps, t] in (0, 1) -> the distribution over
+    the passes a token: p_s = lam_s prod_{j<s}(1 - lam_j), the last pass
+    taking what is left, p_steps = prod_{j<steps}(1 - lam_j). Sums to one."""
+    stay = jnp.cumprod(1.0 - lam[..., :-1, :], axis=-2)          # still running after pass s
+    reach = jnp.concatenate([jnp.ones_like(lam[..., :1, :]), stay], axis=-2)
+    leave = jnp.concatenate([lam[..., :-1, :], jnp.ones_like(lam[..., :1, :])], axis=-2)
+    return reach * leave
+
+
+def entropy(p, axis):
+    """- sum p log p over `axis`, with p log p = 0 at p = 0 in value and in
+    gradient (a gate that saturated gives an exact zero)."""
+    some = p > 0
+    return -jnp.sum(jnp.where(some, p * jnp.log(jnp.where(some, p, 1.0)), 0.0), axis=axis)
+
+
+@register_layer
+@dataclass
+class LoopExitOutput(RnnOutput):
+    """The head and loss of a looped stack: x [b, steps, t, f]
+    (`LoopedStack`'s output), integer labels [b, t]. ONE head matrix W reads
+    the state of every pass, a learned gate lam_s = sigmoid(h_s . w + b)
+    (shared by the passes) makes the exit distribution p (`exit_pdf`), and a
+    token's score is the loss expected under it less an entropy bonus:
+
+        sum_s p_s l_s - beta H(p),   l_s the cross-entropy of pass s
+
+    so the gate learns through both terms and the stack through every l_s
+    and through lam. The rows of all passes go through `losses.sparse_xent`
+    in one call (no [.., n_out] array, a block's logits recomputed in the
+    backward); the gate, the distribution and the expectation are float32.
+    `output()` is the LAST pass's softmax: no pass is skipped at inference.
+    Params `W` (`b` with `has_bias`) and `gate` {w [f], b []}. State
+    `counters` (running sums a step, `telemetry.fit_log()` key `exit`): the
+    mean distribution `exit_p` [steps], its mean entropy `exit_entropy`, the
+    mean cross-entropy a pass `loss_by_pass` [steps]."""
+
+    beta: float = 0.1
+
+    sp_safe = False
+
+    def _passes(self, input_type):
+        if not isinstance(input_type, it.RecurrentPasses):
+            raise ValueError(f"LoopExitOutput reads a looped stack's passes "
+                             f"[b, steps, t, f], not {input_type}")
+        return input_type.passes
+
+    def output_type(self, input_type):
+        self._passes(input_type)
+        return it.Recurrent(self.n_out, input_type.timesteps)
+
+    def init_params(self, rng, input_type):
+        self._passes(input_type)
+        k_head, k_gate = jax.random.split(rng)
+        p = super().init_params(k_head, input_type)
+        n_in = self.resolve_n_in(input_type)
+        p["gate"] = {"w": init_mod.init(self.weight_init or "xavier", k_gate, (n_in, 1))[:, 0],
+                     "b": jnp.zeros((), F32)}
+        return p
+
+    def init_state(self, input_type):
+        steps = self._passes(input_type)
+        return {"counters": {"steps": jnp.zeros((), jnp.int32),
+                             "exit_p": jnp.zeros((steps,), F32),
+                             "exit_entropy": jnp.zeros((), F32),
+                             "loss_by_pass": jnp.zeros((steps,), F32)}}
+
+    def counter_summary(self, added):
+        """Per-step means of the counters over a fit, under `exit`."""
+        steps = max(int(added["steps"][0]), 1)
+        p = (added["exit_p"] / steps).tolist()
+        return "exit", {
+            "steps": int(added["steps"][0]),
+            "exit_p": p,
+            "expected_passes": sum((s + 1) * v for s, v in enumerate(p)),
+            "exit_entropy": float(added["exit_entropy"][0]) / steps,
+            "loss_by_pass": (added["loss_by_pass"] / steps).tolist(),
+        }
+
+    def regularizable(self, params):
+        return {"W": params["W"], "gate/w": params["gate"]["w"]}
+
+    def tensor_partition_specs(self, params, model_axis="model", model_size=1):
+        from jax.sharding import PartitionSpec as P
+
+        head = {k: v for k, v in params.items() if k != "gate"}
+        return {**column_parallel_specs(head, model_axis, model_size),
+                "gate": {"w": P(), "b": P()}}
+
+    def apply(self, params, x, *, state, train, rng, mask=None):
+        return super().apply(params, x[:, -1], state=state, train=train, rng=rng, mask=mask)
+
+    def compute_loss(self, params, x, labels, *, state, mask=None, rng=None):
+        if (not jnp.issubdtype(jnp.result_type(labels), jnp.integer)
+                or not self._softmax_xent()
+                or jnp.ndim(x) != 4 or labels.shape != (x.shape[0], x.shape[2])):
+            raise TypeError(
+                f"LoopExitOutput: passes [b, steps, t, f] against integer labels [b, t] "
+                f"under softmax + mcxent, not x {jnp.shape(x)} against "
+                f"{jnp.result_type(labels)} labels {jnp.shape(labels)} under "
+                f"{self.activation or 'softmax'} + {self._loss_name()}")
+        by_pass = loss_mod.sparse_xent(
+            x, params["W"], self._bias(params),
+            jnp.broadcast_to(labels[:, None, :], x.shape[:3]))       # [b, steps, t] float32
+        with device_scope("exit"):
+            gate = params["gate"]
+            lam = jax.nn.sigmoid(jnp.einsum("bstf,f->bst", x.astype(F32), gate["w"],
+                                            precision=jax.lax.Precision.HIGHEST) + gate["b"])
+            p = exit_pdf(lam)
+            h = entropy(p, axis=1)
+            score, per_ex = loss_mod.reduce_score(
+                jnp.sum(p * by_pass, axis=1) - self.beta * h, mask)
+        with device_scope("counters"):
+            def mean(a):  # [b, t] -> over the tokens the score counts
+                return loss_mod.reduce_score(jax.lax.stop_gradient(a), mask)[0]
+
+            by_step = jax.vmap(mean, in_axes=1)       # [b, steps, t] -> [steps]
+            c = state["counters"]
+            state = {"counters": {
+                "steps": c["steps"] + 1, "exit_p": c["exit_p"] + by_step(p),
+                "exit_entropy": c["exit_entropy"] + mean(h),
+                "loss_by_pass": c["loss_by_pass"] + by_step(by_pass)}}
+        return score, per_ex, state
 
 
 @register_layer

@@ -52,76 +52,23 @@ Booleans stay accepted where the old single `remat: bool` flag lived
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Tuple
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.parallel import mesh as mesh_mod
+from deeplearning4j_tpu.util.jaxcompat import (  # noqa: F401 — named from here by the runtime packages
+    REMAT_POLICY_NAMES,
+    canonical_policy,
+    maybe_remat,
+    remat_policy,
+)
 
-# ---------------------------------------------------------------------------
-# remat policy registry
-# ---------------------------------------------------------------------------
-
-#: stable policy-name order, weakest to strongest activation saving —
-#: bench/test code iterates this to check watermark monotonicity
-REMAT_POLICY_NAMES = ("none", "dots_saveable", "full", "offload")
-
-_POLICY_CACHE: Dict[str, Any] = {}
-
-
-def canonical_policy(name: Any) -> str:
-    """Normalize a remat selector (None/bool/str) to a canonical name."""
-    if name is None or name is False or name == "none":
-        return "none"
-    if name is True or name == "full":
-        return "full"
-    n = str(name)
-    if n in REMAT_POLICY_NAMES:
-        return n
-    raise ValueError(
-        f"unknown remat policy {name!r}; choose one of "
-        f"{REMAT_POLICY_NAMES} (or a bool: True='full', False='none')")
-
-
-def remat_policy(name: Any):
-    """The jax.checkpoint `policy=` object for a canonical name ('full'
-    saves nothing but what is tagged `base.REMAT_KEEP` — a value that costs
-    more to compute again than to keep: the output of an inner checkpoint
-    (`hybrid.over_row_groups`), so that it is not run a third time, and the
-    flash forward kernel's output and logsumexp (`pallas_kernels`), so that
-    it is not run a second time). Cached so the same name always returns
-    the SAME callable: a fresh policy closure per call would defeat the jit
-    trace cache."""
-    n = canonical_policy(name)
-    if n in _POLICY_CACHE:
-        return _POLICY_CACHE[n]
-    cp = jax.checkpoint_policies
-    if n == "dots_saveable":
-        pol = cp.dots_saveable
-    elif n == "offload":
-        # dot outputs leave HBM for pinned host memory
-        pol = cp.offload_dot_with_no_batch_dims("device", "pinned_host")
-    elif n == "full":
-        from deeplearning4j_tpu.nn.layers.base import REMAT_KEEP
-
-        pol = cp.save_only_these_names(REMAT_KEEP)
-    else:  # 'none'
-        pol = None
-    _POLICY_CACHE[n] = pol
-    return pol
-
-
-def maybe_remat(fn: Callable, name: Any) -> Callable:
-    """Wrap `fn` in jax.checkpoint under the named policy; identity for
-    'none'. The single seam both parallel/transformer.py stages and the
-    config-DSL per-layer forward route through."""
-    n = canonical_policy(name)
-    if n == "none":
-        return fn
-    return jax.checkpoint(fn, policy=remat_policy(n))
-
+# The remat policy registry (REMAT_POLICY_NAMES, canonical_policy, remat_policy,
+# maybe_remat) stands in util/jaxcompat.py beside `REMAT_KEEP`, where `nn/` can
+# reach it too; the runtime packages name it from here.
 
 #: modeled fraction of the full activation stash each policy keeps —
 #: nn/memory.py and the analyzer read this so static estimates and the

@@ -717,6 +717,8 @@ SCOPE_PARTS = frozenset({
     "rope", "attend", "out",
     # routed experts
     "route", "sort", "gather", "product", "combine", "shared",
+    # the exit gate and distribution of a looped stack's loss
+    "exit",
 })
 
 

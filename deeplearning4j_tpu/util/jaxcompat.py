@@ -3,11 +3,16 @@
 ``jit`` is ``jax.jit`` plus the compile watcher (telemetry/introspect.py)
 and the donation metadata the analyzer audits. ``REMAT_KEEP`` is the one
 name the 'full' remat policy reads; it stands here because `ops/` and `nn/`
-both tag with it and `ops/` imports nothing of `nn/`.
+both tag with it and `ops/` imports nothing of `nn/`. The remat policies'
+names and their lowering (``maybe_remat``) stand beside it for the same
+reason: the models wrap a layer's call in them, and so does a container
+LAYER around its nested layers' applications (`nn/` imports nothing of
+`parallel/`, whose `layout.py` names them for the runtime packages).
 """
 from __future__ import annotations
 
 import functools
+from typing import Any, Callable, Dict
 
 import jax
 
@@ -21,6 +26,64 @@ import jax
 #: q, k, v, so the block's recompute does not call the forward kernel again).
 #: Outside a `jax.checkpoint` the tag lowers to nothing.
 REMAT_KEEP = "dl4j_remat_keep"
+
+#: stable policy-name order, weakest to strongest activation saving —
+#: bench/test code iterates this to check watermark monotonicity
+REMAT_POLICY_NAMES = ("none", "dots_saveable", "full", "offload")
+
+_POLICY_CACHE: Dict[str, Any] = {}
+
+
+def canonical_policy(name: Any) -> str:
+    """Normalize a remat selector (None/bool/str) to a canonical name."""
+    if name is None or name is False or name == "none":
+        return "none"
+    if name is True or name == "full":
+        return "full"
+    n = str(name)
+    if n in REMAT_POLICY_NAMES:
+        return n
+    raise ValueError(
+        f"unknown remat policy {name!r}; choose one of "
+        f"{REMAT_POLICY_NAMES} (or a bool: True='full', False='none')")
+
+
+def remat_policy(name: Any):
+    """The jax.checkpoint `policy=` object for a canonical name ('full'
+    saves nothing but what is tagged `REMAT_KEEP` — a value that costs
+    more to compute again than to keep: the output of an inner checkpoint
+    (`hybrid.over_row_groups`), so that it is not run a third time, and the
+    flash forward kernel's output and logsumexp (`pallas_kernels`), so that
+    it is not run a second time). Cached so the same name always returns
+    the SAME callable: a fresh policy closure per call would defeat the jit
+    trace cache."""
+    n = canonical_policy(name)
+    if n in _POLICY_CACHE:
+        return _POLICY_CACHE[n]
+    cp = jax.checkpoint_policies
+    if n == "dots_saveable":
+        pol = cp.dots_saveable
+    elif n == "offload":
+        # dot outputs leave HBM for pinned host memory
+        pol = cp.offload_dot_with_no_batch_dims("device", "pinned_host")
+    elif n == "full":
+        pol = cp.save_only_these_names(REMAT_KEEP)
+    else:  # 'none'
+        pol = None
+    _POLICY_CACHE[n] = pol
+    return pol
+
+
+def maybe_remat(fn: Callable, name: Any) -> Callable:
+    """Wrap `fn` in jax.checkpoint under the named policy; identity for
+    'none'. The single seam parallel/transformer.py's stages, the
+    config-DSL per-layer forward and a container layer's nested
+    applications route through (the runtime packages name it as
+    `parallel.layout.maybe_remat`)."""
+    n = canonical_policy(name)
+    if n == "none":
+        return fn
+    return jax.checkpoint(fn, policy=remat_policy(n))
 
 
 def jit(fn, *, watch_name=None, **jit_kwargs):

@@ -44,6 +44,8 @@ from deeplearning4j_tpu.nn.layers import (
     HybridBlock,
     KimiDeltaAttention,
     LatentAttention,
+    LoopedStack,
+    LoopExitOutput,
     Mamba2Mixer,
     Output,
     RMSNorm,
@@ -513,24 +515,35 @@ class _DecoderLM(ZooModel):
             experts_held=(self.experts_first, self.num_experts),
             capacity_factor=self.capacity_factor, **recipe)
 
-    def conf(self):
+    def blocks(self, **block_args):
+        """The residual blocks and the final norm, in network order;
+        `block_args` go to every `SubLayerBlock`."""
         key, zero_centered = self.NORM
         eps = getattr(self, key)
-        blocks = [
+        return [
             HybridBlock(mixer=sub[0], moe=sub[1], eps=eps, remat=self.remat)
-            if isinstance(sub, tuple) else SubLayerBlock(sub=sub, eps=eps, remat=self.remat)
+            if isinstance(sub, tuple)
+            else SubLayerBlock(sub=sub, eps=eps, remat=self.remat, **block_args)
             for sub in self.sublayers()
-        ]
+        ] + [RMSNorm(eps=eps, zero_centered=zero_centered)]
+
+    def network(self, layers):
+        """Token ids -> the embedding -> `layers`, under the skeleton's
+        optimizer and initialisation."""
         return NeuralNetConfiguration(
             seed=self.seed, updater=updaters.Adam(learning_rate=3e-4),
             weight_init="xavier",
         ).list([
             EmbeddingSequence(n_in=self.vocab_size, n_out=self.hidden_size),
-            *blocks,
-            RMSNorm(eps=eps, zero_centered=zero_centered),
+            *layers,
+        ]).set_input_type(it.recurrent(self.vocab_size, self.max_length))
+
+    def conf(self):
+        return self.network([
+            *self.blocks(),
             RnnOutput(n_out=self.vocab_size, loss="mcxent",
                       activation="softmax", has_bias=False),
-        ]).set_input_type(it.recurrent(self.vocab_size, self.max_length))
+        ])
 
 
 @dataclass
@@ -795,6 +808,44 @@ class ShortConvMoELM(_DecoderLM):
                 shared_gated=False, norm_eps=1e-6),
         }
         return [sub[kind]() for pair in self.sublayer_kinds() for kind in pair]
+
+
+@dataclass
+class LoopLM(_DecoderLM):
+    """Decoder-only LM whose layers run `total_ut_steps` times over the SAME
+    weights (the `ouro` shape): `num_hidden_layers` layers of full multi-head
+    attention with rotary positions over the whole head and a dense swiglu,
+    each sub-layer between two RMS norms (the sandwich: y = x +
+    rms(sub(rms(x)))), the final norm INSIDE the loop — its output is what
+    the next pass reads and what the head reads. One `LoopedStack` holds
+    them; `LoopExitOutput` reads the state of every pass with one head, a
+    learned gate turns them into an exit distribution a token, and the score
+    is the loss expected under it less `beta` times its entropy."""
+
+    num_hidden_layers: int = 4
+    num_attention_heads: int = 4
+    num_key_value_heads: int = 4
+    head_dim: int = 64
+    intermediate_size: int = 512
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1e6
+    total_ut_steps: int = 4
+    beta: float = 0.1
+
+    def sublayers(self):
+        return [sub for _ in range(self.num_hidden_layers) for sub in (
+            GatedAttention(
+                n_heads=self.num_attention_heads, n_kv_heads=self.num_key_value_heads,
+                head_dim=self.head_dim, rotary_fraction=1.0, rope_theta=float(self.rope_theta),
+                eps=self.rms_norm_eps, gated=False, qk_norm=False, qk_norm_zero_centered=False),
+            GatedMLP(width=self.intermediate_size, act="swiglu"))]
+
+    def conf(self):
+        return self.network([
+            LoopedStack(layers=self.blocks(post_norm=True), steps=self.total_ut_steps),
+            LoopExitOutput(n_out=self.vocab_size, loss="mcxent", activation="softmax",
+                           has_bias=False, beta=self.beta),
+        ])
 
 
 @dataclass

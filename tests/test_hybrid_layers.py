@@ -535,3 +535,64 @@ def test_delta_core_holds_one_layout(rows, rng, monkeypatch):
              and np.prod(e.outvars[0].aval.shape) >= 4 * t * hk * dk]   # not b | a, the mask
     assert len(moved) == 4, moved                          # q | k, v, z; the result
 
+
+
+# ---------------------------------------------------------------------------
+# the sandwich norm of a residual block (PR 44)
+# ---------------------------------------------------------------------------
+def _sandwich_case(rng, **block_args):
+    from deeplearning4j_tpu.nn.layers import GatedMLP, SubLayerBlock
+
+    block = SubLayerBlock(sub=GatedMLP(width=24, act="swiglu"), eps=1e-6, **block_args)
+    params = block.init_params(jax.random.PRNGKey(0), IN)
+    params = jax.tree.map(
+        lambda a: a + 0.1 * jnp.asarray(rng.normal(size=a.shape), a.dtype), params)
+    x = jnp.asarray(rng.normal(size=(2, T, 32)), jnp.float32)
+    return block, params, x
+
+
+def _plain_rms(u, w, eps=1e-6):
+    return u / jnp.sqrt(jnp.mean(u * u, axis=-1, keepdims=True) + eps) * w
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_sandwich_block_is_its_formula(masked, rng):
+    """y = x + rms(sub(rms(x; w)); w_out), value and every gradient."""
+    block, params, x = _sandwich_case(rng, post_norm=True)
+    assert sorted(params) == ["norm", "norm_out", "sub"]
+    mask = jnp.asarray(rng.integers(0, 2, (2, T)), jnp.float32) if masked else None
+
+    def formula(p, a):
+        inner, _ = block.sub.apply(p["sub"], _plain_rms(a, p["norm"]["w"]), state={},
+                                   train=True, rng=None, mask=mask)
+        return a + _plain_rms(inner, p["norm_out"]["w"])
+
+    run = lambda p, a: block.apply(p, a, state={}, train=True, rng=None, mask=mask)[0]  # noqa: E731
+    np.testing.assert_allclose(run(params, x), formula(params, x), rtol=2e-5, atol=2e-6)
+    weigh = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
+    got = jax.grad(lambda p, a: jnp.sum(run(p, a) * weigh), argnums=(0, 1))(params, x)
+    want = jax.grad(lambda p, a: jnp.sum(formula(p, a) * weigh), argnums=(0, 1))(params, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5 * float(jnp.abs(b).max() + 1e-9))
+    # the second norm undoes the sub-layer's scale: what is added has rms ~ w_out
+    added = run(params, x) - x
+    if not masked:
+        rms = jnp.sqrt(jnp.mean((added / params["norm_out"]["w"]) ** 2, axis=-1))
+        np.testing.assert_allclose(rms, 1.0, rtol=1e-3)
+
+
+def test_sandwich_norm_is_off_by_default_and_leaves_the_block_as_it_was(rng):
+    block, params, x = _sandwich_case(rng)
+    assert block.post_norm is False and sorted(params) == ["norm", "sub"]
+    inner, _ = block.sub.apply(params["sub"], _plain_rms(x, params["norm"]["w"]), state={},
+                               train=True, rng=None)
+    got, _ = block.apply(params, x, state={}, train=True, rng=None)
+    np.testing.assert_allclose(got, x + inner, rtol=2e-5, atol=2e-6)
+    # an old configuration (no such key) reads back as the block it was
+    d = block.to_json()
+    d.pop("post_norm")
+    assert Layer.from_json(d) == block
+    # and the jaxpr of the default block holds no second norm (one rsqrt)
+    text = str(jax.make_jaxpr(
+        lambda p, a: block.apply(p, a, state={}, train=True, rng=None)[0])(params, x))
+    assert text.count("rsqrt") == 1

@@ -174,3 +174,81 @@ def test_vision_transformer_forward_and_fit(rng):
     import pytest
     with pytest.raises(ValueError, match="patch"):
         VisionTransformer(input_shape=(30, 30, 3), patch_size=4).conf()
+
+
+# ---------------------------------------------------------------------------
+# the looped LM against the benchmark's plain reference (PR 44)
+# ---------------------------------------------------------------------------
+LOOP_LIMITS = {"loss_gap": 2e-6, "grad_norm_gap": 2e-4, "grad_norm_gap_median": 2e-5,
+               "delta_norm_gap": 2e-3}
+
+
+@pytest.fixture(scope="module")
+def loop_lm_case():
+    import jax
+
+    from benchmark.reference import ouro as ref
+    from benchmark.tests import tiny_ouro
+    from benchmark.traffic import train_stream_ids as tsi
+
+    cfg = tiny_ouro.ouro()
+    seed = 2 ** 31 + 44
+    data = tsi.make_batches(cfg, dict(tiny_ouro.TRAIN_IDS, distinct_batches=3), 2, seed)
+    p0 = jax.device_get(ref.init_params(cfg, seed))
+    want = tsi.reference_numbers(ref, cfg, p0, {}, data, 3)
+    return cfg, ref, data, p0, want
+
+
+def test_loop_lm_takes_the_references_three_adam_steps(loop_lm_case):
+    """zoo.LoopLM -> config DSL -> `ParallelWrapper.fit` on integer labels
+    against benchmark/reference/ouro.py (four explicit passes, float32): each
+    loss, the first gradient of EVERY leaf as Adam got it, the parameters'
+    change after three steps. `install` names every leaf of the program: ONE
+    pass's."""
+    import jax
+
+    from benchmark import program
+    from benchmark.reference import common
+    from benchmark.traffic import train_stream as ts
+    from deeplearning4j_tpu import telemetry
+    from deeplearning4j_tpu.parallel import MeshSpec, ParallelWrapper
+    from deeplearning4j_tpu.parallel.mesh import build_mesh
+
+    cfg, ref, data, p0, want = loop_lm_case
+    net = program.build_net(cfg)
+    program.install(net, ref, cfg, p0, {})
+    paths = ref.program_paths(cfg)
+    layers = cfg["num_hidden_layers"]
+    assert (len(jax.tree_util.tree_leaves(net.params)) == len(paths)
+            == len(ref.leaf_shapes(cfg)) == 8 * layers + 5)
+    assert net.num_params() == sum(int(np.prod(s)) for s in ref.leaf_shapes(cfg).values())
+    log = ts.StepLog()
+    net.set_listeners(log)
+    pw = ParallelWrapper(net, mesh=build_mesh(MeshSpec(data=1), jax.devices()[:1]))
+    stream = ts.make_stream([program.dataset(x, y) for x, y, _ in data], 2)
+    got = ts.program_numbers(net, pw, stream, log, ref, cfg, p0, 3)
+    rows = common.compare_training(got, want, LOOP_LIMITS, ref.COMPARISONS)
+    assert all(r[3] for r in rows), rows
+    # every leaf's gradient, not the worst alone; the gate's is no zero
+    gaps = common.leaf_gaps(got["grad_norms"], want["grad_norms"])
+    assert set(gaps) == set(paths) and max(gaps.values()) < 2e-4, gaps
+    assert want["grad_norms"]["gate.w"] > 0 and want["grad_norms"]["gate.b"] > 0
+    exits = telemetry.fit_log()[-1]["exit"]
+    assert len(exits) == 1 and len(exits[0]["exit_p"]) == cfg["total_ut_steps"]
+    assert abs(sum(exits[0]["exit_p"]) - 1.0) < 1e-5 and 1.0 < exits[0]["expected_passes"] < 4.0
+    assert 0.0 < exits[0]["exit_entropy"] < np.log(4.0)
+
+
+@pytest.mark.parametrize("control", ["three_passes", "last_pass_loss", "norm_outside"])
+def test_each_fault_of_the_loop_is_another_model(control, loop_lm_case):
+    """The reference's structural controls fail the limits the program holds."""
+    from benchmark.reference import common
+    from benchmark.traffic import train_stream_ids as tsi
+
+    cfg, ref, data, p0, want = loop_lm_case
+    other = tsi.reference_numbers(ref, cfg, p0, {}, data, 3, control)
+    rows = common.compare_training(other, want, LOOP_LIMITS, ref.COMPARISONS)
+    assert not all(r[3] for r in rows), rows
+    # and by the limits the cell itself is held to on the chip
+    rows = common.compare_training(other, want, ref.LIMITS, ref.COMPARISONS)
+    assert not all(r[3] for r in rows), rows
