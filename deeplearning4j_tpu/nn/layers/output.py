@@ -79,24 +79,29 @@ class Output(Dense, BaseOutputLayer):
         return fused_linear_xent(_flatten_if_needed(x), params.get("W"),
                                  self._bias(params), labels)
 
-    def _index_xent_per_example(self, params, x, labels):
+    def _index_xent(self, params, x, labels, mask):
         """Integer class labels ([b] or [b, t]) on a softmax head with
-        mcxent: the head and the loss in row blocks
-        (`losses.sparse_xent`), so no [.., n_out] label array exists.
-        None for dense labels or another loss (-> the paths below; an
-        integer label is then expanded by `losses.compute`)."""
+        mcxent: (score, per_example) from the head and the loss in row
+        blocks (`losses.sparse_xent`), so no [.., n_out] label array exists.
+        The rows go in with the weights `reduce_score` would give them, so
+        under a gradient a block's share of it is made while its logits are
+        there. None for dense labels or another loss (-> the paths below;
+        an integer label is then expanded by `losses.compute`)."""
         if (not jnp.issubdtype(jnp.result_type(labels), jnp.integer)
                 or not self._softmax_xent()):
             return None
         x2 = x if jnp.ndim(x) == jnp.ndim(labels) + 1 else _flatten_if_needed(x)
         if x2.shape[:-1] != labels.shape:
             return None
-        return loss_mod.sparse_xent(x2, params["W"], self._bias(params), labels)
+        weights, m = loss_mod.mean_weights(labels.shape, mask)
+        score, ce = loss_mod.sparse_xent(x2, params["W"], self._bias(params), labels, weights)
+        return score, ce if m is None else ce * m
 
     def compute_loss(self, params, x, labels, *, state, mask=None, rng=None):
-        per_example = self._index_xent_per_example(params, x, labels)
-        if per_example is None:
-            per_example = self._fused_xent_per_example(params, x, labels)
+        scored = self._index_xent(params, x, labels, mask)
+        if scored is not None:
+            return (*scored, state)
+        per_example = self._fused_xent_per_example(params, x, labels)
         if per_example is not None:
             score, per_ex = loss_mod.reduce_score(per_example, mask)
             return score, per_ex, state
@@ -156,8 +161,11 @@ class LoopExitOutput(RnnOutput):
 
     so the gate learns through both terms and the stack through every l_s
     and through lam. The rows of all passes go through `losses.sparse_xent`
-    in one call (no [.., n_out] array, a block's logits recomputed in the
-    backward); the gate, the distribution and the expectation are float32.
+    in one call (no [.., n_out] array), each with the weight its l_s enters
+    the score with, p_s mask / sum(mask): under a gradient a block's share of
+    it is made while its logits are there (nothing is recomputed), and the
+    gate's gradient through the expectation is the weights' own, l_s. The
+    gate, the distribution and the weights are float32.
     `output()` is the LAST pass's softmax: no pass is skipped at inference.
     Params `W` (`b` with `has_bias`) and `gate` {w [f], b []}. State
     `counters` (running sums a step, `telemetry.fit_log()` key `exit`): the
@@ -228,27 +236,32 @@ class LoopExitOutput(RnnOutput):
                 f"under softmax + mcxent, not x {jnp.shape(x)} against "
                 f"{jnp.result_type(labels)} labels {jnp.shape(labels)} under "
                 f"{self.activation or 'softmax'} + {self._loss_name()}")
-        by_pass = loss_mod.sparse_xent(
-            x, params["W"], self._bias(params),
-            jnp.broadcast_to(labels[:, None, :], x.shape[:3]))       # [b, steps, t] float32
         with device_scope("exit"):
             gate = params["gate"]
             lam = jax.nn.sigmoid(jnp.einsum("bstf,f->bst", x.astype(F32), gate["w"],
                                             precision=jax.lax.Precision.HIGHEST) + gate["b"])
             p = exit_pdf(lam)
             h = entropy(p, axis=1)
-            score, per_ex = loss_mod.reduce_score(
-                jnp.sum(p * by_pass, axis=1) - self.beta * h, mask)
+            weights, m = loss_mod.mean_weights(labels.shape, mask)
+        # the gate learns through the weights: d(expected)/d(p weights) = by_pass
+        expected, by_pass = loss_mod.sparse_xent(
+            x, params["W"], self._bias(params),
+            jnp.broadcast_to(labels[:, None, :], x.shape[:3]),
+            p * weights[:, None, :])                                 # by_pass [b, steps, t] float32
+        with device_scope("exit"):
+            score = expected - self.beta * jnp.sum(h * weights)
+            per_ex = jnp.sum(p * by_pass, axis=1) - self.beta * h
+            if m is not None:
+                per_ex = per_ex * m
         with device_scope("counters"):
-            def mean(a):  # [b, t] -> over the tokens the score counts
-                return loss_mod.reduce_score(jax.lax.stop_gradient(a), mask)[0]
+            def mean(a):  # over the tokens the score counts: [b, t] -> [], [b, steps, t] -> [steps]
+                return jnp.einsum("b...t,bt->...", jax.lax.stop_gradient(a), weights)
 
-            by_step = jax.vmap(mean, in_axes=1)       # [b, steps, t] -> [steps]
             c = state["counters"]
             state = {"counters": {
-                "steps": c["steps"] + 1, "exit_p": c["exit_p"] + by_step(p),
+                "steps": c["steps"] + 1, "exit_p": c["exit_p"] + mean(p),
                 "exit_entropy": c["exit_entropy"] + mean(h),
-                "loss_by_pass": c["loss_by_pass"] + by_step(by_pass)}}
+                "loss_by_pass": c["loss_by_pass"] + mean(by_pass)}}
         return score, per_ex, state
 
 

@@ -719,6 +719,9 @@ SCOPE_PARTS = frozenset({
     "route", "sort", "gather", "product", "combine", "shared",
     # the exit gate and distribution of a looped stack's loss
     "exit",
+    # a row block's gradient, made in the forward visit of the block
+    # (`losses.sparse_xent_weighted`)
+    "grad",
 })
 
 

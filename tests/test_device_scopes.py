@@ -254,6 +254,21 @@ def test_every_part_on_both_passes(steps, family):
     assert {str(i) for i in range(n_layers - 1)} | {None} == layers
 
 
+@pytest.mark.parametrize("family", FAMILIES)
+def test_the_loss_makes_its_gradient_in_its_forward_visit(steps, family):
+    """Integer labels on softmax + mcxent: the row-blocked head + loss
+    (`losses.sparse_xent_weighted`) forms a block's gradient under the part
+    `grad`, on the forward pass by the reader's grammar (no `transpose(`),
+    and nothing of the loss is run again: three products in all."""
+    loss = [(line, s) for line, n in steps(family)
+            for s in [scope_reduce.parse(n)] if s is not None and s.kind == "loss"]
+    assert any(s.parts == ("grad",) and not s.backward for _, s in loss)
+    assert not any(s.recompute for _, s in loss)
+    products = [s for line, s in loss if re.search(r" (dot|convolution)\(", line)]
+    assert len(products) == 3 and [s.parts for s in products].count(("grad",)) == 2
+    assert not any(s.backward for s in products)
+
+
 @pytest.mark.parametrize("family", ["qwen3next", "nemotron", "kimilinear"])
 def test_written_out_backwards_carry_their_part(steps, family):
     """`_conv_silu_bwd`, `_to_buffer_bwd`, `_from_buffer_bwd` (and
