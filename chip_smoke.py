@@ -78,6 +78,10 @@ class Sizes:
     # the short convolution's operands [n, r, h, c, d] and whether a bias: Kimi-Linear's, Nemotron's two
     conv_shapes: tuple = (((128, 1, 96, 64, 128), False), ((64, 1, 64, 128, 64), True),
                           ((64, 1, 16, 128, 128), True))
+    # rows, tokens, (heads a part, parts that turn), head width, features turned: Ouro's [q | k | v],
+    # and a head of two lane tiles of which a quarter turns (Qwen3-Next's)
+    rope_shapes: tuple = ((1, 8192, (16, 16, 16), (True, True, False), 128, 128),
+                          (2, 8192, (16, 2), (True, False), 256, 64))
     bn_batch: int = 128
     bn_shapes: tuple = ((112, 112, 64), (28, 28, 128), (56, 56, 256),
                         (28, 28, 512), (14, 14, 1024), (7, 7, 2048))
@@ -688,6 +692,33 @@ def phase_kernels(sz: Sizes):
 
     for shape, bias in sz.conv_shapes:
         run(f"conv silu {shape}", lambda shape=shape, bias=bias: conv_case(shape, bias))
+
+    # ---- the rotation + head split of an attention projection's columns fwd+bwd, through its door
+    def rope_case(b, t, heads, turned, d, rot):
+        from deeplearning4j_tpu.nn.layers import hybrid
+        from deeplearning4j_tpu.ops import attention as att
+
+        a = rnd((b, t, sum(heads) * d), jnp.bfloat16)
+        cts = tuple(rnd((b, n, t, d), jnp.bfloat16) for n in heads)
+        assert att.rope_impl("auto", b, t, d, rot, a.dtype) == "pallas" or interpret
+
+        def xla(a_):
+            cols = jnp.split(a_, list(np.cumsum([n * d for n in heads])[:-1]), axis=-1)
+            split = [c.reshape(b, t, n, d).transpose(0, 2, 1, 3) for c, n in zip(cols, heads)]
+            return tuple(hybrid.rotary(x, rot, 1e6) if turn else x for x, turn in zip(split, turned))
+
+        def both(f):
+            def run(a_):
+                out, vjp = jax.vjp(f, a_)
+                return tuple(out) + tuple(vjp(cts))
+            return jax.jit(run)
+
+        got = both(lambda a_: att.rope_heads(a_, heads, turned, d, rot, 1e6, impl="pallas"))(a)
+        _compare(f"rope heads b={b} t={t} heads={heads} d={d} rot={rot} bf16 parts,da",
+                 jnp.bfloat16, got, both(xla)(a), failures)
+
+    for shape in sz.rope_shapes:
+        run(f"rope heads {shape}", lambda shape=shape: rope_case(*shape))
 
     assert not failures, "kernels: " + "; ".join(failures)
 

@@ -13,14 +13,22 @@ state are float32.
   RMSNorm        y = x rsqrt(mean x^2 + eps) (1 + w)   (zero-centred weight;
                  or the plain form .. w)
   rotary         on `rotary_dim` features of a head from an offset on,
-                 half-split or interleaved pairing
+                 half-split or interleaved pairing: ONE XLA form for both,
+                 y = x cos + (x S) sin over the whole width, S the 0 / 1
+                 matrix of the pairing (nothing sliced or concatenated)
   GatedAttention [q | g | k | v] = x Wqkv; per-head RMS norm of q and k;
                  partial rotary; each key/value head repeated to its query
                  heads; causal softmax through `ops.attention.attend`
                  (the flash kernels where its rule admits them);
                  o sigmoid(g) Wo. Gate, norms and positions can each be
                  left out (plain grouped-query attention); the norms'
-                 weights zero-centred (1 + w) or plain (w)
+                 weights zero-centred (1 + w) or plain (w). Where the
+                 projection is [q | k | v] with no norm in front, the split
+                 into heads and the rotation of q and k are ONE pass over its
+                 columns (`ops.attention.rope_heads`: the kernel pair
+                 `dl4j_rope_fwd` / `dl4j_rope_bwd` where its rule admits the
+                 shapes — a TPU, heads of whole lane tiles); `rotary` behind
+                 the transpose to heads everywhere else
   GatedDeltaNet  [q | k | v | z] = x Wqkvz, [b | a] = x Wba; short causal
                  depthwise convolution + silu over [q | k | v]; the gated
                  delta rule S <- exp(g) S; S += k (beta (v - S^T k))^T;
@@ -105,38 +113,31 @@ def rotary(x, rotary_dim: int, theta: float, start: int = 0, interleave: bool = 
     `start` on; the rest passes through. Pair j turns by the angle
     pos theta^(-2j/rotary_dim), pos the index in the sequence, computed in
     float32. Half-split: feature j of the part pairs with j + rotary_dim/2;
-    `interleave`: feature 2j with 2j + 1."""
-    if interleave:
-        return _rotary_neighbours(x, rotary_dim, theta, start)
-    t, half, stop = x.shape[2], rotary_dim // 2, start + rotary_dim
-    j = jnp.arange(half, dtype=F32)
-    ang = jnp.arange(t, dtype=F32)[:, None] * theta ** (-2.0 * j / rotary_dim)
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    xf = x.astype(F32)
-    a, b, rest = xf[..., start:start + half], xf[..., start + half:stop], xf[..., stop:]
-    before = [xf[..., :start]] if start else []
-    return jnp.concatenate(before + [a * cos - b * sin, b * cos + a * sin, rest],
-                           axis=-1).astype(x.dtype)
+    `interleave`: feature 2j with 2j + 1.
 
-
-def _rotary_neighbours(x, rotary_dim: int, theta: float, start: int):
-    """`rotary` with neighbours paired: y = x cos + swap(x) sin over the WHOLE
-    width, cos 1 and sin 0 outside the part, swap(x) = x S with S the 0 / 1
-    matrix that puts each feature's partner in its place — one MXU product,
-    exact in any dtype (a column selects one element), in place of slicing
-    the part out, rolling it by a lane both ways and concatenating it back:
-    compiled for a v5e those passes move 11 x the array's bytes, this 3 x."""
+    Either pairing is y = x cos + swap(x) sin over the WHOLE width, cos 1 and
+    sin 0 outside the part, swap(x) = x S with S the 0 / 1 matrix that puts
+    each feature's partner in its place — one MXU product, exact in any dtype
+    (a column selects one element), in place of slicing the part out, moving
+    its halves (or rolling it by a lane both ways) and concatenating it back:
+    on a v5e the sliced form takes 1.1 - 1.7 x this one's time forward and
+    1.2 - 2.5 x backward at every head width measured (PERF.md section 6,
+    PR 46; PR 38 for the neighbours). Where an attention layer's projection
+    needs no norm in front, `ops.attention.rope_heads` does the half-split
+    rotation and the head split in one kernel pass instead."""
     t, width = x.shape[2], x.shape[3]
-    lane = np.arange(width) - start
+    lane, half = np.arange(width) - start, rotary_dim // 2
     inside = (lane >= 0) & (lane < rotary_dim)
-    even = lane % 2 == 0
+    # the first of a pair takes -sin of the second, `away` lanes further on
+    first, away, pair = ((lane % 2 == 0, 1, lane // 2) if interleave
+                         else (lane < half, half, lane % half))
     swap = np.zeros((width, width), np.float32)
     at = np.arange(width)[inside]
-    swap[at + np.where(even[inside], 1, -1), at] = 1.0           # y[i] takes x[i + 1] or x[i - 1]
-    pair = jnp.asarray(np.where(inside, lane // 2, 0), F32)
+    swap[at + np.where(first[inside], away, -away), at] = 1.0    # y[i] takes x[i + away] or x[i - away]
+    pair = jnp.asarray(np.where(inside, pair, 0), F32)
     ang = jnp.arange(t, dtype=F32)[:, None] * theta ** (-2.0 * pair / rotary_dim)
     cos = jnp.where(inside, jnp.cos(ang), 1.0)
-    sin = jnp.where(inside, jnp.where(even, -jnp.sin(ang), jnp.sin(ang)), 0.0)
+    sin = jnp.where(inside, jnp.where(first, -jnp.sin(ang), jnp.sin(ang)), 0.0)
     partner = jnp.einsum("bhtd,de->bhte", x, jnp.asarray(swap, x.dtype),
                          precision=lax.Precision.HIGHEST, preferred_element_type=F32)
     return (x.astype(F32) * cos + partner * sin).astype(x.dtype)
@@ -213,27 +214,44 @@ class GatedAttention(Layer):
     def apply(self, params, x, *, state, train, rng, mask=None):
         b, t, _ = x.shape
         h, kv, d = self.n_heads, self.n_kv_heads, self.head_dim
+        rot, theta = int(d * self.rotary_fraction), self.rope_theta
         with device_scope("proj"):
             z = ops.dot(x, params["Wqkv"])
-        if self.gated:
-            q, g, k, v = jnp.split(z, [h * d, 2 * h * d, (2 * h + kv) * d], axis=-1)
-        else:
-            q, k, v = jnp.split(z, [h * d, (h + kv) * d], axis=-1)
 
         def heads(a, n):  # [b, t, n d] -> [b, n, t, d]
             return a.reshape(b, t, n, d).transpose(0, 2, 1, 3)
 
-        rot = int(d * self.rotary_fraction)
+        split = None
+        if rot and not (self.gated or self.qk_norm):
+            # [q | k | v] as it leaves the product: ONE pass splits the heads
+            # and turns q's and k's, and its backward writes dz whole. Behind a
+            # norm the pass would read an array XLA otherwise never writes
+            # (norm, transpose and rotation are one fusion): slower, measured
+            with device_scope("rope"):
+                split = att.rope_heads(z, (h, kv, kv), (True, True, False), d, rot, theta)
+        if split is not None:
+            q, k, v = split
+        else:
+            if self.gated:
+                q, g, k, v = jnp.split(z, [h * d, 2 * h * d, (2 * h + kv) * d], axis=-1)
+            else:
+                q, k, v = jnp.split(z, [h * d, (h + kv) * d], axis=-1)
 
-        def prepared(a, n, norm):  # a head's norm, then its positions
-            a = heads(a, n)
-            if self.qk_norm:
-                a = rms_norm(a, params[norm], self.eps, self.qk_norm_zero_centered)
-            return rotary(a, rot, self.rope_theta) if rot else a
+            def prepared(a, n, norm):  # a head's norm, then its positions
+                with device_scope("gates"):
+                    a = heads(a, n)
+                    if self.qk_norm:
+                        a = rms_norm(a, params[norm], self.eps, self.qk_norm_zero_centered)
+                if not rot:
+                    return a
+                with device_scope("rope"):
+                    return rotary(a, rot, theta)
 
-        with device_scope("gates"):
             q, k = prepared(q, h, "q_norm"), prepared(k, kv, "k_norm")
-            k, v = (jnp.repeat(a, h // kv, axis=1) for a in (k, heads(v, kv)))
+            with device_scope("gates"):
+                v = heads(v, kv)
+        with device_scope("gates"):
+            k, v = (jnp.repeat(a, h // kv, axis=1) for a in (k, v))
         with device_scope("attend"):
             o = att.attend(q, k, v, causal=True, mask=mask)
         o = o.transpose(0, 2, 1, 3).reshape(b, t, h * d)

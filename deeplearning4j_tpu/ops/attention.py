@@ -39,6 +39,7 @@ from jax import lax
 from deeplearning4j_tpu.ops import kernel_call
 from deeplearning4j_tpu.ops import linear as ops
 from deeplearning4j_tpu.ops import pallas_kernels as pk
+from deeplearning4j_tpu.ops import rope_kernels
 
 NEG_INF = -1e30  # finite ⇒ fully-masked rows give exp(·)=0, never NaN
 
@@ -308,3 +309,32 @@ def attend(q, k, v, *, causal: bool, mask: Optional[jnp.ndarray] = None,
                                                   bq, bk, interpret),
             (q, k, v), (True, True, True))
     return sdpa(q, k, v, mask=mask, causal=causal)
+
+
+def rope_impl(impl: str, b: int, t: int, d: int, rot: int, dtype) -> str:
+    """'pallas' | 'xla' for the half-split rotation of `rot` features of
+    d-wide heads over t tokens with the split into heads: the kernels take
+    bfloat16 or float32, heads of whole lane tiles (a multiple of 128), t a
+    multiple of 16, a turned part within one lane tile or of whole tiles
+    whose half is whole tiles too (`rope_kernels.fits`). 'auto' wants a TPU
+    backend with the helpers on and rows that split evenly over an ambient
+    data mesh; an explicit 'pallas' skips those gates (`pk.which`)."""
+    return pk.which(impl, rope_kernels.fits(t, d, rot, dtype), b)
+
+
+def rope_heads(a, heads, turned, d: int, rot: int, theta: float, impl: str = "auto"):
+    """The columns of a [b, t, sum(heads) d] — a projection's output as it
+    leaves the product, or a part of it behind a norm — as one array
+    [b, heads[p], t, d] a part, the first `rot` features of every head of a
+    part with `turned[p]` rotated by its position (`hybrid.rotary`'s
+    half-split pairing from feature 0), in ONE pass a direction through the
+    kernel pair `dl4j_rope_fwd` / `dl4j_rope_bwd` — or None where
+    `rope_impl` declines and the caller keeps its XLA form. Under a data mesh
+    each device runs its own rows."""
+    b, t, _ = a.shape
+    if rope_impl(impl, b, t, d, rot, a.dtype) != "pallas":
+        return None
+    interpret = kernel_call.interpret()
+    return kernel_call.per_batch_shard(
+        lambda a_: rope_kernels.rope_split_kernels(
+            a_, tuple(heads), tuple(turned), d, rot, theta, interpret), (a,), (True,))

@@ -4,7 +4,6 @@ projections, and the rule that says where a kernel runs (one entry a kernel
 family; a rule on what the call site can see, and nothing else)."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -16,16 +15,6 @@ from deeplearning4j_tpu.ops import kernel_call
 from deeplearning4j_tpu.ops import linear
 from deeplearning4j_tpu.ops import pallas_kernels as pk
 from deeplearning4j_tpu.ops import ssd_kernels
-
-
-def _which(impl: str, fits: bool, rows: int) -> str:
-    """'pallas' where the kernels fit the operands and `impl` asks for them:
-    'auto' also wants a TPU backend with the helpers on and rows that split
-    evenly over an ambient data mesh; an explicit 'pallas' skips those gates."""
-    if impl == "auto":
-        fits = (fits and pk.helpers_enabled() and jax.default_backend() == "tpu"
-                and bool(kernel_call.per_device_batch(rows)))
-    return "pallas" if fits and impl in ("auto", "pallas") else "xla"
 
 
 def kda_impl(impl: str, q, v) -> str:
@@ -40,7 +29,7 @@ def kda_impl(impl: str, q, v) -> str:
     n, r, h, c, dk = q.shape
     fits = (q.dtype == v.dtype == jnp.float32 and c == chunk_kernels.CHUNK
             and dk == v.shape[-1] and dk % 128 == 0)
-    return _which(impl, fits, r)
+    return pk.which(impl, fits, r)
 
 
 def kda_chunks(q, k, v, g, beta, impl: str = "auto"):
@@ -75,7 +64,7 @@ def gdn_impl(impl: str, q, v) -> str:
     n, r, hk, c, dk = q.shape
     fits = (q.dtype == v.dtype == jnp.float32 and c == chunk_kernels.CHUNK
             and dk == v.shape[-1] and dk % 128 == 0 and v.shape[2] % hk == 0)
-    return _which(impl, fits, r)
+    return pk.which(impl, fits, r)
 
 
 def gdn_chunks(q, k, v, g, beta, impl: str = "auto"):
@@ -108,7 +97,7 @@ def ssd_impl(impl: str, x, b) -> str:
     n, r, h, c, p = x.shape
     fits = (x.dtype == b.dtype == jnp.float32
             and ssd_kernels.fits(c, p, b.shape[-1], h, b.shape[2]))
-    return _which(impl, fits, r)
+    return pk.which(impl, fits, r)
 
 
 def ssd_chunks(x, dt, a, b, c, impl: str = "auto"):
@@ -145,7 +134,7 @@ def conv_silu_impl(impl: str, x, w) -> str:
     `kda_impl`."""
     n, r, h, c, d = x.shape
     fits = convsilu_kernels.fits(c, d, w.shape[0], x.dtype)
-    return _which(impl, fits, r)
+    return pk.which(impl, fits, r)
 
 
 def conv_silu_chunks(x, w, b=None, impl: str = "auto"):

@@ -78,6 +78,17 @@ def helpers_enabled() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def which(impl: str, fits: bool, rows: int) -> str:
+    """'pallas' where a kernel family fits the operands and `impl` asks for
+    it: 'auto' also wants a TPU backend with the helpers on and rows that
+    split evenly over an ambient data mesh; an explicit 'pallas' skips those
+    gates (the CPU tests run the kernels interpreted)."""
+    if impl == "auto":
+        fits = (fits and helpers_enabled() and jax.default_backend() == "tpu"
+                and bool(kernel_call.per_device_batch(rows)))
+    return "pallas" if fits and impl in ("auto", "pallas") else "xla"
+
+
 def lstm_helper_mode() -> str:
     """Tri-state DL4J_TPU_PALLAS_LSTM: 'forced' (truthy — both kernel
     families admitted wherever their plans fit), 'off' (set falsy — both

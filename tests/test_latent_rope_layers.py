@@ -194,25 +194,6 @@ def test_without_a_theta_the_layer_is_the_parents(masked, weights, rng):
     assert len(turned.eqns) > len(jax.make_jaxpr(now)(p, x).eqns)
 
 
-def test_gated_attentions_rotation_lowers_as_before(rng):
-    """`rotary`'s default arguments are the half-split rotation of the first
-    features, operation for operation (the Qwen3-Next step must not move)."""
-    def before(x, rotary_dim, theta):   # hybrid.rotary at the parent commit
-        t, half = x.shape[2], rotary_dim // 2
-        j = jnp.arange(half, dtype=F32)
-        ang = jnp.arange(t, dtype=F32)[:, None] * theta ** (-2.0 * j / rotary_dim)
-        cos, sin = jnp.cos(ang), jnp.sin(ang)
-        xf = x.astype(F32)
-        a, b, rest = xf[..., :half], xf[..., half:rotary_dim], xf[..., rotary_dim:]
-        return jnp.concatenate([a * cos - b * sin, b * cos + a * sin, rest],
-                               axis=-1).astype(x.dtype)
-
-    x = jnp.asarray(rng.standard_normal((2, 3, 21, 16)), jnp.bfloat16)
-    for rot in (4, 16):
-        assert str(jax.make_jaxpr(lambda a: hybrid.rotary(a, rot, 1e7))(x)) == \
-            str(jax.make_jaxpr(lambda a: before(a, rot, 1e7))(x))
-
-
 def test_a_shuffled_prefix_moves_the_last_token_only_with_rotary(weights, rng):
     """The last token sees every token: without positions its output does
     not depend on their order; with the rotation it does."""

@@ -501,7 +501,7 @@ def test_the_lowered_step_names_the_mixers_four_parts():
     for layer in (1, 5, 7):                               # the three conv mixers' blocks
         for part in ("proj", "gates", "conv", "out"):
             assert f"dl4j.L{layer}.sublayerblock/dl4j.gatedshortconv/{part}" in text, (layer, part)
-    for part in ("proj", "gates", "attend", "out"):       # the attention block's stay as they are
+    for part in ("proj", "gates", "rope", "attend", "out"):   # the attention block's, the rotation its own
         assert f"dl4j.L3.sublayerblock/dl4j.gatedattention/{part}" in text, part
-    assert "/rope" not in text and "routedexperts/shared" not in text
+    assert "routedexperts/shared" not in text
     assert "transpose(jvp(" in text and "gatedshortconv/conv" in text.split("transpose(jvp(", 1)[1]
