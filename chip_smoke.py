@@ -404,18 +404,18 @@ def phase_kernels(sz: Sizes):
             failures.append(f"{label}: {type(e).__name__}")
 
     # ---- flash attention fwd+bwd
-    def flash_case(t, dtype):
+    def flash_case(t, dtype, window=None):
         b, h, d = 4, 8, 64
         bq, bk = pk.pick_flash_blocks(t, d, dtype)
         q, k, v, g = (rnd((b, h, t, d), dtype, 0.5) for _ in range(4))
 
         def kern(q, k, v):
             return pk.flash_attention(q, k, v, True, None, bq, bk,
-                                      interpret)
+                                      interpret, window)
 
         def ref(q, k, v):
             with highest:
-                return att.sdpa(q, k, v, causal=True)
+                return att.sdpa(q, k, v, causal=True, window=window)
 
         def both(f):
             def run(q, k, v, g):
@@ -426,12 +426,16 @@ def phase_kernels(sz: Sizes):
         got = both(kern)(q, k, v, g)
         want = both(ref)(*_f32(q, k, v, g))
         _compare(f"flash t={t} d={d} {jnp.dtype(dtype).name} blocks="
-                 f"({bq},{bk}) out,dq,dk,dv", dtype, got, want, failures)
+                 f"({bq},{bk}) window={window} out,dq,dk,dv", dtype, got, want, failures)
 
     for t in sz.flash_t:
         for dtype in (jnp.bfloat16, jnp.float32):
             run(f"flash t={t} {jnp.dtype(dtype).name}",
                 lambda t=t, dtype=dtype: flash_case(t, dtype))
+    # the band's second bound, a head a program (static bounds) and a block a program (traced)
+    for t, window in ((sz.flash_t[0], 192), (sz.flash_t[-1], 512)):
+        run(f"flash t={t} window={window} bfloat16",
+            lambda t=t, window=window: flash_case(t, jnp.bfloat16, window))
 
     # ---- fused linear + softmax-xent fwd+bwd
     softmax = act_mod.get("softmax")

@@ -28,7 +28,16 @@ state are float32.
                  columns (`ops.attention.rope_heads`: the kernel pair
                  `dl4j_rope_fwd` / `dl4j_rope_bwd` where its rule admits the
                  shapes — a TPU, heads of whole lane tiles); `rotary` behind
-                 the transpose to heads everywhere else
+                 the transpose to heads everywhere else. Three things a
+                 layer may have that its neighbour in the same stack has
+                 not: a WINDOW — a query sees the `window` keys up to and
+                 with its own (512 keys: the query's own among them, 511
+                 back), the flash kernels visit that band's blocks alone —,
+                 a gate a HEAD (`gate="head"`: one sigmoid(x Wg) a head and
+                 token, `Wg` a leaf of its own) in place of one a feature,
+                 and a frequency SCHEDULE for its rotation (`rope_scaling`,
+                 `frequencies`: yarn's ramp between the trained and the
+                 `factor` times slower pairs, cos and sin times its factor)
   GatedDeltaNet  [q | k | v | z] = x Wqkvz, [b | a] = x Wba; short causal
                  depthwise convolution + silu over [q | k | v]; the gated
                  delta rule S <- exp(g) S; S += k (beta (v - S^T k))^T;
@@ -91,6 +100,7 @@ from deeplearning4j_tpu.nn.layers.base import REMAT_KEEP, Layer, register_layer
 from deeplearning4j_tpu.ops import attention as att
 from deeplearning4j_tpu.ops import delta
 from deeplearning4j_tpu.ops import linear as ops
+from deeplearning4j_tpu.ops.rope_kernels import frequencies  # noqa: F401 — `rotary`'s schedule
 from deeplearning4j_tpu.telemetry.trace import device_scope
 
 F32 = jnp.float32
@@ -108,12 +118,15 @@ def rms_norm(x, w, eps: float, zero_centered: bool = True):
     return (y * (1.0 + w if zero_centered else w)).astype(x.dtype)
 
 
-def rotary(x, rotary_dim: int, theta: float, start: int = 0, interleave: bool = False):
+def rotary(x, rotary_dim: int, theta: float, start: int = 0, interleave: bool = False,
+           scaling: Optional[dict] = None):
     """Rotary positions on the `rotary_dim` features of x [b, h, t, d] from
     `start` on; the rest passes through. Pair j turns by the angle
     pos theta^(-2j/rotary_dim), pos the index in the sequence, computed in
-    float32. Half-split: feature j of the part pairs with j + rotary_dim/2;
-    `interleave`: feature 2j with 2j + 1.
+    float32 — or, with a `scaling` (a published `rope_parameters` entry), by
+    pos f_j of its schedule, cos and sin times its factor (`frequencies`: the
+    turned part is scaled, the rest is not). Half-split: feature j of the part
+    pairs with j + rotary_dim/2; `interleave`: feature 2j with 2j + 1.
 
     Either pairing is y = x cos + swap(x) sin over the WHOLE width, cos 1 and
     sin 0 outside the part, swap(x) = x S with S the 0 / 1 matrix that puts
@@ -135,9 +148,15 @@ def rotary(x, rotary_dim: int, theta: float, start: int = 0, interleave: bool = 
     at = np.arange(width)[inside]
     swap[at + np.where(first[inside], away, -away), at] = 1.0    # y[i] takes x[i + away] or x[i - away]
     pair = jnp.asarray(np.where(inside, pair, 0), F32)
-    ang = jnp.arange(t, dtype=F32)[:, None] * theta ** (-2.0 * pair / rotary_dim)
-    cos = jnp.where(inside, jnp.cos(ang), 1.0)
-    sin = jnp.where(inside, jnp.where(first, -jnp.sin(ang), jnp.sin(ang)), 0.0)
+    pos = jnp.arange(t, dtype=F32)[:, None]
+    freq, scale = frequencies(rotary_dim, theta, scaling, pair)
+    ang = pos * freq
+
+    def scaled(a):   # a schedule's factor on both tables; none: the operations as they were
+        return a if scale == 1.0 else a * scale
+
+    cos = jnp.where(inside, scaled(jnp.cos(ang)), 1.0)
+    sin = jnp.where(inside, jnp.where(first, -scaled(jnp.sin(ang)), scaled(jnp.sin(ang))), 0.0)
     partner = jnp.einsum("bhtd,de->bhte", x, jnp.asarray(swap, x.dtype),
                          precision=lax.Precision.HIGHEST, preferred_element_type=F32)
     return (x.astype(F32) * cos + partner * sin).astype(x.dtype)
@@ -180,7 +199,23 @@ class GatedAttention(Layer):
     Each of the three can be left out (`gated`, `qk_norm`, `rotary_fraction`
     0): then Wqkv = [q | k | v] and there are no norm weights — plain
     grouped-query attention that knows no positions. The norms multiply by
-    1 + w, w from zero (`qk_norm_zero_centered`), or by w from one."""
+    1 + w, w from zero (`qk_norm_zero_centered`), or by w from one.
+
+    `gate` says what a gate weighs: "element" (the [q | g | ..] columns
+    above, one gate a feature of every head) or "head" — ONE scalar a head
+    and token, sigmoid(x Wg) with a leaf `Wg` [f, n_heads] of its own, times
+    the head's output before `Wo`; Wqkv then stays [q | k | v]. `window`: a
+    query sees the `window` keys up to and with its own (512: 511 back) and
+    the flash kernels visit that band's blocks alone; None: the whole past.
+    `rope_scaling`: a published `rope_parameters` entry (`rope_type` "yarn",
+    `factor`, `original_max_position_embeddings`, `beta_fast`, `beta_slow`,
+    `attention_factor`) — the frequency schedule and the factor on cos and
+    sin of `frequencies`; None: theta^(-2j/rot).
+
+    A windowed layer keeps `counters` in its state (`steps`, and the keys a
+    query has inside its band / in the blocks the kernels' plan visits,
+    summed over steps): `telemetry.fit_log()` reports them under `attention`
+    with the window and the head counts (`counter_summary`)."""
 
     n_heads: int = 16
     n_kv_heads: int = 2
@@ -191,22 +226,53 @@ class GatedAttention(Layer):
     gated: bool = True
     qk_norm: bool = True
     qk_norm_zero_centered: bool = True
+    gate: str = "element"
+    window: Optional[int] = None
+    rope_scaling: Optional[dict] = None
 
     def output_type(self, input_type):
         return input_type
+
+    def _gate(self):
+        """None | "element" | "head"."""
+        if self.gate not in ("element", "head"):
+            raise ValueError(f"gate={self.gate!r}: 'element' or 'head'")
+        return self.gate if self.gated else None
 
     def init_params(self, rng, input_type):
         f = input_type.size
         h, kv, d = self.n_heads, self.n_kv_heads, self.head_dim
         if h % kv:
             raise ValueError(f"n_kv_heads={kv} must divide n_heads={h}")
-        r = jax.random.split(rng, 2)
-        p = {"Wqkv": _w(self, r[0], (f, ((2 if self.gated else 1) * h + 2 * kv) * d)),
+        gate = self._gate()
+        r = jax.random.split(rng, 3 if gate == "head" else 2)
+        p = {"Wqkv": _w(self, r[0], (f, ((2 if gate == "element" else 1) * h + 2 * kv) * d)),
              "Wo": _w(self, r[1], (h * d, f))}
+        if gate == "head":
+            p["Wg"] = _w(self, r[2], (f, h))
         if self.qk_norm:
             start = jnp.zeros if self.qk_norm_zero_centered else jnp.ones
             p.update(q_norm=start((d,), F32), k_norm=start((d,), F32))
         return p
+
+    def init_state(self, input_type):
+        if self.window is None:
+            return {}
+        return {"counters": {"steps": jnp.zeros((), jnp.int32),
+                             "band_keys": jnp.zeros((), F32),
+                             "visited_keys": jnp.zeros((), F32)}}
+
+    def counter_summary(self, added):
+        """A windowed layer's plan over a fit, under `attention`: the keys a
+        query has inside its band and in the blocks visited for it (means over
+        the sequence and the steps), and the first over the second."""
+        steps = max(int(added["steps"][0]), 1)
+        band, visited = (float(added[k][0]) / steps for k in ("band_keys", "visited_keys"))
+        return "attention", {
+            "steps": int(added["steps"][0]), "window": self.window,
+            "n_heads": self.n_heads, "n_kv_heads": self.n_kv_heads,
+            "band_keys_per_query": band, "visited_keys_per_query": visited,
+            "band_fill": band / visited if visited else 0.0}
 
     def regularizable(self, params):
         return {k: v for k, v in params.items() if k.startswith("W")}
@@ -215,6 +281,7 @@ class GatedAttention(Layer):
         b, t, _ = x.shape
         h, kv, d = self.n_heads, self.n_kv_heads, self.head_dim
         rot, theta = int(d * self.rotary_fraction), self.rope_theta
+        gate = self._gate()
         with device_scope("proj"):
             z = ops.dot(x, params["Wqkv"])
 
@@ -222,17 +289,18 @@ class GatedAttention(Layer):
             return a.reshape(b, t, n, d).transpose(0, 2, 1, 3)
 
         split = None
-        if rot and not (self.gated or self.qk_norm):
+        if rot and not (gate == "element" or self.qk_norm):
             # [q | k | v] as it leaves the product: ONE pass splits the heads
             # and turns q's and k's, and its backward writes dz whole. Behind a
             # norm the pass would read an array XLA otherwise never writes
             # (norm, transpose and rotation are one fusion): slower, measured
             with device_scope("rope"):
-                split = att.rope_heads(z, (h, kv, kv), (True, True, False), d, rot, theta)
+                split = att.rope_heads(z, (h, kv, kv), (True, True, False), d, rot, theta,
+                                       scaling=self.rope_scaling)
         if split is not None:
             q, k, v = split
         else:
-            if self.gated:
+            if gate == "element":
                 q, g, k, v = jnp.split(z, [h * d, 2 * h * d, (2 * h + kv) * d], axis=-1)
             else:
                 q, k, v = jnp.split(z, [h * d, (h + kv) * d], axis=-1)
@@ -245,7 +313,7 @@ class GatedAttention(Layer):
                 if not rot:
                     return a
                 with device_scope("rope"):
-                    return rotary(a, rot, theta)
+                    return rotary(a, rot, theta, scaling=self.rope_scaling)
 
             q, k = prepared(q, h, "q_norm"), prepared(k, kv, "k_norm")
             with device_scope("gates"):
@@ -253,15 +321,27 @@ class GatedAttention(Layer):
         with device_scope("gates"):
             k, v = (jnp.repeat(a, h // kv, axis=1) for a in (k, v))
         with device_scope("attend"):
-            o = att.attend(q, k, v, causal=True, mask=mask)
+            o = att.attend(q, k, v, causal=True, mask=mask, window=self.window)
+        if gate == "head":
+            with device_scope("gates"):
+                # one scalar a head and token, on [b, h, t, d] before the transpose back
+                gh = jax.nn.sigmoid(ops.dot(x, params["Wg"]).astype(F32))
+                o = o * gh.transpose(0, 2, 1)[..., None].astype(o.dtype)
         o = o.transpose(0, 2, 1, 3).reshape(b, t, h * d)
-        if self.gated:
+        if gate == "element":
             with device_scope("gates"):
                 o = o * jax.nn.sigmoid(g.astype(F32)).astype(o.dtype)
         with device_scope("out"):
             y = ops.dot(o, params["Wo"])
         if mask is not None:
             y = y * mask[..., None].astype(y.dtype)
+        if train and self.window is not None:
+            with device_scope("counters"):
+                band, visited = att.band_fill(t, d, q.dtype, self.window)
+                c = state["counters"]
+                state = {"counters": {"steps": c["steps"] + 1,
+                                      "band_keys": c["band_keys"] + band / t,
+                                      "visited_keys": c["visited_keys"] + visited / t}}
         return y, state
 
 
@@ -1169,11 +1249,12 @@ class RoutedExperts(Layer):
     runs without the exchange that would bring other ranks' tokens).
 
     Two recipes share everything below the scores. `scoring` "softmax":
-    the weights are the top-k of the softmax. "sigmoid": every expert is
-    scored sigmoid(x Wr) on its own; the k are CHOSEN by score +
-    `select_bias` (a leaf no gradient reaches: it chooses, it does not
-    weigh), weighted by the bare scores, renormalised (over their sum +
-    `norm_eps`) and scaled by `routed_scale`. `expert_act` (`EXPERT_ACTS`) is
+    the weights are the top-k of the softmax, renormalised over the chosen
+    (`norm_topk`). "sigmoid": every expert is scored sigmoid(x Wr) on its
+    own; the k are CHOSEN by score + `select_bias` (a leaf no gradient
+    reaches: it chooses, it does not weigh), weighted by the bare scores,
+    renormalised (over their sum + `norm_eps`). Under BOTH scorings the
+    weights are then scaled by `routed_scale`. `expert_act` (`EXPERT_ACTS`) is
     the non-linearity of routed and shared experts alike; `shared_gated`
     multiplies the shared expert by sigmoid(x w) or adds it as it is.
 
@@ -1289,7 +1370,7 @@ class RoutedExperts(Layer):
         top, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), self.top_k)
         if self.norm_topk:
             top = top / jnp.sum(top, axis=-1, keepdims=True)
-        return top, idx
+        return top * self.routed_scale, idx
 
     def routed(self, params, xf, top, idx):
         """The held experts' terms for tokens xf [n, f] -> ([n, f] float32,

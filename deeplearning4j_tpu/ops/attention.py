@@ -26,7 +26,11 @@ Three formulations, all numerically the softmax(QKᵀ/√d)·V contraction:
 Shapes: q [b, h, tq, d], k [b, h, tk, d], v [b, h, tk, dv] (dv = d unless a
 head's keys carry a part its values lack). Masks are key-padding masks
 [b, tk] (1 = attend) — the BTF mask convention the RNN layers use; `causal`
-adds the lower-triangular constraint.
+adds the lower-triangular constraint, and a `window` (with `causal`) the
+band's other edge: query i sees the `window` keys i - window + 1 .. i, its
+own among them (a window of 512 is 512 keys, 511 of them back). Every
+formulation takes it; the flash kernels visit the blocks the band touches
+and no other; ring attention refuses it by name.
 """
 from __future__ import annotations
 
@@ -55,13 +59,19 @@ def _scores(q, k, scale):
     return s.astype(jnp.float32) if s.dtype == jnp.bfloat16 else s
 
 
-def _apply_masks(s, *, mask, causal, q_offset, k_offset, tq, tk, dtype):
+def _apply_masks(s, *, mask, causal, q_offset, k_offset, tq, tk, dtype,
+                 window=None):
+    if window is not None and not causal:
+        raise ValueError(f"window={window} needs causal=True: it is the number of "
+                         f"keys a query looks back over, its own among them")
     if mask is not None:
         s = jnp.where(mask[:, None, None, :].astype(bool), s, NEG_INF)
     if causal:
         qi = q_offset + jnp.arange(tq)
         ki = k_offset + jnp.arange(tk)
         keep = qi[:, None] >= ki[None, :]
+        if window is not None:
+            keep = keep & (qi[:, None] - ki[None, :] < window)
         s = jnp.where(keep[None, None], s, NEG_INF)
     return s
 
@@ -74,13 +84,14 @@ def sdpa(
     mask: Optional[jnp.ndarray] = None,
     causal: bool = False,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Full-materialization attention: softmax(QKᵀ·scale [+mask]) V."""
     d = q.shape[-1]
     scale = (d ** -0.5) if scale is None else scale
     s = _scores(q, k, jnp.asarray(scale, q.dtype))
     s = _apply_masks(s, mask=mask, causal=causal, q_offset=0, k_offset=0,
-                     tq=q.shape[2], tk=k.shape[2], dtype=q.dtype)
+                     tq=q.shape[2], tk=k.shape[2], dtype=q.dtype, window=window)
     p = jax.nn.softmax(s, axis=-1)
     # primitives return q.dtype regardless of policy/path (blockwise
     # delegates here for short sequences — one output dtype per primitive)
@@ -98,18 +109,22 @@ def online_block(
     causal: bool = False,
     q_offset=0,
     k_offset=0,
+    window: Optional[int] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One step of the online-softmax recurrence.
 
     acc = (o [b,h,tq,d] unnormalized, l [b,h,tq] row sum, m [b,h,tq] row max).
     Offsets are the global positions of q/k block starts (traced or static),
-    needed for causal masking of remote blocks in ring attention.
+    needed for causal masking of remote blocks in ring attention. A row that
+    sees no key of this block (all behind its window) adds exp(0) a key to a
+    state whose max is still NEG_INF; the block that holds its own key comes
+    later and its correction exp(NEG_INF - m) = 0 wipes that out.
     """
     o, l, m = acc
     s = _scores(q, k_blk, jnp.asarray(scale, q.dtype))
     s = _apply_masks(s, mask=mask_blk, causal=causal, q_offset=q_offset,
                      k_offset=k_offset, tq=q.shape[2], tk=k_blk.shape[2],
-                     dtype=q.dtype)
+                     dtype=q.dtype, window=window)
     m_new = jnp.maximum(m, s.max(axis=-1))
     p = jnp.exp(s - m_new[..., None])
     corr = jnp.exp(m - m_new)
@@ -139,7 +154,8 @@ def online_finish(acc):
 
 
 def online_chunks(acc, q, k, v, *, scale, mask=None, causal=False,
-                  q_offset=0, k_offset=0, block_size: int = 512):
+                  q_offset=0, k_offset=0, block_size: int = 512,
+                  window: Optional[int] = None):
     """Scan K/V chunks of `block_size` into an online-softmax state —
     the shared flash inner loop behind `blockwise` and ring attention's
     per-hop chunking (ops/ring.py). Ragged tails are PADDED (padded
@@ -167,7 +183,7 @@ def online_chunks(acc, q, k, v, *, scale, mask=None, causal=False,
             mc = None
         return online_block(acc, q, kc, vc, scale=scale, mask_blk=mc,
                             causal=causal, q_offset=q_offset,
-                            k_offset=k_offset + i * block_size), None
+                            k_offset=k_offset + i * block_size, window=window), None
 
     xs = (jnp.arange(nblk), kb, vb) + ((mb,) if mb is not None else ())
     acc, _ = lax.scan(step, acc, xs)
@@ -183,14 +199,16 @@ def blockwise(
     causal: bool = False,
     scale: Optional[float] = None,
     block_size: int = 512,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
-    """Flash-style O(t) memory attention: lax.scan over key/value chunks."""
+    """Flash-style O(t) memory attention: lax.scan over key/value chunks
+    (every chunk, whatever the window: the band is a mask here)."""
     d = k.shape[-1]
     scale = (d ** -0.5) if scale is None else scale
     if k.shape[2] <= block_size:
-        return sdpa(q, k, v, mask=mask, causal=causal, scale=scale)
+        return sdpa(q, k, v, mask=mask, causal=causal, scale=scale, window=window)
     acc = online_chunks(online_init(q, v.shape[-1]), q, k, v, scale=scale, mask=mask,
-                        causal=causal, block_size=block_size)
+                        causal=causal, block_size=block_size, window=window)
     return online_finish(acc).astype(q.dtype)
 
 
@@ -282,33 +300,52 @@ def choose_impl(impl: str, b: int, t: int, d, masked: bool,
 
 
 def attend(q, k, v, *, causal: bool, mask: Optional[jnp.ndarray] = None,
-           impl: str = "auto", block_size: int = 512) -> jnp.ndarray:
+           impl: str = "auto", block_size: int = 512,
+           window: Optional[int] = None) -> jnp.ndarray:
     """Self-attention o [b, h, t, dv] of q, k [b, h, t, dk] and v
     [b, h, t, dv] by the implementation `choose_impl` names: everything
     between a layer's heads and its output projection, scaled by
     dk^-0.5. `impl` is the layer's `attention_impl`, `mask` its [b, t]
     key-padding mask; `block_size` chunks the keys of the ring and
-    blockwise recurrences."""
+    blockwise recurrences; `window` (with `causal`): the keys a query
+    looks back over, its own among them — None, or t and more: all."""
     from deeplearning4j_tpu.ops import ring  # ring.py builds on this module
 
     b, h, t, dk = q.shape
+    window = None if window is None else pk.band(window, causal, t)
     axis = ring.active_sequence_axis()
     how = choose_impl(impl, b, t, (dk, v.shape[-1]), mask is not None, axis, h)
     if how == "ring":
         return ring.ring_attention_sharded(
             q, k, v, axis_name=axis, mask=mask, causal=causal,
-            block_size=block_size)
+            block_size=block_size, window=window)
     if how == "blockwise":
         return blockwise(q, k, v, mask=mask, causal=causal,
-                         block_size=block_size)
+                         block_size=block_size, window=window)
     if how == "flash":
         bq, bk = pk.pick_flash_blocks(t, dk, q.dtype)
         interpret = kernel_call.interpret()
         return kernel_call.per_batch_shard(
             lambda q_, k_, v_: pk.flash_attention(q_, k_, v_, causal, None,
-                                                  bq, bk, interpret),
+                                                  bq, bk, interpret, window),
             (q, k, v), (True, True, True))
-    return sdpa(q, k, v, mask=mask, causal=causal)
+    return sdpa(q, k, v, mask=mask, causal=causal, window=window)
+
+
+def band_fill(t: int, dk: int, dtype, window: Optional[int]):
+    """(score elements inside the causal band of `window` keys, score
+    elements in the blocks the flash kernels' forward visits for it) of one
+    head over t tokens, by the plan `attend` would give the call
+    (`pk.pick_flash_blocks`, `pk.flash_visits`); a length the plan does not
+    divide is visited whole, as `sdpa` does. A function of shapes alone."""
+    w = t if window is None else min(window, t)
+    band = w * (w + 1) // 2 + (t - w) * w
+    if not (t <= 128 or t % 128 == 0):
+        return band, t * t
+    bq, bk = pk.pick_flash_blocks(t, dk, dtype)
+    bq, bk = min(bq, t), min(bk, t)
+    visits = pk.flash_visits(t, bq, bk, True, None if w >= t else w)["q_major"]
+    return band, len(visits) * bq * bk
 
 
 def rope_impl(impl: str, b: int, t: int, d: int, rot: int, dtype) -> str:
@@ -322,12 +359,14 @@ def rope_impl(impl: str, b: int, t: int, d: int, rot: int, dtype) -> str:
     return pk.which(impl, rope_kernels.fits(t, d, rot, dtype), b)
 
 
-def rope_heads(a, heads, turned, d: int, rot: int, theta: float, impl: str = "auto"):
+def rope_heads(a, heads, turned, d: int, rot: int, theta: float, impl: str = "auto",
+               scaling: Optional[dict] = None):
     """The columns of a [b, t, sum(heads) d] — a projection's output as it
     leaves the product, or a part of it behind a norm — as one array
     [b, heads[p], t, d] a part, the first `rot` features of every head of a
     part with `turned[p]` rotated by its position (`hybrid.rotary`'s
-    half-split pairing from feature 0), in ONE pass a direction through the
+    half-split pairing from feature 0; `scaling`: its frequency schedule,
+    `rope_kernels.frequencies`), in ONE pass a direction through the
     kernel pair `dl4j_rope_fwd` / `dl4j_rope_bwd` — or None where
     `rope_impl` declines and the caller keeps its XLA form. Under a data mesh
     each device runs its own rows."""
@@ -337,4 +376,5 @@ def rope_heads(a, heads, turned, d: int, rot: int, theta: float, impl: str = "au
     interpret = kernel_call.interpret()
     return kernel_call.per_batch_shard(
         lambda a_: rope_kernels.rope_split_kernels(
-            a_, tuple(heads), tuple(turned), d, rot, theta, interpret), (a,), (True,))
+            a_, tuple(heads), tuple(turned), d, rot, theta, interpret,
+            None if scaling is None else tuple(sorted(scaling.items()))), (a,), (True,))

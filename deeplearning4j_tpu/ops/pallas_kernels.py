@@ -11,9 +11,13 @@ the table:
       (batch·head, q-block) — or per batch·head at short t — online
       softmax in VMEM, K/V streamed block by block; only the blocks the
       diagonal crosses are masked, none that lies in the future is
-      visited. O(t) memory like ops.attention.blockwise but without
-      materializing per-block intermediates in HBM; the cuDNN-fused-
-      softmax-attention analogue.
+      visited. With a window — query i sees the `window` keys
+      i - window + 1 .. i: 512 keys, the query's own among them — the
+      walk also STARTS at the first block the band touches and the
+      blocks the window's edge crosses take the same mask; the
+      kernel's name then carries `w<window>`. O(t) memory like
+      ops.attention.blockwise but without materializing per-block
+      intermediates in HBM; the cuDNN-fused-softmax-attention analogue.
   lstm_scan — the fused recurrent loop (cudnnRNNForwardTraining's role):
       input projections are pre-computed as one big gemm outside (XLA);
       this kernel runs ALL timesteps with h/c resident in VMEM, one
@@ -130,35 +134,74 @@ def _flash_vmem(t: int, columns: int, dtype, *, rows: int, scores: int) -> dict:
         vmem_limit_bytes=min(need + _SCOPED_VMEM_DEFAULT, _VMEM_CEILING))}
 
 
-def _at_most(x, n: int):
-    """min(x, n) for a Python int or a traced scalar."""
-    return min(x, n) if isinstance(x, int) else lax.min(x, jnp.int32(n))
+def _both(x, n):
+    return (jnp.int32(a) if isinstance(a, int) else a for a in (x, n))
 
 
-def _q_major_bounds(qi, bq: int, bk: int, nk: int, causal: bool):
-    """Key blocks that the forward walks for q block `qi`: (first masked,
-    end). Blocks [0, first masked) lie wholly on the visible side of the
-    diagonal — their last key is no later than the block's first query —
-    and take no mask; [first masked, end) are the ones the diagonal
-    crosses; from `end` on every key is later than the block's last query
-    and nothing is visited. Works on Python ints and on traced scalars
+def _at_most(x, n):
+    """min(x, n) for Python ints or traced scalars."""
+    if isinstance(x, int) and isinstance(n, int):
+        return min(x, n)
+    return lax.min(*_both(x, n))
+
+
+def _at_least(x, n):
+    """max(x, n) for Python ints or traced scalars."""
+    if isinstance(x, int) and isinstance(n, int):
+        return max(x, n)
+    return lax.max(*_both(x, n))
+
+
+def _q_major_bounds(qi, bq: int, bk: int, nk: int, causal: bool,
+                    window: Optional[int] = None):
+    """Key blocks that the forward walks for q block `qi`: (start, edge end,
+    first masked, end). Without a window start and edge end are 0: blocks
+    [0, first masked) lie wholly on the visible side of the diagonal —
+    their last key is no later than the block's first query — and take no
+    mask; [first masked, end) are the ones the diagonal crosses; from `end`
+    on every key is later than the block's last query and nothing is
+    visited. With a window (query i sees the `window` keys i - window + 1
+    .. i) the walk STARTS at the block that holds the first key the block's
+    FIRST query still sees; [start, edge end) are the blocks the window's
+    edge crosses — some query of the block is too late for some key of
+    them — and take the mask as the diagonal's do; [edge end, first masked)
+    lie wholly inside the band. Works on Python ints and on traced scalars
     alike (`flash_visits` and the kernels share it)."""
     if not causal:
-        return nk, nk
-    return (qi * bq) // bk, _at_most(((qi + 1) * bq + bk - 1) // bk, nk)
+        return 0, 0, nk, nk
+    first_masked = (qi * bq) // bk
+    end = _at_most(((qi + 1) * bq + bk - 1) // bk, nk)
+    if window is None:
+        return 0, 0, first_masked, end
+    start = _at_least(qi * bq - (window - 1), 0) // bk
+    # the first block whose first key the block's LAST query still sees
+    inside = _at_least((qi + 1) * bq - window + bk - 1, 0) // bk
+    return start, _at_most(_at_least(inside, start), first_masked), first_masked, end
 
 
-def _k_major_bounds(kj, bq: int, bk: int, nq: int, causal: bool):
+def _k_major_bounds(kj, bq: int, bk: int, nq: int, causal: bool,
+                    window: Optional[int] = None):
     """q blocks that the backward walks for key block `kj`: (start, first
-    unmasked). Blocks before `start` end before the first key and are not
-    visited; [start, first unmasked) are the ones the diagonal crosses;
-    [first unmasked, nq) see the whole key block."""
+    unmasked, edge start, end). Blocks before `start` end before the first
+    key and are not visited; [start, first unmasked) are the ones the
+    diagonal crosses; [first unmasked, edge start) see the whole key block;
+    without a window edge start = end = nq. With one, [edge start, end) are
+    the q blocks the window's edge crosses (their last query is too late
+    for the block's first key) and from `end` on no query sees the block's
+    last key."""
     if not causal:
-        return 0, 0
-    return (kj * bk) // bq, _at_most(((kj + 1) * bk + bq - 1) // bq, nq)
+        return 0, 0, nq, nq
+    start = (kj * bk) // bq
+    unmasked = _at_most(((kj + 1) * bk + bq - 1) // bq, nq)
+    if window is None:
+        return start, unmasked, nq, nq
+    end = _at_most(((kj + 1) * bk + window - 2) // bq + 1, nq)
+    edge = _at_most(_at_least((kj * bk + window) // bq, unmasked), end)
+    return start, unmasked, edge, end
 
 
-def flash_visits(t: int, bq: int, bk: int, causal: bool) -> dict:
+def flash_visits(t: int, bq: int, bk: int, causal: bool,
+                 window: Optional[int] = None) -> dict:
     """Which (q block, key block) pairs each kernel visits, and whether it
     masks them: {"q_major": [(qi, kj, masked), ...] (the forward, which
     walks key blocks for a q block), "k_major": [...] (the backward,
@@ -167,11 +210,11 @@ def flash_visits(t: int, bq: int, bk: int, causal: bool) -> dict:
     nq, nk = t // bq, t // bk
     q_major, k_major = [], []
     for qi in range(nq):
-        first_masked, end = _q_major_bounds(qi, bq, bk, nk, causal)
-        q_major += [(qi, kj, kj >= first_masked) for kj in range(end)]
+        start, edge, first_masked, end = _q_major_bounds(qi, bq, bk, nk, causal, window)
+        q_major += [(qi, kj, kj < edge or kj >= first_masked) for kj in range(start, end)]
     for kj in range(nk):
-        start, unmasked = _k_major_bounds(kj, bq, bk, nq, causal)
-        k_major += [(qi, kj, qi < unmasked) for qi in range(start, nq)]
+        start, unmasked, edge, end = _k_major_bounds(kj, bq, bk, nq, causal, window)
+        k_major += [(qi, kj, qi < unmasked or qi >= edge) for qi in range(start, end)]
     return {"q_major": q_major, "k_major": k_major}
 
 
@@ -194,13 +237,20 @@ def _walk(whole: bool, lo, hi, blk: int, fn, carry):
         lo, hi, lambda j, c: fn(c, pl.multiple_of(j * blk, blk), blk), carry)
 
 
-def _causal_keep(rows: int, cols: int, row0, col0, transposed=False):
+def _causal_keep(rows: int, cols: int, row0, col0, transposed=False,
+                 window: Optional[int] = None):
     """[rows, cols] bool: query row0 + r sees key col0 + c — or, transposed
     (rows are keys, columns queries), key row0 + r is seen by query
-    col0 + c."""
+    col0 + c. A query i sees key j where 0 <= i - j, and with a window
+    where i - j < window too."""
     rel = (lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
            - lax.broadcasted_iota(jnp.int32, (rows, cols), 1))
-    return rel <= col0 - row0 if transposed else rel >= col0 - row0
+    off = col0 - row0
+    if window is None:
+        return rel <= off if transposed else rel >= off
+    if transposed:
+        return (rel <= off) & (rel > off - window)
+    return (rel >= off) & (rel < off + window)
 
 
 def _whole_head(t: int, blk: int) -> bool:
@@ -215,7 +265,8 @@ def _whole_head(t: int, blk: int) -> bool:
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, bq: int,
-                      bk: int, whole: bool, causal: bool, scale: float):
+                      bk: int, whole: bool, causal: bool, scale: float,
+                      window: Optional[int] = None):
     """One (batch·head, q-block) program — q_ref [bq, dk] — or one
     batch·head program that walks its q blocks itself — q_ref [t, dk];
     k_ref [t, dk], v_ref [t, dv] (the value width may differ from the key
@@ -234,7 +285,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, bq: int,
             v_blk = v_ref[pl.ds(k0, width), :]
             s = _dot_nt(q, k_blk)
             if masked:
-                s = jnp.where(_causal_keep(bq, width, qi * bq, k0), s, NEG_INF)
+                s = jnp.where(_causal_keep(bq, width, qi * bq, k0, window=window),
+                              s, NEG_INF)
+            # a row whose keys in an edge block are all outside its window
+            # adds exp(0) here; its own diagonal block comes later and its
+            # correction exp(NEG_INF - m) = 0 wipes that out
             m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
             corr = jnp.exp(m - m_new)
@@ -246,8 +301,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, bq: int,
         carry = (jnp.full((bq, 1), NEG_INF, jnp.float32),
                  jnp.zeros((bq, 1), jnp.float32),
                  jnp.zeros((bq, dv), jnp.float32))
-        first_masked, end = _q_major_bounds(qi, bq, bk, nk, causal)
-        carry = _walk(whole, 0, first_masked, bk,
+        start, edge, first_masked, end = _q_major_bounds(qi, bq, bk, nk, causal, window)
+        if window is not None:
+            carry = _walk(whole, start, edge, bk,
+                          functools.partial(step, masked=True), carry)
+        carry = _walk(whole, edge, first_masked, bk,
                       functools.partial(step, masked=False), carry)
         m, l, acc = _walk(whole, first_masked, end, bk,
                           functools.partial(step, masked=True), carry)
@@ -265,15 +323,19 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, bq: int,
         q_block(pl.program_id(1), slice(None))
 
 
-def _flash_widths(d: int, dv: int) -> dict:
+def _flash_widths(d: int, dv: int, window: Optional[int] = None) -> dict:
     """The head widths as a kernel's name carries them: `d` alone where
     keys and values are equally wide (the names every reader knows), else
-    the key width `d` and the value width `dv`."""
-    return {"d": d} if d == dv else {"d": d, "dv": dv}
+    the key width `d` and the value width `dv`; then the window `w`, only
+    where there is one (`.._d128_w512_..`: a trace tells the banded calls
+    from the whole triangles by it)."""
+    dims = {"d": d} if d == dv else {"d": d, "dv": dv}
+    return dims if window is None else {**dims, "w": window}
 
 
 def _flash_fwd(q, k, v, *, causal: bool, scale: float, bq: int, bk: int,
-               interpret: bool, return_lse: bool = False):
+               interpret: bool, return_lse: bool = False,
+               window: Optional[int] = None):
     b, h, t, d = q.shape
     dv = v.shape[-1]
     qf = q.reshape(b * h, t, d)
@@ -283,7 +345,7 @@ def _flash_fwd(q, k, v, *, causal: bool, scale: float, bq: int, bk: int,
     rows = t if whole else bq
     grid = (b * h, t // rows)
     kernel = functools.partial(_flash_fwd_kernel, bq=bq, bk=bk, whole=whole,
-                               causal=causal, scale=scale)
+                               causal=causal, scale=scale, window=window)
     out_shape = jax.ShapeDtypeStruct((b * h, t, dv), q.dtype)
     out_spec = pl.BlockSpec((None, rows, dv), lambda i, j: (i, j, 0))
     if return_lse:
@@ -302,7 +364,7 @@ def _flash_fwd(q, k, v, *, causal: bool, scale: float, bq: int, bk: int,
         ],
         out_specs=out_spec,
         name=kernel_name("flash_fwd", q.dtype, bh=b * h, t=t,
-                         **_flash_widths(d, dv), bq=bq, bk=bk),
+                         **_flash_widths(d, dv, window), bq=bq, bk=bk),
         interpret=interpret,
         # K, V (+ q, o: a head a program)
         **_flash_vmem(t, _lanes(d, dv, *((d, dv) if whole else ())), q.dtype,
@@ -314,13 +376,30 @@ def _flash_fwd(q, k, v, *, causal: bool, scale: float, bq: int, bk: int,
     return got.reshape(b, h, t, dv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def band(window: Optional[int], causal: bool, t: int) -> Optional[int]:
+    """The window a call runs with: None where it reaches back over the
+    whole sequence anyway (then the call IS the causal one, name, kernel
+    and bits)."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError(f"window={window}: a window is a number of keys >= 1 "
+                         f"that a query looks back over, its own among them; "
+                         f"it needs causal=True")
+    return None if window >= t else int(window)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal: bool = True,
                     scale: Optional[float] = None, bq: int = 128,
-                    bk: int = 128, interpret: bool = False):
+                    bk: int = 128, interpret: bool = False,
+                    window: Optional[int] = None):
     """Fused attention o = softmax(qkᵀ·scale)v over q, k [b, h, t, d] and
     v [b, h, t, dv] -> [b, h, t, dv] (dv = d for most models; a latent-
-    attention head carries a positional part in its keys only).
+    attention head carries a positional part in its keys only). With a
+    `window` query i sees the keys i - window + 1 .. i (`window` keys, its
+    own among them) and the kernels visit the blocks that band touches and
+    no other; a window of t or more is the causal call.
 
     t must divide by the block sizes (pad upstream); numerics match
     ops.attention.sdpa. Backward is one blockwise pallas kernel
@@ -330,12 +409,13 @@ def flash_attention(q, k, v, causal: bool = True,
     bq = min(bq, q.shape[2])
     bk = min(bk, q.shape[2])
     return _flash_fwd(q, k, v, causal=causal, scale=s, bq=bq, bk=bk,
-                      interpret=interpret)
+                      interpret=interpret, window=band(window, causal, q.shape[2]))
 
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dk_ref, dv_ref, dqt_ref, *, bq: int, bk: int,
-                      whole: bool, causal: bool, scale: float):
+                      whole: bool, causal: bool, scale: float,
+                      window: Optional[int] = None):
     """The backward for one (batch·head, k-block) program — or for a whole
     batch·head, its key blocks walked here. P is rebuilt from the saved
     logsumexp ONCE a block and transposed from the start (rows are keys):
@@ -361,7 +441,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             do = do_ref[at, :]
             pt = jnp.exp(_dot_nt(k_blk, q) - lse_ref[:, at])
             if masked:
-                pt = jnp.where(_causal_keep(bk, width, kj * bk, q0, True),
+                pt = jnp.where(_causal_keep(bk, width, kj * bk, q0, True, window),
                                pt, 0.0)
             dv = dv + jnp.dot(pt.astype(do.dtype), do,
                               preferred_element_type=jnp.float32)
@@ -373,13 +453,16 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 preferred_element_type=jnp.float32)
             return dk, dv
 
-        start, unmasked = _k_major_bounds(kj, bq, bk, nq, causal)
+        start, unmasked, edge, end = _k_major_bounds(kj, bq, bk, nq, causal, window)
         carry = _walk(whole, start, unmasked, bq,
                       functools.partial(step, masked=True),
                       (jnp.zeros((bk, d), jnp.float32),
                        jnp.zeros((bk, dv_), jnp.float32)))
-        dk, dv = _walk(whole, unmasked, nq, bq,
-                       functools.partial(step, masked=False), carry)
+        dk, dv = carry = _walk(whole, unmasked, edge, bq,
+                               functools.partial(step, masked=False), carry)
+        if window is not None:
+            dk, dv = _walk(whole, edge, end, bq,
+                           functools.partial(step, masked=True), carry)
         dk_ref[rows, :] = dk.astype(dk_ref.dtype)
         dv_ref[rows, :] = dv.astype(dv_ref.dtype)
 
@@ -403,7 +486,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd(q, k, v, o, lse, g, *, causal: bool, scale: float, bq: int,
-               bk: int, interpret: bool):
+               bk: int, interpret: bool, window: Optional[int] = None):
     b, h, t, d = q.shape
     dv = v.shape[-1]
     bh = b * h
@@ -424,7 +507,7 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal: bool, scale: float, bq: int,
     row = pl.BlockSpec((None, 1, t), lambda i, j: (i, 0, 0))
     dq, dk, dv_out = pl.pallas_call(
         functools.partial(_flash_bwd_kernel, bq=bq, bk=bk, whole=whole,
-                          causal=causal, scale=scale),
+                          causal=causal, scale=scale, window=window),
         out_shape=tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
                         for a in (qf, kf, vf)),
         grid=(bh, t // krows),
@@ -432,7 +515,7 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal: bool, scale: float, bq: int,
         out_specs=(seq(d), kblk(d), kblk(dv)),
         scratch_shapes=[pltpu.VMEM((d, t), jnp.float32)],
         name=kernel_name("flash_bwd", q.dtype, bh=bh, t=t,
-                         **_flash_widths(d, dv), bq=bq, bk=bk),
+                         **_flash_widths(d, dv, window), bq=bq, bk=bk),
         interpret=interpret,
         # q, dO, dQ, the float32 dQᵀ (+ K, V, dK, dV: a head a program)
         **_flash_vmem(t, _lanes(d, dv, d, d, *((d, dv, d, dv) if whole else ())),
@@ -442,25 +525,27 @@ def _flash_bwd(q, k, v, o, lse, g, *, causal: bool, scale: float, bq: int,
             dv_out.reshape(b, h, t, dv))
 
 
-def _flash_vjp_fwd(q, k, v, causal, scale, bq, bk, interpret):
+def _flash_vjp_fwd(q, k, v, causal, scale, bq, bk, interpret, window=None):
     s = (q.shape[-1] ** -0.5) if scale is None else scale
     bq_ = min(bq, q.shape[2])
     bk_ = min(bk, q.shape[2])
     out, lse = _flash_fwd(q, k, v, causal=causal, scale=s, bq=bq_, bk=bk_,
-                          interpret=interpret, return_lse=True)
+                          interpret=interpret, return_lse=True,
+                          window=band(window, causal, q.shape[2]))
     # all the backward kernel needs beside q, k, v: kept by a block's 'full'
     # remat, whose recompute then does not call the forward kernel again
     out, lse = (checkpoint_name(a, REMAT_KEEP) for a in (out, lse))
     return out, (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(causal, scale, bq, bk, interpret, res, g):
+def _flash_vjp_bwd(causal, scale, bq, bk, interpret, window, res, g):
     q, k, v, o, lse = res
     s = (q.shape[-1] ** -0.5) if scale is None else scale
     bq_ = min(bq, q.shape[2])
     bk_ = min(bk, q.shape[2])
     return _flash_bwd(q, k, v, o, lse, g, causal=causal, scale=s, bq=bq_,
-                      bk=bk_, interpret=interpret)
+                      bk=bk_, interpret=interpret,
+                      window=band(window, causal, q.shape[2]))
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -1536,7 +1621,13 @@ def pick_flash_blocks(t: int, d: int, dtype=None) -> Tuple[int, int]:
     block is a program — head 256, t 8192: 26.5 ms against 28.3 at
     (256, 512) and 30.1 at 256. Square, so that forward and backward mask
     the same blocks; `d` and `dtype` do not move the choice today (head
-    64 and 256, bf16 and float32 were timed). The
+    64 and 256, bf16 and float32 were timed), and neither does a window:
+    36 heads of 128 at t 8192 under a window of 512 take 5.27 ms forward +
+    backward at (512, 512), where a q block visits 2 key blocks for a band
+    of 1, against 6.22 at 256 (3 for 2), 10.56 at 128 (5 for 4), 5.89 at
+    (256, 512), 5.97 at (512, 256) — a block's fixed cost outweighs the
+    scores it saves — and 16.09 as a masked whole triangle (PERF.md
+    section 6, PR 47). The
     returned blocks always divide t (or t fits in one block): a block
     that doesn't divide t would make the kernel grid silently drop rows,
     so unaligned lengths above one block raise instead."""

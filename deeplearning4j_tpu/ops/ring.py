@@ -87,6 +87,7 @@ def ring_attention_sharded(
     causal: bool = False,
     scale: Optional[float] = None,
     block_size: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Exact attention where q/k/v are the LOCAL sequence shards
     [b, h, t_loc, d] of a sequence sharded over `axis_name`.
@@ -96,7 +97,16 @@ def ring_attention_sharded(
     for its local queries. `block_size` additionally chunks each hop's
     K/V (see _hop_update) so per-chip attention memory is
     O(t_loc · block_size) instead of O(t_loc²).
+
+    A `window` is refused: the ring sends every key/value shard past every
+    device, where a band of `window` keys needs the hops its queries see
+    and no other (ROADMAP R7).
     """
+    if window is not None:
+        raise NotImplementedError(
+            f"ring attention has no window (window={window}): every hop's keys "
+            f"go round the whole ring; run the windowed layer without a "
+            f"sequence axis (ROADMAP R7)")
     n = lax.psum(1, axis_name)
     idx = lax.axis_index(axis_name)
     t_loc = q.shape[2]

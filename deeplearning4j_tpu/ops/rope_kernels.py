@@ -36,6 +36,8 @@ v5e, PERF.md section 6, PR 46). Here
 from __future__ import annotations
 
 import functools
+import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -69,15 +71,58 @@ def fits(t: int, d: int, rot: int, dtype) -> bool:
             and (lanes == 128 or (rot == lanes and rot % 256 == 0)))
 
 
-def tables(t: int, d: int, rot: int, theta: float):
+def frequencies(rot: int, theta: float, scaling=None, j=None):
+    """(f_j, scale): the angle a position turns pair j of `rot` rotary
+    features by, float32, and what cos and sin are multiplied by — the ONE
+    place a frequency schedule is computed (`hybrid.rotary` and `tables`
+    both call it). `j`: the pair indices as a float32 array (default 0 ..
+    rot/2 - 1). `scaling`: a published `rope_parameters` entry as it stands
+    (a dict, or its items); None, or `rope_type` "default": f_j =
+    theta^(-2j/rot), scale 1.0. "yarn": with e_j = theta^(-2j/rot), c(n) =
+    rot ln(original_max_position_embeddings / (2 pi n)) / (2 ln theta), lo =
+    floor(c(beta_fast)), hi = ceil(c(beta_slow)) (both kept inside 0 ..
+    rot - 1), r_j = clip((j - lo) / (hi - lo), 0, 1): f_j = e_j (1 - r_j) +
+    (e_j / factor) r_j — the fast pairs turn as they were trained, the slow
+    ones `factor` times slower — and scale = `attention_factor` (default
+    0.1 ln(factor) + 1)."""
+    if j is None:
+        j = jnp.arange(rot // 2, dtype=F32)
+    plain = theta ** (-2.0 * j / rot)
+    scaling = dict(scaling or {})
+    kind = scaling.get("rope_type", "default")
+    if kind == "default":
+        return plain, 1.0
+    if kind != "yarn":
+        raise ValueError(f"rope_type={kind!r}: 'default' or 'yarn'")
+    factor = float(scaling["factor"])
+    span = float(scaling["original_max_position_embeddings"])
+
+    def turns_at(n):   # the pair that turns n times over the original span
+        return rot * math.log(span / (n * 2 * math.pi)) / (2 * math.log(theta))
+
+    lo = max(math.floor(turns_at(float(scaling.get("beta_fast", 32)))), 0)
+    hi = min(math.ceil(turns_at(float(scaling.get("beta_slow", 1)))), rot - 1)
+    ramp = jnp.clip((j - lo) / (hi - lo if hi > lo else 0.001), 0.0, 1.0)
+    scale = scaling.get("attention_factor")
+    return (plain * (1.0 - ramp) + plain / factor * ramp,
+            float(0.1 * math.log(factor) + 1.0 if scale is None else scale))
+
+
+def tables(t: int, d: int, rot: int, theta: float, scaling=None):
     """(cos, sin) [t, lanes] float32 such that a head's first `lanes`
     features turn as y = x cos + partner(x) sin: `rotary`'s angles, the
     pairing's sign in sin (feature j < rot/2 takes -sin of its partner
-    j + rot/2, which takes +sin of it), cos 1 and sin 0 beyond `rot`."""
+    j + rot/2, which takes +sin of it), cos 1 and sin 0 beyond `rot`; with a
+    `scaling` the schedule's frequencies and its factor on both tables
+    (`frequencies`)."""
     half, rest = rot // 2, _lanes(d, rot) - rot
     j = jnp.arange(half, dtype=F32)
-    ang = jnp.arange(t, dtype=F32)[:, None] * theta ** (-2.0 * j / rot)
+    pos = jnp.arange(t, dtype=F32)[:, None]
+    freq, scale = frequencies(rot, theta, scaling, j)
+    ang = pos * freq
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     return (jnp.concatenate([cos, cos, jnp.ones((t, rest), F32)], axis=1),
             jnp.concatenate([-sin, sin, jnp.zeros((t, rest), F32)], axis=1))
 
@@ -147,8 +192,9 @@ def _tokens(t: int, width: int) -> int:
                if t % k == 0 and (k * width <= _BLOCK or k == _ROWS))
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7))
-def _call(operands, heads, turned, d: int, rot: int, theta: float, back: bool, interpret: bool):
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7, 8))
+def _call(operands, heads, turned, d: int, rot: int, theta: float, back: bool, interpret: bool,
+          scaling=None):
     """One direction's `pallas_call` with its tables: a [b, t, N d] (`back`:
     the parts' cotangents) -> the parts (a's cotangent). A jitted function of
     its own, so that the call sites of a step with the same shapes — 72 in
@@ -179,23 +225,26 @@ def _call(operands, heads, turned, d: int, rot: int, theta: float, back: bool, i
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=min(max(need + 2 ** 23, 32 * 2 ** 20), 100 * 2 ** 20)),
-    )(*operands, *tables(t, d, rot, theta))
+    )(*operands, *tables(t, d, rot, theta, scaling))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
-def rope_split_kernels(a, heads, turned, d: int, rot: int, theta: float, interpret: bool):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6, 7))
+def rope_split_kernels(a, heads, turned, d: int, rot: int, theta: float, interpret: bool,
+                       scaling: Optional[tuple] = None):
     """a [b, t, sum(heads) d] -> one [b, heads[p], t, d] a part, the heads of
     a part with `turned[p]` rotated (the module docstring), through the
-    kernel pair `dl4j_rope_fwd` / `dl4j_rope_bwd`."""
-    return _call((a,), heads, turned, d, rot, theta, False, interpret)
+    kernel pair `dl4j_rope_fwd` / `dl4j_rope_bwd`. `scaling`: a frequency
+    schedule's items as a sorted tuple (hashable: it is a static argument),
+    or None."""
+    return _call((a,), heads, turned, d, rot, theta, False, interpret, scaling)
 
 
-def _vjp_fwd(a, heads, turned, d, rot, theta, interpret):
-    return _call((a,), heads, turned, d, rot, theta, False, interpret), None
+def _vjp_fwd(a, heads, turned, d, rot, theta, interpret, scaling=None):
+    return _call((a,), heads, turned, d, rot, theta, False, interpret, scaling), None
 
 
-def _vjp_bwd(heads, turned, d, rot, theta, interpret, _, dys):
-    return (_call(tuple(dys), heads, turned, d, rot, theta, True, interpret),)
+def _vjp_bwd(heads, turned, d, rot, theta, interpret, scaling, _, dys):
+    return (_call(tuple(dys), heads, turned, d, rot, theta, True, interpret, scaling),)
 
 
 rope_split_kernels.defvjp(_vjp_fwd, _vjp_bwd)

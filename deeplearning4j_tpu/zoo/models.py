@@ -811,6 +811,95 @@ class ShortConvMoELM(_DecoderLM):
 
 
 @dataclass
+class WindowedMoELM(_DecoderLM):
+    """Decoder-only LM whose attention layers are NOT alike: published layer
+    i (from 0) is `layer_types[i]` — "sliding_attention", whose queries see
+    the `sliding_window` keys up to and with their own, or "full_attention",
+    which sees the whole past — with `num_attention_heads_per_layer[i]` query
+    heads over `num_key_value_heads` key/value heads of `head_dim`, the
+    rotary recipe of its type (`rope_parameters[type]`: `rope_theta`,
+    `partial_rotary_factor` of the head turned, half-split pairs, and for
+    `rope_type` "yarn" the frequency schedule and the factor on cos and sin
+    of `hybrid.frequencies`), no q/k norm and ONE sigmoid gate a head and
+    token on the head's output. Its feed-forward is a dense swiglu of
+    `intermediate_size` for i in `mlp_only_layers`, else softmax-routed
+    swiglu experts — top-k renormalised over the chosen, times
+    `moe_routed_scaling_factor` — beside an ungated shared expert (the
+    `laguna` shape). `num_hidden_layers` layers are BUILT, the published
+    layers `layers_first` .. (a pipeline stage's share); both lists are
+    indexed by the published layer. The head counts are the counts this
+    rank HOLDS (a tensor-parallel rank's share of each layer's query and
+    key/value heads, in the published ratio), as `num_experts` is."""
+
+    num_hidden_layers: int = 4
+    layers_first: int = 0
+    rms_norm_eps: float = 1e-6
+    # which published layer attends how, and over how many query heads;
+    # default: a global layer of 4 heads, then three windowed ones of 6
+    layer_types: Optional[Sequence[str]] = None
+    num_attention_heads_per_layer: Optional[Sequence[int]] = None
+    num_key_value_heads: int = 2
+    head_dim: int = 64
+    sliding_window: int = 16
+    rope_parameters: Optional[dict] = None
+    # feed-forward
+    mlp_only_layers: Sequence[int] = (0,)
+    intermediate_size: int = 512
+    # routed experts
+    num_experts_per_tok: int = 2
+    moe_intermediate_size: int = 64
+    shared_expert_intermediate_size: int = 64
+    moe_routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+
+    #: a published layer type -> does it attend through the window
+    KINDS = {"full_attention": False, "sliding_attention": True}
+
+    def layers_built(self):
+        """(published index, type, query heads) of the layers built."""
+        held = range(self.layers_first, self.layers_first + self.num_hidden_layers)
+        types = self.layer_types or [
+            "sliding_attention" if i % 4 else "full_attention" for i in range(held.stop)]
+        heads = self.num_attention_heads_per_layer or [
+            6 if self.KINDS.get(kind) else 4 for kind in types]
+        bad = sorted(set(types) - set(self.KINDS))
+        if bad or held.stop > min(len(types), len(heads)):
+            raise ValueError(
+                f"layer_types: {len(types)} entries and num_attention_heads_per_layer: "
+                f"{len(heads)} for layers {held}; {bad} are none of {sorted(self.KINDS)} "
+                f"in {list(types)}")
+        return [(i, types[i], heads[i]) for i in held]
+
+    def sublayers(self):
+        recipes = self.rope_parameters or {}
+
+        def attention(kind, n_heads):
+            recipe = recipes.get(kind, {})
+            return GatedAttention(
+                n_heads=n_heads, n_kv_heads=self.num_key_value_heads, head_dim=self.head_dim,
+                rotary_fraction=float(recipe.get("partial_rotary_factor", 1.0)),
+                rope_theta=float(recipe.get("rope_theta", 10000.0)),
+                rope_scaling=(dict(recipe) if recipe.get("rope_type", "default") != "default"
+                              else None),
+                eps=self.rms_norm_eps, gated=True, gate="head", qk_norm=False,
+                qk_norm_zero_centered=False,
+                window=self.sliding_window if self.KINDS[kind] else None)
+
+        def feed_forward(i):
+            if i in tuple(self.mlp_only_layers):
+                return GatedMLP(width=self.intermediate_size, act="swiglu")
+            return self._experts(
+                top_k=self.num_experts_per_tok, expert_width=self.moe_intermediate_size,
+                shared_width=self.shared_expert_intermediate_size,
+                norm_topk=self.norm_topk_prob, scoring="softmax",
+                routed_scale=self.moe_routed_scaling_factor, expert_act="swiglu",
+                shared_gated=False, norm_eps=1e-20)
+
+        return [sub for i, kind, n_heads in self.layers_built()
+                for sub in (attention(kind, n_heads), feed_forward(i))]
+
+
+@dataclass
 class LoopLM(_DecoderLM):
     """Decoder-only LM whose layers run `total_ut_steps` times over the SAME
     weights (the `ouro` shape): `num_hidden_layers` layers of full multi-head

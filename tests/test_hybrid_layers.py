@@ -596,3 +596,266 @@ def test_sandwich_norm_is_off_by_default_and_leaves_the_block_as_it_was(rng):
     text = str(jax.make_jaxpr(
         lambda p, a: block.apply(p, a, state={}, train=True, rng=None)[0])(params, x))
     assert text.count("rsqrt") == 1
+
+
+# ---------------------------------------------------------------------------
+# attention layers that are not alike (PR 47, the `laguna` shape): a gate a
+# head, a window, a frequency schedule; `routed_scale` under both scorings
+# ---------------------------------------------------------------------------
+from benchmark.reference import common as ref_common  # noqa: E402
+from benchmark.reference import laguna as laguna_ref  # noqa: E402
+from benchmark.tests import tiny_laguna  # noqa: E402
+from deeplearning4j_tpu.ops import rope_kernels  # noqa: E402
+
+YARN = {"rope_theta": 500000, "rope_type": "yarn", "factor": 128,
+        "original_max_position_embeddings": 8192, "beta_slow": 1, "beta_fast": 32,
+        "attention_factor": 1.4852030263919618, "partial_rotary_factor": 0.5}
+
+
+def _attention_case(rng, **args):
+    layer = GatedAttention(**{**dict(n_heads=4, n_kv_heads=2, head_dim=16, rotary_fraction=1.0,
+                                     rope_theta=1e4, qk_norm=False, gated=True, gate="head"),
+                              **args})
+    params = layer.init_params(jax.random.PRNGKey(3), IN)
+    x = jnp.asarray(rng.normal(size=(2, 40, 32)), jnp.float32)
+    return layer, params, x
+
+
+def _run(layer, params, x):
+    return layer.apply(params, x, state=layer.init_state(IN), train=False, rng=None)[0]
+
+
+def test_head_gate_is_its_formula(rng):
+    """o_h sigmoid(x Wg)_h before Wo, Wg [f, n_heads] a leaf of its own beside a
+    Wqkv that stays [q | k | v]."""
+    layer, params, x = _attention_case(rng)
+    assert {k: v.shape for k, v in params.items()} == {
+        "Wqkv": (32, (4 + 2 * 2) * 16), "Wo": (64, 32), "Wg": (32, 4)}
+    ungated = dataclasses.replace(layer, gated=False)
+    bare = {k: v for k, v in params.items() if k != "Wg"}
+    # the heads' outputs before Wo: the ungated layer with Wo the identity
+    eye = dict(bare, Wo=jnp.eye(64, dtype=jnp.float32))
+    with jax.default_matmul_precision("highest"):
+        o = ungated.apply(eye, x, state={}, train=False, rng=None)[0].reshape(2, 40, 4, 16)
+        g = jax.nn.sigmoid(x @ params["Wg"])
+        want = (o * g[..., None]).reshape(2, 40, 64) @ params["Wo"]
+        got = _run(layer, params, x)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    assert float(jnp.abs(got - _run(ungated, bare, x)).max()) > 0.01
+
+
+def test_head_gate_is_the_element_gate_with_its_columns_repeated(rng):
+    layer, params, x = _attention_case(rng)
+    element = dataclasses.replace(layer, gate="element")
+    q, k, v = jnp.split(params["Wqkv"], [64, 96], axis=-1)
+    wide = {"Wqkv": jnp.concatenate([q, jnp.repeat(params["Wg"], 16, axis=1), k, v], axis=-1),
+            "Wo": params["Wo"]}
+    assert element.init_params(jax.random.PRNGKey(0), IN)["Wqkv"].shape == wide["Wqkv"].shape
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(_run(layer, params, x), _run(element, wide, x),
+                                   rtol=2e-5, atol=2e-6)
+    with pytest.raises(ValueError, match="gate='row'"):
+        dataclasses.replace(layer, gate="row").init_params(jax.random.PRNGKey(0), IN)
+
+
+def test_frequencies_are_the_closed_yarn_form():
+    f, scale = hybrid.frequencies(64, 500000.0, YARN)
+    e = 500000.0 ** (-2.0 * np.arange(32) / 64)
+    c = lambda n: 64 * np.log(8192 / (2 * np.pi * n)) / (2 * np.log(500000.0))  # noqa: E731
+    assert (int(np.floor(c(32))), int(np.ceil(c(1)))) == (9, 18)
+    r = np.clip((np.arange(32) - 9) / 9, 0, 1)
+    np.testing.assert_allclose(f, e * (1 - r) + e / 128 * r, rtol=1e-6)
+    assert float(f[0]) == 1.0 and scale == 1.4852030263919618
+    np.testing.assert_allclose(f[:10], e[:10], rtol=1e-6)          # the fast pairs as trained
+    np.testing.assert_allclose(f[18:], e[18:] / 128, rtol=1e-6)    # the slow ones 128 x slower
+    np.testing.assert_allclose(float(f[31]), e[31] / 128, rtol=1e-6)
+    assert all(f[j] > f[j + 1] for j in range(31))
+    # the published factor is the family's default for its factor
+    assert hybrid.frequencies(64, 5e5, {k: v for k, v in YARN.items()
+                                        if k != "attention_factor"})[1] == pytest.approx(
+        0.1 * np.log(128) + 1)
+    # the reference writes the same schedule out on its own
+    fr, sr = laguna_ref.frequencies(64, YARN)
+    np.testing.assert_allclose(f, fr, rtol=1e-6)
+    assert sr == scale
+    with pytest.raises(ValueError, match="rope_type='linear'"):
+        hybrid.frequencies(64, 1e4, {"rope_type": "linear"})
+
+
+def test_no_schedule_is_todays_rotation_and_tables(rng):
+    """`rope_scaling=None`, and `rope_type` "default": bit-equal to the
+    rotation and the kernel tables as they were (the jaxpr too)."""
+    x = jnp.asarray(rng.normal(size=(1, 2, 24, 16)), jnp.float32)
+    default = {"rope_type": "default", "rope_theta": 10000, "partial_rotary_factor": 1}
+    want = hybrid.rotary(x, 8, 1e4)
+    for scaling in (None, default):
+        assert (np.asarray(hybrid.rotary(x, 8, 1e4, scaling=scaling)) == np.asarray(want)).all()
+    assert str(jax.make_jaxpr(lambda a: hybrid.rotary(a, 8, 1e4, scaling=None))(x)) == str(
+        jax.make_jaxpr(lambda a: hybrid.rotary(a, 8, 1e4))(x))
+    # the angles the tables held before there was a schedule
+    ang = jnp.arange(32, dtype=jnp.float32)[:, None] * 1e4 ** (
+        -2.0 * jnp.arange(64, dtype=jnp.float32) / 128)
+    cos, sin = rope_kernels.tables(32, 128, 128, 1e4)
+    assert (np.asarray(cos[:, :64]) == np.asarray(jnp.cos(ang))).all()
+    assert (np.asarray(sin[:, 64:]) == np.asarray(jnp.sin(ang))).all()
+    for a, b in zip(rope_kernels.tables(32, 128, 128, 1e4, default), (cos, sin)):
+        assert (np.asarray(a) == np.asarray(b)).all()
+
+
+def test_a_schedule_turns_and_scales_the_turned_part_alone(rng):
+    """Half the head turns at the yarn frequencies, cos and sin times the
+    factor; the other half passes through unscaled — in `rotary`, in the
+    kernel tables and in the reference alike."""
+    x = jnp.asarray(rng.normal(size=(1, 3, 40, 128)), jnp.float32)
+    got = hybrid.rotary(x, 64, 5e5, scaling=YARN)
+    want = jnp.moveaxis(laguna_ref.rotate(jnp.moveaxis(x[0], 1, 0), YARN), 0, 1)[None]
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    assert (np.asarray(got[..., 64:]) == np.asarray(x[..., 64:])).all()
+    norm = lambda a: jnp.sqrt(jnp.sum(a * a, axis=-1))  # noqa: E731
+    np.testing.assert_allclose(norm(got[..., :64]), 1.4852030263919618 * norm(x[..., :64]),
+                               rtol=1e-5)
+    cos, sin = rope_kernels.tables(40, 128, 64, 5e5, tuple(sorted(YARN.items())))
+    f, scale = hybrid.frequencies(64, 5e5, YARN)
+    ang = jnp.arange(40, dtype=jnp.float32)[:, None] * f
+    np.testing.assert_allclose(cos[:, :32], scale * jnp.cos(ang), rtol=1e-6)
+    np.testing.assert_allclose(sin[:, 32:64], scale * jnp.sin(ang), rtol=1e-6, atol=1e-7)
+    assert (np.asarray(cos[:, 64:]) == 1).all() and (np.asarray(sin[:, 64:]) == 0).all()
+
+
+@pytest.mark.parametrize("kind", ["sliding", "full"])
+def test_windowed_attention_layer_matches_the_reference(kind, rng):
+    """`GatedAttention` as the zoo builds it for a layer of each type against
+    the reference's layer, forward and every gradient (the XLA forms here;
+    the kernels with a window are tests/test_window_attention.py's)."""
+    cfg = tiny_laguna.laguna(seq_len=128)
+    windowed = kind == "sliding"
+    rec = laguna_ref.recipe(cfg, windowed)
+    h = 3 if windowed else 2
+    layer = GatedAttention(
+        n_heads=h, n_kv_heads=1, head_dim=16, rotary_fraction=rec["partial_rotary_factor"],
+        rope_theta=float(rec["rope_theta"]), qk_norm=False, gated=True, gate="head",
+        rope_scaling=None if windowed else rec, window=8 if windowed else None)
+    draw = lambda *s: jnp.asarray(0.3 * rng.standard_normal(s), jnp.float32)  # noqa: E731
+    p = {"wqkv": draw(32, (h + 2) * 16), "wg": draw(32, h), "wo": draw(h * 16, 32)}
+    x = jnp.asarray(rng.standard_normal((2, 128, 32)), jnp.float32)
+    weigh = jnp.asarray(rng.standard_normal((2, 128, 32)), jnp.float32)
+    mm = ref_common.matmul(None)
+
+    def reference(p, x):
+        return jnp.stack([laguna_ref.attention(p, row, cfg, mm, windowed) for row in x])
+
+    def program(p, x):
+        mine = {"Wqkv": p["wqkv"], "Wg": p["wg"], "Wo": p["wo"]}
+        return layer.apply(mine, x, state=layer.init_state(it.recurrent(32, 128)), train=True,
+                           rng=None)[0]
+
+    with jax.default_matmul_precision("highest"):
+        want, want_g = jax.value_and_grad(lambda p, x: jnp.sum(reference(p, x) * weigh),
+                                          argnums=(0, 1))(p, x)
+        got, got_g = jax.value_and_grad(lambda p, x: jnp.sum(program(p, x) * weigh),
+                                        argnums=(0, 1))(p, x)
+        np.testing.assert_allclose(program(p, x), reference(p, x), rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(got, want, rtol=1e-4)
+    for a, b in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
+        np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-5 * float(jnp.abs(b).max()))
+    # every control of the reference is another layer
+    for control in laguna_ref.CONTROLS:
+        other = jnp.stack([laguna_ref.attention(p, row, cfg, mm, windowed, control) for row in x])
+        moved = float(jnp.abs(other - reference(p, x)).max()) > 1e-3
+        applies = {"drop_window": windowed, "window_511": windowed, "drop_yarn": not windowed,
+                   "drop_rope_scale": not windowed, "drop_gate": True}[control]
+        assert moved == applies, control
+
+
+def test_a_windowed_layer_counts_its_plan(rng):
+    layer, params, x = _attention_case(rng, window=8)
+    state = layer.init_state(IN)
+    assert sorted(state["counters"]) == ["band_keys", "steps", "visited_keys"]
+    _, state = layer.apply(params, x, state=state, train=True, rng=None)
+    _, state = layer.apply(params, x, state=state, train=True, rng=None)
+    added = {k: np.atleast_1d(np.asarray(v)) for k, v in state["counters"].items()}
+    key, entry = layer.counter_summary(added)
+    band = (8 * 9 // 2 + 32 * 8) / 40
+    assert key == "attention" and entry == {
+        "steps": 2, "window": 8, "n_heads": 4, "n_kv_heads": 2,
+        "band_keys_per_query": pytest.approx(band), "visited_keys_per_query": 40.0,
+        "band_fill": pytest.approx(band / 40)}
+    # no window: no state, nothing counted, the layer as it was
+    plain_layer, params, x = _attention_case(rng)
+    assert plain_layer.init_state(IN) == {}
+    assert plain_layer.apply(params, x, state={}, train=True, rng=None)[1] == {}
+
+
+def test_the_shares_add_up_to_the_uncut_layer(rng):
+    """The cut's two kinds of share at a small size: the outputs of both head
+    ranks of an attention layer (each with its rank's columns of Wq, Wk, Wv
+    and Wg and its rows of Wo) add up to the uncut reference's layer, and so
+    do the outputs of the four expert ranks with the shared expert — which
+    every rank computes alike — counted once."""
+    cfg = dict(tiny_laguna.laguna(seq_len=40), num_experts=8, num_experts_published=8,
+               experts_first=0)
+    draw = lambda *s: jnp.asarray(0.3 * rng.standard_normal(s), jnp.float32)  # noqa: E731
+    x = jnp.asarray(rng.standard_normal((2, 40, 32)), jnp.float32)
+    xf = x.reshape(-1, 32)
+    mm = ref_common.matmul(None)
+    for windowed, h in ((True, 6), (False, 4)):
+        kv, hd = 2, 16
+        wq, wk, wv = draw(32, h * hd), draw(32, kv * hd), draw(32, kv * hd)
+        wg, wo = draw(32, h), draw(h * hd, 32)
+        whole = {"wqkv": jnp.concatenate([wq, wk, wv], axis=1), "wg": wg, "wo": wo}
+        rec = laguna_ref.recipe(cfg, windowed)
+        total = 0.0
+        with jax.default_matmul_precision("highest"):
+            want = jnp.stack([laguna_ref.attention(whole, row, cfg, mm, windowed) for row in x])
+            for rank in range(2):
+                q_ = slice(rank * h // 2 * hd, (rank + 1) * h // 2 * hd)
+                k_ = slice(rank * hd, (rank + 1) * hd)
+                mine = {"Wqkv": jnp.concatenate([wq[:, q_], wk[:, k_], wv[:, k_]], axis=1),
+                        "Wg": wg[:, rank * h // 2:(rank + 1) * h // 2], "Wo": wo[q_]}
+                layer = GatedAttention(
+                    n_heads=h // 2, n_kv_heads=1, head_dim=hd,
+                    rotary_fraction=rec["partial_rotary_factor"],
+                    rope_theta=float(rec["rope_theta"]), qk_norm=False, gated=True, gate="head",
+                    rope_scaling=None if windowed else rec, window=8 if windowed else None)
+                y = layer.apply(mine, x, state=layer.init_state(IN), train=False, rng=None)[0]
+                assert float(jnp.abs(y).max()) > 0.05 * float(jnp.abs(want).max())
+                total = total + y
+        np.testing.assert_allclose(total, want, rtol=2e-4, atol=2e-5 * float(jnp.abs(want).max()))
+    p = {"router": draw(32, 8), "wgu": draw(8, 32, 32), "wd": draw(8, 16, 32),
+         "shared_wgu": draw(32, 32), "shared_wd": draw(16, 32)}
+    total = 0.0
+    with jax.default_matmul_precision("highest"):
+        want = laguna_ref.moe(p, xf, cfg, mm)
+        shared = laguna_ref.swiglu(xf, p["shared_wgu"], p["shared_wd"], mm)
+        for rank in range(4):
+            held = slice(2 * rank, 2 * rank + 2)
+            layer = RoutedExperts(
+                n_experts=8, top_k=3, expert_width=16, shared_width=16, experts_held=(2 * rank, 2),
+                capacity_factor=4.0, norm_topk=True, scoring="softmax", routed_scale=2.5,
+                shared_gated=False)
+            mine = {"router": p["router"], "Wgu": p["wgu"][held], "Wd": p["wd"][held],
+                    "shared_Wgu": p["shared_wgu"], "shared_Wd": p["shared_wd"]}
+            y, st = layer.apply(mine, x, state=layer.init_state(IN), train=True, rng=None)
+            assert int(st["counters"]["dropped"]) == 0
+            part = laguna_ref.moe(dict(p, wgu=p["wgu"][held], wd=p["wd"][held]), xf, cfg, mm,
+                                  held=(2 * rank, 2))
+            np.testing.assert_allclose(y.reshape(-1, 32), part, rtol=2e-4, atol=1e-5)
+            total = total + y.reshape(-1, 32) - shared
+    np.testing.assert_allclose(total + shared, want, rtol=2e-4,
+                               atol=2e-5 * float(jnp.abs(want).max()))
+
+
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
+def test_routed_scale_holds_under_both_scorings(scoring, rng):
+    layer = RoutedExperts(n_experts=8, top_k=3, expert_width=16, shared_width=0,
+                          scoring=scoring, routed_scale=2.5, capacity_factor=4.0)
+    params = layer.init_params(jax.random.PRNGKey(1), IN)
+    xf = jnp.asarray(rng.normal(size=(24, 32)), jnp.float32)
+    top, idx = layer.route(params, xf)
+    one, idx1 = dataclasses.replace(layer, routed_scale=1.0).route(params, xf)
+    assert (np.asarray(idx) == np.asarray(idx1)).all()
+    np.testing.assert_allclose(top, 2.5 * one, rtol=1e-6)
+    np.testing.assert_allclose(top.sum(-1), 2.5, rtol=1e-5)        # renormalised, then scaled
+    # the default scale multiplies by one: the routes of every model that had none
+    assert (np.asarray(dataclasses.replace(layer, routed_scale=1.0).route(params, xf)[0])
+            == np.asarray(one)).all()
