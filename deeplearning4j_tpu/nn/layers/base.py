@@ -39,8 +39,8 @@ _LAYER_TYPES: Dict[str, type] = {}
 
 #: the tag for a value the 'full' remat policy keeps: one that costs more to
 #: compute again than to keep (the row groups' outputs, `hybrid.over_row_groups`;
-#: the flash forward's output and logsumexp, in `ops/`). Defined where `ops/`
-#: can import it too.
+#: the flash forward's output and logsumexp, in `ops/`; the q, k, v of
+#: `hybrid.LatentAttention`). Defined where `ops/` can import it too.
 REMAT_KEEP = jaxcompat.REMAT_KEEP
 
 

@@ -1016,7 +1016,15 @@ class LatentAttention(Layer):
     `rope_interleave`), the nope parts and the values never; with None the
     layer knows no positions. Scores q k^T (nope_dim + rope_dim)^-0.5
     through `ops.attention.attend`, whose flash kernels take a key width
-    that differs from the value width; Wo [n_heads v_dim, f]. No bias."""
+    that differs from the value width; Wo [n_heads v_dim, f]. No bias.
+
+    q after its rotation, k after the concatenate and v are tagged
+    `REMAT_KEEP`: the flash backward only reads them, and to make them a
+    second time a block's 'full' remat would run `x Wq`, `c Wkvb`, both
+    rotations, the broadcast and the concatenate again (at five layers of
+    [2, 32, 8192, 192 | 192 | 128] bfloat16 a 769 ms step fell by 29.6 ms for
+    0.57 GB a layer kept: PERF.md section 6, PR 48). `x Wkva` and the norm
+    stay in the recompute: the norm's backward reads `c`."""
 
     n_heads: int = 32
     kv_rank: int = 512
@@ -1066,8 +1074,13 @@ class LatentAttention(Layer):
         with device_scope("proj"):
             kr = jnp.broadcast_to(kr, (b, h, t, self.rope_dim))
             k = jnp.concatenate([kv[..., :nope], kr], axis=-1)
+            # the kernels' three operands, kept by a block's 'full' remat: its
+            # recompute stops at `ckr` and the norm (the docstring's last paragraph).
+            # Under `proj`: XLA books the fusions that write them to the tag's
+            # scope, and `attend` stays the kernels' own time
+            q, k, v = (checkpoint_name(a, REMAT_KEEP) for a in (q, k, kv[..., nope:]))
         with device_scope("attend"):
-            o = att.attend(q, k, kv[..., nope:], causal=True, mask=mask)
+            o = att.attend(q, k, v, causal=True, mask=mask)
         with device_scope("out"):
             y = ops.dot(o.transpose(0, 2, 1, 3).reshape(b, t, h * self.v_dim), params["Wo"])
         if mask is not None:

@@ -43,7 +43,9 @@ policy BY NAME — names lower to jax.checkpoint policies here:
     'none'            no checkpointing: full activation stash
     'dots_saveable'   save matmul outputs, recompute elementwise
     'full'            recompute the block; save nothing but what is tagged REMAT_KEEP
-                      (a value that costs more to compute again than to keep)
+                      (a value that costs more to compute again than to keep: the
+                      row groups' outputs, the flash forward's output and
+                      logsumexp, a latent-attention layer's q, k, v)
     'offload'         save dot outputs to host memory (pinned_host)
 
 Booleans stay accepted where the old single `remat: bool` flag lived

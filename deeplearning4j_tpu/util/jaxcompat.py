@@ -18,12 +18,18 @@ import jax
 
 #: `jax.ad_checkpoint.checkpoint_name` tag for a value the 'full' remat
 #: policy keeps although it recomputes everything else: a value that costs
-#: more to compute again than to keep. Two uses, one rule: what comes back
+#: more to compute again than to keep. Three uses, one rule: what comes back
 #: from a sub-computation that is ITSELF a checkpoint (`hybrid.over_row_groups`:
 #: it reruns in its own backward, and would run a third time in the block's
-#: recompute), and the flash forward kernel's output and logsumexp
+#: recompute), the flash forward kernel's output and logsumexp
 #: (`pallas_kernels._flash_vjp_fwd`: all its backward kernel needs beside
-#: q, k, v, so the block's recompute does not call the forward kernel again).
+#: q, k, v, so the block's recompute does not call the forward kernel again),
+#: and the q, k, v a latent-attention layer hands the kernels
+#: (`hybrid.LatentAttention`: two wide products, two rotations, a broadcast and
+#: a concatenate to remake — a 769 ms step of five such layers fell by 29.6 ms —
+#: for 0.57 GB a layer at [2, 32, 8192, 192 | 192 | 128] bfloat16; where q, k, v
+#: are ONE product and a rotation away, as in the other attention layers,
+#: they are recomputed).
 #: Outside a `jax.checkpoint` the tag lowers to nothing.
 REMAT_KEEP = "dl4j_remat_keep"
 
@@ -52,9 +58,11 @@ def remat_policy(name: Any):
     """The jax.checkpoint `policy=` object for a canonical name ('full'
     saves nothing but what is tagged `REMAT_KEEP` — a value that costs
     more to compute again than to keep: the output of an inner checkpoint
-    (`hybrid.over_row_groups`), so that it is not run a third time, and the
+    (`hybrid.over_row_groups`), so that it is not run a third time, the
     flash forward kernel's output and logsumexp (`pallas_kernels`), so that
-    it is not run a second time). Cached so the same name always returns
+    it is not run a second time, and a latent-attention layer's q, k, v
+    (`hybrid.LatentAttention`), so that its projections, rotations and
+    concatenate are not). Cached so the same name always returns
     the SAME callable: a fresh policy closure per call would defeat the jit
     trace cache."""
     n = canonical_policy(name)

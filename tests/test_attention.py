@@ -324,12 +324,14 @@ def test_both_attention_layers_go_through_one_door(rng):
 
 
 # ---------------------------------------------------------------------------
-# remat 'full' keeps the flash forward's output and logsumexp (REMAT_KEEP)
+# remat 'full' keeps the flash forward's output and logsumexp, and a latent
+# layer's q, k, v (REMAT_KEEP)
 # ---------------------------------------------------------------------------
 FLASH_B, FLASH_T, FLASH_F, FLASH_H, FLASH_DV = 2, 128, 32, 2, 16
+LATENT_RANK, LATENT_NOPE, LATENT_ROPE = 16, 16, 8
 
 
-@pytest.fixture(params=["latent", "gated", "mha"])
+@pytest.fixture(params=["latent", "latent_rope", "gated", "mha"])
 def flash_stack(request, rng):
     """Two attention blocks, each behind `maybe_remat(., policy)`, whose heads
     `attend` hands to the flash kernels (interpreted here): `loss(policy)`
@@ -340,10 +342,13 @@ def flash_stack(request, rng):
     from deeplearning4j_tpu.nn.layers import GatedAttention, LatentAttention
     from deeplearning4j_tpu.parallel.layout import maybe_remat
 
+    latent = dict(n_heads=FLASH_H, kv_rank=LATENT_RANK, nope_dim=LATENT_NOPE,
+                  rope_dim=LATENT_ROPE, v_dim=FLASH_DV)
     layer = {
-        # keys 192 = 128 + 64 and values 128 (kanana2, kimi-linear), an eighth the size
-        "latent": LatentAttention(n_heads=FLASH_H, kv_rank=16, nope_dim=16, rope_dim=8,
-                                  v_dim=FLASH_DV),
+        # keys 192 = 128 + 64 and values 128, an eighth the size: kimi-linear's
+        # (no positions) and kanana2's (the rope parts rotated)
+        "latent": LatentAttention(**latent),
+        "latent_rope": LatentAttention(rope_theta=10000.0, **latent),
         "gated": GatedAttention(n_heads=FLASH_H, n_kv_heads=1, head_dim=FLASH_DV),
         "mha": MultiHeadAttention(n_heads=FLASH_H, causal=True),
     }[request.param]
@@ -364,24 +369,35 @@ def flash_stack(request, rng):
         yield (lambda policy: functools.partial(loss, policy=policy)), block, params, x
 
 
-def _untagged():
-    """The flash forward rule as the parent commit had it: nothing named."""
+def _untagged(flash=True):
+    """The flash forward rule and the latent layer as the commits before
+    their tags had them: nothing named. `flash=False`: the layer's tag alone."""
+    import contextlib
     import unittest.mock as mock
 
+    from deeplearning4j_tpu.nn.layers import hybrid
     from deeplearning4j_tpu.ops import pallas_kernels as pk
 
-    return mock.patch.object(pk, "checkpoint_name", lambda a, name: a)
+    stack = contextlib.ExitStack()
+    for module in (hybrid, pk)[:1 + flash]:
+        stack.enter_context(mock.patch.object(module, "checkpoint_name", lambda a, name: a))
+    return stack
+
+
+def _eqns(jaxpr, primitive):
+    """Every equation of one primitive in a jaxpr, at any depth, in order."""
+    found = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == primitive:
+            found.append(e)
+        for inner in jax.core.jaxprs_in_params(e.params):
+            found += _eqns(inner, primitive)
+    return found
 
 
 def _kernel_calls(jaxpr):
-    """The family of every `pallas_call` in a jaxpr, at any depth, in order."""
-    found = []
-    for e in jaxpr.eqns:
-        if e.primitive.name == "pallas_call":
-            found.append(e.params["name"].split("_bh")[0])
-        for inner in jax.core.jaxprs_in_params(e.params):
-            found += _kernel_calls(inner)
-    return found
+    """The family of every `pallas_call` in a jaxpr."""
+    return [e.params["name"].split("_bh")[0] for e in _eqns(jaxpr, "pallas_call")]
 
 
 def test_full_remat_calls_the_flash_forward_once_a_layer(flash_stack):
@@ -410,9 +426,10 @@ def test_full_remat_gradient_is_the_unrematted_gradient(flash_stack):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_full_remat_saves_the_inputs_the_output_and_the_logsumexp(flash_stack, capsys):
+def test_full_remat_saves_the_inputs_the_output_and_the_logsumexp(flash_stack, request, capsys):
     """What a checkpointed block keeps for its backward: its arguments, o
-    [b, h, t, dv] and the logsumexp [b, h, t] in float32 — and nothing else;
+    [b, h, t, dv] and the logsumexp [b, h, t] in float32, around a latent
+    layer also q, k [b, h, t, nope + rope] and v — and nothing else;
     untagged, its arguments alone."""
     from deeplearning4j_tpu.parallel.layout import maybe_remat
 
@@ -430,9 +447,43 @@ def test_full_remat_saves_the_inputs_the_output_and_the_logsumexp(flash_stack, c
 
     o = f"f32[{FLASH_B},{FLASH_H},{FLASH_T},{FLASH_DV}]"
     lse = f"f32[{FLASH_B},{FLASH_H},{FLASH_T}]"
-    assert kept() == sorted([o, lse])
+    qk = f"f32[{FLASH_B},{FLASH_H},{FLASH_T},{LATENT_NOPE + LATENT_ROPE}]"
+    latent = "latent" in request.node.callspec.id
+    assert kept() == sorted([o, lse] + [qk, qk, o] * latent)   # v has o's shape
     with _untagged():
         assert kept() == []
+
+
+def test_full_remat_makes_a_latent_layers_q_k_v_once(flash_stack, request):
+    """The gradient of two latent blocks under 'full' holds `x Wq` and
+    `c Wkvb` once a layer forward — each with its two products backward —
+    and not again in the recompute, nor the rotations' two products, and the
+    key's concatenate once a layer; untagged, each of them twice. `x Wkva`
+    stays in the recompute (the norm's backward reads c). Around the other
+    two layers the latent layer's tag changes no count."""
+    loss, _, params, x = flash_stack
+    case = request.node.callspec.id
+
+    def counts():
+        jaxpr = jax.make_jaxpr(jax.grad(loss("full")))(params, x).jaxpr
+        dots = _eqns(jaxpr, "dot_general")
+        made = [tuple(e.outvars[0].aval.shape) for e in dots]
+        wq = made.count((FLASH_B, FLASH_T, FLASH_H * (LATENT_NOPE + LATENT_ROPE)))
+        wkvb = made.count((FLASH_B, FLASH_T, FLASH_H * (LATENT_NOPE + FLASH_DV)))
+        wkva = made.count((FLASH_B, FLASH_T, LATENT_RANK + LATENT_ROPE))
+        return len(dots), wq, wkvb, wkva, len(_eqns(jaxpr, "concatenate"))
+
+    tagged = counts()
+    with _untagged(flash=False):     # the same 2 + 2 kernel calls on both sides
+        untagged = counts()
+    if "latent" not in case:
+        assert tagged == untagged
+        return
+    # x Wq, c Wkvb, x Wkva as made forward + in the recompute; the concatenates
+    assert tagged[1:] == (2, 2, 4, 2) and untagged[1:] == (4, 4, 4, 4)
+    gone = 4 if case == "latent_rope" else 2     # a layer: Wq, Wkvb (+ q's and kr's rotation)
+    assert (tagged[0], untagged[0]) == {"latent_rope": (46, 54), "latent": (38, 42)}[case]
+    assert untagged[0] - tagged[0] == 2 * gone
 
 
 def test_the_flash_tag_lowers_to_nothing_outside_a_checkpoint(flash_stack):

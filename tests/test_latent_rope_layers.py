@@ -7,6 +7,8 @@ layer the parent had, jaxpr and all; the 8 shares of an expert layer against
 the uncut layer; zoo -> config DSL -> `ParallelWrapper.fit` against the
 reference's three Adam steps; what a shuffled sequence changes; the `rope`
 scope in the lowered step."""
+from unittest import mock
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -155,7 +157,8 @@ def test_layer_matches_the_reference_forward_and_gradients(interleave, weights, 
 
 def parent_apply(self, params, x, mask=None):
     """`LatentAttention.apply` as the parent commit had it (PR 37), to the
-    letter but for `device_scope`, which names and changes nothing."""
+    letter but for `device_scope` and the `REMAT_KEEP` tag on q, k, v (PR 48),
+    which name and change nothing."""
     b, t, _ = x.shape
     h, nope = self.n_heads, self.nope_dim
 
@@ -178,7 +181,8 @@ def parent_apply(self, params, x, mask=None):
 
 @pytest.mark.parametrize("masked", [False, True])
 def test_without_a_theta_the_layer_is_the_parents(masked, weights, rng):
-    """Output AND jaxpr: `rope_theta=None` adds no operation and moves none."""
+    """Output AND jaxpr: `rope_theta=None` adds no operation and moves none
+    (the three `name` equations of the tag apart: they lower to nothing)."""
     layer = latent()
     assert layer.rope_theta is None
     p = renamed(sub(weights, "l0.mla."))
@@ -186,7 +190,10 @@ def test_without_a_theta_the_layer_is_the_parents(masked, weights, rng):
     mask = jnp.asarray(np.arange(T)[None, :] < np.array([T, 50])[:, None], F32) if masked else None
     now = lambda q, x_: layer.apply(q, x_, state={}, train=True, rng=None, mask=mask)[0]  # noqa: E731
     then = lambda q, x_: parent_apply(layer, q, x_, mask)  # noqa: E731
-    assert str(jax.make_jaxpr(now)(p, x)) == str(jax.make_jaxpr(then)(p, x))
+    assert [e.primitive.name for e in jax.make_jaxpr(now)(p, x).eqns].count("name") == 3
+    with mock.patch.object(hybrid, "checkpoint_name", lambda a, name: a):
+        # a function object of its own: a trace is cached by it
+        assert str(jax.make_jaxpr(lambda q, x_: now(q, x_))(p, x)) == str(jax.make_jaxpr(then)(p, x))
     np.testing.assert_array_equal(jax.jit(now)(p, x), jax.jit(then)(p, x))
     with_theta = latent(rope_theta=THETA)
     turned = jax.make_jaxpr(lambda q, x_: with_theta.apply(q, x_, state={}, train=True,
