@@ -22,7 +22,15 @@ Top-level layout (mirrors SURVEY.md §1 layer map):
     earlystopping/, nlp/, graphembed/, knn/, ui/, util/
 """
 
+import time as _time
+
+_t_import0 = _time.perf_counter()  # before anything of the package loads
+
 __version__ = "0.1.0"
 
-from deeplearning4j_tpu.nn import conf  # noqa: F401
-from deeplearning4j_tpu.analysis import analyze  # noqa: F401
+from deeplearning4j_tpu.nn import conf  # noqa: E402,F401
+from deeplearning4j_tpu.analysis import analyze  # noqa: E402,F401
+from deeplearning4j_tpu.telemetry import trace as _trace  # noqa: E402
+
+# the `import` row of the phase account (telemetry.setup_log()["import_s"])
+_trace.record_import(_t_import0, _time.perf_counter())

@@ -35,6 +35,7 @@ from deeplearning4j_tpu.nn.graph_vertices import LayerVertex
 from deeplearning4j_tpu.nn.layers import base as base_mod
 from deeplearning4j_tpu.nn.layers.output import BaseOutputLayer
 from deeplearning4j_tpu.nn.regularization import apply_constraints
+from deeplearning4j_tpu.telemetry import introspect as introspect_mod
 from deeplearning4j_tpu.telemetry.trace import device_scope
 
 PyTree = Any
@@ -89,6 +90,7 @@ class ComputationGraph:
             out[name] = u
         return out
 
+    @introspect_mod.init_span()
     def init(self) -> "ComputationGraph":
         key = jax.random.PRNGKey(self.conf.defaults.seed)
         keys = jax.random.split(key, max(len(self.topo), 1))

@@ -43,6 +43,7 @@ from deeplearning4j_tpu.nn.layers.base import Layer
 from deeplearning4j_tpu.nn.layers.output import BaseOutputLayer
 from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrent
 from deeplearning4j_tpu.nn.regularization import apply_constraints
+from deeplearning4j_tpu.telemetry import introspect as introspect_mod
 from deeplearning4j_tpu.telemetry.trace import device_scope
 from deeplearning4j_tpu.datasets.dataset import DataSet
 from deeplearning4j_tpu.training import engine as engine_mod
@@ -130,6 +131,7 @@ class MultiLayerNetwork:
             out.append(u)
         return out
 
+    @introspect_mod.init_span()
     def init(self, params: Optional[Dict[str, PyTree]] = None) -> "MultiLayerNetwork":
         key = jax.random.PRNGKey(self.conf.defaults.seed)
         keys = jax.random.split(key, len(self.layers))

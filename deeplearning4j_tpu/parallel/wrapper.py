@@ -190,6 +190,7 @@ class ParallelWrapper:
             elif not getattr(v, "sp_safe", False):
                 refuse("vertex", f"{type(v).__name__} ('{name}')")
 
+    @trace_mod.traced("place", category="setup")
     def _place_params(self):
         """Place params with layer-declared tensor-parallel shardings
         (replicates everything when the model axis is 1); updater moments

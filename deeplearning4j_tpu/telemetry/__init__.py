@@ -24,15 +24,20 @@ ComputationGraph.fit / ParallelWrapper.fit) a few microseconds a step:
 a ``jax.profiler`` annotation (``dl4j.<name>``, a no-op outside a
 profiler session) and the phase account behind ``fit_log()`` — per fit,
 the calls, seconds and bytes of ``etl`` / ``put`` / ``dispatch`` /
-``score_wait`` / ``listeners``. Metrics at resilience sites (checkpoint
+``score_wait`` / ``listeners``; the cold path's ``import`` / ``init`` /
+``place`` go through the same seam (``setup_log()``). Metrics at resilience sites (checkpoint
 writes, retries, sentry trips, chaos injections) are always live: they
 fire on cold failure/IO paths where a dict update is free, and a crash
 post-mortem must not depend on a gate having been set beforehand.
 
 PR 4 adds the runtime-introspection layer on the same gate:
 
-  introspect  compile watcher (jax.monitoring + the util.jaxcompat.jit
-              seam) with a retrace detector, HBM watermark sampling
+  introspect  compile watcher (the util.jaxcompat.jit seam) with a
+              retrace detector, over the ALWAYS-ON compile account
+              (jax.monitoring: trace / lower / backend-or-cache seconds
+              by function, cache hits and misses — ``fit_log()``'s
+              ``compile``, and ``setup_log()``: import, ``init``,
+              ``place`` and what was compiled in no fit), HBM watermark sampling
               (guarded no-op on CPU) with predicted-vs-actual against
               the PR 1 analyzer, and sampled per-layer fwd/bwd spans
               (``DL4J_TPU_PROFILE_LAYERS``).
@@ -129,6 +134,7 @@ from deeplearning4j_tpu.telemetry.introspect import (  # noqa: F401
     maybe_layer_spans,
     profile_snapshot,
     sample_hbm,
+    setup_log,
     watcher,
 )
 from deeplearning4j_tpu.telemetry.health import (  # noqa: F401

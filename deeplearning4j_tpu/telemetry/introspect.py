@@ -6,9 +6,13 @@ and the per-layer cost structure of a step. Three instruments, all gated
 by ``DL4J_TPU_TELEMETRY`` (the span gate — introspection IS spans+gauges):
 
   CompileWatcher   counts compilations and compile seconds two ways:
-                   (a) a ``jax.monitoring`` duration listener (fires for
-                   EVERY backend compile in the process, including raw
-                   ``jax.jit`` uses the seam below doesn't cover), and
+                   (a) the ``jax.monitoring`` listeners behind its
+                   ``CompileAccount`` (they fire for EVERY trace, lowering
+                   and backend compile in the process, raw ``jax.jit``
+                   uses included, and count with the gate ON OR OFF:
+                   seconds by stage and by function name, and the
+                   persistent cache's hits, misses and read seconds —
+                   ``fit_log()``'s ``compile`` and ``setup_log()``), and
                    (b) the ``util.jaxcompat.jit`` seam, which
                    fingerprints each call's ``(fn, abstract shapes/
                    dtypes)`` — a fingerprint never seen before is a
@@ -47,10 +51,13 @@ double compile is why the gate defaults off.
 
 Disabled-path contract (the PR 3 policy, tier-1 asserted): with the gate
 off every hook here is one attribute/env check — no span records, no
-fingerprint sets, no metric children allocated.
+fingerprint sets, no metric children allocated. The compile account is
+the exception by design: its listeners run only while JAX traces, lowers
+or compiles, never in a warm step.
 """
 from __future__ import annotations
 
+import contextlib
 import re
 import threading
 import time
@@ -224,6 +231,190 @@ def _devices_per_host() -> Optional[int]:
     return None
 
 
+# ---------------------------------------------------------------------------
+# the compile account (always on)
+# ---------------------------------------------------------------------------
+
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+CACHE_SAVED_EVENT = "/jax/compilation_cache/compile_time_saved_sec"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+
+_STAGE_KEYS = ("traces", "trace_s", "lower_s", "backend_compiles",
+               "backend_compile_s")
+_CACHE_KEYS = ("cache_hits", "cache_misses", "cache_retrieval_s",
+               "compile_time_saved_s")
+_TRACES, _TRACE_S, _LOWER_S, _BACKEND, _BACKEND_S = range(5)
+_ZERO_ROW = (0, 0.0, 0.0, 0, 0.0)
+_HITS, _MISSES, _READ_S, _SAVED_S = range(4)
+
+# the lowering and the backend name a function `jit(step)` (`pmap(step)`)
+# where the trace said `step`
+_WRAPPED_NAME_RE = re.compile(r"^\w+\((.*)\)$")
+
+
+def _plain_name(fun_name) -> str:
+    m = _WRAPPED_NAME_RE.match(str(fun_name))
+    return m.group(1) if m else str(fun_name)
+
+
+# a tally: ({function name: the five stage numbers}, the four cache numbers)
+_Tally = Tuple[Dict[str, List[float]], List[float]]
+
+
+def _tally() -> _Tally:
+    return {}, [0, 0, 0.0, 0.0]
+
+
+def _tally_add(into: _Tally, more: _Tally) -> None:
+    for name, row in more[0].items():
+        dst = into[0].setdefault(name, list(_ZERO_ROW))
+        for i, v in enumerate(row):
+            dst[i] += v
+    for i, v in enumerate(more[1]):
+        into[1][i] += v
+
+
+def _tally_less(now: _Tally, base: _Tally) -> _Tally:
+    """`now - base`, functions with nothing new left out (a nanosecond is
+    a sum's rounding, not news)."""
+    by = {}
+    for name, row in now[0].items():
+        b = base[0].get(name)
+        d = list(row) if b is None else [v - w for v, w in zip(row, b)]
+        if any(abs(v) > 1e-9 for v in d):
+            by[name] = d
+    return by, [v - w for v, w in zip(now[1], base[1])]
+
+
+def _tally_dict(t: _Tally) -> Dict[str, Any]:
+    """The form `fit_log()` and `setup_log()` carry: the stage totals over
+    every function, the cache's numbers, and `by_fn`."""
+    by = {name: dict(zip(_STAGE_KEYS, row)) for name, row in sorted(t[0].items())}
+    out: Dict[str, Any] = {
+        k: sum((row[i] for row in t[0].values()), _ZERO_ROW[i])
+        for i, k in enumerate(_STAGE_KEYS)}
+    out.update(zip(_CACHE_KEYS, t[1]))
+    out["by_fn"] = by
+    return out
+
+
+class CompileAccount:
+    """Always-on totals of what JAX compiled, from its own monitoring
+    events — the `PhaseAccount` of the cold path. Per function name and in
+    total: `traces`, `trace_s` (Python tracing to a jaxpr), `lower_s`
+    (jaxpr to an MLIR module), `backend_compiles` and `backend_compile_s`
+    (what the process waited for the backend OR for the persistent cache:
+    JAX fires the event on a cache hit too); in total only, because JAX
+    names no function on them: `cache_hits`, `cache_misses`,
+    `cache_retrieval_s`, `compile_time_saved_s` (what a hit's compilation
+    had cost when it was cached, less the read: negative for a tiny
+    program).
+
+    A function is listed under the name its LOWERING gives it, `jit(...)`
+    stripped. JAX fires a trace event for every jitted function traced
+    inside another (`matmul`, `tanh` inside `step`), whose seconds the
+    outer event already holds and which closes before it: so a trace is
+    booked when the function it names is then lowered on the same thread,
+    and the inner ones, which never are, are dropped at that lowering —
+    with whatever a kernel's lowering traced meanwhile. Each second of a
+    stage counts once.
+
+    `mark()` / `since(mark)` are `PhaseAccount`'s; `claim(bucket, mark)`
+    also adds what `since` returns to a named bucket, so that
+    `setup_log()` can say what was compiled in no fit."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._now: _Tally = _tally()  # guarded-by: self._lock
+        self._claimed: Dict[str, _Tally] = {}  # guarded-by: self._lock
+        # `.traces`: {function: seconds} of the traces closed on this
+        # thread since its last lowering (a later one of a name wins: the
+        # outer function's, where an inner one has its name)
+        self._closed = threading.local()
+
+    # -- the listeners ---------------------------------------------------
+    def on_duration(self, event: str, seconds: float, fun_name=None,
+                    **kw) -> None:
+        seconds = float(seconds)
+        if event == TRACE_EVENT:
+            traces = self._closed.__dict__.setdefault("traces", {})
+            traces.pop(fun_name, None)          # re-inserted as the newest
+            traces[fun_name] = seconds
+        elif event == LOWER_EVENT:
+            name = _plain_name(fun_name)
+            traces = self._closed.__dict__.pop("traces", {})
+            # a `functools.partial` is lowered nameless: the newest trace
+            traced = (traces.popitem()[1] if name == "<unknown>" and traces
+                      else traces.get(name))
+            with self._lock:
+                row = self._now[0].setdefault(name, list(_ZERO_ROW))
+                row[_LOWER_S] += seconds
+                if traced is not None:
+                    row[_TRACES] += 1
+                    row[_TRACE_S] += traced
+        elif event == BACKEND_EVENT:
+            with self._lock:
+                row = self._now[0].setdefault(_plain_name(fun_name),
+                                              list(_ZERO_ROW))
+                row[_BACKEND] += 1
+                row[_BACKEND_S] += seconds
+        elif event == CACHE_READ_EVENT:
+            self._add_cache(_READ_S, seconds)
+        elif event == CACHE_SAVED_EVENT:
+            self._add_cache(_SAVED_S, seconds)
+
+    def on_event(self, event: str, **kw) -> None:
+        if event == CACHE_HIT_EVENT:
+            self._add_cache(_HITS, 1)
+        elif event == CACHE_MISS_EVENT:
+            self._add_cache(_MISSES, 1)
+
+    def _add_cache(self, index: int, value: float) -> None:
+        with self._lock:
+            self._now[1][index] += value
+
+    # -- readers ---------------------------------------------------------
+    def mark(self) -> _Tally:
+        with self._lock:
+            return ({k: list(v) for k, v in self._now[0].items()},
+                    list(self._now[1]))
+
+    def since(self, mark: _Tally) -> Dict[str, Any]:
+        """What was added after `mark`: zeros and an empty `by_fn` when
+        nothing was traced, lowered or compiled."""
+        return _tally_dict(_tally_less(self.mark(), mark))
+
+    def claim(self, bucket: str, mark: _Tally) -> Dict[str, Any]:
+        """`since(mark)`, also added to `bucket`'s running sum."""
+        delta = _tally_less(self.mark(), mark)
+        with self._lock:
+            _tally_add(self._claimed.setdefault(bucket, _tally()), delta)
+        return _tally_dict(delta)
+
+    def claimed(self, bucket: str) -> Dict[str, Any]:
+        with self._lock:
+            return _tally_dict(self._claimed.get(bucket, _tally()))
+
+    def outside(self, bucket: str) -> Dict[str, Any]:
+        """Everything since the process started that `bucket` did not
+        claim."""
+        with self._lock:
+            base = self._claimed.get(bucket, _tally())
+            return _tally_dict(_tally_less(self._now, base))
+
+    def total(self, key: str) -> float:
+        """One of the nine totals since the process started."""
+        with self._lock:
+            if key in _CACHE_KEYS:
+                return self._now[1][_CACHE_KEYS.index(key)]
+            i = _STAGE_KEYS.index(key)
+            return sum(row[i] for row in self._now[0].values())
+
+
 def _fingerprint(leaves) -> Tuple:
     """Abstract (shape, dtype) tuple over already-flattened call args —
     the jit trace-cache key modulo weak types. Non-arrays hash by value
@@ -246,6 +437,8 @@ class CompileWatcher:
 
     def __init__(self):
         self._lock = threading.Lock()
+        #: what JAX traced, lowered and compiled, gate on or off
+        self.account = CompileAccount()
         # fn name -> {fingerprint: compile-inclusive first-call seconds}
         self._fns: Dict[str, Dict[Tuple, float]] = {}  # guarded-by: self._lock
         self._warned: set = set()  # guarded-by: self._lock
@@ -363,7 +556,12 @@ class CompileWatcher:
 
     # ------------------------------------------------------------------
     def snapshot(self) -> Dict[str, Any]:
-        """Machine-readable state for /profile and the profile CLI."""
+        """Machine-readable state for /profile and the profile CLI. `fns`,
+        `seam_compiles` and `retraced_fns` come from the jit seam and need
+        the gate; the rest reads the compile account and is true without.
+        `backend_compile_seconds` is what the process waited for the
+        backend OR the persistent cache (JAX fires the event on a hit
+        too); `cache_retrieval_seconds` is the part of it spent reading."""
         with self._lock:
             fns = {name: {"traces": len(fps),
                           "compile_seconds": round(sum(fps.values()), 4)}
@@ -373,38 +571,37 @@ class CompileWatcher:
             "fns": fns,
             "collectives": self.collective_census(),
             "seam_compiles": int(sum(f["traces"] for f in fns.values())),
-            "backend_compiles": int(_backend_compiles.value),
-            "backend_compile_seconds": round(_compile_seconds.value, 4),
-            "persistent_cache_hits": int(_cache_hits.value),
+            "backend_compiles": self.compile_count(),
+            "backend_compile_seconds": round(
+                self.account.total("backend_compile_s"), 4),
+            "cache_retrieval_seconds": round(
+                self.account.total("cache_retrieval_s"), 4),
+            "persistent_cache_hits": self.cache_hit_count(),
             "cold_compiles": self.cold_compile_count(),
             "retraced_fns": retraced,
         }
 
     def compile_count(self) -> int:
-        """XLA backend compilations of this process (jax.monitoring; gate
-        on or off). Only where the listener could not be registered, the
-        jit seam's count of trace-cache misses, which needs the gate."""
-        if _monitoring_installed:  # noqa: DLC002 — written once, under _watcher_lock, before any watcher exists
-            return int(_backend_compiles.value)
-        return self.snapshot()["seam_compiles"]
+        """XLA backend compilations of this process (the compile account;
+        gate on or off)."""
+        return int(self.account.total("backend_compiles"))
 
     def cold_compile_count(self) -> int:
         """Backend compiles that actually RAN XLA. jax fires a
         backend_compile_duration event even when the executable came out
-        of the persistent compilation cache (the retrieval also fires a
-        cache-retrieval event), so the true cold count is the difference
-        — the number a zero-cold-start restart test pins to zero
+        of the persistent compilation cache (the hit fires its own
+        event), so the true cold count is the difference — the number a
+        zero-cold-start restart test pins to zero
         (serving/warmstart.py)."""
-        return max(0, int(_backend_compiles.value) - int(_cache_hits.value))
+        return max(0, self.compile_count() - self.cache_hit_count())
 
     def cache_hit_count(self) -> int:
         """Backend compiles satisfied from the persistent cache."""
-        return int(_cache_hits.value)
+        return int(self.account.total("cache_hits"))
 
 
 _watcher: Optional[CompileWatcher] = None  # guarded-by: _watcher_lock
 _watcher_lock = threading.Lock()
-_monitoring_installed = False  # guarded-by: _watcher_lock
 
 
 def watcher() -> CompileWatcher:
@@ -415,42 +612,84 @@ def watcher() -> CompileWatcher:
             w = _watcher
             if w is None:
                 w = _watcher = CompileWatcher()
-                _install_monitoring()
+                _install_monitoring(w.account)
     return w
 
 
-def _install_monitoring() -> None:
-    """Register the jax.monitoring compile-duration listener once per
-    process. It counts with the telemetry gate on or off: a compilation
-    is a cold path, and `compile_count()` is what `telemetry.fit_log()`
-    reports for every fit."""
-    global _monitoring_installed
-    if _monitoring_installed:  # noqa: DLC002 — only reachable from watcher(), which already holds _watcher_lock around the call
-        return
-    try:
-        from jax import monitoring
-    except ImportError:  # pragma: no cover - every supported jax has it
-        return
+def _install_monitoring(account: CompileAccount) -> None:
+    """Register the two jax.monitoring listeners that feed `account`, once
+    per process (from `watcher()`, under its lock). They count with the
+    telemetry gate on or off — stages, seconds, function names, cache
+    reads: a compilation is a cold path, and the account is what
+    `telemetry.fit_log()` reports for every fit (`compiles`, `compile`)
+    and `telemetry.setup_log()` for the time before. What still needs the
+    gate is the jit seam's own work: argument fingerprints, the retrace
+    warning, the `compile` span and the collective census. The three
+    Prometheus counters are fed here, beside the account."""
+    from jax import monitoring
 
-    def _on_duration(name: str, seconds: float, **kw) -> None:
+    def on_duration(event: str, seconds: float, **kw) -> None:
         try:
-            if name.endswith("backend_compile_duration"):
+            account.on_duration(event, seconds, **kw)
+            if event == BACKEND_EVENT:
                 _backend_compiles.inc()
                 _compile_seconds.inc(float(seconds))
-            elif "cache_retrieval_time" in name:
-                # /jax/compilation_cache/cache_retrieval_time_sec: this
-                # backend compile was a persistent-cache disk read — its
-                # backend_compile_duration event fires too, so cold
-                # compiles = backend_compiles - cache_hits
+            elif event == CACHE_READ_EVENT:
                 _cache_hits.inc()
         except Exception:  # a telemetry hook must never break compilation
             pass  # jaxlint: disable=JX009
 
+    def on_event(event: str, **kw) -> None:
+        try:
+            account.on_event(event, **kw)
+        except Exception:  # a telemetry hook must never break compilation
+            pass  # jaxlint: disable=JX009
+
+    monitoring.register_event_duration_secs_listener(on_duration)
+    monitoring.register_event_listener(on_event)
+
+
+# ---------------------------------------------------------------------------
+# the set-up account: what the program spent outside any fit
+# ---------------------------------------------------------------------------
+
+
+@contextlib.contextmanager
+def init_span():
+    """The `init` span of `MultiLayerNetwork.init` / `ComputationGraph.init`
+    (a decorator there). What the initialisers compile meanwhile is
+    claimed for `setup_log()["init"]["compile"]`."""
+    account = watcher().account
+    mark = account.mark()
     try:
-        monitoring.register_event_duration_secs_listener(_on_duration)
-        _monitoring_installed = True  # noqa: DLC002 — only reachable from watcher(), which already holds _watcher_lock around the call
-    except Exception:  # pragma: no cover - defensive: API drift
-        pass  # jaxlint: disable=JX009 — jax.monitoring registration optional
+        with trace_mod.tracer().span("init", category="setup"):
+            yield
+    finally:
+        account.claim("init", mark)
+
+
+def setup_log() -> Dict[str, Any]:
+    """What this process spent in the program outside any fit, gate on or
+    off: ``{import_s, init: {calls, total_s, compile}, place: {calls,
+    total_s}, compile_outside_fits}``. `import_s` is the package's own
+    import, `init` every network's parameter and state initialisation with
+    what the initialisers compiled (the `compile` form of `fit_log()`),
+    `place` `ParallelWrapper`'s placement of the model on its mesh, and
+    `compile_outside_fits` everything JAX traced, lowered or compiled in
+    no fit — `init`'s share included, and whatever the caller jitted
+    itself. With `fit_log()`'s `t_start_s` and `wall_s` it lays the process
+    out in order. docs/TELEMETRY.md "Set-up account"."""
+    phases = trace_mod.tracer().account.snapshot()
+    account = watcher().account
+
+    def row(name):
+        p = phases.get(name, {})
+        return {"calls": p.get("calls", 0), "total_s": p.get("total_s", 0.0)}
+
+    return {"import_s": row("import")["total_s"],
+            "init": {**row("init"), "compile": account.claimed("init")},
+            "place": row("place"),
+            "compile_outside_fits": account.outside("fit")}
 
 
 # ---------------------------------------------------------------------------

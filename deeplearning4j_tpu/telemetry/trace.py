@@ -675,6 +675,24 @@ def configure(enabled=_KEEP, capacity: Optional[int] = None) -> Tracer:
 
 FIT_LOG_LENGTH = 64
 _fits: deque = deque(maxlen=FIT_LOG_LENGTH)  # guarded-by: _lock
+#: `perf_counter` at the first line of the package's `__init__`: the
+#: origin of `t_start_s`
+_import_t0 = time.perf_counter()
+
+
+def record_import(t0: float, t1: float) -> None:
+    """The package's `__init__`, at its last line: its import ran from
+    `t0` to `t1` (`perf_counter`). Booked once as the `import` row of the
+    phase account — no context manager goes around an import."""
+    global _import_t0
+    _import_t0 = t0
+    tracer().account.add("import", t1 - t0)
+
+
+def since_import(t: float) -> float:
+    """`perf_counter` reading `t` as seconds since the package's import
+    began (`fit_log()`'s `t_start_s`)."""
+    return t - _import_t0
 
 
 def record_fit(entry: Dict[str, Any]) -> None:
@@ -685,16 +703,20 @@ def record_fit(entry: Dict[str, Any]) -> None:
 
 def fit_log() -> List[Dict[str, Any]]:
     """The last fits of this process, oldest first, gate on or off:
-    ``{path, steps, staged_ahead, wall_s, compiles, phases: {name:
-    {calls, total_s, max_s, bytes}}}`` — which entry point ran, how many
-    optimizer steps, how many of them had their inputs handed to the
-    runtime before the previous step's score was read (the fit loop's
-    one-batch look-ahead: `steps - 1` when every batch could be staged),
-    the wall seconds of the fit, the XLA compilations inside it, and what
-    each span name added to the phase account meanwhile (`max_s` the
-    longest single span of the fit). The account is the process's: spans
-    closed by other threads during the fit (a server, a second fit) are
-    counted in. docs/TELEMETRY.md "Reading a fit's phases"."""
+    ``{path, steps, staged_ahead, t_start_s, wall_s, compiles, compile,
+    phases: {name: {calls, total_s, max_s, bytes}}}`` — which entry point
+    ran, how many optimizer steps, how many of them had their inputs
+    handed to the runtime before the previous step's score was read (the
+    fit loop's one-batch look-ahead: `steps - 1` when every batch could be
+    staged), when the fit began (seconds since the package's import
+    began) and its wall seconds, the XLA compilations inside it, what JAX
+    traced, lowered and compiled inside it by stage and by function
+    (`introspect.CompileAccount`: all zeros and an empty `by_fn` for a
+    warm fit), and what each span name added to the phase account
+    meanwhile (`max_s` the longest single span of the fit). Both accounts
+    are the process's: spans closed and functions compiled by other
+    threads during the fit (a server, a second fit) are counted in.
+    docs/TELEMETRY.md "Reading a fit's phases"."""
     with _lock:
         return list(_fits)
 

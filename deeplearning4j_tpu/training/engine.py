@@ -756,11 +756,11 @@ class TrainingRun:
         from deeplearning4j_tpu.telemetry import tuner as tuner_mod
 
         m = self.model
-        # the always-on account of this fit (telemetry.fit_log()): what
-        # the spans and the compile counter add between here and the end
+        # the always-on accounts of this fit (telemetry.fit_log()): what
+        # the spans and JAX's compile events add between here and the end
         account = trace_mod.tracer().account
         watcher = introspect_mod.watcher()
-        phases0, compiles0 = account.mark(), watcher.compile_count()
+        phases0, compile0 = account.mark(), watcher.account.mark()
         counters0 = counters_mod.begin(m)
         iteration0, t_fit0 = m.iteration, time.perf_counter()
         ahead0 = loop.staged_ahead
@@ -815,12 +815,15 @@ class TrainingRun:
             fire_lifecycle(m.listeners, "on_fit_end", m, swallow=True)
             if ctx_token is not None:
                 context_mod.detach(ctx_token)
+            compiled = watcher.account.claim("fit", compile0)
             entry = {
                 "path": self.phase,
                 "steps": m.iteration - iteration0,
                 "staged_ahead": loop.staged_ahead - ahead0,
+                "t_start_s": trace_mod.since_import(t_fit0),
                 "wall_s": time.perf_counter() - t_fit0,
-                "compiles": watcher.compile_count() - compiles0,
+                "compiles": compiled["backend_compiles"],
+                "compile": compiled,
                 "phases": account.since(phases0)}
             entry.update(counters_mod.end(m, counters0))
             trace_mod.record_fit(entry)

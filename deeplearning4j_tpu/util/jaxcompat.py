@@ -102,7 +102,13 @@ def jit(fn, *, watch_name=None, **jit_kwargs):
 
     Gate contract: with ``DL4J_TPU_TELEMETRY`` off the wrapper is the
     raw jitted call behind one enabled-check — no fingerprinting, no
-    allocation (the PR 3 disabled-path policy). ``.lower`` (and the raw
+    allocation (the PR 3 disabled-path policy). What is counted all the
+    same, by the compile account's ``jax.monitoring`` listeners and not
+    here: every trace, lowering and backend compile (or cache read) with
+    its seconds and its function's name, and the persistent cache's hits
+    and misses (``telemetry.fit_log()``'s ``compile``). What needs the
+    gate is this seam's own: argument fingerprints, the retrace warning,
+    the ``compile`` span, the collective census. ``.lower`` (and the raw
     jitted fn as ``__wrapped_jit__``) pass through for cost analysis.
     """
     jitted = jax.jit(fn, **jit_kwargs)
