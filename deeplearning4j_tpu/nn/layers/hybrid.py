@@ -707,6 +707,12 @@ _conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
 CORE_BYTES = 2 ** 28
 
 
+def _keep(tree):
+    """`tree`'s arrays tagged `REMAT_KEEP`: a block's 'full' remat keeps them
+    (`util/jaxcompat.py` has the rule); anywhere else the tag lowers to nothing."""
+    return jax.tree_util.tree_map(lambda a: checkpoint_name(a, REMAT_KEEP), tree)
+
+
 def rows_at_a_time(b: int, row_bytes: int, limit: int = CORE_BYTES) -> int:
     """The largest divisor of the batch whose rows stay within `limit`."""
     rows = min(b, max(1, limit // row_bytes))
@@ -733,7 +739,7 @@ def over_row_groups(core, arrays, rows: int, chunk: int = CHUNK):
     if rows == b:
         return core(*(a[0] for a in args))
     out = lax.map(jax.checkpoint(lambda a: core(*a)), args)
-    return jax.tree_util.tree_map(lambda y: checkpoint_name(y, REMAT_KEEP), out)
+    return _keep(out)
 
 
 @register_layer
@@ -1078,7 +1084,7 @@ class LatentAttention(Layer):
             # recompute stops at `ckr` and the norm (the docstring's last paragraph).
             # Under `proj`: XLA books the fusions that write them to the tag's
             # scope, and `attend` stays the kernels' own time
-            q, k, v = (checkpoint_name(a, REMAT_KEEP) for a in (q, k, kv[..., nope:]))
+            q, k, v = _keep((q, k, kv[..., nope:]))
         with device_scope("attend"):
             o = att.attend(q, k, v, causal=True, mask=mask)
         with device_scope("out"):
@@ -1250,6 +1256,22 @@ def _from_buffer_bwd(res, g):
 _from_buffer.defvjp(_from_buffer_fwd, _from_buffer_bwd)
 
 
+#: The most bytes of `h` — the first grouped product's output
+#: [capacity, wide x grouped_width(expert_width)], what the activation reads —
+#: that `RoutedExperts` tags `REMAT_KEEP`, compared with the traced array's own
+#: bytes. `h` is the narrow side of the block and two thirds of a swiglu
+#: expert's forward work to remake: kept, a block's 'full' recompute drops
+#: `xs Wgu` (PERF.md section 6, PR 50). At 2 x 8192 tokens in bfloat16 a
+#: layer's `h` is 288 MiB (Kanana), 320 (Qwen3-Next, and Laguna at 1 x 8192),
+#: 384 (LFM2, Nemotron) and 512 (Kimi-Linear) in the benchmark's six expert
+#: configurations, four expert layers each: the first three keep it. At 384
+#: Nemotron's step would hold 16.09 GB by `memory_analysis()`, over the 16e9
+#: its compile test allows. The cost is a LAYER's and the chip's memory is
+#: shared by all of them — `bound x expert layers` more under 'full'; a budget
+#: for the stack belongs to whoever owns the remat policy (ROADMAP S10 (e)).
+H_KEEP_BYTES = 320 * 2 ** 20
+
+
 @register_layer
 @dataclass
 class RoutedExperts(Layer):
@@ -1287,11 +1309,25 @@ class RoutedExperts(Layer):
     model width is padded and cut at token level; the parameters keep their
     published shapes, and aligned widths take the call they always took.
 
+    What a block's 'full' remat keeps of this layer (`REMAT_KEEP`; outside a
+    `jax.checkpoint`, and under every other policy, the tags lower to
+    nothing): `h`, the first product's output, where its bytes as traced are
+    at most `H_KEEP_BYTES`; the sort's `order`, `inv` and group sizes; the
+    router's logits and, under the sigmoid recipe, the chosen ids. The block's
+    recompute then neither sorts nor runs the router's product again (nor,
+    sigmoid, selects) and drops `xs Wgu`; it still gathers the buffer, applies
+    the activation and runs `act(h) Wd` (the router weights' gradient reads
+    its rows). The kept arrays have static shapes: nothing follows the
+    routing.
+
     State `counters` (int32, wrapping; per-fit differences are exact):
     `steps`, `load` [count] assignments routed to each held expert,
     `dropped`, `capacity` (buffer rows offered), `ratio_sum` (float32 sum
     over steps of max-over-mean load). `telemetry.fit_log()` reports them
-    per fit under `experts` (`counter_summary`)."""
+    per fit under `experts` (`counter_summary`), with `h_kept_mb`: the MB of
+    `h` a step that carry the tag as the training step was last traced, 0.0
+    beyond the bound — "tagged", since the layer cannot see whether a 'full'
+    checkpoint wraps it, and only there does the tag hold bytes."""
 
     n_experts: int = 512
     top_k: int = 10
@@ -1367,15 +1403,24 @@ class RoutedExperts(Layer):
             "load_max_over_mean": float(added["ratio_sum"][0]) / max(steps, 1),
             "dropped_assignments": dropped,
             "capacity_fill": (routed - dropped) / max(int(added["capacity"][0]), 1),
+            "h_kept_mb": getattr(self, "_h_kept_mb", 0.0),
         }
 
     def route(self, params, xf):
-        """(weights [n, top_k] float32, expert ids [n, top_k])."""
-        logits = jnp.matmul(xf.astype(F32), params["router"],
-                            precision=lax.Precision.HIGHEST)
+        """(weights [n, top_k] float32, expert ids [n, top_k]). The logits are
+        tagged `REMAT_KEEP`, so a block's 'full' recompute does not run the
+        router's product again, and the sigmoid recipe's ids too, so that it
+        does not select again either. (The softmax recipe's selection stays in
+        the recompute: `top_k`'s own derivative reads the ids it made, not a
+        tagged copy, and gathering the weights at tagged ids instead costs
+        more than the selection — 0.84 against 0.24 ms a layer on a v5e at
+        [8192, 256], PERF.md section 6, PR 50.)"""
+        logits = _keep(jnp.matmul(xf.astype(F32), params["router"],
+                                  precision=lax.Precision.HIGHEST))
         if self.scoring == "sigmoid":
             scores = jax.nn.sigmoid(logits)
-            _, idx = lax.top_k(scores + lax.stop_gradient(params["select_bias"]), self.top_k)
+            idx = _keep(lax.top_k(scores + lax.stop_gradient(params["select_bias"]),
+                                  self.top_k)[1])
             top = jnp.take_along_axis(scores, idx, axis=-1)
             if self.norm_topk:
                 top = top / (jnp.sum(top, axis=-1, keepdims=True) + self.norm_eps)
@@ -1401,6 +1446,8 @@ class RoutedExperts(Layer):
             bounds = jnp.minimum(starts, cap)
             sizes = bounds[1:] - bounds[:-1]
             sizes = sizes.at[-1].add(cap - bounds[-1])   # the padding is computed
+            # two argsorts to remake, under 2 MB to hold
+            order, inv, sizes = _keep((order, inv, sizes))
         # the buffer is born at the width the grouped product runs well at:
         # zero columns added to the TOKENS here and cut from the tokens below.
         # The barrier keeps XLA from moving the pad behind the gather, where it
@@ -1412,8 +1459,16 @@ class RoutedExperts(Layer):
             xs = _to_buffer(xf, order, inv, cap)
         act, wide, up = self._act()
         with device_scope("product"):
-            ys = ops.grouped_dot(act(ops.grouped_dot(xs, params[up], sizes, wide)),
-                                 params["Wd"], sizes)
+            h = ops.grouped_dot(xs, params[up], sizes, wide)
+            # most of the block's forward work and its narrow side: kept within the
+            # bound. The MB tagged are a fact of this trace: `apply` reports the
+            # training step's
+            h_bytes = h.size * h.dtype.itemsize
+            tagged = h_bytes <= H_KEEP_BYTES
+            if tagged:
+                h = _keep(h)
+            self._h_tagged_mb = tagged * h_bytes / 1e6
+            ys = ops.grouped_dot(act(h), params["Wd"], sizes)
         with device_scope("combine"):
             # a slot counts when its expert is held and its position is inside the
             # buffer; the rows of the others (the last group's padding) weigh 0
@@ -1444,6 +1499,7 @@ class RoutedExperts(Layer):
         if mask is not None:
             y = y * mask[..., None].astype(y.dtype)
         if train:
+            self._h_kept_mb = self._h_tagged_mb   # a fact of the traced shapes: no device work
             with device_scope("counters"):
                 c = state["counters"]
                 mean = jnp.maximum(jnp.mean(load.astype(F32)), 1e-9)

@@ -45,7 +45,9 @@ policy BY NAME — names lower to jax.checkpoint policies here:
     'full'            recompute the block; save nothing but what is tagged REMAT_KEEP
                       (a value that costs more to compute again than to keep: the
                       row groups' outputs, the flash forward's output and
-                      logsumexp, a latent-attention layer's q, k, v)
+                      logsumexp, a latent-attention layer's q, k, v, a
+                      routed-expert layer's first product within a byte bound,
+                      its sort, its router's logits)
     'offload'         save dot outputs to host memory (pinned_host)
 
 Booleans stay accepted where the old single `remat: bool` flag lived

@@ -18,18 +18,25 @@ import jax
 
 #: `jax.ad_checkpoint.checkpoint_name` tag for a value the 'full' remat
 #: policy keeps although it recomputes everything else: a value that costs
-#: more to compute again than to keep. Three uses, one rule: what comes back
+#: more to compute again than to keep. Four uses, one rule: what comes back
 #: from a sub-computation that is ITSELF a checkpoint (`hybrid.over_row_groups`:
 #: it reruns in its own backward, and would run a third time in the block's
 #: recompute), the flash forward kernel's output and logsumexp
 #: (`pallas_kernels._flash_vjp_fwd`: all its backward kernel needs beside
 #: q, k, v, so the block's recompute does not call the forward kernel again),
-#: and the q, k, v a latent-attention layer hands the kernels
+#: the q, k, v a latent-attention layer hands the kernels
 #: (`hybrid.LatentAttention`: two wide products, two rotations, a broadcast and
 #: a concatenate to remake — a 769 ms step of five such layers fell by 29.6 ms —
 #: for 0.57 GB a layer at [2, 32, 8192, 192 | 192 | 128] bfloat16; where q, k, v
 #: are ONE product and a rotation away, as in the other attention layers,
-#: they are recomputed).
+#: they are recomputed), and what a routed-expert layer's backward reads that
+#: is dear to remake and small to hold (`hybrid.RoutedExperts`): `h`, the first
+#: grouped product's output — two thirds of a swiglu expert's forward work,
+#: the narrow side of the block — where it is at most `hybrid.H_KEEP_BYTES`,
+#: the sort's `order`, `inv` and group sizes (two argsorts to remake, under
+#: 2 MB), the router's logits and, under the sigmoid recipe, the chosen ids (a
+#: float32 HIGHEST product and a selection to remake; a few MB). The recompute still gathers the buffer, applies the
+#: activation and runs `act(h) Wd`: outputs as large as a block's input.
 #: Outside a `jax.checkpoint` the tag lowers to nothing.
 REMAT_KEEP = "dl4j_remat_keep"
 
@@ -60,9 +67,11 @@ def remat_policy(name: Any):
     more to compute again than to keep: the output of an inner checkpoint
     (`hybrid.over_row_groups`), so that it is not run a third time, the
     flash forward kernel's output and logsumexp (`pallas_kernels`), so that
-    it is not run a second time, and a latent-attention layer's q, k, v
+    it is not run a second time, a latent-attention layer's q, k, v
     (`hybrid.LatentAttention`), so that its projections, rotations and
-    concatenate are not). Cached so the same name always returns
+    concatenate are not, and a routed-expert layer's first grouped product
+    within `hybrid.H_KEEP_BYTES`, its sort and its router's logits (sigmoid:
+    the chosen ids too) (`hybrid.RoutedExperts`)). Cached so the same name always returns
     the SAME callable: a fresh policy closure per call would defeat the jit
     trace cache."""
     n = canonical_policy(name)

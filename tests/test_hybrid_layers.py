@@ -375,6 +375,23 @@ def test_counters_reach_the_fit_log_once_a_fit(rng):
     assert total == 4                        # cumulative on the device
 
 
+@pytest.mark.parametrize("within", [True, False])
+def test_the_fit_log_says_how_much_of_h_is_tagged(within, rng, monkeypatch):
+    """`fit_log()['experts']` carries `h_kept_mb`: the MB of `h`, the first
+    grouped product's output, that carry `REMAT_KEEP` a step (float32 here:
+    no mixed precision), 0.0 beyond `hybrid.H_KEEP_BYTES`."""
+    if not within:
+        monkeypatch.setattr(hybrid, "H_KEEP_BYTES", 1024)
+    net = zoo.HybridMoELM(**ZOO_ARGS).init()
+    ids = rng.integers(0, 48, (2, T)).astype(np.int32)
+    net.fit(DataSet(ids, np.roll(ids, -1, 1).astype(np.int32)))
+    moe = RoutedExperts(n_experts=8, top_k=3, expert_width=16, experts_held=(2, 4),
+                        capacity_factor=2.0)
+    mb = moe.capacity(2 * T) * 2 * 16 * 4 / 1e6
+    experts = telemetry.fit_log()[-1]["experts"]
+    assert [e["h_kept_mb"] for e in experts] == [mb if within else 0.0] * 4
+
+
 @dataclasses.dataclass
 class RowCounter(Layer):
     """A second kind of counting layer, with no summary of its own."""
