@@ -87,21 +87,27 @@ def flash_bytes(cfg: dict, rows: int) -> int:
     return n_attn * (4 + 8) * rows * cfg["input"]["seq_len"] * width * 2
 
 
-def delta_scan_flops(cfg: dict, rows: int) -> int:
-    """The scan over chunks alone (the part that is sequential in time),
-    forward + backward, in every delta layer."""
-    n_delta, _ = _kinds(cfg)
-    return (3 * n_delta * rows * cfg["input"]["seq_len"]
-            * cfg["linear_num_value_heads"] * _scan_flops_per_token_head(cfg))
+def gdn_flops(cfg: dict, rows: int) -> int:
+    """Forward + backward of the chunk rule in every delta layer, its least
+    work whatever implements it (`delta_rule_flops` at the configured
+    length)."""
+    return delta_rule_flops(cfg, rows, cfg["input"]["seq_len"])
 
 
-def delta_scan_bytes(cfg: dict, rows: int) -> int:
-    """The least that scan can move, float32, the state never leaving the
-    chip: forward reads A [dk, dk] and B [dk, dv] of every chunk and writes
-    the state the chunk starts from; backward reads them and the states'
-    cotangents again and writes dA, dB: three times the forward's bytes."""
+def gdn_bytes(cfg: dict, rows: int) -> int:
+    """The least that rule can move, float32 as the configuration runs it, a
+    chunk's decays, scores, inverse and writes never leaving the chip and
+    q, k read once a KEY head: forward reads q, k (key heads), v, the decay g
+    and beta (a value head each) and writes o and the state every chunk
+    starts from; backward reads those, do and the states and writes dq, dk,
+    dv, dg, dbeta. Nothing recomputed: the forward a checkpoint runs again
+    is waste the roofline shows. (`kda_bytes` and `ssd_bytes` count the same
+    way.)"""
     n_delta, _ = _kinds(cfg)
     dk, dv = cfg["linear_key_head_dim"], cfg["linear_value_head_dim"]
-    per_chunk_head = (dk * dk + 2 * dk * dv) * 4
-    chunks = rows * cfg["input"]["seq_len"] // CHUNK
-    return 3 * n_delta * chunks * cfg["linear_num_value_heads"] * per_chunk_head
+    hk, hv = cfg["linear_num_key_heads"], cfg["linear_num_value_heads"]
+    state = hv * dk * dv / CHUNK                       # floats a token
+    inputs = 2 * hk * dk + hv * dv + 2 * hv
+    forward = inputs + hv * dv + state
+    backward = inputs + hv * dv + state + inputs
+    return int(n_delta * rows * cfg["input"]["seq_len"] * (forward + backward) * 4)

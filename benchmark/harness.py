@@ -66,8 +66,27 @@ def peaks(device_kind: str) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# the chip
+# the model and the chip
 # ---------------------------------------------------------------------------
+def require_model(cfg: dict) -> None:
+    """Stop at once, with no result line, when this program cannot build the
+    configuration: its zoo has no such class, or the class does not take
+    the configuration's arguments. Builds `zoo.<class>(**args)` — no array,
+    no device — so that a parent without the model fails BEFORE the process
+    waits for the chip (it took 13-135 s to say so: PERF.md section 7,
+    PR 38 (e), PR 40 (f))."""
+    if "program" not in cfg:
+        return
+    name = cfg["program"]["zoo"]
+    try:
+        from benchmark import program
+
+        program.model(cfg)
+    except (ImportError, AttributeError, TypeError, ValueError) as e:
+        raise SystemExit(f"benchmark: this program cannot build zoo.{name} "
+                         f"as the configuration gives it: {type(e).__name__}: {e}")
+
+
 def require_chips(chips: int) -> dict:
     """Exit non-zero, with no result line, unless JAX's devices are exactly
     the TPU chips the cell asks for. No fallback, no smaller size."""
@@ -120,9 +139,11 @@ def enable_compile_cache() -> str:
 
 class CompileCounter:
     """Counts XLA backend compilations through JAX's own monitoring events
-    — a host count that costs the hot path nothing. (The program's
-    CompileWatcher counts only while its telemetry is on, which puts spans
-    on the path being timed.)"""
+    — a host count that costs the hot path nothing, and the benchmark's own:
+    `compiles_in_window` is held to 0 by `correct` whatever the program
+    reports. (The program's `compile_count()` reads its compile account,
+    gate on or off, since PR 49: the window fit's `compile.backend_compiles`
+    is this count's inside twin.)"""
 
     EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -141,28 +162,74 @@ class CompileCounter:
 # the clock of set-up
 # ---------------------------------------------------------------------------
 class Setup:
-    """setup_s = process start to the first measured step or request, less
-    the time spent in the plain reference (which every run pays, but which
-    is the yardstick's cost and not the system's)."""
+    """`setup_s` = from the instant the runtime has the chip (`chip_ready`:
+    `require_chips` returned) to the first measured step or request, less
+    the time spent in the plain reference — its seeded weights, its steps,
+    their compiles: every run pays them, but they are the yardstick's cost
+    and not the system's — plus what the program was asked for before the
+    chip (`early()`: building the configuration, which imports the package).
+    The package's import, `init`, placement, tracing, lowering, compiling or
+    the cache's read are all inside it.
+
+    `launch_s` = process start to `chip_ready` less those early seconds: the
+    interpreter, JAX, libtpu reaching the chip. The launcher's and the
+    runtime's, 10.8-17.2 s that swing by themselves (PERF.md section 6,
+    PR 49); printed, reported in the result's `device`, no metric."""
 
     def __init__(self, t0: float):
         self.t0 = t0
-        self.reference_s = 0.0
+        self.t_ready = None
+        self.early_s = 0.0
+        self.reference_parts = {}
 
     def mark(self, what: str) -> None:
         print(f"[bench +{time.perf_counter() - self.t0:7.2f}s] {what}", flush=True)
 
     @contextlib.contextmanager
-    def reference(self):
-        """Time spent inside is the reference's, not set-up."""
+    def _timed(self, add):
         t = time.perf_counter()
         try:
             yield
         finally:
-            self.reference_s += time.perf_counter() - t
+            add(time.perf_counter() - t)
+
+    def early(self):
+        """The program's seconds before the chip is asked for: set-up."""
+        def add(s):
+            self.early_s += s
+        return self._timed(add)
+
+    def chip_ready(self) -> None:
+        self.t_ready = time.perf_counter()
+
+    @property
+    def launch_s(self) -> float:
+        return self.t_ready - self.t0 - self.early_s
+
+    def reference(self, part: str = "steps"):
+        """Time spent inside is the reference's, not set-up."""
+        def add(s):
+            self.reference_parts[part] = self.reference_parts.get(part, 0.0) + s
+        return self._timed(add)
+
+    @property
+    def reference_s(self) -> float:
+        return sum(self.reference_parts.values())
 
     def setup_s(self, window_start: float) -> float:
-        return window_start - self.t0 - self.reference_s
+        return window_start - self.t_ready + self.early_s - self.reference_s
+
+    def report(self, window_start: float) -> str:
+        """One line: both clocks from the same run (the clock before PR 51
+        ran from process start and left the seeded weights in; PERF.md's
+        set-up table compares them)."""
+        new = self.setup_s(window_start)
+        old = new + self.launch_s + self.reference_parts.get("weights", 0.0)
+        parts = ", ".join(f"{k} {v:.2f}" for k, v in self.reference_parts.items())
+        return (f"[bench] launch_s {self.launch_s:.2f} (no metric); reference "
+                f"{self.reference_s:.2f}s (not in setup_s: {parts}); setup_s {new:.2f} "
+                f"(process start to window less the reference's steps, "
+                f"the clock before PR 51: {old:.2f})")
 
 
 # ---------------------------------------------------------------------------

@@ -1,9 +1,11 @@
 """Per cent of the train step's device time in the PRIMAL forward pass:
 operations under any device scope whose name stack has no `transpose(`
-wrapper (`benchmark/scope_reduce.py`). Under remat 'full' the backward
-region runs about as much again as recompute (the printed table's
-`(recompute)` column: what sits under `rematted_computation`). Left out for
-a program without scopes."""
+wrapper and no part `grad` (`benchmark/scope_reduce.py`: the gradient
+products a `custom_vjp`'s forward rule makes ahead of the backward pass —
+the row-blocked head's dz, dx and dW since PR 45 — are the backward
+region's). Under remat 'full' the backward region runs about as much again
+as recompute (`recompute_share_of_step.train`). Left out for a program
+without scopes."""
 from benchmark import scope_reduce
 
 

@@ -2,10 +2,10 @@
 between its projections and AROUND its chunk rule, both passes: the parts
 `conv` + `gates` + `norm_gate` + `retile` + `counters` of the kinds that open
 a `rule` (`GatedDeltaNet`, `KimiDeltaAttention`, `Mamba2Mixer`). With
-`mixer_rule_share_of_step.train` it is the core that
-`delta_core_share_of_step` / `kda_share_of_step` / `ssd_share_of_step` find by
-shapes, plus the re-tiling outside the row loops. Left out where no `rule`
-ran under a scope."""
+`mixer_rule_share_of_step.train` and the rows that carry no part (the row
+loops' own slicing) it is what `delta_core_share_of_step` /
+`kda_share_of_step` / `ssd_share_of_step` read. Left out where no `rule` ran
+under a scope."""
 from benchmark import scope_reduce
 
 AROUND = ("conv", "gates", "norm_gate", "retile", "counters")

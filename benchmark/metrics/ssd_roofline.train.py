@@ -1,23 +1,14 @@
-"""The state-space core against the roofline of its recurrence: the least
-time the chip could take for the chunked recurrence's operations and bytes
+"""The state-space chunk rule against its roofline: the least time the chip
+could take for the chunked recurrence's operations and bytes
 (benchmark/flops: `ssd_flops`, `ssd_bytes`; the larger of ops / peak FLOP/s
 and bytes / peak B/s — the bytes, with a chunk's decays and scores kept on
-the chip) over the device time of the core's `while` operations on chip 0
-(`ssd_share_of_step.train`: the convolution, the gate and the norm run inside
-them and are in the time, not in the least work)."""
-from benchmark import harness
-
-_share = harness.module("metrics", "ssd_share_of_step.train")
+the chip) over the device seconds under the mixer's `rule` scope ALONE,
+chip 0 — the `dl4j_ssd_fwd` + `dl4j_ssd_bwd` kernels and the small XLA split
+in front of them today, whatever implements the rule tomorrow. The forward
+the block's remat runs again is in the seconds and not in the least work."""
+from benchmark import scope_reduce
 
 
 def read(run):
-    _, runs = run.trace.main_module()
-    if not runs or not hasattr(run.flops, "ssd_flops"):
-        return None
-    measured = _share.core_seconds(run)
-    if not measured:
-        return None
-    rows = run.counters["rows_per_step"] // run.cell["chips"]
-    least = max(run.flops.ssd_flops(run.cfg, rows) / run.peaks["bf16_flops_per_s"],
-                run.flops.ssd_bytes(run.cfg, rows) / run.peaks["hbm_bytes_per_s"])
-    return 100.0 * least * len(runs) / measured
+    return scope_reduce.roofline(run, scope_reduce.rule_of("mamba2mixer"),
+                                 "ssd_flops", "ssd_bytes")

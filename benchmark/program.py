@@ -13,11 +13,22 @@ import jax
 import jax.numpy as jnp
 
 
+def model(cfg: dict):
+    """`zoo.<class>(**args)` as the configuration's `program` gives them:
+    the model's description, no array and no device."""
+    from deeplearning4j_tpu import zoo
+
+    prog = cfg["program"]
+    args = {k: tuple(v) if isinstance(v, list) else v
+            for k, v in prog["args"].items()}
+    return getattr(zoo, prog["zoo"])(**args)
+
+
 def build_net(cfg: dict):
-    """`zoo.<class>(**args)` with the configuration's precision policy and
-    learning rate, initialised by the program (the weights are replaced by
+    """The model with the configuration's precision policy and learning
+    rate, initialised by the program (the weights are replaced by
     `install`)."""
-    from deeplearning4j_tpu import dtypes, zoo
+    from deeplearning4j_tpu import dtypes
     from deeplearning4j_tpu.models import ComputationGraph, MultiLayerNetwork
     from deeplearning4j_tpu.nn.graph_conf import ComputationGraphConfiguration
 
@@ -25,9 +36,7 @@ def build_net(cfg: dict):
     if prog["precision"] not in ("mixed_bf16", "float32"):
         raise ValueError(f"unknown precision {prog['precision']!r}")
     dtypes.set_mixed_precision(prog["precision"] == "mixed_bf16")
-    args = {k: tuple(v) if isinstance(v, list) else v
-            for k, v in prog["args"].items()}
-    conf = getattr(zoo, prog["zoo"])(**args).conf()
+    conf = model(cfg).conf()
     lr = cfg["optimizer"]["args"]["learning_rate"]
     conf.defaults.updater.learning_rate = lr
     graph = isinstance(conf, ComputationGraphConfiguration)

@@ -10,6 +10,8 @@ result line unless JAX's devices are exactly the TPU chips the cell asks for
 (no CPU fallback, no smaller size). The last line of standard output is the
 result object of the contract; with --trace 0 it carries the cell's
 end-to-end metrics, with --trace 1 its per-layer metrics and `breakdown`.
+`setup_s` runs from the instant the runtime has the chip (`harness.Setup`);
+the seconds before it are `launch_s` in the result's `device`.
 """
 from __future__ import annotations
 
@@ -69,7 +71,12 @@ def main(argv=None) -> int:
     args = parse(argv)
     cell = harness.load_cell(args.workload)
     setup = harness.Setup(T0)
+    import jax  # noqa: F401  the launcher's seconds, with libtpu below
+
+    with setup.early():
+        harness.require_model(cell["cfg"])          # SystemExit: no such model here
     device = harness.require_chips(cell["chips"])   # SystemExit off-chip
+    setup.chip_ready()
     peaks = harness.peaks(device["kind"])
     cache = harness.enable_compile_cache()
     setup.mark(f"cell {cell['name']} seed {args.seed} on {device['count']} x "
@@ -81,6 +88,7 @@ def main(argv=None) -> int:
 
     correct = harness.print_checks(out["checks"])
     device["memory_peak_bytes"] = harness.memory_peak_bytes()
+    device["launch_s"] = setup.launch_s
     print(f"[bench] memory_stats {harness.memory_stats()}", flush=True)
     values = dict(out["values"])
     values["setup_s"] = setup.setup_s(out["window_start"])
@@ -109,8 +117,7 @@ def main(argv=None) -> int:
     result["metrics"] = {n: {"value": float(v), "unit": units[n]}
                          for n, v in metrics.items()}
     result["device"] = device
-    print(f"[bench] reference {setup.reference_s:.2f}s (not in setup_s); "
-          f"setup_s {values['setup_s']:.2f}", flush=True)
+    print(setup.report(out["window_start"]), flush=True)
     harness.emit(result)
     return 0
 

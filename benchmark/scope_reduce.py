@@ -19,6 +19,11 @@ to the INNERMOST `XLA Ops` event open on it (`span_reduce.exclusive`: a
 says (layer, kind, part path, pass). Pass = forward when no `transpose(`
 wrapper is on the stack, else the backward region; inside it a
 `rematted_computation` component marks what a `jax.checkpoint` runs again.
+The ACCOUNT books the part `grad` to the backward region too, whatever its
+stack says (`parse` reports the stack as it is): it is what a `custom_vjp`'s
+FORWARD rule makes of the gradient ahead of the backward pass
+(`losses.sparse_xent_weighted`, PR 45: a row block's dz, dx and share of dW
+while the block's logits are there) and carries no `transpose(`.
 An operation with no `dl4j.` component is `unscoped`; time inside a run with
 no operation open is `no operation`.
 
@@ -30,8 +35,14 @@ rewrites `lax.ragged_dot` into a custom call `ragged-dot-none.N` whose
 `op_name` is "ragged-dot-none" and nothing else (my chip runs, PR 35: 130 ms
 of Qwen3-Next's 933 ms step). Such an operation is known by its instruction's
 name alone (`ADOPTED`): it is booked to the part that name stands for, in
-the layer and pass of the last operation before it that carries a scope of
-the same kind.
+the layer of the last operation before it that carries a scope of the same
+kind, and in the region its OPERANDS say (the event's name is the whole
+instruction, operands included, and the operations that made them ran
+before it): backward where one of them was made by the backward pass (a
+cotangent), else recompute where one was recomputed, else — all of them
+parameters, kept values or primal results — the neighbour's region. (The
+neighbour alone is not enough: XLA schedules a recomputed gather right in
+front of the backward's first grouped product.)
 
 A program without scopes (the parent of PR 35) gives `None` everywhere and
 the result line leaves the metrics out. A program WITH the seam whose trace
@@ -60,6 +71,9 @@ LAYER = re.compile(r"^L([A-Za-z0-9_-]+)\.([a-z0-9_]+)$")
 TRANSFORM = re.compile(r"^(jvp|transpose|vmap)\((.*)\)$")
 RECOMPUTE = "rematted_computation"
 CHECKPOINT = "checkpoint"
+#: the part a custom_vjp's forward rule makes the gradient's products under
+GRADIENT = "grad"
+OPERAND = re.compile(r"%([A-Za-z_][\w.\-]*)")
 UNSCOPED, NO_OP = "unscoped", "no operation"
 #: instruction name's beginning, where the compiler strips its stack -> (kind, parts) it is
 #: the work of: XLA's grouped product, called from `ops.linear.grouped_dot`
@@ -292,6 +306,11 @@ class Account:
         return sum(r[0] for (_, kind, _), r in self.rows.items()
                    if kind not in (UNSCOPED, NO_OP))
 
+    @property
+    def recompute_s(self) -> float:
+        """What runs under `rematted_computation`: a checkpoint's forward again."""
+        return sum(r[2] for r in self.rows.values())
+
     def mixers(self) -> set:
         """The kinds that open a `rule`: the recurrent mixers."""
         return {kind for (_, kind, parts) in self.rows if parts[:1] == ("rule",)}
@@ -364,6 +383,27 @@ def _resolve(metas, program_id):
     return metas[0]
 
 
+def _booked(scope):
+    """The scope as the account books it: gradient products made in a
+    forward visit (part `grad`) are the backward region's."""
+    if scope is not None and GRADIENT in scope.parts:
+        return scope._replace(backward=True)
+    return scope
+
+
+def _region(scope, name, made):
+    """`scope` with the region the operands of instruction `name` say:
+    `made` {instruction: Scope} of the operations that ran before it."""
+    head = name.split(" = ", 1)
+    operands = [made[o] for o in OPERAND.findall(head[1] if len(head) > 1 else "")
+                if made.get(o) is not None]
+    if any(o.backward and not o.recompute for o in operands):
+        return scope._replace(backward=True, recompute=False)
+    if any(o.recompute for o in operands):
+        return scope._replace(backward=True, recompute=True)
+    return scope
+
+
 def account(ops, module_name, runs, metadata, parts=None):
     """`ops` [(start, end, event name)] of one chip's `XLA Ops` line, the
     main program's name and `runs` [(start, end)], `metadata` from
@@ -380,23 +420,25 @@ def account(ops, module_name, runs, metadata, parts=None):
     for name in {n for _, _, n in inside}:
         stats = _resolve(metadata[name], program_id) if name in metadata else None
         found = found or stats is not None
-        by_name[name] = ((parse(stats["tf_op"], words), stats.get("hlo_category", ""))
+        by_name[name] = ((_booked(parse(stats["tf_op"], words)), stats.get("hlo_category", ""))
                          if stats else (None, "no metadata"))
     if not found:
         return None
     # one scope an EVENT, in the order they open: what the compiler stripped
-    # of its stack adopts the layer and pass of the last scoped event of its kind
-    scopes, last = [], {}
+    # of its stack adopts the layer of the last scoped event of its kind and
+    # the region of what made its operands
+    scopes, last, made = [], {}, {}
     for _, _, name in inside:
         scope, category = by_name[name]
+        short = trace_reduce.short(name)
         if scope is not None:
             last[scope.kind] = scope
         else:
-            short = trace_reduce.short(name)
             kind, parts = next((v for k, v in ADOPTED.items() if short.startswith(k)),
                                (None, ()))
             if kind in last:
-                scope = last[kind]._replace(parts=parts)
+                scope = _region(last[kind]._replace(parts=parts), name, made)
+        made[short] = scope
         scopes.append((scope, category))
         acct.call(scope)
     covered = 0
@@ -455,6 +497,41 @@ def scope_account(run):
                       "cache (docs/TELEMETRY.md, Device scopes)", flush=True)
         _cache[path] = acct
     return _cache[path]
+
+
+def between_projections(kind: str):
+    """`keep` for what a mixer of `kind` does between its projections: every
+    part under its scope but `proj` and `out` — the convolutions, gates,
+    chunk rule, norm and gate, re-tiling, counters, and the partless rows
+    (the row loops' own slicing while the core is mapped over rows).
+    Whatever holds that work — a `while` over row groups, one call for all
+    rows, a jitted function — it reads the same."""
+    return lambda layer, k, parts: k == kind and parts[:1] not in (("proj",), ("out",))
+
+
+def rule_of(kind: str):
+    """`keep` for the chunk rule alone of a mixer of `kind`."""
+    return lambda layer, k, parts: k == kind and parts[:1] == ("rule",)
+
+
+def roofline(run, keep, flops: str, bytes_: str):
+    """Per cent of its roofline at which the work under `keep` ran: the
+    least time the chip could take for the operations and bytes that
+    `run.flops.<flops>` / `<bytes_>` count for a step (the larger of ops /
+    peak FLOP/s and bytes / peak B/s) over the device seconds under `keep`,
+    both passes and what is recomputed — a kernel run twice halves its
+    share. None where the configuration's flops file has no such functions,
+    there is no account or nothing ran under `keep`."""
+    if not hasattr(run.flops, flops):
+        return None
+    acct = scope_account(run)
+    measured = acct.seconds(keep) if acct is not None else 0.0
+    if not measured:
+        return None
+    rows = run.counters["rows_per_step"] // run.cell["chips"]
+    least = max(getattr(run.flops, flops)(run.cfg, rows) / run.peaks["bf16_flops_per_s"],
+                getattr(run.flops, bytes_)(run.cfg, rows) / run.peaks["hbm_bytes_per_s"])
+    return 100.0 * least * acct.steps / measured
 
 
 def share(run, keep=None):

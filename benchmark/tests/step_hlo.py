@@ -15,7 +15,8 @@ last component of its name stack) and, from each Mosaic kernel's payload,
 the debug locations (a payload carries the file and line of every Python
 frame above its `pallas_call`). `memory_analysis()` goes in a line of its
 own. One process a checkout: each imports its own `deeplearning4j_tpu`.
-PR 35 (device scopes): all four steps equal the parent's.
+PR 35 (device scopes): all four steps equal the parent's. All eight
+configurations since PR 51 (11 min for the first four, ~7 for the rest).
 """
 import base64
 import os
@@ -24,7 +25,9 @@ import sys
 
 CELLS = [("gpt2-small", "train_ids_t1024_b8"), ("qwen3-next-80b-a3b-l4", "train_ids_t8192_b2"),
          ("nemotron-3-nano-30b-a3b-l9", "train_ids_t8192_b2"),
-         ("kimi-linear-48b-a3b-l5", "train_ids_t8192_b2")]
+         ("kimi-linear-48b-a3b-l5", "train_ids_t8192_b2"),
+         ("kanana-2-30b-a3b-l5", "train_ids_t8192_b2"), ("lfm2-24b-a2b-l5", "train_ids_t8192_b2"),
+         ("ouro-2.6b-l6", "train_ids_t8192_b1"), ("laguna-s-2.1-l5", "train_ids_t8192_b1")]
 TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
 
 
@@ -56,6 +59,27 @@ def canonical(text: str) -> str:
     names = {}
     return re.sub(r"%[A-Za-z_][\w.\-]*",
                   lambda m: names.setdefault(m.group(0), f"%v{len(names)}"), text)
+
+
+def entry_events(text: str, ns=lambda instruction: 1):
+    """A compiled step's ENTRY computation as a device trace would show it,
+    without a chip: the scheduled instructions in order, back to back, `ns`
+    nanoseconds each -> (`ops` [(start, end, instruction)], `metadata`
+    {instruction: [stats]}) for `scope_reduce.account`. The instruction is
+    the whole text less its `metadata={..}`, as a trace event's name is."""
+    entry = text[text.index("\nENTRY "):]
+    ops, metadata, t = [], {}, 0
+    for line in entry[:entry.index("\n}\n")].split("\n")[1:]:
+        line = line.strip().removeprefix("ROOT ").strip()
+        if not line.startswith("%"):
+            continue
+        name = re.sub(r",? ?metadata=\{[^}]*\}", "", line)
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        if op_name:
+            metadata[name] = [{"tf_op": op_name.group(1) + ":", "program_id": "7"}]
+        ops.append((t, t + ns(name), name))
+        t += ns(name)
+    return ops, metadata
 
 
 def dump(out_dir: str, only=()) -> None:

@@ -40,8 +40,7 @@ def test_kernel_least_costs():
     t = 8192
     # bf16 q k v o forward, q k v o do dq dk dv backward, 16 heads of 256
     assert f.flash_bytes(c, 2) == 12 * 2 * t * 4096 * 2
-    assert f.delta_scan_flops(c, 2) == 3 * 3 * 2 * t * 32 * 4 * 128 * 128
-    # float32 A [128, 128], B [128, 128] in, S out, a chunk and head; x 3 with the backward
-    assert f.delta_scan_bytes(c, 2) == 3 * 3 * (2 * t // 64) * 32 * 3 * 128 * 128 * 4
-    # the scan is bound by its bytes on a v5e (197 TFLOP/s, 819 GB/s)
-    assert f.delta_scan_bytes(c, 2) / 819e9 > f.delta_scan_flops(c, 2) / 197e12
+    # the chunk rule with a chunk kept on the chip (PR 51; by hand in test_scoped_readers.py):
+    # bound by its bytes on a v5e (197 TFLOP/s, 819 GB/s)
+    assert f.gdn_flops(c, 2) == f.delta_rule_flops(c, 2, t)
+    assert f.gdn_bytes(c, 2) / 819e9 > f.gdn_flops(c, 2) / 197e12

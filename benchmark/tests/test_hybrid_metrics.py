@@ -72,29 +72,14 @@ def read(name, run):
 def test_shares_of_the_step():
     run = view()
     assert read("flash_share_of_step.train", run) == pytest.approx(10.0)
-    assert read("delta_scan_share_of_step.train", run) == pytest.approx(30.0)
-    assert read("expert_share_of_step.train", run) == pytest.approx(10.0)
-
-
-def test_delta_scan_roofline_by_hand():
-    run = view()
-    f = run.flops
-    least = max(f.delta_scan_flops(run.cfg, 2) / 197e12,
-                f.delta_scan_bytes(run.cfg, 2) / 819e9)
-    assert read("delta_scan_roofline.train", run) == pytest.approx(
-        100 * least * 2 / 600e-9)
 
 
 def test_nothing_to_read_is_none_not_an_error():
-    bare = view(with_layers=False)
-    for name in ("flash_share_of_step.train", "delta_scan_share_of_step.train",
-                 "delta_scan_roofline.train", "expert_share_of_step.train"):
-        assert read(name, bare) is None, name
+    """A kernel found by its name: the scope readers' cases are
+    test_scoped_readers.py's."""
+    assert read("flash_share_of_step.train", view(with_layers=False)) is None
     gpt2 = view("gpt2-small", rows=8)          # the layers' events, another model
     assert read("flash_share_of_step.train", gpt2) == pytest.approx(10.0)
-    for name in ("delta_scan_share_of_step.train", "delta_scan_roofline.train",
-                 "expert_share_of_step.train"):
-        assert read(name, gpt2) is None, name
 
 
 def test_expert_counters_come_from_the_windows_fit(monkeypatch):

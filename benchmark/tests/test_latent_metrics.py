@@ -115,4 +115,6 @@ def test_benchmark_json_lists_them_where_they_read():
     for m in (latent, rope):
         assert (m["layer"], m["moves"], m["source"], m["unit"]) == (
             "kernels", "train_throughput", "device_trace", "%")
-    assert [m["name"] for m in bench["per_layer"][-2:]] == [latent["name"], rope["name"]]
+    for cell in latent["workloads"]:
+        assert latent["name"] in {m["name"] for m in harness.load_cell(cell)["per_layer"]}
+    assert rope["name"] in {m["name"] for m in harness.load_cell("kanana2_train_t8192")["per_layer"]}

@@ -15,16 +15,19 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 SMALL = os.path.join(HERE, "small_tpu.xplane.pb")
 SCOPED = os.path.join(HERE, "scoped_tpu.xplane.pb")
 READERS = ("step_scoped_share.train", "head_loss_share_of_step.train",
-           "forward_share_of_step.train", "expert_product_share_of_step.train",
+           "forward_share_of_step.train", "recompute_share_of_step.train",
+           "expert_product_share_of_step.train",
            "mixer_rule_share_of_step.train", "mixer_around_rule_share_of_step.train",
-           "delta_solve_share_of_step.train")
+           "expert_share_of_step.train", "delta_core_share_of_step.train",
+           "kda_share_of_step.train", "ssd_share_of_step.train", "gdn_roofline.train",
+           "kda_roofline.train", "ssd_roofline.train")
 PARTS = frozenset({"proj", "rule", "solve", "scan", "conv", "product", "norm"})
 # what the recorded scoped trace reads (nanoseconds over its three steps:
 # forward, backward region, of it recompute, calls; per cent of the step)
 STEP_NS = 1151872
 ATTEND_L2 = [24565, 78720, 21992, 36]
 LOSS = [132626, 208742, 65133, 162]
-SCOPED_SHARE, LOSS_SHARE, FORWARD_SHARE = 87.78745, 29.63593, 26.82078
+SCOPED_SHARE, LOSS_SHARE, FORWARD_SHARE, RECOMPUTE_SHARE = 87.78745, 29.63593, 26.82078, 15.30648
 
 
 # ---------------------------------------------------------------------------
@@ -164,7 +167,7 @@ def fake_run(tmp_path, monkeypatch, trace_file=None):
         with open(trace_file, "rb") as f:
             (d / "host.xplane.pb").write_bytes(f.read())
         red = trace_reduce.reduce_file(trace_file, 1)
-    return NS(cell={"name": "cell", "chips": 1}, trace=red)
+    return NS(cell={"name": "cell", "chips": 1}, trace=red, flops=None, counters={}, peaks={})
 
 
 @pytest.mark.parametrize("name", READERS)
@@ -215,7 +218,8 @@ def test_scope_table_of_the_recorded_scoped_step(scoped, tmp_path, monkeypatch, 
     assert values["step_scoped_share.train"] == pytest.approx(SCOPED_SHARE, abs=1e-3)
     assert values["head_loss_share_of_step.train"] == pytest.approx(LOSS_SHARE, abs=1e-3)
     assert values["forward_share_of_step.train"] == pytest.approx(FORWARD_SHARE, abs=1e-3)
-    assert {values[n] for n in READERS[3:]} == {None}               # no experts, no mixer
+    assert values["recompute_share_of_step.train"] == pytest.approx(RECOMPUTE_SHARE, abs=1e-3)
+    assert {values[n] for n in READERS[4:]} == {None}               # no experts, no mixer
     assert capsys.readouterr().out.count("device ms a step by dl4j scope") == 2
 
 

@@ -126,4 +126,9 @@ def test_a_program_without_the_model_stops_at_once():
     cfg = tiny_ids.qwen3_next()
     cfg["program"]["zoo"] = "NoSuchModel"
     with pytest.raises(SystemExit):
-        tsi.require_model(cfg)
+        harness.require_model(cfg)
+    cfg = tiny_ids.qwen3_next()
+    cfg["program"]["args"]["no_such_argument"] = 1          # the class is there, not this model
+    with pytest.raises(SystemExit):
+        harness.require_model(cfg)
+    harness.require_model(tiny_ids.qwen3_next())            # builds: no array, no device

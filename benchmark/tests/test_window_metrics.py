@@ -137,7 +137,7 @@ def test_benchmark_json_lists_them_for_the_one_cell():
     with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     by_name = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"][-4:]] == list(NAMES)       # appended, in order
+    assert set(NAMES) <= set(by_name)                                         # listed
     for name in NAMES:
         m = by_name[name]
         assert m["workloads"] == ["laguna_train_t8192_b1"] and m["moves"] == "train_throughput"

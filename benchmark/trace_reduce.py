@@ -10,7 +10,8 @@ Pallas kernel's instruction carries its `kernel_name`:
 "%transpose_jvp_dl4j_flash_bwd_dkv_bh96_t1024_..."). Host threads are lines
 of the plane "/host:CPU": the runtime's own spans ("XlaLinearize" is the
 host re-tiling an array for the device, "np.asarray(jax.Array)" the host
-waiting for a result) and the benchmark's `TraceAnnotation`s ("bench.*"),
+waiting for a result), the benchmark's `TraceAnnotation`s ("bench.*") and
+the program's own spans ("dl4j.score_wait", "dl4j.dispatch", ...: PR 24),
 on the same clock.
 
     python3 -m benchmark.trace_reduce <file.xplane.pb> [chips]   # a summary
@@ -28,10 +29,11 @@ MODULES_LINE = "XLA Modules"
 COLLECTIVE = re.compile(
     r"^(all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)")
 HOST_NAMES = re.compile(
-    r"^(bench\.|XlaLinearize$|np\.asarray\(jax\.Array\)|PjitFunction\(|"
+    r"^(bench\.|dl4j\.|XlaLinearize$|np\.asarray\(jax\.Array\)|PjitFunction\(|"
     r"DevicePut|shard_args$|tpu::System::TransferToDevice$)")
 KERNEL = re.compile(r"dl4j_[a-z]+(?:_[a-z]+)*?(?=_(?:bh|n|b)\d)")
 SHAPE = re.compile(r"[a-z]+[0-9]*\[[0-9,]*\]")
+BREAKDOWN_ROWS = 10     # entries a list of the result's `breakdown` may hold (the contract)
 
 
 def union(intervals):
@@ -75,9 +77,9 @@ def short(name: str) -> str:
 
 def family(name: str) -> str:
     """A stable name for an operation: a Pallas kernel is its family
-    (dl4j_flash_fwd, dl4j_flash_bwd_dq, ...) whatever the layer and the
-    autodiff prefix; everything else loses its numeric suffix
-    (fusion.123 -> fusion)."""
+    (dl4j_flash_fwd, dl4j_flash_bwd, dl4j_gdn_fwd, ...) whatever the layer,
+    the shapes in its name and the autodiff prefix; everything else loses
+    its numeric suffix (fusion.123 -> fusion)."""
     s = short(name)
     m = KERNEL.search(s)
     if m:
@@ -176,8 +178,9 @@ class Reduction:
         """Seconds of the chip's idle time that each kind of host span
         overlaps. Spans nest and run on several threads, so the shares are
         not exclusive: each says how much of the idle time that activity
-        was going on in. The program's own spans are not on this clock yet
-        (PERF.md section 7); "no host span" is idle time none overlaps."""
+        was going on in — the program's `dl4j.step` holds its
+        `dl4j.score_wait`, and both count (`span_reduce.idle_by_span` is the
+        exclusive attribution). "no host span" is idle time none overlaps."""
         gaps = self.idle_gaps(chip)
         by = defaultdict(list)
         for s, e, name in self.host:
@@ -192,20 +195,24 @@ class Reduction:
         return out
 
     def device_ops(self, chip=0):
-        """The ten operations (kernels by family, the rest by instruction)
-        that took most device time: [[label, seconds], ...]."""
+        """[[label, seconds], ...], `BREAKDOWN_ROWS` entries by seconds: every `dl4j_*` kernel family that ran, WHATEVER ITS RANK —
+        a family under 1 % of a step never reached the ten largest, and a
+        claim on it had no row (PERF.md section 7, PR 41 (c)) — and beside
+        them the largest other operations, by instruction."""
         by = defaultdict(list)
         for s, e, name in self.ops[chip]:
             by[describe(name)].append((s, e))
-        ops = sorted(((total(union(v)) / 1e9, k) for k, v in by.items()),
-                     reverse=True)[:10]
-        return [[k, v] for v, k in ops]
+        ranked = sorted(((total(union(v)) / 1e9, k) for k, v in by.items()), reverse=True)
+        kernels = [r for r in ranked if r[1].startswith("dl4j_")][:BREAKDOWN_ROWS]
+        others = [r for r in ranked
+                  if not r[1].startswith("dl4j_")][:BREAKDOWN_ROWS - len(kernels)]
+        return [[k, v] for v, k in sorted(kernels + others, reverse=True)]
 
     def idle_gaps_by_host(self, chip=0):
         """The ten kinds of host span that overlap most of the chip's idle
         time: [[span, seconds of idle time it overlaps], ...]."""
         idle = sorted(((v, k) for k, v in self.idle_by_host_span(chip).items()
-                       if v > 0), reverse=True)[:10]
+                       if v > 0), reverse=True)[:BREAKDOWN_ROWS]
         return [[k, v] for v, k in idle]
 
     def breakdown(self) -> dict:

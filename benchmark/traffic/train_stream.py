@@ -130,6 +130,34 @@ def program_numbers(net, pw, stream, log, ref_mod, cfg, params0, steps):
             "delta_norms": delta}
 
 
+def step_report(times, t_open: float) -> dict:
+    """The window's steps by the host clock of their listeners: the median
+    interval, the three longest with their step numbers, and the program's
+    own phase account of the fit (calls, total and longest of each). A stall
+    of the process shows as a rate that fell beside a median that did not
+    (`step_wall_median_ms.train` reads `median_s`)."""
+    from deeplearning4j_tpu import telemetry
+
+    gaps = np.diff(np.concatenate([[t_open], times]))
+    longest = np.argsort(-gaps)[:3]
+    log = getattr(telemetry, "fit_log", None)
+    phases = log()[-1].get("phases", {}) if log and log() else {}
+    return {"steps": len(gaps),
+            "median_s": round(float(np.median(gaps)), 4) if len(gaps) else None,
+            "longest_s": {int(i) + 1: round(float(gaps[i]), 4) for i in longest},
+            "phases": {k: (v["calls"], round(v["total_s"], 3), round(v["max_s"], 4))
+                       for k, v in phases.items()}}
+
+
+def window_counters(n, rows, elapsed, compiled, steps: dict) -> dict:
+    """What the per-layer readers get of a window, either driver's."""
+    out = {"steps": n, "rows_per_step": rows, "window_s": elapsed,
+           "compiles_in_window": compiled}
+    if steps["median_s"] is not None:
+        out["step_wall_median_ms"] = 1e3 * steps["median_s"]
+    return out
+
+
 def window(net, pw, stream, log, seconds: float):
     """Fit until the deadline; the window closes when the last step's
     parameters are on the device. Returns (steps, elapsed seconds)."""
@@ -157,10 +185,12 @@ def run(ctx) -> dict:
 
     batches = make_batches(cfg, traffic, rows, ctx.seed)
     setup.mark(f"{len(batches)} host batches of {rows} rows built")
-    params0 = ref_mod.init_params(cfg, ctx.seed)
-    state0 = ref_mod.init_state(cfg, ctx.seed)
-    jax.block_until_ready(params0)
-    setup.mark("seeded weights on the device")
+    with setup.reference("weights"):    # the yardstick's initialisers and their compiles
+        params0 = ref_mod.init_params(cfg, ctx.seed)
+        state0 = ref_mod.init_state(cfg, ctx.seed)
+        jax.block_until_ready(params0)
+    setup.mark(f"seeded weights on the device "
+               f"({setup.reference_s:.1f}s, the reference's: not in setup_s)")
 
     with setup.reference():
         place = ctx.place_rows
@@ -189,10 +219,12 @@ def run(ctx) -> dict:
     with ctx.capture:
         n, elapsed, t_open = window(net, pw, stream, log, seconds)
     compiled = ctx.compiles.count - compiles0
+    steps_seen = step_report(log.times[-n:] if n else [], t_open)
+    print(f"[bench] window steps {steps_seen}", flush=True)
+    win_losses = log.losses[-n:] if n else []
     if ctx.trace:   # what the host does in the idle time: a capture of its own
         with ctx.capture_host:
             window(net, pw, stream, log, traffic["attribution_seconds"])
-    win_losses = log.losses[-n:] if n else []
 
     rows_out = common.compare_training(got, want, ref_mod.LIMITS,
                                        ref_mod.COMPARISONS)
@@ -210,6 +242,5 @@ def run(ctx) -> dict:
         "attempted": n, "failed": 0 if finite else int(np.sum(~np.isfinite(win_losses))),
         "window_start": t_open,
         "values": {"train_throughput": n * rows / elapsed},
-        "counters": {"steps": n, "rows_per_step": rows, "window_s": elapsed,
-                     "compiles_in_window": compiled},
+        "counters": window_counters(n, rows, elapsed, compiled, steps_seen),
     }

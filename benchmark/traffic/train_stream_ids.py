@@ -11,9 +11,10 @@ chip (626 M float32 parameters with gradient and Adam moments are 10 GB):
 the seeded weights go to the HOST as soon as they are made and stay there
 while the reference and then the program take their checked steps, and a
 reference file may bring its own lean `train_steps` (same result as
-`common.train_steps`). A model with routed experts is also held to
-`expert_dropped_assignments == 0` over every fit of the run: the reference
-drops nothing.
+`common.train_steps`); both stand inside `setup.reference()`, the weights'
+initialisers with their compiles too: they are the yardstick's. A model
+with routed experts is also held to `expert_dropped_assignments == 0` over
+every fit of the run: the reference drops nothing.
 """
 from __future__ import annotations
 
@@ -128,47 +129,22 @@ class HostWatch:
                 "wall_s": round(self.wall_s, 3)}
 
 
-def step_report(times, t_open: float) -> dict:
-    """The window's steps by the host clock of their listeners: the median
-    interval, the three longest with their step numbers, and the program's
-    own phase account of the fit (calls, total and longest of each)."""
-    from deeplearning4j_tpu import telemetry
-
-    gaps = np.diff(np.concatenate([[t_open], times]))
-    longest = np.argsort(-gaps)[:3]
-    log = getattr(telemetry, "fit_log", None)
-    phases = log()[-1].get("phases", {}) if log and log() else {}
-    return {"steps": len(gaps), "median_s": round(float(np.median(gaps)), 4),
-            "longest_s": {int(i) + 1: round(float(gaps[i]), 4) for i in longest},
-            "phases": {k: (v["calls"], round(v["total_s"], 3), round(v["max_s"], 4))
-                       for k, v in phases.items()}}
-
-
-def require_model(cfg: dict) -> None:
-    """Stop at once, with no result line, when the program has no such zoo
-    class: the reference of a model it cannot build is a minute wasted."""
-    from deeplearning4j_tpu import zoo
-
-    if not hasattr(zoo, cfg["program"]["zoo"]):
-        raise SystemExit(f"benchmark: this program's zoo has no "
-                         f"{cfg['program']['zoo']!r}")
-
-
 def run(ctx) -> dict:
     import jax
 
     cell, cfg, traffic, setup = ctx.cell, ctx.cfg, ctx.traffic, ctx.setup
     if cell["chips"] != 1:
         raise SystemExit("train_stream_ids: one chip (the reference runs unsharded)")
-    require_model(cfg)
     ref_mod = harness.module("reference", cfg["reference"])
     rows, steps = traffic["per_chip_batch"], traffic["check_steps"]
 
     batches = make_batches(cfg, traffic, rows, ctx.seed)
     setup.mark(f"{len(batches)} host batches of {rows} rows built")
-    params0 = jax.device_get(ref_mod.init_params(cfg, ctx.seed))
-    state0 = ref_mod.init_state(cfg, ctx.seed)
-    setup.mark("seeded weights made on the device, kept on the host")
+    with setup.reference("weights"):    # the yardstick's initialisers and their compiles
+        params0 = jax.device_get(ref_mod.init_params(cfg, ctx.seed))
+        state0 = ref_mod.init_state(cfg, ctx.seed)
+    setup.mark(f"seeded weights made on the device, kept on the host "
+               f"({setup.reference_s:.1f}s, the reference's: not in setup_s)")
 
     with setup.reference():
         want = reference_numbers(ref_mod, cfg, params0, state0, batches, steps)
@@ -191,14 +167,14 @@ def run(ctx) -> dict:
         n, elapsed, t_open = ts.window(net, pw, stream, log, seconds)
     compiled = ctx.compiles.count - compiles0
     print(f"[bench] window host {watch.report()}", flush=True)
-    print(f"[bench] window steps {step_report(log.times[-n:] if n else [], t_open)}",
-          flush=True)
+    steps_seen = ts.step_report(log.times[-n:] if n else [], t_open)
+    print(f"[bench] window steps {steps_seen}", flush=True)
+    win_losses = log.losses[-n:] if n else []
     fits = 3                    # two fits of checked steps, the window
     if ctx.trace:
         with ctx.capture_host:
             ts.window(net, pw, stream, log, traffic["attribution_seconds"])
         fits += 1
-    win_losses = log.losses[-n:] if n else []
 
     rows_out = common.compare_training(got, want, ref_mod.LIMITS, ref_mod.COMPARISONS)
     finite = bool(np.all(np.isfinite(log.losses)))
@@ -222,6 +198,5 @@ def run(ctx) -> dict:
         "attempted": n, "failed": 0 if finite else int(np.sum(~np.isfinite(win_losses))),
         "window_start": t_open,
         "values": {"train_throughput": n * rows / elapsed},
-        "counters": {"steps": n, "rows_per_step": rows, "window_s": elapsed,
-                     "compiles_in_window": compiled},
+        "counters": ts.window_counters(n, rows, elapsed, compiled, steps_seen),
     }
