@@ -146,6 +146,15 @@ class Layer:
 
         return jax.tree_util.tree_map(lambda _: P(), params)
 
+    def partition_specs(self, params: PyTree, axis_sizes, model_axis: str = "model") -> PyTree:
+        """PartitionSpec pytree for this layer's params over a mesh whose axes
+        have `axis_sizes` — what `parallel/mesh.py` places them by. Default:
+        the tensor-parallel rule above on `model_axis`. A layer whose
+        parameters live split over another axis says so here
+        (`RoutedExperts`: its experts over its `exchange_axis`); a block hands
+        the question down to the layers it wraps."""
+        return self.tensor_partition_specs(params, model_axis, axis_sizes.get(model_axis, 1))
+
     # mask propagation: default passthrough (DL4J Layer.feedForwardMaskArray)
     def propagate_mask(
         self, mask: Optional[jnp.ndarray], input_type: it.InputType

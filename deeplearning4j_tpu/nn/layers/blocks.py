@@ -52,6 +52,16 @@ def _handed_down(block: Layer, layer: Layer) -> Layer:
     return dataclasses.replace(layer, weight_init=block.weight_init)
 
 
+def _specs_of(block: Layer, params, axis_sizes, model_axis, **wrapped):
+    """A block's `partition_specs`: each wrapped layer's own for its subtree
+    (an expert layer's matrices over its exchange axis), the block's norms
+    whole on every device."""
+    specs = Layer.partition_specs(block, params, axis_sizes, model_axis)
+    for name, layer in wrapped.items():
+        specs[name] = layer.partition_specs(params[name], axis_sizes, model_axis)
+    return specs
+
+
 @register_layer
 @dataclass
 class SubLayerBlock(Layer):
@@ -87,6 +97,9 @@ class SubLayerBlock(Layer):
 
     def regularizable(self, params):
         return {"sub/" + k: v for k, v in self.sub.regularizable(params["sub"]).items()}
+
+    def partition_specs(self, params, axis_sizes, model_axis="model"):
+        return _specs_of(self, params, axis_sizes, model_axis, sub=self.sub)
 
     def apply(self, params, x, *, state, train, rng, mask=None):
         with device_scope("norm"):
@@ -138,6 +151,9 @@ class HybridBlock(Layer):
         out = {"mixer/" + k: v for k, v in self.mixer.regularizable(params["mixer"]).items()}
         out.update({"moe/" + k: v for k, v in self.moe.regularizable(params["moe"]).items()})
         return out
+
+    def partition_specs(self, params, axis_sizes, model_axis="model"):
+        return _specs_of(self, params, axis_sizes, model_axis, mixer=self.mixer, moe=self.moe)
 
     def apply(self, params, x, *, state, train, rng, mask=None):
         with device_scope("norm"):

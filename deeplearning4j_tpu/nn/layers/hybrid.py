@@ -70,7 +70,11 @@ state are float32.
                  sorted buffer of static capacity and XLA's grouped
                  product (`ops.linear.grouped_dot`, which pads widths);
                  SwiGLU or relu^2 experts; a shared expert, gated or not,
-                 or none.
+                 or none. With an `exchange_axis`: ALL experts, split over
+                 the mesh axis's ranks, tokens exchanged with the ranks that
+                 hold their experts (`lax.all_to_all` out and back inside a
+                 `shard_map` island; pair buffers of `capacity_factor` x the
+                 load).
                  Its device work is a function of shapes alone; overflow is
                  counted and left out. Counters live in the layer's state
                  (`counters`) and reach `telemetry.fit_log()` once a fit.
@@ -1256,6 +1260,77 @@ def _from_buffer_bwd(res, g):
 _from_buffer.defvjp(_from_buffer_fwd, _from_buffer_bwd)
 
 
+# The exchange's buffers. A rank's assignments, sorted by expert, fall into one
+# run a destination rank; the pair buffer [ranks, pair_rows, f] holds the first
+# `pair_rows` of each run. `src` [ranks * pair_rows] is the assignment whose
+# token a buffer row carries (any token where the run is shorter: such a row
+# weighs 0 on its way back), `row` [k * n] the buffer row of an assignment
+# (clipped where the run was cut: `kept` says which). Both ways across are
+# gathers, forward and backward, as across the one-rank buffer above.
+@jax.custom_vjp
+def _rows_out(xf, src, row, kept):
+    """xf [n, f] -> the pair buffers' rows [ranks * pair_rows, f]."""
+    return xf[src % xf.shape[0]]
+
+
+def _rows_out_fwd(xf, src, row, kept):
+    return _rows_out(xf, src, row, kept), (row, kept, xf.shape[0])
+
+
+def _rows_out_bwd(res, g):
+    row, kept, n = res
+    mine = g[row].astype(F32) * kept[:, None]   # an assignment cut off: no gradient
+    return mine.reshape(-1, n, g.shape[-1]).sum(axis=0).astype(g.dtype), None, None, None
+
+
+_rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
+
+
+@jax.custom_vjp
+def _rows_back(ret, wt, src, row, valid):
+    """The pair buffers as they came back, ret [ranks * pair_rows, f], to
+    tokens: out[n] = sum over the token's k slots of wt[slot, n] ret[row of
+    (slot, n)], float32. `wt` [k, n] is zero for an assignment that was cut."""
+    k, n = wt.shape
+    return jnp.sum(ret[row].reshape(k, n, -1).astype(F32) * wt[..., None], axis=0)
+
+
+def _rows_back_fwd(ret, wt, src, row, valid):
+    return _rows_back(ret, wt, src, row, valid), (ret, wt, src, row, valid)
+
+
+def _rows_back_bwd(res, g):
+    ret, wt, src, row, valid = res
+    n = wt.shape[1]
+    # as `_from_buffer_bwd`: the cotangent crosses in the buffer's dtype and a
+    # slot's weight gradient <g[token], ret[row]> is taken in buffer order
+    rows = g.astype(ret.dtype)[src % n].astype(F32)
+    d_ret = (rows * jnp.where(valid, wt.reshape(-1)[src], 0.0)[:, None]).astype(ret.dtype)
+    dots = jnp.sum(rows * ret.astype(F32), axis=-1)
+    return d_ret, dots[row].reshape(wt.shape), None, None, None
+
+
+_rows_back.defvjp(_rows_back_fwd, _rows_back_bwd)
+
+
+@jax.custom_vjp
+def _permuted(x, order, inv):
+    """x[order] for a permutation `order` with inverse `inv`: a gather both
+    ways (autodiff would scatter-add the cotangent)."""
+    return x[order]
+
+
+def _permuted_fwd(x, order, inv):
+    return x[order], (inv,)
+
+
+def _permuted_bwd(res, g):
+    return g[res[0]], None, None
+
+
+_permuted.defvjp(_permuted_fwd, _permuted_bwd)
+
+
 #: The most bytes of `h` — the first grouped product's output
 #: [capacity, wide x grouped_width(expert_width)], what the activation reads —
 #: that `RoutedExperts` tags `REMAT_KEEP`, compared with the traced array's own
@@ -1271,6 +1346,13 @@ _from_buffer.defvjp(_from_buffer_fwd, _from_buffer_bwd)
 #: for the stack belongs to whoever owns the remat policy (ROADMAP S10 (e)).
 H_KEEP_BYTES = 320 * 2 ** 20
 
+#: bins a unit of `pair_fill_hist`: an exchanging layer books each step's
+#: fullest pair (assignments one rank had for another over the pair buffer's
+#: rows, demanded, so it can pass 1) into one of 2 x FILL_BINS bins over
+#: [0, 2) — a histogram adds up over steps where a maximum does not, so a
+#: fit's fullest pair is its highest bin's upper edge, 1 / FILL_BINS fine
+FILL_BINS = 128
+
 
 @register_layer
 @dataclass
@@ -1280,8 +1362,16 @@ class RoutedExperts(Layer):
     (`shared_width` 0: none, no `shared_*` leaf, nothing added). The
     router scores all `n_experts`, keeps the `top_k` largest and
     renormalises them over the chosen wherever they live; this layer adds
-    the terms of its own experts and leaves the others' out (on one chip it
-    runs without the exchange that would bring other ranks' tokens).
+    the terms of its own experts and leaves the others' out: a rank alone
+    runs without the exchange that would bring other ranks' tokens. With an
+    `exchange_axis` the layer holds ALL experts, split over that mesh axis's
+    ranks, and exchanges: where the ambient mesh has the axis larger than 1
+    every rank routes its own tokens, sends each to the ranks that hold its
+    experts and takes the results back (`exchanged`: a `shard_map` island
+    with two `lax.all_to_all`s; its pair buffers hold `capacity_factor` x the
+    load one rank expects for another, `pair_rows`); anywhere else the layer
+    lowers to the program it always was. `partition_specs` tells the wrapper
+    which leaves live split (docs/HYBRID_LAYERS.md, the exchange).
 
     Two recipes share everything below the scores. `scoring` "softmax":
     the weights are the top-k of the softmax, renormalised over the chosen
@@ -1323,7 +1413,9 @@ class RoutedExperts(Layer):
     State `counters` (int32, wrapping; per-fit differences are exact):
     `steps`, `load` [count] assignments routed to each held expert,
     `dropped`, `capacity` (buffer rows offered), `ratio_sum` (float32 sum
-    over steps of max-over-mean load). `telemetry.fit_log()` reports them
+    over steps of max-over-mean load); with an `exchange_axis` also
+    `rank_ratio_sum` (the same over the ranks' loads) and `pair_fill_hist`
+    (`FILL_BINS`), all summed over the ranks. `telemetry.fit_log()` reports them
     per fit under `experts` (`counter_summary`), with `h_kept_mb`: the MB of
     `h` a step that carry the tag as the training step was last traced, 0.0
     beyond the bound — "tagged", since the layer cannot see whether a 'full'
@@ -1341,6 +1433,7 @@ class RoutedExperts(Layer):
     expert_act: str = "swiglu"
     shared_gated: bool = True
     norm_eps: float = 1e-20
+    exchange_axis: Optional[str] = None
 
     def held(self):
         return tuple(self.experts_held) if self.experts_held else (0, self.n_experts)
@@ -1358,12 +1451,48 @@ class RoutedExperts(Layer):
         c = -(-int(self.capacity_factor * expected) // 128) * 128
         return max(1, min(c, rows * self.top_k))
 
+    def pair_rows(self, rows: int, ranks: int) -> int:
+        """Rows of the buffer one rank sends another for its `rows` tokens: the
+        factor times the expected count (rows x top_k / ranks), to a multiple
+        of 128, at most every assignment of the sender."""
+        c = -(-int(self.capacity_factor * rows * self.top_k / ranks) // 128) * 128
+        return max(1, min(c, rows * self.top_k))
+
+    def exchange_ranks(self) -> int:
+        """How many ranks share this layer's experts where it is traced: the
+        size of the ambient mesh's `exchange_axis` (`ParallelWrapper` calls
+        its step under `jax.set_mesh`), 1 without the axis, without a mesh or
+        inside a `shard_map` that is already manual over it."""
+        if self.exchange_axis is None:
+            return 1
+        am = jax.sharding.get_abstract_mesh()
+        if am.empty or self.exchange_axis not in am.axis_names \
+                or self.exchange_axis in am.manual_axes:
+            return 1
+        return int(am.shape[self.exchange_axis])
+
+    def partition_specs(self, params, axis_sizes, model_axis: str = "model"):
+        """The expert matrices split on their expert dimension over
+        `exchange_axis` where the mesh has it larger than 1 (a rank holds
+        n_experts / ranks whole experts, their Adam moments with them);
+        everything else — router, shared expert, selection bias — whole on
+        every rank."""
+        from jax.sharding import PartitionSpec as P
+
+        ranks = axis_sizes.get(self.exchange_axis, 1) if self.exchange_axis else 1
+        split = {self._act()[2], "Wd"} if ranks > 1 else set()
+        return {k: P(self.exchange_axis) if k in split else P() for k in params}
+
     def output_type(self, input_type):
         return input_type
 
     def init_params(self, rng, input_type):
         f = input_type.size
         first, count = self.held()
+        if self.exchange_axis is not None and count != self.n_experts:
+            raise ValueError(
+                f"exchange_axis={self.exchange_axis!r} spreads ALL {self.n_experts} experts "
+                f"over the axis; experts_held={self.experts_held} holds a share of them")
         if first < 0 or first + count > self.n_experts:
             raise ValueError(f"experts_held={self.experts_held} outside 0..{self.n_experts}")
         if self.scoring not in ("softmax", "sigmoid"):
@@ -1386,9 +1515,13 @@ class RoutedExperts(Layer):
     def init_state(self, input_type):
         _, count = self.held()
         zero = lambda: jnp.zeros((), jnp.int32)  # noqa: E731 — a buffer each: state is donated
-        return {"counters": {"steps": zero(), "load": jnp.zeros((count,), jnp.int32),
-                             "dropped": zero(), "capacity": zero(),
-                             "ratio_sum": jnp.zeros((), F32)}}
+        counters = {"steps": zero(), "load": jnp.zeros((count,), jnp.int32),
+                    "dropped": zero(), "capacity": zero(),
+                    "ratio_sum": jnp.zeros((), F32)}
+        if self.exchange_axis is not None:
+            counters.update(rank_ratio_sum=jnp.zeros((), F32),
+                            pair_fill_hist=jnp.zeros((2 * FILL_BINS,), jnp.int32))
+        return {"counters": counters}
 
     def regularizable(self, params):
         return {k: v for k, v in params.items() if "W" in k}
@@ -1397,7 +1530,7 @@ class RoutedExperts(Layer):
         """Per-step means of the counters over a fit, under `experts`."""
         steps = int(added["steps"][0])
         routed, dropped = int(added["load"].sum()), int(added["dropped"][0])
-        return "experts", {
+        entry = {
             "steps": steps,
             "assignments_per_step": routed / max(steps, 1),
             "load_max_over_mean": float(added["ratio_sum"][0]) / max(steps, 1),
@@ -1405,6 +1538,16 @@ class RoutedExperts(Layer):
             "capacity_fill": (routed - dropped) / max(int(added["capacity"][0]), 1),
             "h_kept_mb": getattr(self, "_h_kept_mb", 0.0),
         }
+        if "pair_fill_hist" in added:
+            # an exchanging layer: `load`, `dropped` and `capacity` are sums over
+            # the ranks; the fullest pair of the fit from the steps' histogram
+            filled = np.flatnonzero(added["pair_fill_hist"])
+            entry.update(
+                capacity=int(added["capacity"][0]) // max(steps, 1),
+                pair_fill_max=(int(filled[-1]) + 1) / FILL_BINS if filled.size else 0.0,
+                rank_load_max_over_mean=float(added["rank_ratio_sum"][0]) / max(steps, 1),
+                exchange_bytes=getattr(self, "_exchange_bytes", 0))
+        return "experts", entry
 
     def route(self, params, xf):
         """(weights [n, top_k] float32, expert ids [n, top_k]). The logits are
@@ -1480,12 +1623,120 @@ class RoutedExperts(Layer):
             dropped = jnp.maximum(starts[-1] - cap, 0).astype(jnp.int32)
         return out, load, dropped
 
+    def exchanged(self, params, xf, ranks: int):
+        """The routed terms of ALL experts for tokens xf [n, f] whose rows, like
+        the experts, are split over the `ranks` of `exchange_axis`: a
+        `shard_map` island in which every rank routes its own tokens, buckets
+        their rows by destination rank into a [ranks, pair_rows, f] buffer
+        (`pair_rows`; a run beyond it is cut and counted), sends each rank its
+        part (`all_to_all`), sorts what it received by its own experts, runs
+        the two grouped products, sends the rows back the way they came and
+        adds them up weighted, in token order. The transpose of the island is
+        the same exchange run backwards: an expert's gradient is complete on
+        the rank that holds it, the router's is summed over the ranks.
+        -> ([n, f] float32, assignments an expert [n_experts] and dropped
+        assignments summed over the ranks, the fullest pair's demand in rows,
+        a pair buffer's rows)."""
+        from jax.sharding import PartitionSpec as P
+
+        axis, k = self.exchange_axis, self.top_k
+        if self.n_experts % ranks or xf.shape[0] % ranks:
+            raise ValueError(f"{self.n_experts} experts and {xf.shape[0]} tokens over "
+                             f"{ranks} ranks of {axis!r}: both must divide")
+        held = self.n_experts // ranks
+        n, f = xf.shape[0] // ranks, xf.shape[1]
+        pair = self.pair_rows(n, ranks)
+        rows = ranks * pair
+        act, wide, up = self._act()
+        padded = ops.grouped_width(f)
+
+        def island(p, xf):
+            with device_scope("route"):
+                top, idx = self.route(p, xf)
+            with device_scope("sort"):
+                key = idx.T.reshape(-1)             # assignment = slot * n + token -> its expert
+                order = jnp.argsort(key, stable=True)
+                inv = jnp.argsort(order)
+                starts = jnp.searchsorted(key[order], jnp.arange(self.n_experts + 1),
+                                          side="left")
+                order, inv, starts = _keep((order, inv, starts))
+            with device_scope("bucket"):
+                # the sorted assignments are one run a destination rank
+                first = starts[:-1:held]                                  # [ranks]
+                demand = starts[held::held] - first
+                at = jnp.arange(pair)
+                valid = at[None, :] < demand[:, None]                     # [ranks, pair]
+                src = order[jnp.minimum(first[:, None] + at[None, :], k * n - 1)]
+                local = jnp.where(valid, key[src] % held, held)           # held: no row here
+                to = key // held
+                off = inv - first[to]
+                kept = off < pair
+                row = to * pair + jnp.minimum(off, pair - 1)
+                src, valid = src.reshape(-1), valid.reshape(-1)
+                xc = ops._mixed_cast(xf, xf)[0]
+                if padded != f:     # born at the grouped product's width, as `routed`'s
+                    xc = lax.optimization_barrier(jnp.pad(xc, ((0, 0), (0, padded - f))))
+                send = _rows_out(xc, src, row, kept)
+            with device_scope("exchange"), device_scope("out"):
+                got = lax.all_to_all(send.reshape(ranks, pair, padded), axis, 0, 0)
+                got_local = lax.all_to_all(local, axis, 0, 0)
+            with device_scope("sort"):
+                key2 = got_local.reshape(-1)        # by my expert; the rows no one sent last
+                order2 = jnp.argsort(key2, stable=True)
+                inv2 = jnp.argsort(order2)
+                starts2 = jnp.searchsorted(key2[order2], jnp.arange(held + 1), side="left")
+                sizes = starts2[1:] - starts2[:-1]
+                sizes = sizes.at[-1].add(rows - starts2[-1])    # the padding is computed
+                order2, inv2, sizes = _keep((order2, inv2, sizes))
+            with device_scope("gather"):
+                xs = _permuted(got.reshape(rows, padded), order2, inv2)
+            with device_scope("product"):
+                h = ops.grouped_dot(xs, p[up], sizes, wide)
+                h_bytes = h.size * h.dtype.itemsize
+                tagged = h_bytes <= H_KEEP_BYTES
+                if tagged:
+                    h = _keep(h)
+                self._h_tagged_mb = tagged * h_bytes / 1e6
+                ys = ops.grouped_dot(act(h), p["Wd"], sizes)
+            with device_scope("gather"):
+                back = _permuted(ys, inv2, order2)
+            with device_scope("exchange"), device_scope("back"):
+                ret = lax.all_to_all(back.reshape(ranks, pair, -1), axis, 0, 0)
+            with device_scope("combine"):
+                wt = jnp.where(kept, top.T.reshape(-1), 0.0).reshape(k, n)
+                out = _rows_back(ret.reshape(rows, -1), wt, src, row, valid)[:, :f]
+            with device_scope("counters"):
+                load = lax.psum((starts[1:] - starts[:-1]).astype(jnp.int32), axis)
+                dropped = lax.psum(jnp.sum(jnp.maximum(demand - pair, 0)).astype(jnp.int32),
+                                   axis)
+                fullest = lax.pmax(jnp.max(demand).astype(jnp.int32), axis)
+            # what one chip sends in a step's forward and backward: the rows both
+            # ways and their cotangents (a block's recompute sends again)
+            self._exchange_traced = (ranks - 1) * pair * (
+                4 * padded * send.dtype.itemsize + local.dtype.itemsize)
+            return out, load, dropped, fullest
+
+        # manual over every axis still automatic here, as `kernel_call.per_batch_shard`
+        am = jax.sharding.get_abstract_mesh()
+        out, load, dropped, fullest = jax.shard_map(
+            island, in_specs=(self.partition_specs(params, {axis: ranks}), P(axis)),
+            out_specs=(P(axis), P(), P(), P()),
+            axis_names=set(am.axis_names) - set(am.manual_axes), check_vma=False)(params, xf)
+        return out, load, dropped, fullest, pair
+
     def apply(self, params, x, *, state, train, rng, mask=None):
         shape = x.shape
         xf = x.reshape(-1, shape[-1])
-        with device_scope("route"):
-            top, idx = self.route(params, xf)
-        out, load, dropped = self.routed(params, xf, top, idx)
+        ranks = self.exchange_ranks()
+        if ranks > 1:
+            out, load, dropped, fullest, pair = self.exchanged(params, xf, ranks)
+            offered = ranks * ranks * pair
+        else:
+            with device_scope("route"):
+                top, idx = self.route(params, xf)
+            out, load, dropped = self.routed(params, xf, top, idx)
+            offered = self.capacity(xf.shape[0])
+            fullest, pair = xf.shape[0] * self.top_k, offered      # one pair: every assignment
         if self.shared_width:
             act, _, up = self._act()
             with device_scope("shared"):
@@ -1503,9 +1754,18 @@ class RoutedExperts(Layer):
             with device_scope("counters"):
                 c = state["counters"]
                 mean = jnp.maximum(jnp.mean(load.astype(F32)), 1e-9)
-                state = {"counters": {
+                counters = {
                     "steps": c["steps"] + 1, "load": c["load"] + load,
                     "dropped": c["dropped"] + dropped,
-                    "capacity": c["capacity"] + self.capacity(xf.shape[0]),
-                    "ratio_sum": c["ratio_sum"] + jnp.max(load.astype(F32)) / mean}}
+                    "capacity": c["capacity"] + offered,
+                    "ratio_sum": c["ratio_sum"] + jnp.max(load.astype(F32)) / mean}
+                if self.exchange_axis is not None:
+                    self._exchange_bytes = getattr(self, "_exchange_traced", 0) if ranks > 1 else 0
+                    by_rank = load.reshape(ranks, -1).sum(axis=1).astype(F32)
+                    fill_bin = jnp.minimum(fullest * FILL_BINS // pair, 2 * FILL_BINS - 1)
+                    counters.update(
+                        rank_ratio_sum=c["rank_ratio_sum"] + jnp.max(by_rank) / (
+                            jnp.maximum(jnp.mean(by_rank), 1e-9)),
+                        pair_fill_hist=c["pair_fill_hist"].at[fill_bin].add(1))
+                state = {"counters": counters}
         return y, state

@@ -165,9 +165,29 @@ def fsdp_param_shardings(mesh: Mesh, specs):
     }
 
 
+def specs_beyond(shardings, model_axis: str = "model"):
+    """The PartitionSpec trees of `shardings` ({key: NamedSharding tree}) when
+    some leaf is split over another axis than `model_axis` — a layer's own
+    declaration (`Layer.partition_specs`) that the step has to pin as it pins
+    the fsdp axis —, else None."""
+    def names(spec):
+        return {a for e in spec if e is not None
+                for a in (e if isinstance(e, (tuple, list)) else (e,))}
+
+    leaves = jax.tree_util.tree_leaves(shardings)
+    if not any(names(sh.spec) - {model_axis} for sh in leaves):
+        return None
+    return {k: jax.tree_util.tree_map(lambda sh: sh.spec, tree)
+            for k, tree in shardings.items()}
+
+
 class FsdpArrangement:
     """Attached to a model (as `model._fsdp_layout`) by ParallelWrapper
-    when the mesh's fsdp axis is >1. The model's functional core consults
+    when the mesh's fsdp axis is >1, or when a layer declared leaves split
+    over another axis than the tensor-parallel one (`specs_beyond`: expert
+    matrices over their exchange axis; `gather` then changes nothing and
+    `shard_tree` keeps gradients, parameters and moments split as they
+    rest). The model's functional core consults
     it at trace time: `gather` constrains one layer/vertex subtree to its
     fsdp-free spec right before use (the per-layer all-gather XLA overlaps
     with that layer's compute), `shard_tree` constrains a params/grads

@@ -112,15 +112,15 @@ def param_partition_spec(path: str, shape: Tuple[int, ...],
 
 def model_param_shardings(mesh: Mesh, model, model_axis: str = "model"):
     """NamedSharding tree for a MultiLayerNetwork / ComputationGraph's
-    params built from LAYER-DECLARED tensor-parallel rules
-    (Layer.tensor_partition_specs) — the any-model contract of
+    params built from LAYER-DECLARED rules (Layer.partition_specs: the
+    tensor-parallel Layer.tensor_partition_specs, and what a layer keeps
+    split over another axis — an expert layer's matrices over its exchange
+    axis) — the any-model contract of
     ParallelWrapper.java:59-73 extended to the model axis: Dense layers
     column-split, MultiHeadAttention head-splits + row-parallel output,
     TransformerBlock FFN Megatron-splits, everything else replicates.
     Models without a layer structure fall back to the generic last-axis
     rule (shard_params_tree)."""
-    msize = mesh.shape.get(model_axis, 1)
-
     def spec_to_sharding(tree):
         return jax.tree_util.tree_map(
             lambda s: NamedSharding(mesh, s), tree,
@@ -130,8 +130,8 @@ def model_param_shardings(mesh: Mesh, model, model_axis: str = "model"):
         out = {}
         for i, layer in enumerate(model.layers):
             k = f"layer_{i}"
-            out[k] = spec_to_sharding(layer.tensor_partition_specs(
-                model.params[k], model_axis, msize))
+            out[k] = spec_to_sharding(layer.partition_specs(
+                model.params[k], dict(mesh.shape), model_axis))
         return out
     if hasattr(model, "topo") and hasattr(model.conf, "vertices"):
         from deeplearning4j_tpu.nn.graph_vertices import LayerVertex
@@ -140,8 +140,8 @@ def model_param_shardings(mesh: Mesh, model, model_axis: str = "model"):
         for name in model.topo:
             v = model.conf.vertices[name]
             if isinstance(v, LayerVertex):
-                out[name] = spec_to_sharding(v.layer.tensor_partition_specs(
-                    model.params[name], model_axis, msize))
+                out[name] = spec_to_sharding(v.layer.partition_specs(
+                    model.params[name], dict(mesh.shape), model_axis))
             else:
                 out[name] = jax.tree_util.tree_map(
                     lambda _: NamedSharding(mesh, P()), model.params[name])

@@ -47,12 +47,27 @@ TPU-native redesign: one process, one jitted SPMD program over a Mesh.
     match to f32 roundoff; stochastic nets (dropout/weight noise) draw
     per-(data-shard, microbatch) keys instead of the single-device
     per-layer split — independent masks, not identical ones.
+  * experts over the data axis (net-new) — a layer may keep leaves split
+    over ANOTHER axis than the tensor-parallel one and say so itself
+    (Layer.partition_specs): `RoutedExperts(exchange_axis="data")` holds
+    all its experts spread over the data axis's ranks, [n_experts, ..] as
+    ranks x [n_experts / ranks, ..], Adam's moments with them. The
+    expert-parallel group IS the data-parallel group (as in GShard): each
+    rank routes its own rows, and the layer exchanges tokens with the ranks
+    that hold their experts in a `shard_map` island of its own inside the
+    GSPMD step (`lax.all_to_all` out and back; nn/layers/hybrid.py). The
+    step pins gradients and updated leaves to the declared specs
+    (layout.specs_beyond + FsdpArrangement.shard_tree): an expert's
+    gradient is complete on its rank and is not reduced over the axis,
+    every other gradient rides the all-reduce as before; `model.params`
+    stay global arrays, read whole by `device_get` / checkpoints.
 Composition: data×model, data×seq, model×seq, and data×pipe are all
-supported here; pipe×seq, pipe×model, and expert parallelism for MoE nets
-still need the explicit-collective formulation in parallel/transformer.py
-(ShardedTransformerLM — lax.ppermute inside the stage switch does not
-compose with a GSPMD-managed model axis: shards reach different
-collective-permute ids and deadlock, so those meshes are refused loudly).
+supported here; pipe×seq and pipe×model still need the explicit-collective
+formulation in parallel/transformer.py (ShardedTransformerLM —
+lax.ppermute inside the stage switch does not compose with a GSPMD-managed
+model axis: shards reach different collective-permute ids and deadlock, so
+those meshes are refused loudly), as do experts over an axis of their own
+(`expert`) beside a data axis (ROADMAP R4).
 """
 # jaxlint: disable-file=JX018 — batch/carry staging specs (data-axis input
 # split, sp/pp plumbing); param placement routes through mesh.py/layout.py
@@ -211,6 +226,15 @@ class ParallelWrapper:
         else:
             self._param_shardings = mesh_mod.model_param_shardings(
                 mesh, model)
+            # a layer that keeps leaves split over another axis than the
+            # tensor-parallel one (an expert layer's matrices over its
+            # exchange axis): the step pins gradients and updated params to
+            # these specs, so the leaves and their moments stay split at rest
+            specs = layout_mod.specs_beyond(self._param_shardings, "model")
+            if specs is not None:
+                model._fsdp_layout = layout_mod.FsdpArrangement(mesh, specs)
+                model._train_step = None
+                model._train_step_raw = None
         repl = mesh_mod.replicated(mesh)
         model.params = jax.device_put(model.params, self._param_shardings)
         model.state = jax.device_put(model.state, repl)

@@ -739,6 +739,9 @@ SCOPE_PARTS = frozenset({
     "rope", "attend", "out",
     # routed experts
     "route", "sort", "gather", "product", "combine", "shared",
+    # their exchange between expert-parallel ranks: `exchange` holds `out`
+    # (tokens to the ranks that hold their experts) and `back`
+    "bucket", "exchange", "back",
     # the exit gate and distribution of a looped stack's loss
     "exit",
     # a row block's gradient, made in the forward visit of the block

@@ -829,7 +829,16 @@ class WindowedMoELM(_DecoderLM):
     layers `layers_first` .. (a pipeline stage's share); both lists are
     indexed by the published layer. The head counts are the counts this
     rank HOLDS (a tensor-parallel rank's share of each layer's query and
-    key/value heads, in the published ratio), as `num_experts` is."""
+    key/value heads, in the published ratio), as `num_experts` is.
+
+    Each of the three extras can be absent (the `mellum` shape): `head_gate`
+    off, `shared_expert_intermediate_size` 0, `mlp_only_layers` empty; one
+    `num_attention_heads` then serves every layer in place of the list, and
+    `moe_routed_scaling_factor` 1.0 scales nothing. `expert_exchange_axis`
+    names the mesh axis whose ranks share every expert layer: all
+    `num_experts` live split over it and the tokens go to their experts and
+    back through `RoutedExperts`' exchange; on a mesh without the axis (or
+    with one rank on it) the layers run as they do with every expert here."""
 
     num_hidden_layers: int = 4
     layers_first: int = 0
@@ -838,10 +847,12 @@ class WindowedMoELM(_DecoderLM):
     # default: a global layer of 4 heads, then three windowed ones of 6
     layer_types: Optional[Sequence[str]] = None
     num_attention_heads_per_layer: Optional[Sequence[int]] = None
+    num_attention_heads: Optional[int] = None
     num_key_value_heads: int = 2
     head_dim: int = 64
     sliding_window: int = 16
     rope_parameters: Optional[dict] = None
+    head_gate: bool = True
     # feed-forward
     mlp_only_layers: Sequence[int] = (0,)
     intermediate_size: int = 512
@@ -851,6 +862,7 @@ class WindowedMoELM(_DecoderLM):
     shared_expert_intermediate_size: int = 64
     moe_routed_scaling_factor: float = 2.5
     norm_topk_prob: bool = True
+    expert_exchange_axis: Optional[str] = None
 
     #: a published layer type -> does it attend through the window
     KINDS = {"full_attention": False, "sliding_attention": True}
@@ -861,7 +873,7 @@ class WindowedMoELM(_DecoderLM):
         types = self.layer_types or [
             "sliding_attention" if i % 4 else "full_attention" for i in range(held.stop)]
         heads = self.num_attention_heads_per_layer or [
-            6 if self.KINDS.get(kind) else 4 for kind in types]
+            self.num_attention_heads or (6 if self.KINDS.get(kind) else 4) for kind in types]
         bad = sorted(set(types) - set(self.KINDS))
         if bad or held.stop > min(len(types), len(heads)):
             raise ValueError(
@@ -881,7 +893,7 @@ class WindowedMoELM(_DecoderLM):
                 rope_theta=float(recipe.get("rope_theta", 10000.0)),
                 rope_scaling=(dict(recipe) if recipe.get("rope_type", "default") != "default"
                               else None),
-                eps=self.rms_norm_eps, gated=True, gate="head", qk_norm=False,
+                eps=self.rms_norm_eps, gated=self.head_gate, gate="head", qk_norm=False,
                 qk_norm_zero_centered=False,
                 window=self.sliding_window if self.KINDS[kind] else None)
 
@@ -893,7 +905,7 @@ class WindowedMoELM(_DecoderLM):
                 shared_width=self.shared_expert_intermediate_size,
                 norm_topk=self.norm_topk_prob, scoring="softmax",
                 routed_scale=self.moe_routed_scaling_factor, expert_act="swiglu",
-                shared_gated=False, norm_eps=1e-20)
+                shared_gated=False, norm_eps=1e-20, exchange_axis=self.expert_exchange_axis)
 
         return [sub for i, kind, n_heads in self.layers_built()
                 for sub in (attention(kind, n_heads), feed_forward(i))]
