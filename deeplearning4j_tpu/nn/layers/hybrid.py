@@ -1266,7 +1266,16 @@ _from_buffer.defvjp(_from_buffer_fwd, _from_buffer_bwd)
 # token a buffer row carries (any token where the run is shorter: such a row
 # weighs 0 on its way back), `row` [k * n] the buffer row of an assignment
 # (clipped where the run was cut: `kept` says which). Both ways across are
-# gathers, forward and backward, as across the one-rank buffer above.
+# gathers, forward and backward, as across the one-rank buffer above. The way
+# OUT (`_rows_out`, then the `all_to_all`, then the receiver's `_permuted`)
+# keeps no row: its backward reads index arrays alone. The way HOME
+# (`_rows_home`) is ONE rule from the experts' `ys` to the tokens' sum whose
+# residuals are `ys`, the weights and the index arrays — nothing that crossed:
+# the rows that came back are `ys` in another order on another rank, bit for
+# bit, so the one thing the backward wants of them, a slot's weight gradient
+# <g[token], row>, is taken on the expert side from `ys` and the cotangent rows
+# that arrive there anyway, and goes home as a scalar a row. A block's
+# recompute then has no use for the permutation back or the `all_to_all` back.
 @jax.custom_vjp
 def _rows_out(xf, src, row, kept):
     """xf [n, f] -> the pair buffers' rows [ranks * pair_rows, f]."""
@@ -1286,31 +1295,63 @@ def _rows_out_bwd(res, g):
 _rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
 
 
-@jax.custom_vjp
-def _rows_back(ret, wt, src, row, valid):
-    """The pair buffers as they came back, ret [ranks * pair_rows, f], to
-    tokens: out[n] = sum over the token's k slots of wt[slot, n] ret[row of
-    (slot, n)], float32. `wt` [k, n] is zero for an assignment that was cut."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _rows_home(ys, wt, src, row, valid, order2, inv2, axis, ranks):
+    """The experts' rows ys [ranks * pair_rows, f], in the expert side's sorted
+    order, home to their tokens: into pair-buffer order (`inv2`, the inverse
+    of `order2`), `all_to_all` over `axis` to the ranks they came from, and
+    there out[n] = sum over the token's k slots of wt[slot, n] ret[row of
+    (slot, n)], float32. `wt` [k, n] is zero for an assignment that was cut.
+    ONE rule from the expert side to the token side, so that nothing that
+    crossed is a residual (`_rows_home_bwd`)."""
     k, n = wt.shape
-    return jnp.sum(ret[row].reshape(k, n, -1).astype(F32) * wt[..., None], axis=0)
+    with device_scope("gather"):
+        back = ys[inv2]
+    with device_scope("exchange"), device_scope("back"):
+        ret = lax.all_to_all(back.reshape(ranks, -1, back.shape[-1]), axis, 0, 0)
+    with device_scope("combine"):
+        ret = ret.reshape(back.shape)
+        return jnp.sum(ret[row].reshape(k, n, -1).astype(F32) * wt[..., None], axis=0)
 
 
-def _rows_back_fwd(ret, wt, src, row, valid):
-    return _rows_back(ret, wt, src, row, valid), (ret, wt, src, row, valid)
+def _rows_home_fwd(ys, wt, src, row, valid, order2, inv2, axis, ranks):
+    return (_rows_home(ys, wt, src, row, valid, order2, inv2, axis, ranks),
+            (ys, wt, src, row, valid, order2, inv2))
 
 
-def _rows_back_bwd(res, g):
-    ret, wt, src, row, valid = res
+def _rows_home_bwd(axis, ranks, res, g):
+    ys, wt, src, row, valid, order2, inv2 = res
     n = wt.shape[1]
-    # as `_from_buffer_bwd`: the cotangent crosses in the buffer's dtype and a
-    # slot's weight gradient <g[token], ret[row]> is taken in buffer order
-    rows = g.astype(ret.dtype)[src % n].astype(F32)
-    d_ret = (rows * jnp.where(valid, wt.reshape(-1)[src], 0.0)[:, None]).astype(ret.dtype)
-    dots = jnp.sum(rows * ret.astype(F32), axis=-1)
-    return d_ret, dots[row].reshape(wt.shape), None, None, None
+    # as `_from_buffer_bwd`: the cotangent crosses in the buffer's dtype. It
+    # crosses UNweighted, each row's weight beside it, and is weighted where the
+    # experts are: there a slot's weight gradient <g[token], ys[position]> is
+    # taken from `ys` itself — what came back of it on the forward is `ys` in
+    # another order on another rank, bit for bit — and goes home a scalar a row
+    with device_scope("combine"):
+        # the barrier keeps the pad of the grouped product's columns (the caller
+        # cuts them from `out`) in front of the gather, as `exchanged` does on the
+        # way out: behind it the pad is a pass over the buffer
+        rows = lax.optimization_barrier(g.astype(ys.dtype))[src % n]
+        w = jnp.where(valid, wt.reshape(-1)[src], 0.0)
+    with device_scope("exchange"), device_scope("back"):
+        rows = lax.all_to_all(rows.reshape(ranks, -1, rows.shape[-1]), axis, 0, 0)
+        w = lax.all_to_all(w.reshape(ranks, -1), axis, 0, 0)
+    with device_scope("gather"):
+        rows = rows.reshape(ys.shape)[order2].astype(F32)
+        w = w.reshape(-1)[order2]
+    with device_scope("combine"):
+        d_ys = (rows * w[:, None]).astype(ys.dtype)
+        dots = jnp.sum(rows * ys.astype(F32), axis=-1)
+    with device_scope("gather"):
+        dots = dots[inv2]
+    with device_scope("exchange"), device_scope("back"):
+        dots = lax.all_to_all(dots.reshape(ranks, -1), axis, 0, 0)
+    with device_scope("combine"):
+        d_wt = dots.reshape(-1)[row].reshape(wt.shape)
+    return d_ys, d_wt, None, None, None, None, None
 
 
-_rows_back.defvjp(_rows_back_fwd, _rows_back_bwd)
+_rows_home.defvjp(_rows_home_fwd, _rows_home_bwd)
 
 
 @jax.custom_vjp
@@ -1407,8 +1448,16 @@ class RoutedExperts(Layer):
     recompute then neither sorts nor runs the router's product again (nor,
     sigmoid, selects) and drops `xs Wgu`; it still gathers the buffer, applies
     the activation and runs `act(h) Wd` (the router weights' gradient reads
-    its rows). The kept arrays have static shapes: nothing follows the
-    routing.
+    its rows). Where the layer EXCHANGES it also keeps `xs`, the rows that
+    arrived in the receiver's order (what `dWgu = xs^T dh` reads; two passes
+    over the buffer and a way over the interconnect to remake), with the
+    receiver's sort; and nothing that came BACK is a residual (`_rows_home`):
+    the recompute sends no row across the interconnect, neither out nor back —
+    it runs the products and the activation on the expert side and the index
+    arithmetic of `bucket` on the token side. The backward sends the
+    cotangents of both ways and, beside the rows coming back's, a weight and
+    a dot product a row. The kept arrays have static shapes: nothing follows
+    the routing.
 
     State `counters` (int32, wrapping; per-fit differences are exact):
     `steps`, `load` [count] assignments routed to each held expert,
@@ -1419,7 +1468,10 @@ class RoutedExperts(Layer):
     per fit under `experts` (`counter_summary`), with `h_kept_mb`: the MB of
     `h` a step that carry the tag as the training step was last traced, 0.0
     beyond the bound — "tagged", since the layer cannot see whether a 'full'
-    checkpoint wraps it, and only there does the tag hold bytes."""
+    checkpoint wraps it, and only there does the tag hold bytes — and, for a
+    layer with an `exchange_axis`, `exchange_bytes` (what one rank sends the
+    others in a step's forward and backward) and `exchange_kept_mb` (the MB of
+    arrived rows a rank that carry the tag; both 0 on one rank)."""
 
     n_experts: int = 512
     top_k: int = 10
@@ -1546,7 +1598,8 @@ class RoutedExperts(Layer):
                 capacity=int(added["capacity"][0]) // max(steps, 1),
                 pair_fill_max=(int(filled[-1]) + 1) / FILL_BINS if filled.size else 0.0,
                 rank_load_max_over_mean=float(added["rank_ratio_sum"][0]) / max(steps, 1),
-                exchange_bytes=getattr(self, "_exchange_bytes", 0))
+                exchange_bytes=getattr(self, "_exchange_bytes", 0),
+                exchange_kept_mb=getattr(self, "_exchange_kept_mb", 0.0))
         return "experts", entry
 
     def route(self, params, xf):
@@ -1689,7 +1742,9 @@ class RoutedExperts(Layer):
                 sizes = sizes.at[-1].add(rows - starts2[-1])    # the padding is computed
                 order2, inv2, sizes = _keep((order2, inv2, sizes))
             with device_scope("gather"):
-                xs = _permuted(got.reshape(rows, padded), order2, inv2)
+                # what arrived is kept: two passes over the buffer and its way
+                # over the interconnect to remake (`REMAT_KEEP`'s rule)
+                xs = _keep(_permuted(got.reshape(rows, padded), order2, inv2))
             with device_scope("product"):
                 h = ops.grouped_dot(xs, p[up], sizes, wide)
                 h_bytes = h.size * h.dtype.itemsize
@@ -1698,22 +1753,23 @@ class RoutedExperts(Layer):
                     h = _keep(h)
                 self._h_tagged_mb = tagged * h_bytes / 1e6
                 ys = ops.grouped_dot(act(h), p["Wd"], sizes)
-            with device_scope("gather"):
-                back = _permuted(ys, inv2, order2)
-            with device_scope("exchange"), device_scope("back"):
-                ret = lax.all_to_all(back.reshape(ranks, pair, -1), axis, 0, 0)
             with device_scope("combine"):
                 wt = jnp.where(kept, top.T.reshape(-1), 0.0).reshape(k, n)
-                out = _rows_back(ret.reshape(rows, -1), wt, src, row, valid)[:, :f]
+            # gather, exchange/back and combine, one rule forward and backward
+            out = _rows_home(ys, wt, src, row, valid, order2, inv2, axis, ranks)[:, :f]
             with device_scope("counters"):
                 load = lax.psum((starts[1:] - starts[:-1]).astype(jnp.int32), axis)
                 dropped = lax.psum(jnp.sum(jnp.maximum(demand - pair, 0)).astype(jnp.int32),
                                    axis)
                 fullest = lax.pmax(jnp.max(demand).astype(jnp.int32), axis)
             # what one chip sends in a step's forward and backward: the rows both
-            # ways and their cotangents (a block's recompute sends again)
+            # ways and their cotangents, each row's expert id out and, in the
+            # backward, its weight out and its weight gradient home (a block's
+            # recompute sends nothing); and the MB of arrived rows tagged
             self._exchange_traced = (ranks - 1) * pair * (
-                4 * padded * send.dtype.itemsize + local.dtype.itemsize)
+                4 * padded * send.dtype.itemsize + local.dtype.itemsize
+                + 2 * wt.dtype.itemsize)
+            self._exchange_kept_traced = xs.size * xs.dtype.itemsize / 1e6
             return out, load, dropped, fullest
 
         # manual over every axis still automatic here, as `kernel_call.per_batch_shard`
@@ -1761,6 +1817,7 @@ class RoutedExperts(Layer):
                     "ratio_sum": c["ratio_sum"] + jnp.max(load.astype(F32)) / mean}
                 if self.exchange_axis is not None:
                     self._exchange_bytes = getattr(self, "_exchange_traced", 0) if ranks > 1 else 0
+                    self._exchange_kept_mb = self._exchange_kept_traced if ranks > 1 else 0.0
                     by_rank = load.reshape(ranks, -1).sum(axis=1).astype(F32)
                     fill_bin = jnp.minimum(fullest * FILL_BINS // pair, 2 * FILL_BINS - 1)
                     counters.update(

@@ -36,7 +36,13 @@ import jax
 #: the sort's `order`, `inv` and group sizes (two argsorts to remake, under
 #: 2 MB), the router's logits and, under the sigmoid recipe, the chosen ids (a
 #: float32 HIGHEST product and a selection to remake; a few MB). The recompute still gathers the buffer, applies the
-#: activation and runs `act(h) Wd`: outputs as large as a block's input.
+#: activation and runs `act(h) Wd`: outputs as large as a block's input. A
+#: layer whose rows cross the interconnect to their experts
+#: (`RoutedExperts.exchanged`) also keeps what ARRIVED, `xs` in the receiver's
+#: order: as large as a block's input times `top_k`, but two passes over it and
+#: an `all_to_all` to remake (Mellum 2: 503 MB a layer and chip against 58 ms
+#: of a 723 ms step); what came BACK is no residual of anything
+#: (`hybrid._rows_home`), so a block's recompute sends no row either way.
 #: Outside a `jax.checkpoint` the tag lowers to nothing.
 REMAT_KEEP = "dl4j_remat_keep"
 
@@ -71,7 +77,9 @@ def remat_policy(name: Any):
     (`hybrid.LatentAttention`), so that its projections, rotations and
     concatenate are not, and a routed-expert layer's first grouped product
     within `hybrid.H_KEEP_BYTES`, its sort and its router's logits (sigmoid:
-    the chosen ids too) (`hybrid.RoutedExperts`)). Cached so the same name always returns
+    the chosen ids too) and, where it exchanges rows between expert-parallel
+    ranks, the rows that arrived (`hybrid.RoutedExperts`), so that the
+    recompute sends none again). Cached so the same name always returns
     the SAME callable: a fresh policy closure per call would defeat the jit
     trace cache."""
     n = canonical_policy(name)
